@@ -974,7 +974,7 @@ void partition_hypergraph_ml(const Hypergraph& h0, int k, double imbalance,
 // ---------------------------------------------------- recursive bisection
 // Direct k-way km1 refinement costs O(deg·k) per move, which at k >= 32 and
 // products scale made hp both slow (5 700 s) and ~3% WORSE than gp
-// (BASELINE.md round-5 k-sweep).  Recursive bisection — the PaToH/hMETIS
+// (round-5 k-sweep, bench_artifacts/products_ksweep.json).  Recursive bisection — the PaToH/hMETIS
 // production strategy — eliminates the k factor: log2(k) levels of 2-way
 // partitions, each with the full multilevel machinery at k=2.
 //
@@ -1057,7 +1057,7 @@ void partition_hypergraph_rb(const Hypergraph& h, int k, double imbalance,
 
 // Restart budget: whole-multilevel restarts are the "more V-cycles" quality
 // lever, but they scale linearly in the instance size, so the budget is
-// size-capped (the VERDICT-r3 scale path: one restart at products scale keeps
+// size-capped (the round-3 scale path: one restart at products scale keeps
 // the 2.45M-cell run inside a single-core time budget).  SGCN_RESTARTS
 // overrides for experiments.
 int restart_budget(i64 n) {
@@ -1272,7 +1272,7 @@ int sgcn_partition_graph(i32 n, const i64* xadj, const i32* adjncy,
   if (k == 1) part.assign(n, 0);
   else {
     // multilevel restarts, best final cut kept (same policy as the
-    // hypergraph side; closes the gp-vs-hp quality gap of VERDICT r3)
+    // hypergraph side; closes the round-3 gp-vs-hp quality gap)
     const int restarts = restart_budget(n);
     i64 best = -1;
     std::vector<i32> cand;

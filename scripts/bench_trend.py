@@ -27,10 +27,11 @@ Rules:
     lower-is-better band) get a MULTIPLICATIVE band (default ``--time-band
     2.0``: the newest point must be ≤ 2× the MEDIAN previous point).  The
     anchor is the median, not the historical best — one lucky fast outlier
-    must not permanently tighten the gate — and the band sits above this
-    host's measured cross-session drift (BASELINE.md: identical code
-    2.18 s vs 3.63 s across sessions = 1.665×), so only a regression on
-    top of normal drift trips it.  Deterministic counters
+    must not permanently tighten the gate — and the band sits above the
+    cross-session drift recorded on the rounds-3-5 development chip
+    (identical code 2.18 s vs 3.63 s across sessions = 1.665×; spread on
+    the current chip: not measured), so only a regression on top of
+    normal drift trips it.  Deterministic counters
     (``COUNTER_KEYS``: ``km1_8dev``, ``comm_volume_rows_8dev``) get a ZERO
     band: they are plan-derived, reproducible bit-for-bit, and may never
     increase within a series.
@@ -341,8 +342,8 @@ def check_series(series: dict, time_band: float = DEFAULT_TIME_BAND) -> list:
             #                 universal better-direction for them)
         if kind in ("time", "latency"):
             # median anchor: a single lucky fast point must not tighten
-            # the gate forever, and the band must clear this host's
-            # documented 1.665x cross-session drift (BASELINE.md).
+            # the gate forever, and the band must clear the 1.665x
+            # cross-session drift recorded in rounds 3-5.
             # "latency" is the serve-quantile flavor (ms, lower-is-better
             # like "s" — gated since ISSUE 18 once r01–r05 set the anchor)
             anchor = _median([v for _, v in prev])

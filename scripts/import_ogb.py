@@ -1,5 +1,5 @@
 """Convert on-disk OGB / Reddit / planetoid-snapshot datasets to the repo's
-``.npz`` layout (VERDICT r4 item 6).
+``.npz`` layout.
 
 Zero egress on this box means the real downloads cannot be fetched HERE, but
 the north-star configs (`BASELINE.json`: ogbn-products, ogbn-arxiv, Reddit,

@@ -35,7 +35,7 @@ def main() -> None:
     save_fixture(prefix, a, labels=labels, features=feats)
     pv, _km1 = partition_hypergraph_colnet(a, k=4, seed=1)
     write_partvec(prefix + ".4.hp", pv)
-    # cora's TRUE shape (VERDICT r3 item 3): 2708 papers x 1433-word binary
+    # cora's TRUE shape: 2708 papers x 1433-word binary
     # BoW x 7 classes, ~avg-deg-4 citations (real cora: 5429 edges), real
     # ~18-word documents — the dims of the reference's actual accuracy run
     # (GPU/PGCN-Accuracy.py, README.md:110)

@@ -184,10 +184,15 @@ def render(path: str, max_steps: int = 12) -> str:
                      + _stats([s["wall_s"] for s in steps]))
         roofs = [s["roofline"] for s in steps if s.get("roofline")]
         if roofs:
-            lines.append("  roofline:  gather "
-                         + _stats([r["achieved_gather_GBs"] for r in roofs])
-                         + " GB/s, stream-ceiling frac "
-                         + _stats([r["stream_ceiling_frac"] for r in roofs]))
+            line = ("  roofline:  gather "
+                    + _stats([r["achieved_gather_GBs"] for r in roofs])
+                    + " GB/s")
+            # emitted only on the device kind the ceiling was stated for
+            fracs = [r["stream_ceiling_frac"] for r in roofs
+                     if "stream_ceiling_frac" in r]
+            if fracs:
+                line += ", stream-ceiling frac " + _stats(fracs)
+            lines.append(line)
             ef = [r["exposed_comm_frac"] for r in roofs
                   if "exposed_comm_frac" in r]
             if ef:

@@ -38,8 +38,6 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from jax.sharding import PartitionSpec as P
 
     from sgcn_tpu.io.datasets import er_graph

@@ -1,6 +1,7 @@
-"""Pallas VMEM-kernel A/B in its selection regime, on the real chip
-(VERDICT r4 weak #7: the kernel was wired + parity-tested but its ~1.3×
-claim was a round-1 measurement under the since-corrected timing protocol).
+"""Pallas VMEM-kernel A/B in its selection regime, on the real chip (the
+kernel is wired + parity-tested and runs on the chip — chip_smoke.py — but
+its speed against the ELL path has never been measured on the current
+code; ROADMAP A7).
 
 The kernel's window is per-chip tables small enough to pin in VMEM — what
 k-way sharding produces as k grows (`ops/pallas_spmm.py::use_pallas_spmm`).

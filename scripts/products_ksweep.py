@@ -1,4 +1,4 @@
-"""Products-scale partitioner k-sweep (VERDICT r4 item 3, second half).
+"""Products-scale partitioner k-sweep.
 
 The reference sweeps k over its large graphs offline
 (``GPU/hypergraph/run.sh:1-13`` drives whole dataset directories through the

@@ -1,4 +1,4 @@
-"""Products-scale partitioner proof (VERDICT r3 item 1).
+"""Products-scale partitioner proof.
 
 Runs the native partitioners on the SAME graph the products-shape bench uses
 (``bench.py --graph ba -n 2450000 --avg-deg 50`` => ``ba_graph(n, 25, 0)``,
@@ -104,7 +104,7 @@ def main() -> None:
     out: dict = {
         "graph": graph_meta,
         "k": k,
-        "host": "single CPU core (see BASELINE.md measurement notes)",
+        "host": "single CPU core",
     }
 
     t0 = time.time()

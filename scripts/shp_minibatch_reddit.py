@@ -1,4 +1,4 @@
-"""SHP → mini-batch trainer composition at Reddit shape (VERDICT r3 item 6).
+"""SHP → mini-batch trainer composition at Reddit shape.
 
 The reference pipeline: ``GPU/SHP/main.py`` pickles a baseline full-graph HP
 partvec and a stochastic-HP partvec (``:131-140``), and
@@ -47,7 +47,7 @@ def main() -> None:
     from sgcn_tpu.train.minibatch import MiniBatchTrainer
 
     ap = argparse.ArgumentParser()
-    # dcsbm (VERDICT r4 item 5): the real Reddit is community-structured
+    # dcsbm: the real Reddit is community-structured
     # (41 subreddit classes) like dcsbm, NOT an expander like ba — ba is
     # where partitioning cannot win, so it under-sells the SHP margin
     ap.add_argument("--graph", default="ba", choices=["ba", "dcsbm"])

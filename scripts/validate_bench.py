@@ -988,7 +988,6 @@ _ARTIFACT_CHECKS = {
     "products_partition_dcsbm.json": check_products_partition,
     "shard_epoch_model.json": check_shard_epoch_model,
     "shard_epoch_model_dcsbm.json": check_shard_epoch_model,
-    "shard_epoch_model_bf16wire.json": check_shard_epoch_model,
 }
 
 

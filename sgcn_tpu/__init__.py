@@ -21,6 +21,4 @@ The package is importable both as ``sgcn_tpu`` and via the canonical repo-name
 symlink. See SURVEY.md at the repo root for the reference structural analysis.
 """
 
-from .utils import compat as _compat  # noqa: F401 — installs jax API aliases
-
 __version__ = "0.1.0"

@@ -14,10 +14,7 @@ The registry proper is PURE DATA (name → defining module attribute) so
 the AST pass never imports the SCANNED modules: resolving the tuple
 VALUES imports the consumers (models/ops/serve — heavy, side-effectful),
 and the AST rules must never be defeated by a scanned module's
-import-time behavior.  (The ``sgcn_tpu`` package itself installs the
-jaxlib compat shims at import — ``utils/compat.py`` — so a bare ``jax``
-module import still occurs on any ``sgcn_tpu.*`` import; what the AST
-pass avoids is backend work and the scanned modules' own import graphs.)
+import-time behavior.
 ``resolve_consumer_tuples()`` does the imports for the consumers that
 need values (the plan-contract lint).
 """

@@ -728,10 +728,10 @@ def estimate_gat_hbm_bytes(b: int, r: int, fin: int, widths: list[int],
     return int(7.08 * b * ftot + 64 * nnz + 90 * tail)
 
 
-def check_gat_memory(b: int, r: int, fin: int, widths: list[int],
-                     nnz: int = 0, tail: int = 0, dtype: str | None = None,
-                     hbm_bytes: int | None = None) -> None:
-    """Pre-flight guard for the GAT capacity edge (VERDICT r3): raise a
+def check_gat_memory(device, b: int, r: int, fin: int, widths: list[int],
+                     nnz: int = 0, tail: int = 0,
+                     dtype: str | None = None) -> None:
+    """Pre-flight guard for the GAT capacity edge: raise a
     clear error instead of letting the compile OOM or — worse — the TPU
     worker die at runtime (both observed; the 2-layer BA-products f32 step
     passed compile and then crashed the worker).
@@ -739,21 +739,20 @@ def check_gat_memory(b: int, r: int, fin: int, widths: list[int],
     The threshold is sharp by necessity — the largest RUNNING config
     estimates 15.13 GB of the chip's 15.75 GB and the smallest compile-OOM
     16.76 — so the guard raises above 0.97·HBM and tells the user the
-    levers.  ``SGCN_HBM_BYTES`` overrides the detected/assumed HBM size
-    (set it huge to bypass the guard for capacity experiments);
-    ``SGCN_GAT_UNSAFE=1`` skips both guards outright."""
+    levers.  The HBM size is what ``device`` (a device of the trainer's
+    mesh) reports as ``memory_stats()["bytes_limit"]``; a backend that
+    reports none (CPU) has no capacity to guard and skips that check — a
+    figure is never assumed for a device that could not be asked.
+    ``SGCN_HBM_BYTES`` overrides the reported size (set it huge to bypass
+    the guard for capacity experiments); ``SGCN_GAT_UNSAFE=1`` skips both
+    guards outright."""
     if _os.environ.get("SGCN_GAT_UNSAFE") == "1":
         return
-    if hbm_bytes is None:
-        env = _os.environ.get("SGCN_HBM_BYTES")
-        if env:
-            hbm_bytes = int(env)
-        else:
-            try:
-                hbm_bytes = jax.local_devices()[0].memory_stats()[
-                    "bytes_limit"]
-            except Exception:               # noqa: BLE001 — stats optional
-                hbm_bytes = 16 * 1024**3    # v5e default
+    env = _os.environ.get("SGCN_HBM_BYTES")
+    if env:
+        hbm_bytes = int(env)
+    else:
+        hbm_bytes = (device.memory_stats() or {}).get("bytes_limit")
     # Secondary fence for the runtime-crash blind spot: the 2-layer BA
     # products step (tail 29M) passed both compile and this capacity model
     # and then KILLED the worker, while an 11.9M-tail run (B=1M) was fine —
@@ -766,6 +765,8 @@ def check_gat_memory(b: int, r: int, fin: int, widths: list[int],
             f"fitting the capacity model, while 11.9M ran fine.  Shard "
             f"over more chips (the per-chip tail shrinks ~k-fold) or set "
             f"SGCN_GAT_UNSAFE=1 to bypass both guards knowingly.")
+    if hbm_bytes is None:
+        return
     est = estimate_gat_hbm_bytes(b, r, fin, widths, nnz, tail, dtype)
     if est > 0.97 * hbm_bytes:
         raise RuntimeError(

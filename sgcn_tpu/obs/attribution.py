@@ -10,9 +10,9 @@ roofline fields; it now imports them from here.
 Three quantities per training step:
 
   * **gather bytes** — what the row gathers move (the workload is
-    gather-bound on v5e; ``BASELINE.md`` microbenchmarks put the achievable
-    stream rate at ``STREAM_CEILING_GBS``).  ``achieved_gather_GBs /
-    STREAM_CEILING_GBS`` is the MFU-analogue for this workload.
+    gather-bound on v5e).  ``achieved_gather_GBs / STREAM_CEILING_GBS`` is
+    the MFU-analogue for this workload, emitted only on the device kind
+    the ceiling was stated for.
   * **FLOPs** — per-layer SpMM (2·nnz·f) and dense projection (2·B·fin·fout)
     at the layer's true aggregation width, forward + backward (backward ≈
     2× the dense forward — dX and dW — plus one more SpMM pass under the
@@ -38,10 +38,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-# Measured achievable HBM stream rate through XLA on this chip (BASELINE.md
-# microbenchmarks: 655 GB/s = 80% of nominal); the denominator of the
-# gather-utilization figure — the MFU-analogue for this gather-bound workload.
+# Achievable HBM stream rate through XLA: 655 GB/s = 80% of nominal, the
+# builders' microbenchmark on an earlier shared development v5e (≤
+# 2026-07-31, to be re-measured — ROADMAP A0's peaks table replaces this).
+# The denominator of the gather-utilization figure, the MFU-analogue for
+# this gather-bound workload; stated for ONE device kind, and
+# ``stream_ceiling_frac`` is emitted for no other (CPU included).
 STREAM_CEILING_GBS = 655.0
+STREAM_CEILING_DEVICE_KIND = "TPU v5 lite"
 
 # Nominal v5e per-link ICI rate (400 Gbps/link, each direction) — the
 # serialization rate one wire byte pays in the analytic exchange model.
@@ -77,10 +81,9 @@ def gather_bytes_per_epoch(plan, fin: int, widths,
     and the selected transport's exchange gathers —
     ``_exchange_gather_rows``), at the aggregation width of each layer
     (``models/gcn.py::exchange_widths`` — the trainer's project-first
-    rule).  Accumulate-side traffic (~30% more, BASELINE.md utilization
-    accounting) is deliberately excluded: the metric is 'how fast are the
-    gathers running', matching the measured 655 GB/s stream ceiling
-    denominator.
+    rule).  Accumulate-side traffic (~30% more) is deliberately excluded:
+    the metric is 'how fast are the gathers running', matching the stream
+    ceiling denominator.
     """
     from ..models.gcn import exchange_widths
     ell_slots = sum(nb * wb for nb, wb in plan.ell_buckets)
@@ -323,8 +326,13 @@ def add_partial_refresh(cost: StepCostModel, refresh_rows,
 
 
 def roofline_fields(cost: StepCostModel, wall_s: float,
-                    exchanges: int = 0, exposed_exchanges: int = 0) -> dict:
+                    exchanges: int = 0, exposed_exchanges: int = 0,
+                    device_kind: str | None = None) -> dict:
     """Join the analytic cost against ONE measured step time.
+
+    ``device_kind`` is the ``device_kind`` of the mesh the step ran on:
+    ``stream_ceiling_frac`` is emitted only when it is the kind
+    ``STREAM_CEILING_GBS`` was stated for.
 
     ``exchanges`` / ``exposed_exchanges`` are the step's exchange counts
     (from ``CommStats``); ``exposed_comm_frac`` is the fraction of this
@@ -340,8 +348,6 @@ def roofline_fields(cost: StepCostModel, wall_s: float,
     out = {
         "gather_GB": sig(cost.gather_bytes / 1e9, 6),
         "achieved_gather_GBs": sig(cost.gather_bytes / wall_s / 1e9),
-        "stream_ceiling_frac": sig(
-            cost.gather_bytes / wall_s / 1e9 / STREAM_CEILING_GBS),
         "model_step_GFLOP": sig(cost.step_flops / 1e9, 6),
         "achieved_GFLOPs": sig(cost.step_flops / wall_s / 1e9),
         "halo_bytes_per_step": cost.halo_bytes_per_step,
@@ -355,6 +361,9 @@ def roofline_fields(cost: StepCostModel, wall_s: float,
         "halo_wire_rows_per_exchange": cost.halo_wire_rows,
         "padding_efficiency": cost.padding_efficiency,
     }
+    if device_kind == STREAM_CEILING_DEVICE_KIND:
+        out["stream_ceiling_frac"] = sig(
+            cost.gather_bytes / wall_s / 1e9 / STREAM_CEILING_GBS)
     if exchanges > 0:
         out["exposed_comm_frac"] = round(exposed_exchanges / exchanges, 6)
         # exposed bytes charge the WIRE volume: a padded schedule's dead

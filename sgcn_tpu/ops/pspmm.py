@@ -122,9 +122,9 @@ def halo_exchange(h, send_idx, halo_src, axis_name: str = AXIS,
         the send-side gather and the halo rows are upcast back to ``h.dtype``
         after the halo gather, so exactly the ``all_to_all`` bytes halve
         (``'bfloat16'``) while every table, activation and accumulation
-        stays f32.  Single-chip bf16 compute measured SLOWER (BASELINE.md:
-        gathers are row-rate-bound and master-array casts are pure
-        overhead); the wire is the one place narrow pays, because ICI
+        stays f32.  Single-chip bf16 compute measured SLOWER in round 5
+        (gathers are row-rate-bound and master-array casts are pure
+        overhead; ROADMAP A1); the wire is the one place narrow pays, because ICI
         bytes are the multi-chip bottleneck the partitioner minimizes.
 
     Returns:

@@ -56,8 +56,7 @@ def _initialize_with_retry(heartbeat, detail: str, **kwargs) -> None:
     """``jax.distributed.initialize`` under an explicit stalled-peer
     timeout with ONE retry + backoff.  Heartbeats mark every transition
     (start/stalled/retry/done/failed), so an operator watching the run
-    directory sees WHICH attempt is in flight — the stalled-vs-slow signal
-    the dryrun classifier reads."""
+    directory sees WHICH attempt is in flight."""
     import inspect
 
     timeout = float(os.environ.get("SGCN_RENDEZVOUS_TIMEOUT",

@@ -12,8 +12,7 @@ the layer that makes long runs survivable on preemptible hardware
     ``--checkpoint-every`` / ``--resume auto``, with the kill point where
     fault injection lands;
   * ``faults``      — deterministic env-driven fault injection
-    (kill-after-save, corrupt-after-save, heartbeat stall) + the
-    stalled-vs-slow heartbeat classifier.
+    (kill-after-save, corrupt-after-save).
 
 Attribute access is lazy (PEP 562) so importing ``sgcn_tpu.resilience``
 never drags in the trainer stack — ``utils/checkpoint.py`` imports
@@ -34,8 +33,6 @@ _EXPORTS = {
     "active_fault": ".faults",
     "after_checkpoint_save": ".faults",
     "corrupt_file": ".faults",
-    "maybe_stall": ".faults",
-    "classify_stall": ".faults",
 }
 
 __all__ = sorted(_EXPORTS)

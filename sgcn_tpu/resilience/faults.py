@@ -21,20 +21,12 @@ parity against the uninterrupted run.
     corruption via the checksum loader and fall back to the previous intact
     checkpoint — the fallback path, driven end to end by the harness, never
     by hand-staged files.
-  * ``stall:<phase>:<seconds>`` — sleep injection at a named phase hook
-    (``maybe_stall``): the heartbeat-stall fault.  The multichip dryrun
-    hooks ``'dryrun'``; a stalled child stops heartbeating, which is
-    exactly what the parent's stalled-vs-slow classifier
-    (``classify_stall``) must distinguish from a merely slow child whose
-    heartbeats keep advancing.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import sys
-import time
 from dataclasses import dataclass
 
 # distinctive exit code the hard kill uses — tests assert it so an ordinary
@@ -48,10 +40,8 @@ CORRUPT_MODES = ("bitflip", "truncate")
 
 @dataclass(frozen=True)
 class FaultSpec:
-    kind: str                    # 'kill-after-save'|'corrupt-after-save'|'stall'
-    step: int | None = None      # the triggering optimizer step (save faults)
-    phase: str | None = None     # the triggering phase hook (stall)
-    seconds: float | None = None  # stall duration
+    kind: str                    # 'kill-after-save'|'corrupt-after-save'
+    step: int | None = None      # the triggering optimizer step
     mode: str = "bitflip"        # corruption flavor
 
 
@@ -59,7 +49,7 @@ def _grammar_error(text: str) -> ValueError:
     return ValueError(
         f"unparseable {FAULT_ENV}={text!r} — grammar: "
         "'kill-after-save:<step>', 'corrupt-after-save:<step>[:<mode>]' "
-        f"(mode in {CORRUPT_MODES}), 'stall:<phase>:<seconds>'")
+        f"(mode in {CORRUPT_MODES})")
 
 
 def parse_fault(text: str) -> FaultSpec:
@@ -76,9 +66,6 @@ def parse_fault(text: str) -> FaultSpec:
             if mode not in CORRUPT_MODES:
                 raise _grammar_error(text)
             return FaultSpec(kind=kind, step=int(parts[1]), mode=mode)
-        if kind == "stall" and len(parts) == 3:
-            return FaultSpec(kind=kind, phase=parts[1],
-                             seconds=float(parts[2]))
     except ValueError as e:
         raise _grammar_error(text) from e
     raise _grammar_error(text)
@@ -139,57 +126,3 @@ def after_checkpoint_save(path: str, step: int) -> None:
         _hard_exit()
     if f.kind == "kill-after-save":
         _hard_exit()
-
-
-def maybe_stall(phase: str) -> None:
-    """The stall hook — a named phase (e.g. the dryrun's step phase) sleeps
-    for the injected duration, emitting no heartbeats meanwhile.  No-op
-    without a matching ``stall:<phase>:...`` fault."""
-    f = active_fault()
-    if f is not None and f.kind == "stall" and f.phase == phase:
-        time.sleep(f.seconds)
-
-
-# --------------------------------------------------- stalled-vs-slow reader
-def classify_stall(rundir: str, now: float | None = None,
-                   threshold_s: float = 60.0,
-                   exclude_pid: int | None = None
-                   ) -> tuple[str, float | None]:
-    """Classify a deadline-blown child from its heartbeat trail:
-    ``('slow', age)`` when the last heartbeat in
-    ``rundir/heartbeat.jsonl`` is fresher than ``threshold_s`` (the child
-    was advancing, just not fast enough), ``('stalled', age)`` when it is
-    older (the child stopped making progress), and
-    ``('stalled', None)`` when no heartbeat was ever observed — a child
-    that never reached its first phase is indistinguishable from a wedged
-    one, so it classifies as stalled.  ``exclude_pid`` drops the CALLER's
-    own pings (parent and child share one heartbeat file — a child that
-    wedged before its first heartbeat must not be judged "slow" off the
-    parent's spawn ping).  Pure file read: usable from the parent's
-    timeout handler without touching the dead child."""
-    from ..obs.schema import HEARTBEAT_NAME
-
-    now = time.time() if now is None else float(now)
-    path = os.path.join(rundir, HEARTBEAT_NAME)
-    last_ts = None
-    try:
-        with open(path) as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    ev = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if exclude_pid is not None and ev.get("pid") == exclude_pid:
-                    continue
-                ts = ev.get("ts")
-                if isinstance(ts, (int, float)):
-                    last_ts = float(ts)
-    except OSError:
-        return "stalled", None
-    if last_ts is None:
-        return "stalled", None
-    age = max(0.0, now - last_ts)
-    return ("slow" if age <= threshold_s else "stalled"), age

@@ -345,10 +345,10 @@ def main() -> None:
         import os
         os.environ["SGCN_METRICS_OUT"] = args.metrics_out
 
-    from ..utils.backend import enable_tpu_async_collectives, use_cpu_devices
+    from ..utils.backend import place_compile_cache, use_cpu_devices
     if args.backend == "cpu":
         use_cpu_devices(args.nparts)
-    enable_tpu_async_collectives()   # overlap needs async all-to-all on TPU
+    place_compile_cache()
 
     import jax
 

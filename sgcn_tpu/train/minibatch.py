@@ -393,14 +393,10 @@ class MiniBatchTrainer:
         tr = self.inner
         nb = len(self.plans)
 
-        # the loss/err accumulators enter as REPLICATED arguments rather
-        # than in-body jnp.zeros literals: a fori carry must keep one
-        # replication type, and a literal's is untracked while the psum'd
-        # losses written into it are replicated — shard_map's check_rep
-        # rejects the pair (observed on jaxlib 0.4.37; same fix as
-        # FullBatchTrainer._build_multi)
-        def per_chip(params, opt_state, pa_s, h0, lab, val, z_ep, z_nb):
+        def per_chip(params, opt_state, pa_s, h0, lab, val):
             pa_s, h0, lab, val = _unblock((pa_s, h0, lab, val))
+            z_ep = jnp.zeros((epochs,), jnp.float32)
+            z_nb = jnp.zeros((nb,), jnp.float32)
 
             def batch_body(i, carry):
                 params, opt_state, losses, _ = carry
@@ -420,7 +416,7 @@ class MiniBatchTrainer:
 
         smapped = jax.shard_map(
             per_chip, mesh=self.mesh,
-            in_specs=(P(), P(), P("v"), P("v"), P("v"), P("v"), P(), P()),
+            in_specs=(P(), P(), P("v"), P("v"), P("v"), P("v")),
             out_specs=(P(), P(), P(), P()))
         return jax.jit(smapped, donate_argnums=(0, 1))
 
@@ -451,8 +447,7 @@ class MiniBatchTrainer:
         tr = self.inner
         tr.params, tr.opt_state, losses, tr.last_err = self._fused[epochs](
             tr.params, tr.opt_state, pa_s, data.h0, data.labels,
-            data.train_valid, np.zeros((epochs,), np.float32),
-            np.zeros((len(self.plans),), np.float32))
+            data.train_valid)
         # same 8-number comm accounting as the stepwise path (one counter
         # set per batch plan, merged on report)
         if not hasattr(self, "_fused_stats"):

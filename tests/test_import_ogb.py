@@ -1,4 +1,4 @@
-"""OGB/Reddit import path (VERDICT r4 item 6), driven on synthetic
+"""OGB/Reddit import path, driven on synthetic
 directories that mimic each on-disk layout — the real downloads need egress
 this box lacks; the converter is what must be ready."""
 

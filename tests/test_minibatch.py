@@ -98,7 +98,7 @@ def test_minibatch_empty_train_batches_no_nan(ahat):
 
 def test_minibatch_stats_vocabulary(ahat):
     """fit() reports the full-batch trainer's 8-number comm vocabulary, and
-    volume equals the sum of per-batch plan predictions (VERDICT r2 #6)."""
+    volume equals the sum of per-batch plan predictions."""
     n = ahat.shape[0]
     rng = np.random.default_rng(7)
     pv = balanced_random_partition(n, K, seed=2)

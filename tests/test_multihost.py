@@ -18,7 +18,6 @@ import sys
 import os
 
 import numpy as np
-import pytest
 
 _WORKER = r"""
 import json, sys
@@ -101,12 +100,6 @@ def test_two_process_training_matches_single(tmp_path):
             raise
         outs.append((p.returncode, out, err))
     for rc, out, err in outs:
-        if rc != 0 and "Multiprocess computations aren't implemented" in err:
-            # jaxlib builds before multi-process CPU collectives (observed
-            # 0.4.36) cannot run this path at all — an environment gap, not
-            # a code regression; the sharding/placement logic it exercises
-            # is covered single-process by make_train_data_multihost tests
-            pytest.skip("this jaxlib has no multi-process CPU collectives")
         assert rc == 0, f"worker failed rc={rc}\nstdout:{out}\nstderr:{err}"
     line = [ln for ln in outs[0][1].splitlines() if ln.startswith("LOSSES ")]
     assert line, outs[0][1]

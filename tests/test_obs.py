@@ -294,6 +294,7 @@ def test_step_cost_model_and_roofline():
     from sgcn_tpu.models.gcn import exchange_widths
     from sgcn_tpu.obs import (STREAM_CEILING_GBS, gather_bytes_per_epoch,
                               roofline_fields, step_cost)
+    from sgcn_tpu.obs.attribution import STREAM_CEILING_DEVICE_KIND
 
     plan = _plan()
     fin, widths = 16, [32, 8]
@@ -321,7 +322,14 @@ def test_step_cost_model_and_roofline():
                            exposed_exchanges=1)
     assert roof["achieved_gather_GBs"] == float(
         f"{cost.gather_bytes / 0.01 / 1e9:.4g}")
-    assert roof["stream_ceiling_frac"] == float(
+    # the stream ceiling is a figure for ONE device kind: the fraction is
+    # emitted for that kind and for no other (CPU included)
+    assert "stream_ceiling_frac" not in roof
+    assert "stream_ceiling_frac" not in roofline_fields(
+        cost, wall_s=0.01, device_kind="cpu")
+    on_kind = roofline_fields(cost, wall_s=0.01,
+                              device_kind=STREAM_CEILING_DEVICE_KIND)
+    assert on_kind["stream_ceiling_frac"] == float(
         f"{cost.gather_bytes / 0.01 / 1e9 / STREAM_CEILING_GBS:.4g}")
     assert roof["exposed_comm_frac"] == 0.25
     # exposed bytes charge the WIRE volume of the selected schedule (the

@@ -4,10 +4,9 @@ The reference hides halo-exchange latency with Irecv → local SpMM → Waitany
 (``Parallel-GCN/main.c:238-299``).  Round 3 proved our split-edge structure
 gives XLA the same freedom (the local-src slot passes have no data dependence
 on the all_to_all) but could not show actual concurrency: the virtual CPU
-mesh serializes collectives and this host has one physical chip.
+mesh serializes collectives.
 
-This test extracts the evidence that does NOT need 8 chips (VERDICT r3 item
-4): AOT-compile the real ``FullBatchTrainer`` train step against an 8-chip
+This test extracts the evidence that does NOT need 8 chips: AOT-compile the real ``FullBatchTrainer`` train step against an 8-chip
 v5e TOPOLOGY (``jax.experimental.topologies`` — compile-only, no devices) and
 assert, in the scheduled HLO, that the halo ``all-to-all`` compiles to async
 ``-start``/``-done`` pairs with real compute (fusions — the local slot
@@ -29,9 +28,10 @@ from sgcn_tpu.parallel import build_comm_plan
 from sgcn_tpu.partition import balanced_random_partition
 from sgcn_tpu.train import FullBatchTrainer
 
-# AOT-compiling the 8-chip v5e train step costs ~8 min on this 2-core box
-# (and needs a jaxlib whose TPU AOT path works at all) — far past the tier-1
-# budget, so it runs only in the unfiltered suite
+# AOT-compiling the 8-chip v5e train step runs the real TPU compiler (and
+# needs a jaxlib whose TPU AOT path works at all) — outside the tier-1
+# budget, so it runs only in the unfiltered suite.  libtpu admits one
+# process at a time: never run it beside another process that loads libtpu
 pytestmark = pytest.mark.slow
 
 
@@ -56,10 +56,10 @@ def v5e_mesh():
 def step_text(v5e_mesh, n=4096, avg_deg=12, f=64):
     """Compile one real train step for the v5e slice; return scheduled HLO.
 
-    Compiled with the framework's async-collective flag
-    (``utils/backend.py::ASYNC_COLLECTIVE_FLAGS`` — v5e's DEFAULT is a
-    synchronous all-to-all, measured on this exact program; the trainer CLI
-    and bench set the flag via ``enable_tpu_async_collectives``)."""
+    Compiled with ``xla_tpu_enable_async_all_to_all`` as a COMPILE OPTION:
+    v5e's default schedule is a synchronous all-to-all (on the chip too —
+    PERF.md bring-up), no shipped entry point turns the option on yet
+    (ROADMAP A5), and as an ``XLA_FLAGS`` entry this jaxlib aborts on it."""
     from sgcn_tpu.io.datasets import ba_graph
     from sgcn_tpu.prep import normalize_adjacency
 

@@ -117,26 +117,78 @@ class _FitsPlan:
         raise ValueError("stub has no square counts")
 
 
-def test_pallas_fits_itemsize_boundary(monkeypatch):
-    """Satellite: the VMEM budget check is itemsize-aware — the old
-    hard-coded 4 B/elem charged bf16 compute_dtype tables DOUBLE.  Pin
-    both dtypes exactly at the budget boundary."""
+def test_pallas_fits_table_boundary(monkeypatch):
+    """The VMEM budget check charges the f32 table, exactly at the
+    boundary."""
     from sgcn_tpu.ops.pallas_spmm import pallas_spmm_fits
 
     b, r, fmax = 100, 80, 32
     plan = _FitsPlan(b, r)
-    # f32: budget exactly b·fmax·4 on the larger (local) table → fits;
-    # one byte less → does not
     monkeypatch.setenv("SGCN_PALLAS_VMEM", str(b * fmax * 4))
     assert pallas_spmm_fits(plan, fmax, [8])
     monkeypatch.setenv("SGCN_PALLAS_VMEM", str(b * fmax * 4 - 1))
     assert not pallas_spmm_fits(plan, fmax, [8])
-    # bf16: the same boundary sits at 2 B/elem — the old check refused it
-    monkeypatch.setenv("SGCN_PALLAS_VMEM", str(b * fmax * 2))
-    assert pallas_spmm_fits(plan, fmax, [8], compute_dtype="bfloat16")
-    assert not pallas_spmm_fits(plan, fmax, [8])     # f32 needs 2×
-    monkeypatch.setenv("SGCN_PALLAS_VMEM", str(b * fmax * 2 - 1))
-    assert not pallas_spmm_fits(plan, fmax, [8], compute_dtype="bfloat16")
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_bf16_compute_never_selects_kernel(monkeypatch):
+    """The kernel's single-row dynamic load of a bf16 table does not
+    compile on the chip (PERF.md bring-up), so a bf16 compute_dtype never
+    selects it — forced on, on a TPU, for either model."""
+    from sgcn_tpu.ops import pallas_spmm as ps
+
+    plan = _FitsPlan(100, 80)
+    monkeypatch.setenv("SGCN_PALLAS_SPMM", "1")
+    monkeypatch.setattr(ps, "kernel_device",
+                        lambda: _Dev("tpu", "TPU v5 lite"))
+    for model in ("gcn", "gat"):
+        assert ps.use_pallas_spmm(plan, 32, [8], model=model)
+        assert not ps.use_pallas_spmm(plan, 32, [8], model=model,
+                                      compute_dtype="bfloat16")
+
+
+def test_selection_needs_a_known_tpu_kind(monkeypatch):
+    """Auto mode fires on a TPU whose SMEM capacity the table knows; an
+    unknown TPU kind selects no kernel even when forced; off the chip only
+    the forced (emulated) selection fires."""
+    from sgcn_tpu.ops import pallas_spmm as ps
+
+    plan = _FitsPlan(100, 80)
+    monkeypatch.delenv("SGCN_PALLAS_SPMM", raising=False)
+    assert not ps.use_pallas_spmm(plan, 32, [8])          # CPU, auto
+    monkeypatch.setattr(ps, "kernel_device",
+                        lambda: _Dev("tpu", "TPU v5 lite"))
+    assert ps.use_pallas_spmm(plan, 32, [8])
+    monkeypatch.setattr(ps, "kernel_device",
+                        lambda: _Dev("tpu", "TPU v99"))
+    assert not ps.use_pallas_spmm(plan, 32, [8])
+    monkeypatch.setenv("SGCN_PALLAS_SPMM", "1")
+    assert not ps.use_pallas_spmm(plan, 32, [8])
+
+
+def test_over_smem_class_takes_ell():
+    """A tile class whose three prefetch operands exceed the chip's SMEM
+    takes the XLA gather form — the escape the Emax cap already uses.  The
+    class the v5e compiler refused (10 tiles × Emax 6912: 3 × 432 KiB
+    against 1 MiB) is the pinned case."""
+    from sgcn_tpu.ops.pallas_spmm import (SMEM_BYTES, _assign_kernels,
+                                          prefetch_smem_bytes)
+
+    assert prefetch_smem_bytes(10, 6912) == 3 * 16 * 6912 * 4   # rows → 16
+    assert prefetch_smem_bytes(64, 1288) == 3 * 64 * 1408 * 4   # cols → 128
+    classes = ((10, 6912), (11, 2048), (2, 24288))
+    smem = SMEM_BYTES["TPU v5 lite"]
+    assert _assign_kernels(classes, smem) == (
+        (10, 6912, "ell"), (11, 2048, "vmem"), (2, 24288, "ell"))
+    # no SMEM limit (the emulated path): only the Emax cap applies
+    assert _assign_kernels(classes) == (
+        (10, 6912, "vmem"), (11, 2048, "vmem"), (2, 24288, "ell"))
+    assert _assign_kernels(classes, 0) == tuple(
+        (t, e, "ell") for t, e in classes)
 
 
 def test_pallas_fits_gat_and_ragged_tables(monkeypatch):
@@ -175,7 +227,7 @@ def test_tile_classes_cover_and_align():
 
 
 def test_trainer_plan_driven_pallas_parity(ahat, monkeypatch):
-    """Plan-driven kernel choice (VERDICT r3 #9): with SGCN_PALLAS_SPMM=1
+    """Plan-driven kernel choice: with SGCN_PALLAS_SPMM=1
     the symmetric GCN trainer must auto-select the VMEM Pallas aggregator
     (per-chip tables fit the budget at this size) and train to the SAME
     losses and predictions as the default ELL path."""

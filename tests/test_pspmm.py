@@ -114,10 +114,7 @@ def test_overlap_backward_parity(ahat):
             # per-chip LOCAL objective: its grad is still the GLOBAL
             # d(sum over chips)/dh — every chip runs the same transposed
             # exchange, so cotangents for rows this chip owns arrive from
-            # all consumers.  (A psum'd objective hits the old
-            # psum-transposes-to-psum convention on jaxlib 0.4.37 and
-            # comes back k-times inflated; the local form is
-            # convention-independent.)
+            # all consumers.
             return jnp.sum(out * w[0])
 
         return jax.grad(obj)(h[0])[None]
@@ -183,10 +180,7 @@ def test_ell_sym_backward_parity(ahat):
             # per-chip LOCAL objective: its grad is still the GLOBAL
             # d(sum over chips)/dh — every chip runs the same transposed
             # exchange, so cotangents for rows this chip owns arrive from
-            # all consumers.  (A psum'd objective hits the old
-            # psum-transposes-to-psum convention on jaxlib 0.4.37 and
-            # comes back k-times inflated; the local form is
-            # convention-independent.)
+            # all consumers.
             return jnp.sum(out * w[0])
 
         return jax.grad(obj)(h[0])[None]
@@ -224,10 +218,7 @@ def test_directed_graph_detected_not_symmetric():
             # per-chip LOCAL objective: its grad is still the GLOBAL
             # d(sum over chips)/dh — every chip runs the same transposed
             # exchange, so cotangents for rows this chip owns arrive from
-            # all consumers.  (A psum'd objective hits the old
-            # psum-transposes-to-psum convention on jaxlib 0.4.37 and
-            # comes back k-times inflated; the local form is
-            # convention-independent.)
+            # all consumers.
             return jnp.sum(out * w[0])
 
         return jax.grad(obj)(h[0])[None]
@@ -330,10 +321,7 @@ def test_backward_parity(ahat):
             # per-chip LOCAL objective: its grad is still the GLOBAL
             # d(sum over chips)/dh — every chip runs the same transposed
             # exchange, so cotangents for rows this chip owns arrive from
-            # all consumers.  (A psum'd objective hits the old
-            # psum-transposes-to-psum convention on jaxlib 0.4.37 and
-            # comes back k-times inflated; the local form is
-            # convention-independent.)
+            # all consumers.
             return jnp.sum(out * w[0])
 
         return jax.grad(obj)(h[0])[None]

@@ -120,7 +120,7 @@ def test_cli_accuracy_experiment_npz_minibatch():
     # same 1433-wide CLI pipeline for ~75 s of tier-1 budget; k=4 is the
     # budgeted representative
 def test_cli_accuracy_cora_true_shape(k):
-    """The accuracy experiment at cora's TRUE dims (VERDICT r3 item 3):
+    """The accuracy experiment at cora's TRUE dims:
     2708 x 1433 x 7, planetoid split (20/class train, 1000 test), oracle vs
     k-way partitioned full-batch AND mini-batch, through the .npz snapshot
     ingestion path end-to-end.  The reference's protocol is the real-cora

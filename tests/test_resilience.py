@@ -75,10 +75,8 @@ def test_fault_spec_grammar():
     s = faults.parse_fault("corrupt-after-save:6:truncate")
     assert (s.step, s.mode) == (6, "truncate")
     assert faults.parse_fault("corrupt-after-save:2").mode == "bitflip"
-    s = faults.parse_fault("stall:dryrun:30")
-    assert (s.phase, s.seconds) == ("dryrun", 30.0)
     for bad in ("kill-after-save", "kill-after-save:x", "nope:1",
-                "corrupt-after-save:2:shred", "stall:dryrun"):
+                "corrupt-after-save:2:shred", "stall:dryrun:30"):
         with pytest.raises(ValueError, match="grammar"):
             faults.parse_fault(bad)
 
@@ -93,23 +91,6 @@ def test_corrupt_file_deterministic(tmp_path):
     assert sum(a != b for a, b in zip(data, ref)) == 1   # exactly one byte
     faults.corrupt_file(p, mode="truncate")
     assert os.path.getsize(p) == int(1024 * 0.6)
-
-
-def test_classify_stall(tmp_path):
-    import time
-
-    d = str(tmp_path)
-    hb = os.path.join(d, "heartbeat.jsonl")
-    # no heartbeat file at all: indistinguishable from wedged
-    assert faults.classify_stall(d) == ("stalled", None)
-    now = time.time()
-    with open(hb, "w") as fh:
-        fh.write(json.dumps({"ts": now - 300}) + "\n")
-        fh.write(json.dumps({"ts": now - 5}) + "\n")
-    kind, age = faults.classify_stall(d, now=now, threshold_s=60)
-    assert kind == "slow" and age == pytest.approx(5, abs=0.1)
-    kind, age = faults.classify_stall(d, now=now + 600, threshold_s=60)
-    assert kind == "stalled" and age == pytest.approx(605, abs=0.1)
 
 
 # --------------------------------------------------- tiny in-process trainer
