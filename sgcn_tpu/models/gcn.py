@@ -26,6 +26,7 @@ from ..ops.pspmm import (pspmm_ell_sym, pspmm_overlap, pspmm_ragged_sym,
                          pspmm_replica_ragged, pspmm_replica_stale,
                          pspmm_replica_stale_ragged, pspmm_stale,
                          pspmm_stale_ragged)
+from ..obs.tracing import scope
 from ..parallel.mesh import AXIS
 from .activations import get_activation
 
@@ -200,11 +201,16 @@ def gcn_forward_local(
                 axis_name=axis_name, halo_dtype=halo_dtype)
 
     for i, w in enumerate(params):
-        if w.shape[1] < h.shape[1] and h.shape[1] >= PROJECT_FIRST_MIN_FIN:
-            z = agg(h @ w)
-        else:
-            z = agg(h) @ w
-        h = fact(z) if i == nl - 1 else act(z)
+        with scope("layer", i):
+            if w.shape[1] < h.shape[1] and h.shape[1] >= PROJECT_FIRST_MIN_FIN:
+                with scope("dense"):
+                    x = h @ w
+                z = agg(x)
+            else:
+                z = agg(h)
+                with scope("dense"):
+                    z = z @ w
+            h = fact(z) if i == nl - 1 else act(z)
     return h
 
 

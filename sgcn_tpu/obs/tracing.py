@@ -4,8 +4,21 @@ Every perf gauge in ``attribution.py`` is ANALYTIC: derived from the
 ``CommPlan``, it says how fast a step *should* be.  This module is the
 measured-time source of truth next to it, in two halves:
 
+**Span primitive** (``span`` / ``span_totals`` / ``reset_spans``) — ONE
+host-span context manager for the whole program: it opens a
+``jax.profiler.TraceAnnotation("sgcn.<name>")``, so the span lies on the
+profiler's clock beside the device ops whenever a trace is being taken, and
+it keeps a process-wide in-memory table (count, total seconds, parent, the
+last 256 durations) that the benchmark's ``program_span`` readers read.
+``scope`` is its compiled-program counterpart: ``jax.named_scope`` over the
+fixed vocabulary ``SCOPES``, which reaches the device trace through each
+op's HLO metadata.  ``set_counter`` / ``counters`` hold plan-time counts
+(``CommPlan.work_counts``) the same way.
+
 **Span API** (``SpanTimer`` / ``emit_span`` / ``scoped_span``) — named,
-optionally nested wall-clock spans with ``block_until_ready`` sync points.
+optionally nested wall-clock spans with ``block_until_ready`` sync points;
+``SpanTimer.span`` enters the primitive above, so every trainer span
+(``warmup``, ``train_step``, ``step``, ``eval``) is on the profiler too.
 It generalizes ``utils.timers.PhaseTimer`` (every span IS a phase: the timer
 keeps the CAGNET-vocabulary self-time breakdown, the span additionally
 becomes a schema-v2 ``span`` event in the run's ``events.jsonl``), so
@@ -42,12 +55,100 @@ import gzip
 import json
 import os
 import re
+import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 
 # NOTE: no module-scope import of ..utils.timers — it imports jax, and the
 # trace parser half of this module must stay importable in a jax-free
 # context (SpanTimer imports PhaseTimer lazily)
+
+# ---------------------------------------------------------- span primitive
+
+PREFIX = "sgcn."            # every span and scope name, wherever it lands
+SPAN_KEEP = 256             # durations kept per span name
+
+# The compiled step's scope vocabulary (``scope``).  ``layer`` is indexed
+# (``sgcn.layer0``, ...); every other name is a LEAF scope: an op belongs to
+# the last leaf token of its HLO ``op_name``, whatever ``jvp(...)`` /
+# ``transpose(...)`` wrappers the transforms put around it.
+# ``benchmark/scopes.json`` is the benchmark's own copy (a test pins them
+# equal); docs/observability.md says what each one holds.
+SCOPES = ("layer", "dense", "xchg_pack", "xchg_a2a", "xchg_unpack",
+          "agg_slots", "agg_tail", "agg_halo_fold", "loss", "grad_psum",
+          "optimizer")
+
+_spans: dict = {}           # name -> {count, total_s, parent, durations}
+_spans_lock = threading.Lock()  # spans close on more than one thread
+_open = threading.local()   # .stack: this thread's open span names
+_counters: dict = {}
+
+
+def scope(name: str, index: int | None = None):
+    """``jax.named_scope("sgcn.<name>[index]")`` for a name of ``SCOPES`` —
+    HLO metadata only, no arithmetic changes."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; the vocabulary is {SCOPES}")
+    import jax
+
+    return jax.named_scope(
+        f"{PREFIX}{name}{'' if index is None else int(index)}")
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Host span ``sgcn.<name>``: a ``TraceAnnotation`` on the profiler's
+    clock (a disabled ``TraceMe`` when no trace is being taken) and one row
+    of the in-memory table ``span_totals`` reads.  Nothing is written to
+    disk; the parent is the innermost span open on this thread."""
+    from jax.profiler import TraceAnnotation    # first use, not import time
+
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    parent = stack[-1] if stack else None
+    stack.append(name)
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(PREFIX + name):
+            yield
+    finally:
+        dur = time.perf_counter() - t0
+        stack.pop()
+        with _spans_lock:
+            row = _spans.get(name)
+            if row is None:
+                row = _spans[name] = {"count": 0, "total_s": 0.0,
+                                      "parent": parent,
+                                      "durations": deque(maxlen=SPAN_KEEP)}
+            row["count"] += 1
+            row["total_s"] += dur
+            row["durations"].append(dur)
+
+
+def span_totals() -> dict:
+    """``{name: {count, total_s, parent, durations}}`` of every span closed
+    in this process since ``reset_spans`` (a copy; durations oldest first)."""
+    with _spans_lock:
+        return {name: dict(row, durations=list(row["durations"]))
+                for name, row in _spans.items()}
+
+
+def reset_spans() -> None:
+    with _spans_lock:
+        _spans.clear()
+
+
+def set_counter(name: str, value) -> None:
+    """Leave a plan-time count where a reader in the same process finds it
+    (``counters``); the newest value of a name wins."""
+    _counters[name] = value
+
+
+def counters() -> dict:
+    return dict(_counters)
+
 
 # ---------------------------------------------------------------- span API
 
@@ -68,8 +169,9 @@ class SpanTimer:
     One instance per trainer: ``timer`` keeps the phase breakdown (self
     time per name — the ``PhaseTimer`` nesting contract), and, when a
     ``RunRecorder`` is attached, every span exit appends one validated
-    ``span`` event.  Without a recorder the only cost is the timer's two
-    ``perf_counter`` reads — the default hot path stays un-instrumented.
+    ``span`` event.  Every span also enters the module's ``span``
+    primitive (profiler annotation + in-memory table); without a recorder
+    that and the timer's ``perf_counter`` reads are the only cost.
     """
 
     def __init__(self, timer=None, recorder=None):
@@ -92,7 +194,7 @@ class SpanTimer:
         self._stack.append(name)
         t0 = time.perf_counter()
         try:
-            with self.timer.phase(name, sync=sync):
+            with span(name), self.timer.phase(name, sync=sync):
                 yield sp
         finally:
             sp.dur_s = time.perf_counter() - t0

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.tracing import scope
 from ..parallel.mesh import AXIS
 
 # bound on the gather temps XLA's latency-hiding scheduler can keep live
@@ -60,7 +61,9 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     ``_SCAN_LIVE_LIMIT`` (≤4; measured 2.75 → 2.24 s/epoch at products
     scale going 1 → 4), so liveness stays provably bounded for every
     bucket shape.  The width-major flat layout makes each slot a
-    contiguous ``(nb,)`` run, so the ``(wb, nb)`` reshape is free.
+    contiguous ``(nb,)`` run, so the ``(wb, nb)`` reshape moves nothing
+    row-major; under the TPU's tiled layout the compiler still copies it,
+    every step (~0.04 s an epoch at products scale, PERF.md §6, PR 25).
 
     ``contrib(idx (nb,), w (nb,)) -> pytree of (nb, ...) f32 arrays``;
     ``init(nb)`` builds the matching zero pytree; ``slot_bytes(nb)``
@@ -131,12 +134,15 @@ def halo_exchange(h, send_idx, halo_src, axis_name: str = AXIS,
       (R, f) halo rows (padding rows contain garbage; they are only referenced
       by weight-0 edges).
     """
-    buf = jnp.take(h, send_idx, axis=0)                     # (k, S, f)
-    if halo_dtype is not None:
-        buf = buf.astype(halo_dtype)
-    recv = a2a_or_identity(buf, axis_name)
-    flat = recv.reshape(-1, h.shape[-1])                    # (k*S, f)
-    return jnp.take(flat, halo_src, axis=0).astype(h.dtype)  # (R, f)
+    with scope("xchg_pack"):
+        buf = jnp.take(h, send_idx, axis=0)                 # (k, S, f)
+        if halo_dtype is not None:
+            buf = buf.astype(halo_dtype)
+    with scope("xchg_a2a"):
+        recv = a2a_or_identity(buf, axis_name)
+    with scope("xchg_unpack"):
+        flat = recv.reshape(-1, h.shape[-1])                # (k*S, f)
+        return jnp.take(flat, halo_src, axis=0).astype(h.dtype)  # (R, f)
 
 
 def ragged_live_rounds(rr_sizes) -> tuple:
@@ -345,21 +351,23 @@ def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
     # ogbn-products scale (mixed precision already double-books HBM with the
     # bf16 casts of every master-f32 array, so the slot budget must stay
     # conservative; the f32-equivalent budget is that 2× safety factor)
-    outs = bucketed_slot_reduce(
-        ell_idx, ell_w, buckets,
-        contrib=lambda idx, w: jnp.take(h, idx, axis=0) * w[:, None],
-        init=lambda nb: jnp.zeros((nb, f), h.dtype),
-        slot_bytes=lambda nb: nb * f * 4)
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-    tg = jnp.take(h, tail_src, axis=0) * tail_w[:, None]
-    # the tail is dst-sorted by construction (plan edges are dst-sorted and
-    # padding appends dst=b-1), so a sorted segment_sum beats the scatter-add
-    # form: measured 58.6 -> 54.0 ms/epoch (-8%) at ogbn-arxiv shape on a
-    # power-law (BA) graph where hub spill puts 8% of edges in the tail
-    # (no-op on ER benches, whose tails are empty)
-    tsum = jax.ops.segment_sum(tg, tail_dst, num_segments=out.shape[0],
-                               indices_are_sorted=True)
-    return out + tsum
+    with scope("agg_slots"):
+        outs = bucketed_slot_reduce(
+            ell_idx, ell_w, buckets,
+            contrib=lambda idx, w: jnp.take(h, idx, axis=0) * w[:, None],
+            init=lambda nb: jnp.zeros((nb, f), h.dtype),
+            slot_bytes=lambda nb: nb * f * 4)
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+    with scope("agg_tail"):
+        tg = jnp.take(h, tail_src, axis=0) * tail_w[:, None]
+        # the tail is dst-sorted by construction (plan edges are dst-sorted
+        # and padding appends dst=b-1), so a sorted segment_sum beats the
+        # scatter-add form: measured 58.6 -> 54.0 ms/epoch (-8%) at
+        # ogbn-arxiv shape on a power-law (BA) graph where hub spill puts 8%
+        # of edges in the tail (no-op on ER benches, whose tails are empty)
+        tsum = jax.ops.segment_sum(tg, tail_dst, num_segments=out.shape[0],
+                                   indices_are_sorted=True)
+        return out + tsum
 
 
 def _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
@@ -369,8 +377,9 @@ def _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
     halo = halo_exchange(h, send_idx, halo_src, axis_name, halo_dtype)
     # local ELL aggregation has no data dependence on the exchange (overlap)
     local = spmm_ell(ell_idx, ell_w, ltail_dst, ltail_src, ltail_w, h, buckets)
-    remote = spmm_local(hedge_dst, hedge_src, hedge_w, halo, h.shape[0])
-    return local + remote
+    with scope("agg_halo_fold"):
+        remote = spmm_local(hedge_dst, hedge_src, hedge_w, halo, h.shape[0])
+        return local + remote
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(11, 12, 13))

@@ -545,6 +545,31 @@ class CommPlan:
         wire = self.wire_rows_per_exchange("a2a")
         return float(self.send_counts.sum()) / wire if wire else 1.0
 
+    def work_counts(self) -> dict:
+        """Per chip, the true counts of one aggregation pass beside what
+        EVERY chip executes for them (all chips run one program over the
+        padded shapes): ``slot_edges`` in the ELL buckets (Σ nb·wb slots),
+        ``tail_edges`` (``tl``), ``halo_edges`` (``eh``), ``halo_rows``
+        received into the halo table (``r``) and ``rows_sent`` (``k·s`` send
+        slots).  Plain ints and lists: it is left in
+        ``obs.tracing.counters()`` by ``build_comm_plan``."""
+        return {
+            "true": {
+                "slot_edges": (self.lnnz - self.ltail_nnz).tolist(),
+                "tail_edges": self.ltail_nnz.tolist(),
+                "halo_edges": self.hnnz.tolist(),
+                "halo_rows": self.halo_counts.tolist(),
+                "rows_sent": self.send_counts.sum(axis=1).tolist(),
+            },
+            "executed": {
+                "slot_edges": int(sum(nb * wb for nb, wb in self.ell_buckets)),
+                "tail_edges": int(self.tl),
+                "halo_edges": int(self.eh),
+                "halo_rows": int(self.r),
+                "rows_sent": int(self.k * self.s),
+            },
+        }
+
     def wire_rows_per_exchange(self, schedule: str = "a2a",
                                replica: bool = False) -> int:
         """Padded rows the selected schedule puts on the wire per exchange,
@@ -1730,109 +1755,125 @@ def build_comm_plan(
     is purely a layout choice.  ``row_order='id'`` ranks by global id —
     required by the ``.r``-file emitter whose text formats assume it.
     """
-    a = sp.coo_matrix(a)
-    n = a.shape[0]
+    from ..obs.tracing import set_counter, span
+
     if row_order not in ("degree", "id"):
         raise ValueError(f"unknown row_order {row_order!r}")
-    key = None
-    if row_order == "degree":
-        ow = np.asarray(partvec, dtype=np.int64)
-        local_edge = ow[a.row] == ow[a.col]
-        key = np.bincount(a.row[local_edge], minlength=n)
-    owner, local_idx, part_sizes, b, row_valid = _relabel(
-        n, partvec, k, pad_rows_to, order_key=key)
+    with span("plan.relabel"):
+        a = sp.coo_matrix(a)
+        n = a.shape[0]
+        key = None
+        if row_order == "degree":
+            ow = np.asarray(partvec, dtype=np.int64)
+            local_edge = ow[a.row] == ow[a.col]
+            key = np.bincount(a.row[local_edge], minlength=n)
+        owner, local_idx, part_sizes, b, row_valid = _relabel(
+            n, partvec, k, pad_rows_to, order_key=key)
 
-    src_g, dst_g, w_g = a.col, a.row, a.data.astype(np.float32)
-    eo = owner[dst_g]                                   # chip owning each edge (by row)
+        src_g, dst_g, w_g = a.col, a.row, a.data.astype(np.float32)
+        eo = owner[dst_g]                   # chip owning each edge (by row)
 
-    # per-chip halo vertex lists, sorted by (owner, id)
-    halo_lists: list[np.ndarray] = []
-    for p in range(k):
-        em = eo == p
-        cols = src_g[em]
-        remote = cols[owner[cols] != p]
-        uniq = np.unique(remote)
-        uniq = uniq[np.lexsort((uniq, owner[uniq]))]
-        halo_lists.append(uniq)
-    halo_counts = np.array([len(h) for h in halo_lists], dtype=np.int32)
-    r = max(1, int(halo_counts.max()) if k else 1)
-
-    # send lists per ordered pair (p → q): vertices owned by p in q's halo
-    send_lists: dict[tuple[int, int], np.ndarray] = {}
-    s = 1
-    for q in range(k):
-        hq = halo_lists[q]
-        ho = owner[hq]
+    with span("plan.halo"):
+        # per-chip halo vertex lists, sorted by (owner, id)
+        halo_lists: list[np.ndarray] = []
         for p in range(k):
-            if p == q:
-                continue
-            vs = hq[ho == p]                           # already sorted by id
-            if len(vs):
-                send_lists[(p, q)] = vs
-                s = max(s, len(vs))
-    s = max(1, -(-s // pad_send_to) * pad_send_to)
+            em = eo == p
+            cols = src_g[em]
+            remote = cols[owner[cols] != p]
+            uniq = np.unique(remote)
+            uniq = uniq[np.lexsort((uniq, owner[uniq]))]
+            halo_lists.append(uniq)
+        halo_counts = np.array([len(h) for h in halo_lists], dtype=np.int32)
+        r = max(1, int(halo_counts.max()) if k else 1)
 
-    send_idx = np.zeros((k, k, s), dtype=np.int32)
-    send_counts = np.zeros((k, k), dtype=np.int32)
-    for (p, q), vs in send_lists.items():
-        send_idx[p, q, : len(vs)] = local_idx[vs]
-        send_counts[p, q] = len(vs)
+        # send lists per ordered pair (p → q): vertices owned by p in q's halo
+        send_lists: dict[tuple[int, int], np.ndarray] = {}
+        s = 1
+        for q in range(k):
+            hq = halo_lists[q]
+            ho = owner[hq]
+            for p in range(k):
+                if p == q:
+                    continue
+                vs = hq[ho == p]                       # already sorted by id
+                if len(vs):
+                    send_lists[(p, q)] = vs
+                    s = max(s, len(vs))
+        s = max(1, -(-s // pad_send_to) * pad_send_to)
 
-    # halo gather: chip p's halo row t' (owner q, position t in p's per-owner
-    # sublist == position in q→p send list) reads recv-flat slot q*S + t
-    halo_src = np.zeros((k, r), dtype=np.int32)
-    for p in range(k):
-        hp = halo_lists[p]
-        if not len(hp):
-            continue
-        ho = owner[hp]
-        pos = np.zeros(len(hp), dtype=np.int64)
-        for q in np.unique(ho):
-            m = ho == q
-            pos[m] = q * s + np.arange(m.sum())
-        halo_src[p, : len(hp)] = pos
+        send_idx = np.zeros((k, k, s), dtype=np.int32)
+        send_counts = np.zeros((k, k), dtype=np.int32)
+        for (p, q), vs in send_lists.items():
+            send_idx[p, q, : len(vs)] = local_idx[vs]
+            send_counts[p, q] = len(vs)
 
-    # per-chip padded edge lists
-    nnz = np.bincount(eo, minlength=k)
-    e = max(1, int(nnz.max()) if len(nnz) else 1)
-    # pad dst with the last row (b-1) so each chip's edge_dst stays globally
-    # non-decreasing — segment_sum is told indices_are_sorted=True
-    edge_dst = np.full((k, e), b - 1, dtype=np.int32)
-    edge_src = np.zeros((k, e), dtype=np.int32)
-    edge_w = np.zeros((k, e), dtype=np.float32)
-    for p in range(k):
-        em = eo == p
-        rows = local_idx[dst_g[em]].astype(np.int32)
-        cols = src_g[em]
-        vals = w_g[em]
-        co = owner[cols]
-        csrc = np.empty(len(cols), dtype=np.int32)
-        lm = co == p
-        csrc[lm] = local_idx[cols[lm]].astype(np.int32)
-        if (~lm).any():
-            # halo position via searchsorted on the (owner, id)-sorted halo list
+        # halo gather: chip p's halo row t' (owner q, position t in p's
+        # per-owner sublist == position in q→p send list) reads recv-flat
+        # slot q*S + t
+        halo_src = np.zeros((k, r), dtype=np.int32)
+        for p in range(k):
             hp = halo_lists[p]
-            keys = owner[hp] * (n + 1) + hp
-            qkeys = co[~lm] * (n + 1) + cols[~lm]
-            csrc[~lm] = b + np.searchsorted(keys, qkeys).astype(np.int32)
-        srt = np.argsort(rows, kind="stable")          # sorted dst → fast segsum
-        cnt = em.sum()
-        edge_dst[p, :cnt] = rows[srt]
-        edge_src[p, :cnt] = csrc[srt]
-        edge_w[p, :cnt] = vals[srt]
+            if not len(hp):
+                continue
+            ho = owner[hp]
+            pos = np.zeros(len(hp), dtype=np.int64)
+            for q in np.unique(ho):
+                m = ho == q
+                pos[m] = q * s + np.arange(m.sum())
+            halo_src[p, : len(hp)] = pos
 
-    split = _split_edges(edge_dst, edge_src, edge_w, nnz, b,
-                         halo_fold_key=(np.arange(k)[:, None]
-                                        - halo_src // s) % k)
-    ell = _build_ell(split["ledge_dst"], split["ledge_src"], split["ledge_w"],
-                     split["lnnz"], b, row_order=row_order)
-    return CommPlan(
+    with span("plan.edges"):
+        # per-chip padded edge lists
+        nnz = np.bincount(eo, minlength=k)
+        e = max(1, int(nnz.max()) if len(nnz) else 1)
+        # pad dst with the last row (b-1) so each chip's edge_dst stays
+        # globally non-decreasing — segment_sum is told
+        # indices_are_sorted=True
+        edge_dst = np.full((k, e), b - 1, dtype=np.int32)
+        edge_src = np.zeros((k, e), dtype=np.int32)
+        edge_w = np.zeros((k, e), dtype=np.float32)
+        for p in range(k):
+            em = eo == p
+            rows = local_idx[dst_g[em]].astype(np.int32)
+            cols = src_g[em]
+            vals = w_g[em]
+            co = owner[cols]
+            csrc = np.empty(len(cols), dtype=np.int32)
+            lm = co == p
+            csrc[lm] = local_idx[cols[lm]].astype(np.int32)
+            if (~lm).any():
+                # halo position via searchsorted on the (owner, id)-sorted
+                # halo list
+                hp = halo_lists[p]
+                keys = owner[hp] * (n + 1) + hp
+                qkeys = co[~lm] * (n + 1) + cols[~lm]
+                csrc[~lm] = b + np.searchsorted(keys, qkeys).astype(np.int32)
+            srt = np.argsort(rows, kind="stable")      # sorted dst → fast segsum
+            cnt = em.sum()
+            edge_dst[p, :cnt] = rows[srt]
+            edge_src[p, :cnt] = csrc[srt]
+            edge_w[p, :cnt] = vals[srt]
+
+        split = _split_edges(edge_dst, edge_src, edge_w, nnz, b,
+                             halo_fold_key=(np.arange(k)[:, None]
+                                            - halo_src // s) % k)
+    with span("plan.ell"):
+        ell = _build_ell(split["ledge_dst"], split["ledge_src"],
+                         split["ledge_w"], split["lnnz"], b,
+                         row_order=row_order)
+    with span("plan.symmetric"):
+        symmetric = _check_symmetric(a)
+    plan = CommPlan(
         n=n, k=k, b=b, s=s, r=r, e=e,
         owner=owner, local_idx=local_idx, part_sizes=part_sizes.astype(np.int64),
         send_idx=send_idx, send_counts=send_counts,
         halo_src=halo_src, halo_counts=halo_counts,
         edge_dst=edge_dst, edge_src=edge_src, edge_w=edge_w,
         nnz=nnz.astype(np.int64), row_valid=row_valid,
-        symmetric=_check_symmetric(a), row_order=row_order,
+        symmetric=symmetric, row_order=row_order,
         **split, **ell,
     )
+    # the padding happens here: leave what every chip executes beside what is
+    # true where a reader in this process finds it (obs.tracing.counters)
+    set_counter("plan.work_counts", plan.work_counts())
+    return plan

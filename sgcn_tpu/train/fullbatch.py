@@ -43,6 +43,7 @@ from ..models.gcn import (
     masked_sigmoid_bce_local,
     masked_softmax_xent_local,
 )
+from ..obs.tracing import scope, span
 from ..parallel.mesh import AXIS, make_mesh_1d, replicate, shard_stacked
 from ..parallel.plan import CommPlan
 from ..utils.stats import CommStats
@@ -809,17 +810,20 @@ class FullBatchTrainer:
 
         def loss_fn(ps):
             logits = fwd(ps, pa, h0)
-            loss = self._loss_fn(logits, labels, valid)
-            err = (masked_err_local(logits, labels, valid)
-                   if self.loss_name == "bce" else loss)
+            with scope("loss"):
+                loss = self._loss_fn(logits, labels, valid)
+                err = (masked_err_local(logits, labels, valid)
+                       if self.loss_name == "bce" else loss)
             return loss, err
 
         (loss, err), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         # dense weight-grad allreduce — GPU/PGCN.py:150-154 /
         # Parallel-GCN/main.c:422-425 (psum of local partials = full grad)
-        grads = jax.tree.map(lambda g: lax.psum(g, AXIS), grads)
-        updates, opt_state = self.opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with scope("grad_psum"):
+            grads = jax.tree.map(lambda g: lax.psum(g, AXIS), grads)
+        with scope("optimizer"):
+            updates, opt_state = self.opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if telemetry:
             gnorm = _global_grad_norm(grads)
             return params, opt_state, loss, err, gnorm
@@ -1875,14 +1879,18 @@ class FullBatchTrainer:
             self._step_count += 1
             self._record_step_event(loss, err, gnorm, sp.dur_s, drift=None)
             return loss
-        self.params, self.opt_state, loss, err = self._step(
-            self.params, self.opt_state, self.pa, data.h0, data.labels,
-            data.train_valid,
-        )
+        with span("step.dispatch"):
+            self.params, self.opt_state, loss, err = self._step(
+                self.params, self.opt_state, self.pa, data.h0, data.labels,
+                data.train_valid,
+            )
         self.last_err = err   # the MPI stack's `err` metric under loss='bce'
         self.stats.count_step(nlayers=self.nlayers)
         self._step_count += 1
-        return float(loss) if sync else loss
+        if not sync:
+            return loss
+        with span("step.readback"):
+            return float(loss)
 
     def evaluate(self, data: TrainData) -> tuple[float, float]:
         with self.spans.span("eval") as sp:

@@ -34,11 +34,20 @@ def use_cpu_devices(nparts: int) -> None:
 def place_compile_cache() -> str:
     """Point JAX's persistent compilation cache somewhere that survives the
     process; returns the directory in use.  Where ``JAX_COMPILATION_CACHE_DIR``
-    is set JAX reads it itself and this sets nothing; otherwise the cache
-    lives at ``COMPILE_CACHE_DIR``."""
+    is set JAX reads it itself and this sets no other; otherwise the cache
+    lives at ``COMPILE_CACHE_DIR``.
+
+    Either way op metadata is made part of the cache key.  By default JAX
+    strips it, so a program that differs from a cached one only in its
+    ``jax.named_scope``s (``obs.tracing.scope``) or source lines is handed
+    the cached executable WITH THE OLD NAMES, and a profile of it reads by
+    scopes the running program does not have (or lacks the ones it has:
+    measured on the chip, PR 25 — the parent commit's trace showed the
+    change's scopes through a shared cache directory)."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     return COMPILE_CACHE_DIR

@@ -3,9 +3,9 @@
 The reference accumulates ``data_comm / local_spmm / all_reduce / local_update``
 wall-clock per phase (``Cagnet/main.c:35-38,148-151,171-175,395-413``).  Under
 jit whole steps fuse into one program, so phase timing is host-side around
-block_until_ready boundaries; for intra-step attribution use
-``jax.profiler.trace`` (exposed via ``trace()``) and the trace parser in
-``sgcn_tpu.obs.tracing``.
+block_until_ready boundaries; for intra-step attribution take a
+``jax.profiler`` trace (``python -m sgcn_tpu.train --profile DIR``) and read it
+by the scopes and spans of ``sgcn_tpu.obs.tracing``.
 
 Nesting contract: phases may nest (the span API in ``obs/tracing.py`` wraps
 this timer, and a step-level span runs inside ``fit()``'s epoch phase).
@@ -75,10 +75,3 @@ class PhaseTimer:
                    "inclusive_s": self.inclusive[name]}
             for name in self.totals
         }
-
-    @staticmethod
-    @contextlib.contextmanager
-    def trace(logdir: str):
-        """Full XLA profiler trace (TensorBoard-viewable)."""
-        with jax.profiler.trace(logdir):
-            yield

@@ -61,6 +61,12 @@ SUBPROCESS_BUDGET_ALLOWLIST = {
                           "runs (docs/resilience.md); whole module "
                           "measured 127 s at PR-13 (ROADMAP budget note "
                           "re-measured accordingly)",
+    "test_scopes.py": "one python child that loads obs/tracing.py alone to "
+                      "prove the module imports without jax (~1 s, no mesh)",
+    "test_backend.py": "two pairs of CPU children sharing a temporary "
+                       "compile-cache directory, each compiling one 64x64 "
+                       "jit — whether a cached executable lends its scope "
+                       "names can only be seen across processes (~9 s)",
 }
 
 # Modules that run the static-analysis MATRIX auditor
