@@ -167,7 +167,12 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
         # SHRUNKEN nrep layout; the refresh (fresh=True) step ships the
         # full exact exchange
         rep_wire = bool(mode.replica) and not fresh
-        for i in range(L):                       # forward: every layer
+        # forward: every layer — but the exact full-batch trainer hoists an
+        # aggregate-first layer 0 out of the step (agg0_hoisted: Â·h0 is
+        # made once per data set), so its step starts at layer 1, the way
+        # bwd_layers below already drops layer 0's dead backward
+        fwd_layers = range(1 if trainer.agg0_hoisted else 0, L)
+        for i in fwd_layers:
             exp.exchanges += _exchange_ops(plan, mode.schedule, fs[i], fdt,
                                            replica=rep_wire)
         if mode.staleness or (mode.replica and fresh):

@@ -58,7 +58,14 @@ def exchange_widths(fin: int, widths) -> list[int]:
     """Per-layer exchanged/aggregated row width (lanes) under the
     project-first rule of ``gcn_forward_local`` — THE shared encoding of
     that rule for every cost model (bench roofline, shard epoch model);
-    change the forward's condition and this together."""
+    change the forward's condition and this together.
+
+    Where layer 0 is aggregate-first (``out[0] == fin``), the exact
+    full-batch GCN trainer pays layer 0's entry ONCE PER DATA SET, not per
+    step: ``agg(h0)`` is loop-invariant there and ``FullBatchTrainer``
+    hoists it (``gcn_forward_local(input_aggregated=True)``).  The entry
+    keeps its meaning — the width that exchange has whenever it runs (the
+    one-off build, the stale/replica families, serving, mini-batch)."""
     out, f = [], fin
     for w in widths:
         out.append(w if (w < f and f >= PROJECT_FIRST_MIN_FIN) else f)
@@ -80,52 +87,24 @@ def init_gcn_params(rng: jax.Array, dims: list[tuple[int, int]]):
     ]
 
 
-def gcn_forward_local(
-    params,
-    h,                      # (B, f_in) local feature rows
-    pa,                     # plan arrays dict (gcn_plan_fields(plan))
-    activation: str = "relu",
-    final_activation: str = "none",
+def _gcn_aggregator(
+    pa,
     symmetric: bool = False,
-    ell_buckets: tuple | None = None,   # static plan.ell_buckets (sym path)
-    pallas_tb: int | None = None,       # static: VMEM-kernel tile height —
-                                        # selects the Pallas aggregator
-    pallas_emulate: bool = False,       # static: jnp emulation (off-TPU shard_map CI)
-    pallas_lclasses: tuple | None = None,  # static: degree-binned local
-                                        # tile classes ((T,Emax,kern), ...)
-    pallas_hclasses: tuple | None = None,  # static: halo tile classes
-    halo_dtype: str | None = None,      # static: wire-only exchange dtype
-                                        # ('bfloat16' halves ICI bytes;
-                                        # tables/activations stay f32 —
-                                        # ops/pspmm.py::halo_exchange)
-    comm_schedule: str = "a2a",         # static: 'a2a' (dense all_to_all)
-                                        # or 'ragged' (per-round ppermute
-                                        # ring, docs/comm_schedule.md)
-    rr_sizes: tuple | None = None,      # static plan.rr_sizes (ragged)
-    rr_edge_sizes: tuple | None = None,  # static plan.rr_edge_sizes (ragged)
+    ell_buckets: tuple | None = None,
+    pallas_tb: int | None = None,
+    pallas_emulate: bool = False,
+    pallas_lclasses: tuple | None = None,
+    pallas_hclasses: tuple | None = None,
+    halo_dtype: str | None = None,
+    comm_schedule: str = "a2a",
+    rr_sizes: tuple | None = None,
+    rr_edge_sizes: tuple | None = None,
     axis_name: str = AXIS,
 ):
-    """Per-chip forward: L × (pspmm ⊗ dense matmul → activation) → (B, nout).
-
-    Aggregation uses ``pspmm_overlap`` — the split-edge-list formulation in
-    which the local SpMM has no data dependence on the halo ``all_to_all``,
-    so XLA overlaps communication with compute the way the MPI trainer's
-    Irecv/compute/Waitany loop does (``Parallel-GCN/main.c:238-299``).
-
-    Op order per layer exploits associativity: ``(Â·H)·W = Â·(H·W)``.  When
-    the input is wide and the output narrower, the dense projection runs
-    FIRST, so the halo exchange ships ``fout``-wide rows and the gather-bound
-    SpMM touches ``fout``-wide features — both comm volume and the hot gather
-    shrink by ``fout/fin`` (measured 2.7× per layer for cora-like 1433-wide
-    inputs on v5e).  Below ~256 floats/row the gather is access-bound, not
-    byte-bound (rows are shorter than an HBM burst), so narrowing does not
-    pay and aggregate-first (the reference's fixed order,
-    ``GPU/PGCN.py:144-148``) is kept.  Identical math either way.
-    """
-    act = get_activation(activation)
-    fact = get_activation(final_activation)
-    nl = len(params)
-
+    """``agg(x) = Â·x`` (halo exchange + local fold) for one plan and one
+    set of statics (``gcn_forward_local`` documents them) — the ONE
+    kernel/transport selection of the exact GCN path, shared by the layer
+    loop and by the one-off ``gcn_aggregate_local``."""
     if comm_schedule not in ("a2a", "ragged"):
         raise ValueError(f"unknown comm_schedule {comm_schedule!r} "
                          "(the trainer resolves 'auto' before the forward)")
@@ -200,18 +179,104 @@ def gcn_forward_local(
                 pa["hedge_dst"], pa["hedge_src"], pa["hedge_w"],
                 axis_name=axis_name, halo_dtype=halo_dtype)
 
+    return agg
+
+
+def gcn_forward_local(
+    params,
+    h,                      # (B, f_in) local feature rows
+    pa,                     # plan arrays dict (gcn_plan_fields(plan))
+    activation: str = "relu",
+    final_activation: str = "none",
+    symmetric: bool = False,
+    ell_buckets: tuple | None = None,   # static plan.ell_buckets (sym path)
+    pallas_tb: int | None = None,       # static: VMEM-kernel tile height —
+                                        # selects the Pallas aggregator
+    pallas_emulate: bool = False,       # static: jnp emulation (off-TPU shard_map CI)
+    pallas_lclasses: tuple | None = None,  # static: degree-binned local
+                                        # tile classes ((T,Emax,kern), ...)
+    pallas_hclasses: tuple | None = None,  # static: halo tile classes
+    halo_dtype: str | None = None,      # static: wire-only exchange dtype
+                                        # ('bfloat16' halves ICI bytes;
+                                        # tables/activations stay f32 —
+                                        # ops/pspmm.py::halo_exchange)
+    comm_schedule: str = "a2a",         # static: 'a2a' (dense all_to_all)
+                                        # or 'ragged' (per-round ppermute
+                                        # ring, docs/comm_schedule.md)
+    rr_sizes: tuple | None = None,      # static plan.rr_sizes (ragged)
+    rr_edge_sizes: tuple | None = None,  # static plan.rr_edge_sizes (ragged)
+    axis_name: str = AXIS,
+    input_aggregated: bool = False,     # static: ``h`` is already Â·h0
+                                        # (gcn_aggregate_local) — layer 0
+                                        # goes straight to its dense product
+):
+    """Per-chip forward: L × (pspmm ⊗ dense matmul → activation) → (B, nout).
+
+    Aggregation uses ``pspmm_overlap`` — the split-edge-list formulation in
+    which the local SpMM has no data dependence on the halo ``all_to_all``,
+    so XLA overlaps communication with compute the way the MPI trainer's
+    Irecv/compute/Waitany loop does (``Parallel-GCN/main.c:238-299``).
+
+    Op order per layer exploits associativity: ``(Â·H)·W = Â·(H·W)``.  When
+    the input is wide and the output narrower, the dense projection runs
+    FIRST, so the halo exchange ships ``fout``-wide rows and the gather-bound
+    SpMM touches ``fout``-wide features — both comm volume and the hot gather
+    shrink by ``fout/fin`` (measured 2.7× per layer for cora-like 1433-wide
+    inputs on v5e).  Below ~256 floats/row the gather is access-bound, not
+    byte-bound (rows are shorter than an HBM burst), so narrowing does not
+    pay and aggregate-first (the reference's fixed order,
+    ``GPU/PGCN.py:144-148``) is kept.  Identical math either way.
+
+    ``input_aggregated=True`` says ``h`` is not ``h0`` but ``agg(h0)``,
+    made once by ``gcn_aggregate_local`` with these same statics: layer 0
+    then skips its aggregation (in full-batch training ``h0`` and Â never
+    change, so ``Â·h0`` is the same array every step — and with it go layer
+    0's exchange and folds, forward and, under ``remat``, backward).  Legal
+    only where layer 0 is aggregate-first (``exchange_widths(fin,
+    widths)[0] == fin``): under project-first layer 0 aggregates
+    ``h0·W⁰``, which moves with the weights, and the call raises.  Off (the
+    default) is the program every caller compiled before the argument
+    existed; only the exact full-batch trainer sets it.
+    """
+    act = get_activation(activation)
+    fact = get_activation(final_activation)
+    nl = len(params)
+
+    agg = _gcn_aggregator(
+        pa, symmetric=symmetric, ell_buckets=ell_buckets,
+        pallas_tb=pallas_tb, pallas_emulate=pallas_emulate,
+        pallas_lclasses=pallas_lclasses, pallas_hclasses=pallas_hclasses,
+        halo_dtype=halo_dtype, comm_schedule=comm_schedule,
+        rr_sizes=rr_sizes, rr_edge_sizes=rr_edge_sizes, axis_name=axis_name)
+
     for i, w in enumerate(params):
         with scope("layer", i):
             if w.shape[1] < h.shape[1] and h.shape[1] >= PROJECT_FIRST_MIN_FIN:
+                if i == 0 and input_aggregated:
+                    raise ValueError(
+                        "input_aggregated needs an aggregate-first layer 0; "
+                        f"widths {h.shape[1]} -> {w.shape[1]} project first, "
+                        "and agg(h0 @ W) moves with the weights")
                 with scope("dense"):
                     x = h @ w
                 z = agg(x)
             else:
-                z = agg(h)
+                z = h if (i == 0 and input_aggregated) else agg(h)
                 with scope("dense"):
                     z = z @ w
             h = fact(z) if i == nl - 1 else act(z)
     return h
+
+
+def gcn_aggregate_local(h, pa, **statics):
+    """``agg(h0)`` alone: layer 0's aggregation of ``gcn_forward_local`` as
+    a per-chip program of its own, for ``input_aggregated=True``.
+    ``statics`` are the forward's aggregator statics (``symmetric``,
+    ``ell_buckets``, the Pallas and ragged ones, ``halo_dtype``), so the
+    same kernel and the same wire make the array, under the scopes layer 0
+    has in the step (``sgcn.layer0/agg_slots``, ...)."""
+    with scope("layer", 0):
+        return _gcn_aggregator(pa, **statics)(h)
 
 
 def gcn_forward_local_stale(

@@ -23,6 +23,7 @@ the compiler-native form of the reference's Irecv/compute/Waitany overlap
 from __future__ import annotations
 
 import contextlib
+import weakref
 from dataclasses import dataclass
 from typing import Any
 
@@ -35,6 +36,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
+    exchange_widths,
+    gcn_aggregate_local,
     gcn_forward_local,
     gcn_plan_fields,
     init_gcn_params,
@@ -43,7 +46,7 @@ from ..models.gcn import (
     masked_sigmoid_bce_local,
     masked_softmax_xent_local,
 )
-from ..obs.tracing import scope, span
+from ..obs.tracing import scope, set_counter, span
 from ..parallel.mesh import AXIS, make_mesh_1d, replicate, shard_stacked
 from ..parallel.plan import CommPlan
 from ..utils.stats import CommStats
@@ -390,7 +393,30 @@ def _global_grad_norm(grads):
 
 
 class FullBatchTrainer:
-    """Distributed full-batch trainer (PGCN-equivalent, ``-b jax`` backend)."""
+    """Distributed full-batch trainer (PGCN-equivalent, ``-b jax`` backend).
+
+    **Layer 0's aggregation is paid once per data set, not per step**, on
+    the exact GCN path (``model='gcn'``, ``halo_staleness=0``,
+    ``replica_budget=0``) wherever layer 0 is aggregate-first
+    (``exchange_widths(fin, widths)[0] == fin``): full-batch ``h0`` and Â
+    never change, so ``Â·h0`` is loop-invariant.  ``agg0_hoisted`` says
+    whether that holds for this trainer.  Where it does, the first
+    ``step`` / ``run_epochs`` / ``evaluate`` / ``predict`` that sees a
+    given ``data.h0`` (by identity, not value — do not mutate it in place)
+    runs the aggregation alone (``_agg0_for``: the forward's own aggregator,
+    statics and mesh; compiled, run once and dropped, host span
+    ``agg0.build``), keeps the ``(k, B, fin)`` f32 result on the device and
+    passes it where ``data.h0`` went, to step / epoch-loop / eval /
+    telemetry programs traced with ``input_aggregated=True`` — one
+    exchange, one slot pass and one fold fewer per step, same arithmetic in
+    the same order.  The caller's ``h0`` is never touched.  Counter
+    ``agg0`` (``obs.tracing.counters``) reports engaged / builds /
+    steps_served.  Not covered, and lowering exactly as before: the stale
+    and replica step families (their layer-0 exchange feeds a carried
+    table with contracts of its own), GAT (its aggregation depends on the
+    parameters), project-first layer 0, ``ServeEngine``, and
+    ``MiniBatchTrainer``, which turns ``agg0_hoisted`` off on its inner
+    trainer before any program is traced (its plan changes per batch)."""
 
     def __init__(
         self,
@@ -667,6 +693,15 @@ class FullBatchTrainer:
                 else 0,
                 dtype=compute_dtype)
         self.model = model
+        # layer 0's Â·h0 is loop-invariant on the exact GCN path with an
+        # aggregate-first layer 0 (class docstring): decided here from what
+        # the trainer can observe, read by the programs when they are traced
+        self.agg0_hoisted = (model == "gcn" and not halo_staleness
+                             and not replica_budget
+                             and exchange_widths(fin, widths)[0] == fin)
+        self._agg0 = None           # (k, B, fin) f32 Â·h0, on the device
+        self._agg0_src = None       # weakref to the data.h0 it was made from
+        self._agg0_builds = self._agg0_served = 0
         self.loss_name = loss
         self._loss_fn = LOSSES[loss]
         dims = list(zip([fin] + widths[:-1], widths))
@@ -689,7 +724,6 @@ class FullBatchTrainer:
                 self.widths, compute_dtype))
             wire_itemsize = wire_itemsize_bwd = 4   # lanes encode the dtype
         else:
-            from ..models.gcn import exchange_widths
             lane_widths = tuple(exchange_widths(fin, self.widths))
             # per-DIRECTION wire itemsize (docs/observability.md): the
             # halo-delta cache narrows only the FEATURE wire (and only on
@@ -779,28 +813,80 @@ class FullBatchTrainer:
             self._multi_rep = {}     # epochs -> compiled replica epoch loop
 
     # ------------------------------------------------------------------ build
-    def _forward(self, params, pa, h0):
-        if self.compute_dtype is not None:
-            dt = jnp.dtype(self.compute_dtype)
-            params = jax.tree.map(lambda w: w.astype(dt), params)
-            h0 = h0.astype(dt)
-            pa = {k: v.astype(dt) if v.dtype == jnp.float32 else v
-                  for k, v in pa.items()}
+    def _cast(self, params, pa, h0):
+        """``compute_dtype``'s narrowing of everything the forward reads."""
+        if self.compute_dtype is None:
+            return params, pa, h0
+        dt = jnp.dtype(self.compute_dtype)
+        params = jax.tree.map(lambda w: w.astype(dt), params)
+        pa = {k: v.astype(dt) if v.dtype == jnp.float32 else v
+              for k, v in pa.items()}
+        return params, pa, h0.astype(dt)
+
+    def _agg_statics(self) -> dict:
+        """The forward's aggregator statics (kernel, transport, wire)."""
         extra = ({"halo_dtype": self.halo_dtype}
                  if self.halo_dtype is not None else {})
+        return dict(symmetric=self.plan.symmetric, **self._fwd_static,
+                    **extra)
+
+    def _forward(self, params, pa, h0):
+        """Per-chip logits.  On an ``agg0_hoisted`` trainer ``h0`` is
+        ``_agg0_for(data.h0)`` and layer 0 starts at its dense product."""
+        params, pa, h0 = self._cast(params, pa, h0)
+        hoisted = ({"input_aggregated": True} if self.agg0_hoisted else {})
         out = self._forward_fn(
             params, h0, pa,
             activation=self.activation,
             final_activation=self.final_activation,
-            symmetric=self.plan.symmetric,
-            **self._fwd_static,
-            **extra,
+            **self._agg_statics(),
+            **hoisted,
         )
         return out.astype("float32")
+
+    # ------------------------------------------------- hoisted layer-0 Â·h0
+    def _agg0_for(self, h0, served: int = 1):
+        """What the exact programs take where ``data.h0`` went: ``h0``
+        itself, or on an ``agg0_hoisted`` trainer ``Â·h0``, built the first
+        time this ``h0`` object is seen.  ``served`` counts the steps /
+        forwards the caller is about to run on it (counter ``agg0``).
+
+        The build is the forward's own aggregator under the forward's
+        ``shard_map``, compiled ahead of time, called once and dropped: no
+        ``jit`` cache keeps the executable, so its temporaries do not stay
+        reserved beside the step program's (PERF.md §2), and building
+        before the step is first dispatched means the two are never loaded
+        together."""
+        if self.agg0_hoisted and (self._agg0_src is None
+                                  or self._agg0_src() is not h0):
+            # the old array goes before the new one comes
+            self._agg0 = self._agg0_src = None
+            with self.spans.span("agg0.build", sync=lambda: self._agg0):
+                def per_chip(pa, h0):
+                    pa, h0 = _unblock((pa, h0))
+                    _, pa, h0 = self._cast((), pa, h0)
+                    out = gcn_aggregate_local(h0, pa, **self._agg_statics())
+                    return out.astype("float32")[None]
+
+                build = jax.jit(jax.shard_map(
+                    per_chip, mesh=self.mesh, in_specs=(P(AXIS), P(AXIS)),
+                    out_specs=P(AXIS))).lower(self.pa, h0).compile()
+                self._agg0 = build(self.pa, h0)
+                del build
+            self._agg0_src = weakref.ref(h0)
+            self._agg0_builds += 1
+        if self.agg0_hoisted:
+            self._agg0_served += served
+        set_counter("agg0", {
+            "engaged": self.agg0_hoisted, "builds": self._agg0_builds,
+            "steps_served": self._agg0_served,
+            "rows": int(self.plan.b * self.plan.k), "width": int(self.fin)})
+        return self._agg0 if self.agg0_hoisted else h0
 
     def _one_step(self, params, opt_state, pa, h0, labels, valid,
                   telemetry: bool = False):
         """One per-chip training step (shared by _build_step/_build_multi).
+        ``h0`` is what ``_agg0_for(data.h0)`` gave (``_forward``).
 
         ``telemetry=True`` (the program compiled by ``attach_recorder``)
         additionally returns the global L2 norm of the psum'd weight grads
@@ -1348,7 +1434,9 @@ class FullBatchTrainer:
         host does not have); ``None`` uses the trainer's own mesh.  Inputs
         are ShapeDtypeStructs shaped like this trainer's live arrays, so
         the lowered module is exactly the program ``step()`` runs, just
-        targeted at the given topology.
+        targeted at the given topology — on an ``agg0_hoisted`` trainer the
+        step that takes ``Â·h0`` (same shape and dtype as ``h0``) and holds
+        no layer-0 aggregation.
 
         ``kind`` selects which of the trainer's step programs to lower:
         ``'step'`` the exact-mode step; ``'stale'`` / ``'sync'`` the
@@ -1479,8 +1567,8 @@ class FullBatchTrainer:
         if epochs not in self._multi:
             self._multi[epochs] = self._build_multi(epochs)
         self.params, self.opt_state, losses, errs = self._multi[epochs](
-            self.params, self.opt_state, self.pa, data.h0, data.labels,
-            data.train_valid,
+            self.params, self.opt_state, self.pa,
+            self._agg0_for(data.h0, epochs), data.labels, data.train_valid,
         )
         self.last_err = errs[-1]        # keep step()'s scalar contract
         for _ in range(epochs):
@@ -1866,11 +1954,13 @@ class FullBatchTrainer:
                             if rrows is not None else None)))
                 return loss
             return float(loss) if sync else loss
+        h_in = self._agg0_for(data.h0)      # before the step's spans: the
+        # one-off build (span agg0.build) is set-up, not part of a step
         if self.recorder is not None:
             with self.spans.span("step", step=self._step_count + 1) as sp:
                 self.params, self.opt_state, loss, err, gnorm = \
                     self._step_tel(
-                        self.params, self.opt_state, self.pa, data.h0,
+                        self.params, self.opt_state, self.pa, h_in,
                         data.labels, data.train_valid,
                     )
                 loss = float(loss)      # readback = the span's sync point
@@ -1881,7 +1971,7 @@ class FullBatchTrainer:
             return loss
         with span("step.dispatch"):
             self.params, self.opt_state, loss, err = self._step(
-                self.params, self.opt_state, self.pa, data.h0, data.labels,
+                self.params, self.opt_state, self.pa, h_in, data.labels,
                 data.train_valid,
             )
         self.last_err = err   # the MPI stack's `err` metric under loss='bce'
@@ -1893,9 +1983,10 @@ class FullBatchTrainer:
             return float(loss)
 
     def evaluate(self, data: TrainData) -> tuple[float, float]:
+        h_in = self._agg0_for(data.h0)
         with self.spans.span("eval") as sp:
             loss, acc, _ = self._eval(
-                self.params, self.pa, data.h0, data.labels, data.eval_valid
+                self.params, self.pa, h_in, data.labels, data.eval_valid
             )
             loss, acc = float(loss), float(acc)
         self.stats.count_forward(nlayers=self.nlayers)
@@ -1907,7 +1998,8 @@ class FullBatchTrainer:
     def predict(self, data: TrainData) -> np.ndarray:
         """Global (n, nout) logits in original vertex order."""
         _, _, logits = self._eval(
-            self.params, self.pa, data.h0, data.labels, data.eval_valid
+            self.params, self.pa, self._agg0_for(data.h0), data.labels,
+            data.eval_valid
         )
         self.stats.count_forward(nlayers=self.nlayers)
         return self.plan.gather_rows(np.asarray(logits))

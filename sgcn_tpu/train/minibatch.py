@@ -179,6 +179,10 @@ class MiniBatchTrainer:
             optimizer=optimizer, seed=seed,
             compute_dtype=compute_dtype, comm_schedule=comm_schedule,
             allow_pallas=False, memory_budget=memory_budget)
+        # every batch brings its own Â and h0, so Â·h0 is not loop-invariant
+        # here: the inner trainer's programs keep layer 0's aggregation
+        # (switched off before any of them is traced)
+        self.inner.agg0_hoisted = False
         # the inner trainer's plan IS the shared envelope every batch pads
         # to, so its analytic footprint (obs/memory.py) covers every batch's
         # step — the --memory-budget gate above already held it to account
@@ -473,6 +477,8 @@ class MiniBatchTrainer:
                 mesh=self.mesh, activation=self.inner.activation,
                 model=self.inner.model, loss=self.inner.loss_name,
                 compute_dtype=self.inner.compute_dtype))
+            # new data every call: nothing to hoist (cf. __init__)
+            self._fullgraph_eval[1].agg0_hoisted = False
         plan, tr = self._fullgraph_eval
         tr.params = self.inner.params
         data = make_train_data(plan, features, labels,

@@ -142,15 +142,16 @@ def test_replica_modes_audit_both_programs_and_shrink_the_wire(full_report):
 def test_empty_rounds_elided_in_census(full_report):
     """The banded fixture keeps 2 of k−1 ring rounds; the compiled ragged
     program must carry collective_permutes for EXACTLY the live rounds.
-    Exact mode: 3 exchanges (2 fwd + 1 bwd — aggregate-first layer 0's
-    backward exchange is dead code) × 2 live rounds; stale mode: 4
-    exchanges × 2."""
+    Exact mode: 2 exchanges (layer 1 forward + backward — aggregate-first
+    layer 0's backward exchange is dead code, and since PR 26 its forward
+    exchange is hoisted out of the step: ``agg0_hoisted``; 3 exchanges × 2
+    before) × 2 live rounds; stale mode: 4 exchanges × 2."""
     from sgcn_tpu.ops.pspmm import ragged_live_rounds
 
     live = ragged_live_rounds(audit_plan("banded").ragged_round_sizes())
     assert len(live) == 2
     exact = full_report["modes"]["train/gcn/ragged/s0/f32@banded"]
-    assert exact["programs"]["step"]["census"]["collective_permute"] == 6
+    assert exact["programs"]["step"]["census"]["collective_permute"] == 4
     stale = full_report["modes"]["train/gcn/ragged/s1/f32@banded"]
     for prog in stale["programs"].values():
         assert prog["census"]["collective_permute"] == 8

@@ -36,7 +36,7 @@ def _lowered_a2a_widths(fin, widths):
     dims = [int(m.group(1)) for m in re.finditer(
         r'stablehlo\.all_to_all.*?->\s*tensor<\d+x\d+x(\d+)xf32>', txt)]
     assert dims, "no all_to_all in lowered step"
-    return sorted(set(dims))
+    return sorted(set(dims)), tr.agg0_hoisted
 
 
 @pytest.mark.parametrize("fin,widths", [
@@ -44,6 +44,9 @@ def _lowered_a2a_widths(fin, widths):
     (300, [8, 4]),         # wide input: layer 1 projects first, ships 8
 ])
 def test_exchange_widths_match_lowered_program(fin, widths):
-    want = sorted(set(exchange_widths(fin, widths)))
-    got = _lowered_a2a_widths(fin, widths)
-    assert got == want, (got, want)
+    want = exchange_widths(fin, widths)
+    got, hoisted = _lowered_a2a_widths(fin, widths)
+    # an aggregate-first layer 0 is hoisted out of the exact step (PR 26):
+    # its entry is paid once per data set, the step ships layers 1.. only
+    assert hoisted == (want[0] == fin)
+    assert got == sorted(set(want[1:] if hoisted else want)), (got, want)

@@ -103,11 +103,17 @@ def test_v5e_compiled_step_scopes_every_gather_scatter_and_exchange(
                for ins, op in OP.findall(text))
     found = {_leaf(op) for _, op in OP.findall(text)} - {None}
     assert found == set(LEAVES), set(LEAVES) - found
-    # forward and backward of both layers are told apart by token
+    # forward and backward are told apart by token
     assert any("transpose(" in op and "sgcn.layer1" in op
                and _leaf(op) == "agg_slots" for _, op in hot)
-    assert any("transpose(" not in op and "sgcn.layer0" in op
+    assert any("transpose(" not in op and "sgcn.layer1" in op
                and _leaf(op) == "agg_tail" for _, op in hot)
+    # layer 0's aggregation is hoisted out of the step (PR 26): the step
+    # keeps its dense product and none of its gathers, folds or exchanges
+    assert tr.agg0_hoisted
+    assert not [op for _, op in hot if "sgcn.layer0" in op]
+    assert any("sgcn.layer0" in op and _leaf(op) == "dense"
+               for _, op in OP.findall(text))
 
 
 # ------------------------------------------------------ (b) the lowered step
