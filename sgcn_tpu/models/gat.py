@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from ..ops.pspmm import (a2a_or_identity, halo_exchange, halo_exchange_ragged,
                          halo_exchange_ragged_multi)
-from ..parallel.mesh import AXIS
+from ..parallel.mesh import AXIS, vary
 from .activations import get_activation
 
 # plan arrays the GAT forward consumes (fullbatch ships exactly these):
@@ -891,9 +891,7 @@ def gat_forward_local(
         # the primals; params arrive replicated (unvarying) but the bwd
         # produces per-chip PARTIAL grads (varying — the trainer completes
         # them with its psum), so cast the primals to varying first
-        params = [
-            jax.tree.map(lambda x: jax.lax.pcast(x, axis_name, to="varying"),
-                         p) for p in params]
+        params = vary(params, axis_name)
     cgs = []
     for i, p in enumerate(params):
         if collect_stabilizers:

@@ -217,6 +217,9 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
 
     families: dict[str, int] = {}
     families["params"] = model_param_bytes(fin, widths, model=model)
+    custom = setup.custom       # a model with a setup hook prices itself
+    if custom is not None:
+        families["params"] = 4 * custom.param_count
     # Adam: count scalar + one mu and one nu tree (optax.adam — the only
     # optimizer the CLIs construct); inference carries no optimizer state
     families["opt_state"] = (2 * families["params"] + 4) if train else 0
@@ -238,6 +241,11 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
         from ..models.gat import gat_exchange_lane_widths
         lane_widths = list(gat_exchange_lane_widths(widths, compute_dtype))
         wire_isize = 4                        # lanes encode the dtype
+    elif custom is not None:
+        # the wider of the two directions' tables, layer by layer
+        lane_widths = [max(f, g) for f, g in zip(
+            custom.lane_widths, custom.lane_widths_bwd or custom.lane_widths)]
+        wire_isize = 4
     else:
         from ..models.gcn import exchange_widths
         lane_widths = list(exchange_widths(fin, widths))
@@ -283,6 +291,13 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
         slots = (sum(nb * wb for nb, wb in plan.cell_buckets)
                  + int(plan.ctl or 0)) if plan.cell_buckets is not None else 0
         workspace += npass * slots * max(lane_widths) * compute_isize
+    if custom is not None:
+        # the model's own itemised estimate (mhgat: per-row state kept for
+        # the backward and whole-row slot temporaries) replaces the
+        # activation-mirror figure
+        est = custom.estimate_memory(train=train)
+        workspace = (est["rows_kept"] + est["rows_transient"]
+                     + est["slot_temps"])
     if pallas:
         # the VMEM kernel family's per-tile-block working set (operand
         # windows + accumulator at the tile row count ``pallas_tb``) — in

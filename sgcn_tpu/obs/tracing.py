@@ -79,9 +79,17 @@ SCOPES = ("layer", "dense", "xchg_pack", "xchg_a2a", "xchg_unpack",
           "agg_slots", "agg_tail", "agg_halo_fold", "loss", "grad_psum",
           "optimizer")
 
+# Sub-scopes of the multi-head attention layer (``models/mhgat.py``): what is
+# new in that layer's work, named INSIDE the leaf scope whose work it is
+# (``sgcn.agg_slots/sgcn.att_score/...``).  A reader of ``SCOPES`` skips
+# them (a token outside its vocabulary is no scope), so the op still books
+# to its leaf.  ``benchmark/scopes_att.json`` is the benchmark's own copy.
+SUBSCOPES = ("att_project", "att_max", "att_score", "att_norm")
+
 _spans: dict = {}           # name -> {count, total_s, parent, durations}
 _spans_lock = threading.Lock()  # spans close on more than one thread
-_open = threading.local()   # .stack: this thread's open span names
+_open = threading.local()   # .stack: this thread's open span names;
+#                             .leaves: its open leaf scopes, while tracing
 _counters: dict = {}
 
 
@@ -90,10 +98,36 @@ def scope(name: str, index: int | None = None):
     HLO metadata only, no arithmetic changes."""
     if name not in SCOPES:
         raise ValueError(f"unknown scope {name!r}; the vocabulary is {SCOPES}")
+    return _named(f"{PREFIX}{name}{'' if index is None else int(index)}",
+                  leaf=name != SCOPES[0])
+
+
+@contextlib.contextmanager
+def _named(full: str, leaf: bool):
+    """The named scope, counted while open if it is a leaf (``subscope``)."""
     import jax
 
-    return jax.named_scope(
-        f"{PREFIX}{name}{'' if index is None else int(index)}")
+    _open.leaves = getattr(_open, "leaves", 0) + leaf
+    try:
+        with jax.named_scope(full):
+            yield
+    finally:
+        _open.leaves -= leaf
+
+
+def subscope(name: str):
+    """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``, legal
+    only inside a leaf ``scope`` — a sub-scope on its own would leave its
+    ops unscoped for every reader of ``SCOPES``."""
+    if name not in SUBSCOPES:
+        raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
+                         f"{SUBSCOPES}")
+    if not getattr(_open, "leaves", 0):
+        raise ValueError(f"sub-scope {name!r} opened outside a leaf scope "
+                         f"of {SCOPES[1:]}")
+    import jax
+
+    return jax.named_scope(PREFIX + name)
 
 
 @contextlib.contextmanager

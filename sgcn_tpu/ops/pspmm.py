@@ -47,7 +47,8 @@ _SCAN_LIVE_LIMIT = 3 * 1024**3
 
 
 def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
-                         slot_bytes, scan_live_limit: int | None = None):
+                         slot_bytes, scan_live_limit: int | None = None,
+                         combine=jnp.add, with_rows: bool = False):
     """Σ over width slots of ``contrib(idx_t, w_t)`` per bucket — THE shared
     memory policy for every bucketed width-major layout (GCN SpMM, GAT
     attention passes).
@@ -71,21 +72,26 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     scan-unroll liveness budget below the default — for callers that run
     SEVERAL slot reduces in one program (the GAT num/den passes): at
     products scale each pass unrolling to the full budget measured as the
-    difference between fitting and a 264 MB OOM.  Returns the per-bucket
-    reduced pytrees in bucket order.
+    difference between fitting and a 264 MB OOM.  ``combine`` is the
+    reduction (``jnp.maximum`` for the attention layer's max pass, with an
+    ``init`` of its identity); ``with_rows=True`` hands ``contrib`` and
+    ``init`` the bucket's first output row as a third / second argument
+    (a static int: the attention passes slice their per-destination
+    scalars by it).  Returns the per-bucket reduced pytrees in bucket order.
     """
     live_limit = (_SCAN_LIVE_LIMIT if scan_live_limit is None
                   else scan_live_limit)
     outs = []
-    off = 0
+    off = row = 0
     for nb, wb in buckets:
+        at = (row,) if with_rows else ()
         if (min(wb, _SCHED_OVERLAP_SLOTS) * slot_bytes(nb)
                 <= _CONCURRENT_TEMP_LIMIT) or wb <= 2:
             acc = None
             for t in range(wb):
                 seg = slice(off + t * nb, off + (t + 1) * nb)
-                c = contrib(flat_idx[seg], flat_w[seg])
-                acc = c if acc is None else jax.tree.map(jnp.add, acc, c)
+                c = contrib(flat_idx[seg], flat_w[seg], *at)
+                acc = c if acc is None else jax.tree.map(combine, acc, c)
         else:
             seg_i = flat_idx[off: off + nb * wb].reshape(wb, nb)
             seg_w = flat_w[off: off + nb * wb].reshape(wb, nb)
@@ -95,11 +101,13 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
             # so (unlike 0·h[0,0]) an inf/NaN activation cannot poison it
             zero = seg_i[0, 0] * 0
 
-            def body(carry, iw):
+            def body(carry, iw, at=at):
                 i_t, w_t = iw
-                return jax.tree.map(jnp.add, carry, contrib(i_t, w_t)), None
+                return jax.tree.map(combine, carry,
+                                    contrib(i_t, w_t, *at)), None
 
-            acc0 = jax.tree.map(lambda x: x + zero.astype(x.dtype), init(nb))
+            acc0 = jax.tree.map(lambda x: x + zero.astype(x.dtype),
+                                init(nb, *at))
             # cap 8 measured OOM at ogbn-products f32 (16.59/15.75 GB): the
             # budget models only slot temps, and the rest of the epoch
             # program leaves < _SCAN_LIVE_LIMIT of true headroom there
@@ -107,6 +115,7 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
             acc, _ = jax.lax.scan(body, acc0, (seg_i, seg_w), unroll=unroll)
         outs.append(acc)
         off += nb * wb
+        row += nb
     return outs
 
 
@@ -143,6 +152,26 @@ def halo_exchange(h, send_idx, halo_src, axis_name: str = AXIS,
     with scope("xchg_unpack"):
         flat = recv.reshape(-1, h.shape[-1])                # (k*S, f)
         return jnp.take(flat, halo_src, axis=0).astype(h.dtype)  # (R, f)
+
+
+def halo_exchange_multi(parts, send_idx, halo_src, axis_name: str = AXIS):
+    """``halo_exchange`` of several row tables that share their rows, as ONE
+    ``all_to_all``: each part's send rows are gathered on their own and
+    concatenated at buffer size ``(k, S, Σf)`` — the ``(B, Σf)`` table is
+    never built (at 516 lanes it would be tile-padded to 640) — and the
+    halo block is split back into ``len(parts)`` arrays ``(R, f_p)``.  The
+    multi-head attention layer ships ``[Z ‖ t]`` forward and
+    ``[g ‖ s, m, 1/D, c]`` backward this way (``models/mhgat.py``)."""
+    widths = [p.shape[-1] for p in parts]
+    with scope("xchg_pack"):
+        buf = jnp.concatenate(
+            [jnp.take(p, send_idx, axis=0) for p in parts], axis=-1)
+    with scope("xchg_a2a"):
+        recv = a2a_or_identity(buf, axis_name)
+    with scope("xchg_unpack"):
+        halo = jnp.take(recv.reshape(-1, sum(widths)), halo_src, axis=0)
+        cuts = [sum(widths[:i]) for i in range(len(widths) + 1)]
+        return tuple(halo[:, a:b] for a, b in zip(cuts, cuts[1:]))
 
 
 def ragged_live_rounds(rr_sizes) -> tuple:

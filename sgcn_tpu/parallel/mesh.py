@@ -77,3 +77,19 @@ def replicate(mesh: Mesh, tree):
         return jax.make_array_from_process_local_data(sh, x, x.shape)
 
     return jax.tree.map(put, tree)
+
+
+def vary(tree, axis_name: str = AXIS):
+    """``tree`` with every leaf varying over ``axis_name`` (inside
+    ``shard_map``): a replicated leaf is cast (``lax.pcast``), one that
+    already varies is left alone (casting it again is an error).  A
+    function differentiated with respect to the result returns PER-CHIP
+    PARTIAL gradients, which the caller completes with one explicit
+    ``psum``; differentiated with respect to the replicated leaf itself,
+    the transposition of the cast has already summed them."""
+    import jax
+
+    return jax.tree.map(
+        lambda x: x if axis_name in jax.typeof(x).vma
+        else jax.lax.pcast(x, axis_name, to="varying"), tree)
+

@@ -519,6 +519,18 @@ class CommPlan:
                 setattr(self, name, val)
         return self
 
+    def virtual_rows(self) -> dict:
+        """The slot form of the two COO edge stores (``_build_virtual_rows``):
+        ``{"tail": layout | None, "halo": layout | None}`` from ``ltail_*``
+        and ``hedge_*``.  Built on each call, kept by the caller (today the
+        multi-head attention layer's setup; the GCN folds both stores by
+        scatter-add — ROADMAP A3)."""
+        return {
+            "tail": _build_virtual_rows(self.ltail_dst, self.ltail_src,
+                                        self.ltail_w, self.ltail_nnz, self.b),
+            "halo": _build_virtual_rows(self.hedge_dst, self.hedge_src,
+                                        self.hedge_w, self.hnnz, self.b)}
+
     # -------------------------------------------------------- ragged schedule
     def ragged_round_sizes(self) -> tuple:
         """Natural per-round send sizes S_d = max_p send_counts[p, (p+d)%k]
@@ -1556,6 +1568,56 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
                 ell_buckets=buckets, ell_idx=ell_idx, ell_w=ell_wv,
                 ltail_dst=ltail_dst, ltail_src=ltail_src, ltail_w=ltail_w,
                 ltail_nnz=ltail_nnz)
+
+
+# slots a virtual row holds.  The one width measured on the v5e (PERF.md §6,
+# PR 27: hub tail of the products-eighth graph, 2.04 M edges as 92,344 rows,
+# 0.651 s an epoch where the scatter-add fold took 3.218 s); other widths
+# were not measured against it (PERF.md §7)
+VROW_WIDTH = 32
+
+
+def _build_virtual_rows(dst, src, w, counts, b: int) -> dict | None:
+    """The slot form of a dst-sorted COO edge store — the hub tail
+    (``ltail_*``) or the halo-source edges (``hedge_*``) — beside
+    ``_build_ell``'s: ``(k, E)`` lists with ``counts[p]`` real edges a chip
+    become **virtual rows**, a destination's edges cut into runs of
+    ``VROW_WIDTH``, each run one row of a single-bucket width-major slot
+    layout, so the store goes through ``ops.pspmm.bucketed_slot_reduce``
+    like every other slot and ONE sorted scatter a pass adds the virtual
+    rows' sums to their destinations (instead of one scatter-add per edge).
+
+    Returns ``idx`` / ``mask`` ``(k, W·nv)`` (slot t of virtual row v at
+    ``t·nv + v``; mask int8, 0 on padding), ``row`` ``(k, nv)`` the
+    destination of each virtual row (ascending; padding rows point at
+    ``b − 1`` with an empty mask) and the static ``shape = (nv, W)`` — or
+    ``None`` where no chip has a real edge in the store (k = 1 has no halo
+    edges; a graph without hubs no tail), so the caller skips the pass."""
+    k, wd = dst.shape[0], VROW_WIDTH
+    degs = []
+    for p in range(k):
+        cnt = int(counts[p])
+        degs.append(np.bincount(dst[p, :cnt][w[p, :cnt] != 0], minlength=b))
+    nv = max((int((-(-dg // wd)).sum()) for dg in degs), default=0)
+    if nv == 0:
+        return None
+    nv = -(-nv // 8) * 8
+    idx = np.zeros((k, wd * nv), np.int32)
+    mask = np.zeros((k, wd * nv), np.int8)
+    row = np.full((k, nv), b - 1, np.int32)
+    for p, dg in enumerate(degs):
+        cnt = int(counts[p])
+        real = w[p, :cnt] != 0
+        d, s0 = dst[p, :cnt][real].astype(np.int64), src[p, :cnt][real]
+        nseg = -(-dg // wd)
+        vbase = np.cumsum(nseg) - nseg              # first virtual row
+        start = np.cumsum(dg) - dg                  # first edge of a row
+        pos = np.arange(len(d)) - start[d]
+        slot = (pos % wd) * nv + vbase[d] + pos // wd
+        idx[p, slot] = s0
+        mask[p, slot] = 1
+        row[p, : int(nseg.sum())] = np.repeat(np.arange(b), nseg)
+    return {"idx": idx, "mask": mask, "row": row, "shape": (nv, wd)}
 
 
 def shared_ell_buckets(plans: list, b: int, combined: bool = False) -> tuple:

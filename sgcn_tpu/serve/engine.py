@@ -151,7 +151,14 @@ class ServeEngine:
         if mode not in ("full", "subgraph"):
             raise ValueError(f"unknown serve mode {mode!r} "
                              "(know 'full', 'subgraph')")
-        from ..train.fullbatch import resolve_forward_setup
+        from ..train.fullbatch import model_takes_args, resolve_forward_setup
+
+        if model_takes_args(model):
+            raise ValueError(
+                f"model={model!r} is not served yet: the engine carries no "
+                "model_args (mhgat: heads, concat, slope) and its checkpoint "
+                "provenance does not record them — use "
+                "FullBatchTrainer.predict()")
 
         self.plan = plan
         self.fin = int(fin)

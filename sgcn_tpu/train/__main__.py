@@ -108,7 +108,15 @@ def main() -> None:
     p.add_argument("-f", "--nfeatures", type=int, default=16)
     p.add_argument("-n", "--batch-size", type=int, default=None,
                    help="enable the mini-batch trainer")
-    p.add_argument("--model", default="gcn", choices=["gcn", "gat"])
+    p.add_argument("--model", default="gcn", choices=["gcn", "gat", "mhgat"],
+                   help="gat = the reference's single-head PGAT layer; "
+                        "mhgat = multi-head graph attention as published "
+                        "(LeakyReLU scores, per-edge softmax, bias, linear "
+                        "skips; full-batch, a2a, f32 only): --hidden is the "
+                        "width PER HEAD, hidden layers concatenate --heads "
+                        "heads, the last layer averages them")
+    p.add_argument("--heads", type=int, default=1,
+                   help="attention heads per layer (--model mhgat)")
     p.add_argument("--activation", default=None,
                    choices=["relu", "sigmoid", "elu", "none"],
                    help="inter-layer activation; defaults to relu for gcn "
@@ -413,7 +421,15 @@ def main() -> None:
     hidden = args.hidden or f
     widths = [hidden] * (args.nlayers - 1) + [nclasses]
     # PGAT stacks bare modules: no inter-layer nonlinearity unless asked
-    activation = args.activation or ("none" if args.model == "gat" else "relu")
+    activation = args.activation or {"gat": "none", "mhgat": "elu"}.get(
+        args.model, "relu")
+    model_args = None
+    if args.model == "mhgat":
+        # hidden layers concatenate their heads, the last averages them
+        widths = [args.heads * hidden] * (args.nlayers - 1) + [nclasses]
+        model_args = {"heads": (args.heads,) * args.nlayers}
+        if args.batch_size is not None:
+            raise SystemExit("--model mhgat is full-batch only; drop -n")
 
     prof = (jax.profiler.trace(args.profile) if args.profile
             else contextlib.nullcontext())
@@ -516,7 +532,8 @@ def main() -> None:
                                       comm_schedule=args.comm_schedule,
                                       replica_budget=args.replica_budget,
                                       refresh_band=args.refresh_band,
-                                      memory_budget=args.memory_budget)
+                                      memory_budget=args.memory_budget,
+                                      model_args=model_args)
             except MemoryBudgetError as e:
                 raise SystemExit(str(e)) from e
             if recorder is not None:

@@ -23,6 +23,7 @@ the compiler-native form of the reference's Irecv/compute/Waitany overlap
 from __future__ import annotations
 
 import contextlib
+import functools
 import weakref
 from dataclasses import dataclass
 from typing import Any
@@ -34,6 +35,8 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..models import mhgat
+from ..models import setup as model_setup
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
     exchange_widths,
@@ -47,7 +50,8 @@ from ..models.gcn import (
     masked_softmax_xent_local,
 )
 from ..obs.tracing import scope, set_counter, span
-from ..parallel.mesh import AXIS, make_mesh_1d, replicate, shard_stacked
+from ..parallel.mesh import (AXIS, make_mesh_1d, replicate, shard_stacked,
+                             vary)
 from ..parallel.plan import CommPlan
 from ..utils.stats import CommStats
 from ..utils.timers import PhaseTimer
@@ -59,7 +63,10 @@ from ..utils.timers import PhaseTimer
 # symmetric Â (split COO otherwise); GAT the combined edge list its
 # edge-softmax needs.
 MODELS = {
-    # name -> (init, forward, plan->shipped array fields, plan->static kwargs)
+    # name -> (init, forward, plan->shipped array fields, plan->static kwargs
+    #          [, setup hook -> models/setup.py::ModelSetup: a model with a
+    #          configuration of its own (``model_args``); everything the
+    #          shared code would otherwise need the model's name for])
     "gcn": (init_gcn_params, gcn_forward_local, gcn_plan_fields,
             lambda plan: ({"ell_buckets": plan.ell_buckets}
                           if plan.symmetric else {})),
@@ -67,7 +74,21 @@ MODELS = {
             # ensure_cell: the combined-edge layout is built lazily — only
             # GAT ships it, and it duplicates the edge storage
             lambda plan: {"cell_buckets": plan.ensure_cell().cell_buckets}),
+    # multi-head attention as published (models/mhgat.py): the GCN's own
+    # plan arrays; its hyper-parameters arrive as ``model_args`` and are
+    # bound into init / forward statics through its hook
+    "mhgat": (mhgat.init_mhgat_params, mhgat.mhgat_forward_local,
+              lambda plan: mhgat.MHGAT_PLAN_FIELDS,
+              lambda plan: {"ell_buckets": plan.ell_buckets},
+              mhgat.model_setup),
 }
+
+
+def model_takes_args(model: str) -> bool:
+    """Whether ``model`` is configured by ``model_args`` (its registry entry
+    has a setup hook) — callers that carry none refuse such a model."""
+    return len(MODELS[model]) > 4
+
 
 # loss registry: 'xent' is the torch stack's log-softmax+NLL
 # (GPU/PGCN.py:204-205), 'bce' the MPI stack's sigmoid+BCE
@@ -97,6 +118,9 @@ class ForwardSetup:
     init_fn: object               # param init (MODELS registry)
     decision: dict                # resolve_comm_schedule's selection log
     replica_budget: int = 0       # resolved: 'auto' -> the λ·degree knee B
+    # what the model's own setup hook resolved (models/setup.py), None for
+    # a model without one
+    custom: "model_setup.ModelSetup | None" = None
 
     def ship_arrays(self, plan) -> dict:
         """The plan arrays the forward consumes, ready to shard — including
@@ -113,6 +137,12 @@ class ForwardSetup:
             for f in ("cell_w", "ctail_w", "ptile_cw"):
                 if f in arrays:
                     arrays[f] = (arrays[f] != 0).astype(np.int8)
+        if self.custom is not None:
+            # the same narrowing for the fields the hook names, and the
+            # arrays it derived from the plan
+            for f in self.custom.mask_fields:
+                arrays[f] = (arrays[f] != 0).astype(np.int8)
+            arrays.update(self.custom.extra_arrays)
         return arrays
 
 
@@ -124,7 +154,8 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
                           replica_budget: int | str = 0,
                           refresh_band: float | None = None,
                           serve_subgraph: bool = False,
-                          allow_pallas: bool = True
+                          allow_pallas: bool = True,
+                          model_args: dict | None = None
                           ) -> ForwardSetup:
     """Resolve (schedule, shipped plan fields, static forward kwargs) for one
     plan — the selection logic that used to live inline in
@@ -141,11 +172,18 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
     keeps the selection on the slot-pass/ELL aggregators regardless of the
     VMEM-fit rule — the mini-batch trainer's ONE compiled step must serve
     every per-batch plan, and the Pallas tile layout (per-class Emax_c
-    statics, tiles built per plan) has no shared-envelope form."""
+    statics, tiles built per plan) has no shared-envelope form.
+    ``model_args`` is the configuration of a model that has one (``mhgat``:
+    heads per layer, concat or mean, slope, skip, bias): validated by the
+    registry entry's setup hook and bound into the init function and the
+    forward's statics, so every caller reads one resolved form of it."""
     from ..parallel.plan import choose_replica_budget, resolve_comm_schedule
 
     decision: dict = {}
-    init_fn, forward_fn, fields_fn, static_fn = MODELS[model]
+    init_fn, forward_fn, fields_fn, static_fn, *hook = MODELS[model]
+    if model_args and not hook:
+        raise ValueError(f"model {model!r} takes no model_args "
+                         f"(got {sorted(model_args)})")
     if replica_budget == "auto":
         # --replica-budget auto: the λ·degree-knee rule, resolved BEFORE
         # the schedule selection so the auto transport scores the wire at
@@ -172,6 +210,15 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
         plan.ensure_ragged()
     plan_fields = fields_fn(plan)
     fwd_static = static_fn(plan)
+    custom = None
+    if hook:
+        custom = hook[0](plan, fin, widths, model_args,
+                         comm_schedule=comm_schedule,
+                         compute_dtype=compute_dtype,
+                         serve_subgraph=serve_subgraph)
+        fwd_static = dict(fwd_static, **custom.fwd_static)
+        init_fn = functools.partial(init_fn, **custom.init_static)
+        allow_pallas = allow_pallas and custom.allow_pallas
     if model == "gcn" and comm_schedule == "ragged":
         # the ragged ELL aggregation path (fold-as-you-arrive scatter over
         # the per-owner edge split); the Pallas selection below may swap
@@ -283,7 +330,8 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
     return ForwardSetup(model=model, comm_schedule=comm_schedule,
                         plan_fields=plan_fields, fwd_static=fwd_static,
                         forward_fn=forward_fn, init_fn=init_fn,
-                        decision=decision, replica_budget=replica_budget)
+                        decision=decision, replica_budget=replica_budget,
+                        custom=custom)
 
 
 @dataclass
@@ -443,6 +491,7 @@ class FullBatchTrainer:
         auto_tune_sync: bool = False,
         allow_pallas: bool = True,
         memory_budget: int | None = None,
+        model_args: dict | None = None,
     ):
         """``compute_dtype='bfloat16'`` runs forward/backward (including the
         halo exchange — half the ICI bytes) in bf16 with f32 master params
@@ -610,7 +659,7 @@ class FullBatchTrainer:
             plan, fin, widths, model=model, comm_schedule=comm_schedule,
             compute_dtype=compute_dtype, halo_staleness=halo_staleness,
             replica_budget=replica_budget, refresh_band=refresh_band,
-            allow_pallas=allow_pallas)
+            allow_pallas=allow_pallas, model_args=model_args)
         self.comm_decision = setup.decision   # selection → run manifest
         comm_schedule = setup.comm_schedule
         replica_budget = setup.replica_budget   # 'auto' -> the knee B
@@ -692,6 +741,16 @@ class FullBatchTrainer:
                 tail=int(plan.ctail_nnz.max()) if plan.ctail_nnz is not None
                 else 0,
                 dtype=compute_dtype)
+        self.model_memory = None
+        if setup.custom is not None:
+            # the model's own estimate, from its per-row and per-table
+            # arrays; the old fence above is the factorised layer's and is
+            # not consulted
+            self.model_memory = setup.custom.estimate_memory(train=True)
+            model_setup.check_memory(self.mesh.local_devices[0],
+                                     self.model_memory)
+            for name, value in setup.custom.counters.items():
+                set_counter(name, value)
         self.model = model
         # layer 0's Â·h0 is loop-invariant on the exact GCN path with an
         # aggregate-first layer 0 (class docstring): decided here from what
@@ -718,11 +777,19 @@ class FullBatchTrainer:
         # roofline's attribution (docs/observability.md): GCN ships feature
         # rows at the project-first widths, GAT its attention tables (fused
         # fout+1 / packed fout/2+1 / split pair)
+        lane_widths_bwd = ()                    # the forward's
         if model == "gat":
             from ..models.gat import gat_exchange_lane_widths
             lane_widths = tuple(gat_exchange_lane_widths(
                 self.widths, compute_dtype))
             wire_itemsize = wire_itemsize_bwd = 4   # lanes encode the dtype
+        elif setup.custom is not None:
+            # the hook's own tables, which may differ in width by direction
+            # (mhgat: [Z ‖ t] forward, [g ‖ s, m, 1/D, c] backward):
+            # CommStats books each direction at its own lanes
+            lane_widths = setup.custom.lane_widths
+            lane_widths_bwd = setup.custom.lane_widths_bwd
+            wire_itemsize = wire_itemsize_bwd = 4
         else:
             lane_widths = tuple(exchange_widths(fin, self.widths))
             # per-DIRECTION wire itemsize (docs/observability.md): the
@@ -735,6 +802,7 @@ class FullBatchTrainer:
                                       or compute_dtype == "bfloat16") else 4
         self.stats = CommStats.from_plan(plan, schedule=comm_schedule,
                                          lane_widths=lane_widths,
+                                         lane_widths_bwd=lane_widths_bwd,
                                          wire_itemsize=wire_itemsize,
                                          wire_itemsize_bwd=wire_itemsize_bwd)
         if replica_budget:
@@ -902,7 +970,14 @@ class FullBatchTrainer:
                        if self.loss_name == "bce" else loss)
             return loss, err
 
-        (loss, err), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        # with respect to per-chip copies of the replicated weights
+        # (parallel/mesh.py::vary): the gradients come back as per-chip
+        # partials and are summed once, below.  With respect to ``params``
+        # itself the transposition has summed them already and the psum
+        # below multiplied them by k — what this step did until PR 27 (Adam
+        # hides a constant factor; SGD and the gradient norm do not)
+        (loss, err), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            vary(params))
         # dense weight-grad allreduce — GPU/PGCN.py:150-154 /
         # Parallel-GCN/main.c:422-425 (psum of local partials = full grad)
         with scope("grad_psum"):
@@ -984,7 +1059,7 @@ class FullBatchTrainer:
             return loss, (err, nh, nb, qe)
 
         (loss, (err, nh, nb, qe)), (grads, ngh) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True)(params, ghalos)
+            loss_fn, argnums=(0, 1), has_aux=True)(vary(params), ghalos)
         # weight grads are global partial sums (exact mode's psum); the halo
         # carries are PER-CHIP state — never reduced
         grads = jax.tree.map(lambda g: lax.psum(g, AXIS), grads)
@@ -1165,7 +1240,7 @@ class FullBatchTrainer:
             return loss, (err, nr, nb, ns)
 
         (loss, (err, nr, nb, ns)), (grads, ngr) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True)(params, greps)
+            loss_fn, argnums=(0, 1), has_aux=True)(vary(params), greps)
         grads = jax.tree.map(lambda g: lax.psum(g, AXIS), grads)
         updates, opt_state = self.opt.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)

@@ -32,7 +32,7 @@ from ..parallel.mesh import make_mesh_1d, shard_stacked
 from ..parallel.plan import build_comm_plan, pad_comm_plan, shared_ell_buckets
 from ..utils.stats import CommStats
 from .fullbatch import (FullBatchTrainer, TrainData, _plan_arrays,
-                        _unblock, make_train_data)
+                        _unblock, make_train_data, model_takes_args)
 
 
 def sample_batches(n: int, batch_size: int, nbatches: int | None = None,
@@ -88,6 +88,11 @@ class MiniBatchTrainer:
         replica_budget: int = 0,
         memory_budget: int | None = None,
     ):
+        if model_takes_args(model):
+            raise ValueError(
+                f"model={model!r} is a full-batch model here: its "
+                "model_args and per-row backward state have no mini-batch "
+                "wiring — run the full-batch trainer")
         if replica_budget:
             # the replica carries cache per-layer activations of ONE plan's
             # boundary rows across steps; every mini-batch step runs a

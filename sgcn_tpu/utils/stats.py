@@ -52,6 +52,11 @@ class CommStats:
     # (tests/test_metrics_cli.py, tests/test_gat_ragged.py).  Empty = rows
     # only (pre-PR-5 reports).
     lane_widths: tuple = ()
+    # Gradient-direction lanes where they differ from the forward's (the
+    # multi-head attention layer ships [Z ‖ t], K·C + K lanes, forward and
+    # [g ‖ s, m, 1/D, c], K·C + 4K, backward:
+    # ``models.mhgat.mhgat_exchange_lane_widths``).  Empty = the same.
+    lane_widths_bwd: tuple = ()
     wire_itemsize: int = 4                 # bytes per f32-equivalent lane,
     #                                        FORWARD (feature) direction
     # Gradient-direction wire itemsize (None = same as wire_itemsize): the
@@ -101,7 +106,8 @@ class CommStats:
     def from_plan(cls, plan, schedule: str = "a2a",
                   lane_widths: tuple = (),
                   wire_itemsize: int = 4,
-                  wire_itemsize_bwd: int | None = None) -> "CommStats":
+                  wire_itemsize_bwd: int | None = None,
+                  lane_widths_bwd: tuple = ()) -> "CommStats":
         off = plan.offwire_send_counts()
         send_vol = plan.predicted_send_volume.astype(np.int64)
         send_msg = plan.predicted_message_count.astype(np.int64)
@@ -132,6 +138,7 @@ class CommStats:
             wire_rows_per_exchange=wire,
             padding_efficiency=(true / wire if wire else 1.0),
             lane_widths=tuple(int(w) for w in lane_widths),
+            lane_widths_bwd=tuple(int(w) for w in lane_widths_bwd),
             wire_itemsize=int(wire_itemsize),
             wire_itemsize_bwd=(None if wire_itemsize_bwd is None
                                else int(wire_itemsize_bwd)),
@@ -172,13 +179,14 @@ class CommStats:
         bwd = (self.wire_itemsize if self.wire_itemsize_bwd is None
                else self.wire_itemsize_bwd)
         lane = sum(self.lane_widths)
+        lane_bwd = sum(self.lane_widths_bwd or self.lane_widths)
         if replica:
             per_true = int(self.replica_send_volume_per_exchange.sum())
             wire = self.replica_wire_rows_per_exchange
         else:
             per_true = int(self.send_volume_per_exchange.sum())
             wire = self.wire_rows_per_exchange
-        factor = lane * (fwd * fwd_sweeps + bwd * bwd_sweeps)
+        factor = lane * fwd * fwd_sweeps + lane_bwd * bwd * bwd_sweeps
         self.halo_bytes_true_total += per_true * factor
         self.halo_bytes_wire_total += wire * factor
 
@@ -379,7 +387,8 @@ class CommStats:
             # steps book their f32 re-base wire at 4 bytes).
             bwd = (self.wire_itemsize if self.wire_itemsize_bwd is None
                    else self.wire_itemsize_bwd)
-            lane_b = sum(self.lane_widths) * (self.wire_itemsize + bwd)
+            lane_b = (sum(self.lane_widths) * self.wire_itemsize
+                      + sum(self.lane_widths_bwd or self.lane_widths) * bwd)
             rep.update(
                 halo_bytes_true_per_step=per_ex * lane_b,
                 halo_bytes_wire_per_step=self.wire_rows_per_exchange

@@ -20,6 +20,8 @@ hoists the loop-invariant aggregation out of the step
 CPU, tiny graphs, one and four virtual devices.
 """
 
+import gc
+
 import jax
 import numpy as np
 import pytest
@@ -135,6 +137,7 @@ def test_exact_step_ships_one_exchange_fewer_and_counts_builds(ahat, plans):
     feats, labels = _inputs()
     data = _data(tr, feats, labels)
     client = jax.devices()[0].client
+    gc.collect()        # executables other tests left for the collector
     before = len(client.live_executables())
     for _ in range(3):
         tr.step(data)

@@ -164,3 +164,38 @@ def test_remat_matches_plain(ahat):
     lp = [plain.step(data) for _ in range(4)]
     lr = [rem.step(data) for _ in range(4)]
     np.testing.assert_allclose(lr, lp, rtol=1e-5, atol=1e-6)
+
+
+def test_sgd_step_sums_the_weight_gradients_once(ahat):
+    """One SGD step at k = 4 moves every weight by −rate × the oracle's
+    gradient: Adam hides a constant factor on the gradients, SGD does not
+    (until PR 27 the step summed them twice and they arrived k-fold).  The
+    lowered step holds exactly ONE all-reduce per weight matrix — the
+    explicit ``psum`` under ``sgcn.grad_psum``."""
+    import re
+
+    import jax
+    import optax
+
+    n, k, rate, widths = ahat.shape[0], 4, 0.5, [8, 3]
+    feats, labels = _dataset(ahat)
+    plan = build_comm_plan(ahat, balanced_random_partition(n, k, seed=21), k)
+    trainer = FullBatchTrainer(plan, fin=feats.shape[1], widths=widths,
+                               seed=42, optimizer=optax.sgd(rate))
+    oracle = DenseOracle(ahat, fin=feats.shape[1], widths=widths, seed=42,
+                         optimizer=optax.sgd(rate))
+    before = jax.tree.map(np.asarray, trainer.params)
+    data = make_train_data(plan, feats, labels)
+    np.testing.assert_allclose(trainer.step(data),
+                               oracle.step(feats, labels), rtol=1e-5)
+    for w0, w1, want in zip(before, trainer.params, oracle.params):
+        moved = np.asarray(w1) - w0
+        assert np.abs(moved).max() > 1e-4           # a step worth comparing
+        np.testing.assert_allclose(moved, np.asarray(want) - w0,
+                                   rtol=2e-4, atol=1e-6)
+    text = trainer.lower_step().as_text()
+    # the operand type of each all-reduce follows its reduction body
+    reduced = [re.search(r"\}\) : \(tensor<([^>]*)>\)", piece).group(1)
+               for piece in text.split('"stablehlo.all_reduce"')[1:]]
+    weights = ["x".join(str(d) for d in w.shape) + "xf32" for w in before]
+    assert sorted(r for r in reduced if r != "f32") == sorted(weights), reduced
