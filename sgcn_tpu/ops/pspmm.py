@@ -125,10 +125,13 @@ def halo_exchange(h, send_idx, halo_src, axis_name: str = AXIS,
 
     Args:
       h: (B, f) local feature rows.
-      send_idx: (k, S) local row indices to ship to each peer (padded with 0 —
-        receivers never gather padded slots).
+      send_idx: (k, S) local row indices to ship to each peer (receivers
+        never gather a bucket's padded slots; they name distinct in-bounds
+        rows, ``parallel.plan.padding_rows``, because a send-side gather of
+        one row S times over costs twice what distinct rows cost).
       halo_src: (R,) flat indices into the received (k*S, f) buffer, in the
-        plan's (owner, vertex-id) halo order.
+        plan's (owner, vertex-id) halo order; its padding likewise names
+        distinct slots of that buffer.
       halo_dtype: optional narrower dtype for the WIRE only (the TPU-native
         lever the f32-only reference lacks): the send buffer is cast after
         the send-side gather and the halo rows are upcast back to ``h.dtype``
@@ -140,8 +143,8 @@ def halo_exchange(h, send_idx, halo_src, axis_name: str = AXIS,
         bytes are the multi-chip bottleneck the partitioner minimizes.
 
     Returns:
-      (R, f) halo rows (padding rows contain garbage; they are only referenced
-      by weight-0 edges).
+      (R, f) halo rows (padding rows hold whatever finite rows their slots
+      received; they are only referenced by weight-0 edges).
     """
     with scope("xchg_pack"):
         buf = jnp.take(h, send_idx, axis=0)                 # (k, S, f)

@@ -14,7 +14,10 @@ On TPU, shapes under ``jit`` are static, so we lower the ragged exchange to a
   * vertices are relabeled so chip ``p`` owns local slots ``0..B-1``
     (``B`` = max part size, parts padded with dummy vertices),
   * ``send_idx[p, q, s]`` — the ``S`` local rows chip ``p`` ships to chip ``q``
-    (padded with 0; ``send_counts[p, q]`` masks the tail),
+    (``send_counts[p, q]`` masks the tail, whose entries name distinct
+    in-bounds rows: ``padding_rows`` — every padding index of every array
+    below follows that one rule, because a gather of ONE row a million times
+    over is the slowest work the step does),
   * one ``lax.all_to_all`` of a ``(k, S, f)`` buffer per layer replaces the
     whole two-phase send/recv protocol (deadlock-freedom is structural),
   * ``halo_src[p, r]`` gathers chip ``p``'s ``R`` halo rows out of the received
@@ -174,10 +177,14 @@ class CommPlan:
     part_sizes: np.ndarray    # (k,) true part sizes (<= b)
 
     # halo exchange layout (stacked over chips)
+    # Padding entries of every index array gathered through (send_idx,
+    # halo_src, edge_src, ledge_src, hedge_src, ell_idx, ltail_src) hold
+    # ``padding_rows``: distinct in-bounds rows of the table indexed, never
+    # one row repeated.  The counts and the zero weights say what is padding.
     send_idx: np.ndarray      # (k, k, S) int32: local rows p sends to q
     send_counts: np.ndarray   # (k, k) int32: valid prefix of send_idx[p, q]
     halo_src: np.ndarray      # (k, R) int32: flat (q*S + t) recv-buffer gather
-    halo_counts: np.ndarray   # (k,) int32: valid halo rows per chip
+    halo_counts: np.ndarray   # (k,) int32: valid prefix of halo_src[p]
 
     # local sparse block as padded edge lists (sorted by dst for segment_sum)
     edge_dst: np.ndarray      # (k, E) int32 local row in [0, B)
@@ -220,7 +227,8 @@ class CommPlan:
     ell_k: int                # max bucket width (informational; >= 1)
     tl: int                   # padded tail length
     ell_buckets: tuple        # ((nb, wb), ...) static bucket structure
-    ell_idx: np.ndarray       # (k, ET) int32 flat local src, 0 on padding
+    ell_idx: np.ndarray       # (k, ET) int32 flat local src; padding slots
+    #                           (weight 0) name distinct rows: padding_rows
     ell_w: np.ndarray         # (k, ET) float32 flat, 0 on padding
     ltail_dst: np.ndarray     # (k, TL) int32
     ltail_src: np.ndarray     # (k, TL) int32
@@ -527,9 +535,11 @@ class CommPlan:
         scatter-add — ROADMAP A3)."""
         return {
             "tail": _build_virtual_rows(self.ltail_dst, self.ltail_src,
-                                        self.ltail_w, self.ltail_nnz, self.b),
+                                        self.ltail_w, self.ltail_nnz, self.b,
+                                        height=self.b),
             "halo": _build_virtual_rows(self.hedge_dst, self.hedge_src,
-                                        self.hedge_w, self.hnnz, self.b)}
+                                        self.hedge_w, self.hnnz, self.b,
+                                        height=self.r)}
 
     # -------------------------------------------------------- ragged schedule
     def ragged_round_sizes(self) -> tuple:
@@ -563,8 +573,29 @@ class CommPlan:
         padded shapes): ``slot_edges`` in the ELL buckets (Σ nb·wb slots),
         ``tail_edges`` (``tl``), ``halo_edges`` (``eh``), ``halo_rows``
         received into the halo table (``r``) and ``rows_sent`` (``k·s`` send
-        slots).  Plain ints and lists: it is left in
+        slots) — and what the difference is made of: ``padding``, the
+        padding entries of the store's gathered index array (``ell_idx``,
+        ``ltail_src``, ``hedge_src``, ``halo_src``, ``send_idx``), and
+        ``padding_fanin``, the largest number of them naming one row.  A
+        gather of one row a million times over is the slowest work the step
+        does (``padding_rows``), so the fan-in is ⌈padding ÷ table height⌉
+        by construction, and it is COUNTED here from the arrays, with
+        padding told by the counts (by weight 0 in the ELL, whose padding is
+        not a suffix).  Plain ints and lists: it is left in
         ``obs.tracing.counters()`` by ``build_comm_plan``."""
+        rows = self.ell_idx.shape[0]
+        unsent = _unsent_slots(self.send_counts, self.send_idx.shape[2])
+        pads = {
+            "slot_edges": [self.ell_idx[p][self.ell_w[p] == 0]
+                           for p in range(rows)],
+            "tail_edges": [self.ltail_src[p, int(self.ltail_nnz[p]):]
+                           for p in range(rows)],
+            "halo_edges": [self.hedge_src[p, int(self.hnnz[p]):]
+                           for p in range(rows)],
+            "halo_rows": [self.halo_src[p, int(self.halo_counts[p]):]
+                          for p in range(rows)],
+            "rows_sent": [self.send_idx[p][unsent[p]] for p in range(rows)],
+        }
         return {
             "true": {
                 "slot_edges": (self.lnnz - self.ltail_nnz).tolist(),
@@ -580,6 +611,10 @@ class CommPlan:
                 "halo_rows": int(self.r),
                 "rows_sent": int(self.k * self.s),
             },
+            "padding": {store: [int(x.size) for x in per]
+                        for store, per in pads.items()},
+            "padding_fanin": {store: [padding_fanin(x) for x in per]
+                              for store, per in pads.items()},
         }
 
     def wire_rows_per_exchange(self, schedule: str = "a2a",
@@ -1325,6 +1360,53 @@ def resolve_comm_schedule(schedule: str | None, plans, model: str,
     return resolved("ragged", "padding efficiency below threshold")
 
 
+def padding_rows(count: int, height: int) -> np.ndarray:
+    """Where the padding entries of one chip's index array point: entry ``i``
+    names row ``i mod height`` of the table the array indexes.
+
+    THE rule for every gathered index array a plan ships (PERF.md §6, PR 28).
+    A padding entry does no useful work (its weight or mask is 0, or no valid
+    reader gathers it) but the device still executes its gather, and where it
+    points sets what that costs: written as 0, millions of consecutive
+    gathers read ONE 512-byte row, which the v5e serves at about half the
+    rate of distinct rows — the chip with the most padding set the pace.
+    Consecutive padding entries therefore name consecutive distinct rows, in
+    bounds, and no row is named by more than ``padding_fanin_bound(count,
+    height)`` = ⌈count ÷ height⌉ of them — the least any rule can reach.
+    Nothing may recognise padding by its index: the counts (``lnnz``,
+    ``ltail_nnz``, ``hnnz``, ``send_counts``, ``halo_counts``) and the zero
+    weights / masks say what is padding."""
+    return (np.arange(count, dtype=np.int64) % max(height, 1)).astype(np.int32)
+
+
+def padding_fanin_bound(count: int, height: int) -> int:
+    """The most padding entries ``padding_rows`` lets name one row."""
+    return -(-int(count) // max(int(height), 1))
+
+
+def padding_fanin(idx: np.ndarray) -> int:
+    """The largest number of the given (padding) entries naming one row."""
+    return int(np.bincount(idx).max()) if idx.size else 0
+
+
+def _unsent_slots(send_counts, s: int) -> np.ndarray:
+    """Mask ``(..., peers, S)`` of the padding slots of the send buckets."""
+    return np.arange(s) >= np.asarray(send_counts)[..., None]
+
+
+def _pad_exchange(send_idx, send_counts, halo_src, halo_counts, b: int):
+    """Write the ``padding_rows`` into the exchange layout, in place: per chip
+    the unused tail of every send bucket (rows of the ``b`` local rows, one
+    numbering across the chip's buckets) and of the halo gather (slots of the
+    ``peers·S`` receive buffer)."""
+    k, peers, s = send_idx.shape
+    unsent = _unsent_slots(send_counts, s)
+    for p in range(k):
+        send_idx[p][unsent[p]] = padding_rows(int(unsent[p].sum()), b)
+        hc = int(halo_counts[p])
+        halo_src[p, hc:] = padding_rows(halo_src.shape[1] - hc, peers * s)
+
+
 def _relabel(n: int, partvec: np.ndarray, k: int, pad_rows_to: int,
              order_key: np.ndarray | None = None):
     """Shared vertex relabeling: (owner, local_idx, part_sizes, b, row_valid).
@@ -1356,7 +1438,7 @@ def _relabel(n: int, partvec: np.ndarray, k: int, pad_rows_to: int,
     return owner, local_idx, part_sizes, b, row_valid
 
 
-def _split_edges(edge_dst, edge_src, edge_w, nnz, b,
+def _split_edges(edge_dst, edge_src, edge_w, nnz, b, r,
                  el: int | None = None, eh: int | None = None,
                  halo_fold_key=None):
     """Split padded (k, E) edge lists into local-src and halo-src lists.
@@ -1364,7 +1446,10 @@ def _split_edges(edge_dst, edge_src, edge_w, nnz, b,
     Local edges (``src < b``) keep their src; halo edges re-base src to the
     halo block (``src - b``).  Filtering preserves the sorted-by-dst
     invariant.  ``el`` / ``eh`` force a larger padded width (shared
-    compilation envelopes); padding edges carry dst ``b-1`` and weight 0.
+    compilation envelopes); padding edges carry weight 0, dst ``b-1`` (the
+    lists stay sorted) and the sources ``padding_rows`` gives — distinct rows
+    of the local block (``b``) and of the halo block (``r``), never one row a
+    million times.
 
     ``halo_fold_key`` (optional, (k, R) int): per-chip fold position of each
     halo rank — the ragged ring's arrival round ``(chip − owner) mod k``.
@@ -1396,17 +1481,19 @@ def _split_edges(edge_dst, edge_src, edge_w, nnz, b,
     if el < el_nat or eh < eh_nat:
         raise ValueError("split envelope smaller than natural edge counts")
     ld = np.full((k, el), b - 1, dtype=np.int32)
-    ls = np.zeros((k, el), dtype=np.int32)
+    ls = np.empty((k, el), dtype=np.int32)
     lw = np.zeros((k, el), dtype=np.float32)
     hd = np.full((k, eh), b - 1, dtype=np.int32)
-    hs = np.zeros((k, eh), dtype=np.int32)
+    hs = np.empty((k, eh), dtype=np.int32)
     hw = np.zeros((k, eh), dtype=np.float32)
     for p, (d1, s1, w1, d2, s2, w2) in enumerate(parts):
         ld[p, : len(d1)] = d1
         ls[p, : len(s1)] = s1
+        ls[p, len(s1):] = padding_rows(el - len(s1), b)
         lw[p, : len(w1)] = w1
         hd[p, : len(d2)] = d2
         hs[p, : len(s2)] = s2
+        hs[p, len(s2):] = padding_rows(eh - len(s2), r)
         hw[p, : len(w2)] = w2
     return dict(el=el, eh=eh, ledge_dst=ld, ledge_src=ls, ledge_w=lw,
                 hedge_dst=hd, hedge_src=hs, hedge_w=hw, lnnz=lnnz, hnnz=hnnz)
@@ -1501,6 +1588,10 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
     buckets; only hub rows past the cap spill edges to the COO tail.
     ``row_order='id'``: one bucket of the classic tail-bounded width plus
     the COO overflow tail (emit-compatible row numbering).
+
+    Padding slots and padding tail edges carry weight 0 and the sources
+    ``padding_rows`` gives (rows of ``[0, b)``: in bounds for the local table
+    and for the combined ``[local ‖ halo]`` table of ``ensure_cell`` alike).
     """
     k = ledge_dst.shape[0]
     degs = [np.bincount(ledge_dst[p, : int(lnnz[p])], minlength=b)
@@ -1533,7 +1624,7 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
         row_cap[r0: r0 + nb] = wb
         off += nb * wb
         r0 += nb
-    ell_idx = np.zeros((k, et), dtype=np.int32)
+    ell_idx = np.empty((k, et), dtype=np.int32)
     ell_wv = np.zeros((k, et), dtype=np.float32)
     tails = []
     for p in range(k):
@@ -1549,8 +1640,10 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
         # envelope narrower than a row) spill to the COO tail
         main = pos < row_cap[d]
         slots = row_base[d[main]] + pos[main] * row_stride[d[main]]
-        ell_idx[p][slots] = s0[main]
         ell_wv[p][slots] = w[main]
+        pad = ell_wv[p] == 0
+        ell_idx[p][pad] = padding_rows(int(pad.sum()), b)
+        ell_idx[p][slots] = s0[main]
         tails.append((d[~main].astype(np.int32), s0[~main], w[~main]))
     ltail_nnz = np.array([len(t[0]) for t in tails], dtype=np.int64)
     tl_nat = max(1, int(ltail_nnz.max()) if k else 1)
@@ -1558,11 +1651,12 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
     if tl < tl_nat:
         raise ValueError("tail envelope smaller than natural tail size")
     ltail_dst = np.full((k, tl), b - 1, dtype=np.int32)
-    ltail_src = np.zeros((k, tl), dtype=np.int32)
+    ltail_src = np.empty((k, tl), dtype=np.int32)
     ltail_w = np.zeros((k, tl), dtype=np.float32)
     for p, (d, s0, w) in enumerate(tails):
         ltail_dst[p, : len(d)] = d
         ltail_src[p, : len(s0)] = s0
+        ltail_src[p, len(s0):] = padding_rows(tl - len(s0), b)
         ltail_w[p, : len(w)] = w
     return dict(ell_k=max(wb for _, wb in buckets), tl=tl,
                 ell_buckets=buckets, ell_idx=ell_idx, ell_w=ell_wv,
@@ -1577,7 +1671,8 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
 VROW_WIDTH = 32
 
 
-def _build_virtual_rows(dst, src, w, counts, b: int) -> dict | None:
+def _build_virtual_rows(dst, src, w, counts, b: int,
+                        height: int) -> dict | None:
     """The slot form of a dst-sorted COO edge store — the hub tail
     (``ltail_*``) or the halo-source edges (``hedge_*``) — beside
     ``_build_ell``'s: ``(k, E)`` lists with ``counts[p]`` real edges a chip
@@ -1588,8 +1683,9 @@ def _build_virtual_rows(dst, src, w, counts, b: int) -> dict | None:
     rows' sums to their destinations (instead of one scatter-add per edge).
 
     Returns ``idx`` / ``mask`` ``(k, W·nv)`` (slot t of virtual row v at
-    ``t·nv + v``; mask int8, 0 on padding), ``row`` ``(k, nv)`` the
-    destination of each virtual row (ascending; padding rows point at
+    ``t·nv + v``; mask int8, 0 on padding, where ``idx`` holds the
+    ``padding_rows`` of the source table's ``height``), ``row`` ``(k, nv)``
+    the destination of each virtual row (ascending; padding rows point at
     ``b − 1`` with an empty mask) and the static ``shape = (nv, W)`` — or
     ``None`` where no chip has a real edge in the store (k = 1 has no halo
     edges; a graph without hubs no tail), so the caller skips the pass."""
@@ -1602,7 +1698,7 @@ def _build_virtual_rows(dst, src, w, counts, b: int) -> dict | None:
     if nv == 0:
         return None
     nv = -(-nv // 8) * 8
-    idx = np.zeros((k, wd * nv), np.int32)
+    idx = np.empty((k, wd * nv), np.int32)
     mask = np.zeros((k, wd * nv), np.int8)
     row = np.full((k, nv), b - 1, np.int32)
     for p, dg in enumerate(degs):
@@ -1614,8 +1710,9 @@ def _build_virtual_rows(dst, src, w, counts, b: int) -> dict | None:
         start = np.cumsum(dg) - dg                  # first edge of a row
         pos = np.arange(len(d)) - start[d]
         slot = (pos % wd) * nv + vbase[d] + pos // wd
-        idx[p, slot] = s0
         mask[p, slot] = 1
+        idx[p, mask[p] == 0] = padding_rows(wd * nv - len(slot), height)
+        idx[p, slot] = s0
         row[p, : int(nseg.sum())] = np.repeat(np.arange(b), nseg)
     return {"idx": idx, "mask": mask, "row": row, "shape": (nv, wd)}
 
@@ -1730,7 +1827,9 @@ def pad_comm_plan(plan: CommPlan, b: int, s: int, r: int, e: int,
     padding every batch plan to the max envelope so shapes are static
     (SURVEY.md §7.3).  Padding preserves the plan invariants: pad edges carry
     weight 0 and dst ``b-1`` (keeps ``edge_dst`` non-decreasing), pad send /
-    halo slots index row 0 and are never read by valid gathers.  For the
+    halo slots are never read by valid gathers, and every padding index is
+    written anew for the larger tables by the rule of ``padding_rows``
+    (distinct in-bounds rows; the old padding is told by the counts).  For the
     shared ELL layout pass ``ell_buckets`` covering every plan's degree
     profile (see ``ell_degree_profile`` / ``_choose_buckets``).
     """
@@ -1757,6 +1856,7 @@ def pad_comm_plan(plan: CommPlan, b: int, s: int, r: int, e: int,
     # remap old flat recv slots q*S_old + t -> q*S_new + t
     q_old, t_old = plan.halo_src // plan.s, plan.halo_src % plan.s
     halo_src[:, : plan.r] = (q_old * s + t_old).astype(np.int32)
+    _pad_exchange(send_idx, plan.send_counts, halo_src, plan.halo_counts, b)
     edge_dst = np.full((k, e), b - 1, dtype=np.int32)
     edge_dst[:, : plan.e] = plan.edge_dst
     # old pad edges pointed at plan.b-1; retarget them to b-1 to keep the
@@ -1768,6 +1868,8 @@ def pad_comm_plan(plan: CommPlan, b: int, s: int, r: int, e: int,
     old_src = plan.edge_src
     edge_src[:, : plan.e] = np.where(
         old_src >= plan.b, old_src - plan.b + b, old_src)
+    for p in range(k):
+        edge_src[p, plan.nnz[p]:] = padding_rows(e - int(plan.nnz[p]), b + r)
     edge_w = np.zeros((k, e), dtype=np.float32)
     edge_w[:, : plan.e] = plan.edge_w
     row_valid = np.zeros((k, b), dtype=np.float32)
@@ -1776,7 +1878,8 @@ def pad_comm_plan(plan: CommPlan, b: int, s: int, r: int, e: int,
     chips = (np.asarray(plan.chip_ids) if plan.chip_ids is not None
              else np.arange(k))
     peers = plan.send_counts.shape[1]
-    split = _split_edges(edge_dst, edge_src, edge_w, plan.nnz, b, el=el, eh=eh,
+    split = _split_edges(edge_dst, edge_src, edge_w, plan.nnz, b, r,
+                         el=el, eh=eh,
                          halo_fold_key=(chips[:, None] - halo_src // s) % peers)
     ell = _build_ell(split["ledge_dst"], split["ledge_src"], split["ledge_w"],
                      split["lnnz"], b, row_order=plan.row_order,
@@ -1883,6 +1986,7 @@ def build_comm_plan(
                 m = ho == q
                 pos[m] = q * s + np.arange(m.sum())
             halo_src[p, : len(hp)] = pos
+        _pad_exchange(send_idx, send_counts, halo_src, halo_counts, b)
 
     with span("plan.edges"):
         # per-chip padded edge lists
@@ -1892,7 +1996,7 @@ def build_comm_plan(
         # globally non-decreasing — segment_sum is told
         # indices_are_sorted=True
         edge_dst = np.full((k, e), b - 1, dtype=np.int32)
-        edge_src = np.zeros((k, e), dtype=np.int32)
+        edge_src = np.empty((k, e), dtype=np.int32)
         edge_w = np.zeros((k, e), dtype=np.float32)
         for p in range(k):
             em = eo == p
@@ -1914,9 +2018,10 @@ def build_comm_plan(
             cnt = em.sum()
             edge_dst[p, :cnt] = rows[srt]
             edge_src[p, :cnt] = csrc[srt]
+            edge_src[p, cnt:] = padding_rows(e - cnt, b + r)
             edge_w[p, :cnt] = vals[srt]
 
-        split = _split_edges(edge_dst, edge_src, edge_w, nnz, b,
+        split = _split_edges(edge_dst, edge_src, edge_w, nnz, b, r,
                              halo_fold_key=(np.arange(k)[:, None]
                                             - halo_src // s) % k)
     with span("plan.ell"):
