@@ -5,7 +5,7 @@ Usage::
     python scripts/obs_report.py RUNDIR [--steps N]
 
 Loads (and schema-validates) the directory written by ``--metrics-out``
-(trainer CLI, ``bench.py``) and prints:
+(trainer and serve CLIs) and prints:
 
   * the manifest header (run kind, config highlights, git rev, backend,
     plan digest + partitioner provenance);

@@ -8,8 +8,9 @@ k-way sharding produces as k grows (`ops/pallas_spmm.py::use_pallas_spmm`).
 One physical chip can measure exactly that via the shard proxy: build a
 k-way plan whose per-chip [local] and [halo] tables fit the budget, take
 chip 0's shard, and run the SAME per-chip program with the Pallas
-aggregator on and off (SGCN_PALLAS_SPMM=1/0), differential protocol,
-back-to-back in one session.
+aggregator on and off (SGCN_PALLAS_SPMM=1/0), back-to-back in one session:
+wall seconds per ``step()`` with the loss read back, after one warm-up step,
+median of ``--epochs`` steps (as the benchmark's runner times an epoch).
 
 Writes ``bench_artifacts/pallas_shard_ab.json``.
 
@@ -30,6 +31,18 @@ sys.path.insert(0, REPO)
 ART = os.path.join(REPO, "bench_artifacts")
 
 
+def median_step_s(tr, data, steps: int) -> float:
+    """Median wall seconds of one ``step()`` + loss readback, after a
+    warm-up step that pays the compile."""
+    float(tr.step(data))
+    samples = []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        float(tr.step(data))
+        samples.append(time.perf_counter() - t0)
+    return float(np.median(samples))
+
+
 def main() -> None:
     import argparse
 
@@ -41,7 +54,6 @@ def main() -> None:
     p.add_argument("--epochs", type=int, default=8)
     args = p.parse_args()
 
-    from bench import diff_time_q
     from sgcn_tpu.io.datasets import er_graph
     from sgcn_tpu.ops.pallas_spmm import use_pallas_spmm
     from sgcn_tpu.parallel import build_comm_plan
@@ -65,7 +77,8 @@ def main() -> None:
                    "fin": args.f, "widths": widths, "km1": int(km1),
                    "plan": {"b": plan.b, "r": plan.r, "e": plan.e}},
         "protocol": "chip-0 shard program on the real chip, pallas vs ELL "
-                    "aggregator, differential median-of-3, same session",
+                    "aggregator, median wall s per step() after a warm-up "
+                    "step, same session",
     }
     for name, env in (("pallas", "1"), ("ell", "0")):
         os.environ["SGCN_PALLAS_SPMM"] = env
@@ -79,15 +92,9 @@ def main() -> None:
         tr = FullBatchTrainer(proxy, fin=args.f, widths=widths, seed=2)
         assert (tr._fwd_static.get("pallas_tb") is not None) == \
             (name == "pallas")
-
-        def make_run(nep):
-            def run():
-                losses = tr.run_epochs(data, nep, sync=False)
-                return float(losses[-1])
-            return run
-
-        epoch_s, n_clean = diff_time_q(make_run, 1, max(3, args.epochs))
-        out[name] = {"epoch_s": epoch_s, "clean_estimates": n_clean,
+        steps = max(3, args.epochs)
+        out[name] = {"epoch_s": median_step_s(tr, data, steps),
+                     "steps": steps,
                      "setup_plus_measure_s": round(time.time() - t0, 1)}
         print(name, json.dumps(out[name]), flush=True)
         del tr

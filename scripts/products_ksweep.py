@@ -8,7 +8,7 @@ graphs (BA power-law and dcsbm power-law+communities, n=2.45M, ~125M nnz),
 recording km1 / wall-clock / balance per point.
 
 km1 of the column-net model EQUALS the comm plan's send rows per layer pass
-(verified at products scale, BENCH_r04 ``plan_send_rows_per_pass``), so the
+(the plan-volume invariant ``tests/test_plan.py`` pins), so the
 sweep IS the comm-volume-vs-k curve without 8 more ~2-minute plan builds.
 
 Writes ``bench_artifacts/products_ksweep.json``.  Single-core job, ~1-2 h;

@@ -1,9 +1,9 @@
 """Products-scale partitioner proof.
 
-Runs the native partitioners on the SAME graph the products-shape bench uses
-(``bench.py --graph ba -n 2450000 --avg-deg 50`` => ``ba_graph(n, 25, 0)``,
-normalized) at k=8, and records the evidence the reference produces offline
-for its benchmark matrices (``GCN-HP/main.cpp:284-356`` partitions the real
+Runs the native partitioners on a products-shape power-law graph
+(``ba_graph(n=2450000, 25, seed=0)``, avg degree ~50, normalized; ``--family
+dcsbm`` gives the family of the benchmark's stand-in graph) at k=8, and
+records the evidence the reference produces offline for its benchmark matrices (``GCN-HP/main.cpp:284-356`` partitions the real
 ogbn-scale mtx and self-reports cut/conn + chrono time;
 ``GPU/hypergraph/run.sh:1-13`` sweeps whole dataset dirs):
 
@@ -15,12 +15,12 @@ ogbn-scale mtx and self-reports cut/conn + chrono time;
 
 then writes
 
-  * ``bench_artifacts/products_partition.npz``   (hp + gp part vectors)
+  * ``bench_artifacts/products_partition.npz``   (hp + gp part vectors;
+    written on demand, git-ignored)
   * ``bench_artifacts/products_partition.json``  (all metrics + provenance)
 
-``bench.py`` surfaces the JSON as the ``products_partition_8dev`` block so
-BENCH_r*.json carries a products-scale km1 from the real partitioner without
-re-running a ~20-minute single-core job inside the bench itself.
+A ~20-minute single-core job; nothing here was measured on the chip
+(``PERF.md`` §4 has the benchmark's own partition figures).
 
 Usage: PYTHONPATH=/root/repo python scripts/products_partition.py [-n N] [-k K]
 """
@@ -66,7 +66,7 @@ def main() -> None:
     p.add_argument("-n", type=int, default=2_450_000)
     p.add_argument("--attach", type=int, default=25)   # avg deg ~= 2*attach
     p.add_argument("--family", default="ba", choices=["ba", "dcsbm"],
-                   help="ba = the bench graph (expander: partitioners beat "
+                   help="ba = power-law expander (partitioners beat "
                         "random only marginally, an honest property of "
                         "preferential attachment); dcsbm = power-law + "
                         "planted communities (the real-ogbn structure "
@@ -83,8 +83,8 @@ def main() -> None:
         graph_meta = {
             "family": "ba", "n": int(args.n), "attach": args.attach,
             "seed": 0,
-            "matches_bench": "bench.py --graph ba -n %d --avg-deg %d"
-                             % (args.n, 2 * args.attach)}
+            "matches_bench": "ba_graph(n=%d, attach=%d, seed=0), avg deg %d"
+                             % (args.n, args.attach, 2 * args.attach)}
     else:
         from sgcn_tpu.io.datasets import dcsbm_graph
         a = dcsbm_graph(args.n, ncomm=200, avg_deg=2 * args.attach, seed=0)

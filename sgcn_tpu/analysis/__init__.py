@@ -23,10 +23,10 @@ wins.  This package makes those contracts machine-checked:
     contract tuples (ridden by ``tests/test_plan_contract.py``).
 
 CLI: ``python -m sgcn_tpu.analysis [--fast] [--json] [--out FILE]
-[--memory]`` — emits the schema-validated JSON report
-(``scripts/validate_bench.py`` checks committed copies); ``--memory``
-adds the compiling footprint-reconciliation pass (the ``memory-model``
-rule of ``hlo_audit.run_memory_audit``).
+[--memory]`` — emits the JSON report (``--out`` writes one on demand;
+no copy is committed); ``--memory`` adds the compiling
+footprint-reconciliation pass (the ``memory-model`` rule of
+``hlo_audit.run_memory_audit``).
 """
 
 from __future__ import annotations

@@ -4,9 +4,9 @@ Runs the AST hygiene pass and the compiled-program audit over the
 supported mode matrix on a FORCED virtual 8-device CPU mesh (lowering
 only — deterministic on any host, no accelerator needed), and emits the
 JSON report.  ``--fast`` audits the 2-mode smoke subset (the CI smoke in
-``tests/test_cli.py``); the full run is the one whose report is committed
-as ``bench_artifacts/analysis_report.json`` and re-validated by
-``scripts/validate_bench.py``.
+``tests/test_cli.py``); the full run is what
+``tests/test_analysis.py`` checks mode by mode.  ``--out FILE`` writes the
+report on demand; no copy is committed.
 
 Exit code 1 on any violation — wire this wherever a lint belongs.
 """

@@ -1,6 +1,6 @@
 """The AST hot-path hygiene pass (pass 2 of ``sgcn_tpu.analysis``).
 
-A registry of repo-source rules run over the package (plus ``bench.py``)
+A registry of repo-source rules run over the package
 with ``ast`` — no imports of the scanned modules, so a rule can never be
 defeated by import-time side effects, and every rule function takes
 ``(relpath, src)`` so the tier-1 mutation checks can feed it a seeded
@@ -50,7 +50,7 @@ SYNC_ALLOWLIST = ("sgcn_tpu/utils/timers.py",)
 
 # the CLIs whose mode-like flags must be enumerator-covered
 MODE_FLAG_FILES = ("sgcn_tpu/train/__main__.py",
-                   "sgcn_tpu/serve/__main__.py", "bench.py")
+                   "sgcn_tpu/serve/__main__.py")
 _MODE_LIKE_RE = re.compile(r"^--(comm|halo)-")
 
 _FIELDS_NAME_RE = re.compile(r"^_?[A-Z][A-Z0-9_]*_FIELDS[A-Z0-9_]*$")
@@ -234,7 +234,7 @@ RULES = (
          rule_sanctioned_sync_only),
     Rule("consumer-registered", "sgcn_tpu/**", rule_consumer_registered),
     Rule("mode-flag-enumerated",
-         "train/serve CLIs + bench.py (cross-file)",
+         "train/serve CLIs (cross-file)",
          rule_mode_flag_enumerated),
 )
 
@@ -246,9 +246,6 @@ def _iter_sources(root: str):
             if name.endswith(".py"):
                 full = os.path.join(dirpath, name)
                 yield os.path.relpath(full, root).replace(os.sep, "/"), full
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        yield "bench.py", bench
 
 
 def run_ast_pass(root: str | None = None) -> dict:

@@ -16,12 +16,13 @@ golden dump of a previous lowering:
 
 One constant is pinned empirically rather than derived:
 ``XENT_SCALAR_PSUMS`` — the scalar f32 allreduces the masked-xent loss
-machinery lowers to (two ``lax.psum`` calls in
-``models.gcn.masked_softmax_xent_local`` plus one re-emitted on the
-linearized path by JAX's partial evaluation).  It is a property of the
-loss code + JAX version, not of the plan; the full-matrix audit at HEAD
-validates it for every mode, and a loss-code change that shifts it fails
-the audit loudly (the point of a lint).
+machinery lowers to: the two ``lax.psum`` calls in
+``models.gcn.masked_softmax_xent_local`` (jax 0.9.0's partial evaluation
+re-emits neither on the linearized path; an earlier JAX re-emitted one,
+and the constant read 3).  It is a property of the loss code + JAX
+version, not of the plan; the full-matrix audit at HEAD validates it for
+every mode, and a loss-code change that shifts it fails the audit loudly
+(the point of a lint).
 """
 
 from __future__ import annotations
@@ -32,7 +33,7 @@ import numpy as np
 
 # scalar f32 add-allreduces of one masked-xent train step (see module
 # docstring); every audited train program uses the xent loss
-XENT_SCALAR_PSUMS = 3
+XENT_SCALAR_PSUMS = 2
 
 _DTYPE_SHORT = {
     "float32": "f32", "bfloat16": "bf16", "float64": "f64", "float16":

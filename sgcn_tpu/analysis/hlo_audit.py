@@ -520,6 +520,20 @@ def audit_mode(mode: Mode, plan=None) -> dict:
     return entry
 
 
+# the banded fixture's ragged modes (the empty-round-elision census)
+BANDED_MODES = (
+    Mode("train", "gcn", "ragged"),
+    Mode("train", "gcn", "ragged", staleness=1),
+    # the composed replica × stale ring: the SHRUNKEN nrep ring's empty
+    # rounds must elide too
+    Mode("train", "gcn", "ragged", staleness=1, replica=True),
+    # the ragged-Pallas ring rides the same elision rule
+    # (pallas_ring_concat skips S_d = 0 rounds at trace time) — and the
+    # halo-materialization rule must hold on a partially-live ring too
+    Mode("train", "gcn", "ragged", pallas=True),
+)
+
+
 def run_audit(modes=None, fast: bool = False) -> dict:
     """Audit the mode matrix; returns the ``hlo`` block of the analysis
     report.  ``fast`` audits the 2-mode smoke subset; the full run also
@@ -540,17 +554,7 @@ def run_audit(modes=None, fast: bool = False) -> dict:
         assert len(live) < AUDIT_K - 1, (
             "banded fixture lost its empty rounds — the elision census "
             "checks nothing")
-        for mode in (Mode("train", "gcn", "ragged"),
-                     Mode("train", "gcn", "ragged", staleness=1),
-                     # the composed replica × stale ring: the SHRUNKEN
-                     # nrep ring's empty rounds must elide too
-                     Mode("train", "gcn", "ragged", staleness=1,
-                          replica=True),
-                     # the ragged-Pallas ring rides the same elision rule
-                     # (pallas_ring_concat skips S_d = 0 rounds at trace
-                     # time) — and the halo-materialization rule must
-                     # hold on a partially-live ring too
-                     Mode("train", "gcn", "ragged", pallas=True)):
+        for mode in BANDED_MODES:
             entry = audit_mode(mode, plan=banded)
             out["modes"][mode.mode_id + "@banded"] = entry
             out["ok"] = out["ok"] and entry["ok"]

@@ -17,9 +17,9 @@ Four layers (see ``docs/observability.md`` for the operator guide):
     validated against.
 
 Wired through the trainers (``FullBatchTrainer.attach_recorder`` /
-``MiniBatchTrainer.attach_recorder``), the trainer CLI (``--metrics-out``),
-``bench.py`` and the launch/dryrun layers (heartbeats via
-``$SGCN_METRICS_OUT``).  Rendered by ``scripts/obs_report.py``.
+``MiniBatchTrainer.attach_recorder``), the trainer CLI (``--metrics-out``)
+and the launch/dryrun layers (heartbeats via ``$SGCN_METRICS_OUT``).
+Rendered by ``scripts/obs_report.py``.
 """
 
 from .attribution import (STREAM_CEILING_GBS, StepCostModel,
@@ -29,18 +29,18 @@ from .memory import (MEM_MODEL_TOL, MemoryBudgetError, MemoryModel,
                      parse_bytes, reconcile)
 from .recorder import RunLog, RunRecorder, heartbeat, load_run, plan_digest
 from .schema import SCHEMA_VERSION, validate_event, validate_manifest
-from .tracing import (SpanTimer, TraceSummary, classify_op, emit_span,
-                      find_trace_files, measured_vs_model_block, scoped_span,
+from .tracing import (SpanTimer, TraceSummary, classify_op,
+                      find_trace_files, measured_vs_model_block,
                       summarize_trace, trace_path_for_run)
 
 __all__ = [
     "MEM_MODEL_TOL", "SCHEMA_VERSION", "STREAM_CEILING_GBS",
     "MemoryBudgetError", "MemoryModel", "RunLog", "RunRecorder",
     "SpanTimer", "StepCostModel", "TraceSummary",
-    "check_memory_budget", "classify_op", "emit_span",
+    "check_memory_budget", "classify_op",
     "find_trace_files", "gather_bytes_per_epoch", "heartbeat", "load_run",
     "measure_compiled", "measured_vs_model_block", "memory_model",
     "parse_bytes", "plan_digest", "reconcile", "roofline_fields",
-    "scoped_span", "step_cost", "summarize_trace", "trace_path_for_run",
+    "step_cost", "summarize_trace", "trace_path_for_run",
     "validate_event", "validate_manifest",
 ]

@@ -3,9 +3,9 @@
 One home for every "how fast SHOULD this step be" number, derived from the
 ``CommPlan``'s exact padded layout at the per-layer exchanged widths
 (``models.gcn.exchange_widths`` — the trainer's project-first rule), so the
-recorder, ``bench.py`` and ``scripts/obs_report.py`` all attribute measured
-step time against the SAME model.  Previously ``bench.py`` hand-rolled its
-roofline fields; it now imports them from here.
+recorder and ``scripts/obs_report.py`` attribute measured step time against
+the SAME model.  (The benchmark's byte counts and peaks are its own:
+``benchmark/costmodel.py``, ``benchmark/peaks.json``.)
 
 Three quantities per training step:
 

@@ -311,36 +311,26 @@ class RunRecorder:
 
 
 # ------------------------------------------------- out-of-recorder emission
-def append_env_event(filename: str, ev: dict) -> None:
-    """Validate + append one event to ``$SGCN_METRICS_OUT/<filename>`` — the
-    ONE out-of-recorder emission path (``heartbeat`` pings and
-    ``obs.tracing.emit_span`` bench spans both ride it).  No-op unless the
-    env var names a directory; best-effort by design: a full disk must not
-    kill the run it is observing."""
-    outdir = os.environ.get("SGCN_METRICS_OUT")
-    if not outdir:
-        return
-    try:
-        schema.validate_event(ev)
-        os.makedirs(outdir, exist_ok=True)
-        with open(os.path.join(outdir, filename), "a") as fh:
-            fh.write(json.dumps(_jsonable(ev)) + "\n")
-    except (OSError, ValueError):
-        pass
-
-
 def heartbeat(event: str, **fields) -> None:
     """Append a liveness ping to ``$SGCN_METRICS_OUT/heartbeat.jsonl``.
 
     No-op unless the env var names a directory — callers sprinkle these at
     phase boundaries unconditionally (launch rendezvous, multichip dryrun)
-    and pay nothing when telemetry is off.
+    and pay nothing when telemetry is off.  Best-effort by design: a full
+    disk must not kill the run it is observing.
     """
-    if not os.environ.get("SGCN_METRICS_OUT"):
+    outdir = os.environ.get("SGCN_METRICS_OUT")
+    if not outdir:
         return
-    append_env_event(schema.HEARTBEAT_NAME, {
-        "v": schema.SCHEMA_VERSION, "ts": time.time(), "kind": "heartbeat",
-        "event": str(event), "pid": os.getpid(), **fields})
+    ev = {"v": schema.SCHEMA_VERSION, "ts": time.time(), "kind": "heartbeat",
+          "event": str(event), "pid": os.getpid(), **fields}
+    try:
+        schema.validate_event(ev)
+        os.makedirs(outdir, exist_ok=True)
+        with open(os.path.join(outdir, schema.HEARTBEAT_NAME), "a") as fh:
+            fh.write(json.dumps(_jsonable(ev)) + "\n")
+    except (OSError, ValueError):
+        pass
 
 
 # -------------------------------------------------------------------- loader
@@ -375,8 +365,7 @@ def load_run(path: str) -> RunLog:
     a telemetry consumer must never silently chart garbage.
 
     A directory holding ONLY ``heartbeat.jsonl`` or ``events.jsonl`` is
-    valid: the launch/dryrun layers write heartbeats — and ``bench.py`` and
-    its A/B children write spans (``obs.tracing.emit_span``) — through
+    valid: the launch/dryrun layers write heartbeats through
     ``$SGCN_METRICS_OUT`` without a ``RunRecorder`` (no manifest), and a
     killed run's completed measurements must be loadable from exactly
     that.  ``manifest`` is then ``{}``."""

@@ -104,8 +104,8 @@ _REQUIRED = {
     "heartbeat": {"event": _STR},
     "summary": {"report": dict},
     # v2: one measured wall-clock span (obs/tracing.py::SpanTimer) — the
-    # trainers' step/eval phases and bench.py's A/B phases all emit these,
-    # so measured phase times live in the SAME stream as the analytic gauges
+    # trainers' step/eval phases emit these, so measured phase times live
+    # in the SAME stream as the analytic gauges
     "span": {"name": _STR, "dur_s": _NUM},
     # v3: one serving latency/throughput window (sgcn_tpu/serve/engine.py):
     # measured per-query latency quantiles + achieved QPS over `queries`
@@ -160,7 +160,7 @@ _OPTIONAL = {
         "depth": _NUM,        # nesting depth at entry (0 = root)
         "step": _NUM,         # optimizer step the span belongs to, if any
         "pid": _NUM,          # emitting process (bench A/B children differ)
-        "phase": _STR,        # coarse phase label (bench arms, trainer fit)
+        "phase": _STR,        # coarse phase label (trainer fit)
         "detail": _STR,
     },
     "serve": {

@@ -15,7 +15,7 @@ fixed vocabulary ``SCOPES``, which reaches the device trace through each
 op's HLO metadata.  ``set_counter`` / ``counters`` hold plan-time counts
 (``CommPlan.work_counts``) the same way.
 
-**Span API** (``SpanTimer`` / ``emit_span`` / ``scoped_span``) — named,
+**Span API** (``SpanTimer``) — named,
 optionally nested wall-clock spans with ``block_until_ready`` sync points;
 ``SpanTimer.span`` enters the primitive above, so every trainer span
 (``warmup``, ``train_step``, ``step``, ``eval``) is on the profiler too.
@@ -23,10 +23,7 @@ It generalizes ``utils.timers.PhaseTimer`` (every span IS a phase: the timer
 keeps the CAGNET-vocabulary self-time breakdown, the span additionally
 becomes a schema-v2 ``span`` event in the run's ``events.jsonl``), so
 measured phase times land in the SAME stream as the analytic gauges.  Both
-trainers thread their step/epoch paths through it, and ``bench.py``'s A/B
-children emit arm-level spans through the env-gated ``emit_span`` (span the
-arms, never the steps inside a timed region — instrumentation inside a
-differential-timing loop would perturb the very number being measured).
+trainers thread their step/epoch paths through it.
 
 **Trace parser** (``find_trace_files`` / ``summarize_trace``) — parses the
 trace-event JSON ``jax.profiler.trace`` writes (``--profile DIR`` →
@@ -242,44 +239,6 @@ class SpanTimer:
                 self.recorder.record_span(
                     name=sp.name, dur_s=sp.dur_s, parent=sp.parent,
                     depth=sp.depth, **kw)
-
-
-def emit_span(name: str, dur_s: float, parent: str | None = None,
-              depth: int = 0, phase: str | None = None,
-              detail: str | None = None) -> None:
-    """Append one validated ``span`` event to
-    ``$SGCN_METRICS_OUT/events.jsonl`` — the out-of-recorder span emitter
-    (``recorder.append_env_event``, the same path ``heartbeat`` rides):
-    ``bench.py`` and its A/B child processes inherit the env var, so their
-    arm-level measured times land in the parent run's event stream.  No-op
-    without the env var; best-effort by design (a full disk must not kill
-    the bench it is observing)."""
-    if not os.environ.get("SGCN_METRICS_OUT"):
-        return
-    from . import schema
-    from .recorder import append_env_event
-    ev = {"v": schema.SCHEMA_VERSION, "ts": time.time(), "kind": "span",
-          "name": str(name), "dur_s": float(dur_s), "depth": int(depth),
-          "pid": os.getpid()}
-    if parent is not None:
-        ev["parent"] = str(parent)
-    if phase is not None:
-        ev["phase"] = str(phase)
-    if detail is not None:
-        ev["detail"] = str(detail)
-    append_env_event(schema.EVENTS_NAME, ev)
-
-
-@contextlib.contextmanager
-def scoped_span(name: str, phase: str | None = None,
-                detail: str | None = None):
-    """Time a region and ``emit_span`` it at exit (env-gated no-op without
-    ``$SGCN_METRICS_OUT``) — the bench-side span form."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        emit_span(name, time.perf_counter() - t0, phase=phase, detail=detail)
 
 
 # ------------------------------------------------------------ trace parser

@@ -8,8 +8,8 @@ halo gather, bucketed local SpMM, dense matmuls, loss, backward, Adam — is
 the SAME compiled program on every chip; only gather index *contents* differ.
 Measuring that program on the real chip therefore measures the compute half
 of the k-chip epoch directly; the collectives (halo ``all_to_all``, grad
-``psum``) are the only parts a single device cannot time, and their cost is
-modeled from the plan's exact exchange bytes (``scripts/shard_epoch_model.py``).
+``psum``) are the only parts a single device cannot time (the four-chip
+benchmark cell does; at the deployment's k = 8 they are not measured).
 
 Mechanism: ``dataclasses.replace`` the plan with ``k=1`` and every stacked
 ``(k, ...)`` array sliced to ``[chip:chip+1]``, then train normally on a

@@ -92,7 +92,7 @@ def main() -> None:
                    help="'full' recomputes the whole partitioned forward "
                         "per micro-batch (PR-8); 'subgraph' computes only "
                         "the routed queries' L-hop receptive sets — "
-                        "query-proportional FLOPs, bit-identical logits "
+                        "query-proportional FLOPs, logits equal to the ulp "
                         "(docs/serving.md phase 2)")
     p.add_argument("--concurrent", action="store_true",
                    help="double-buffered dispatch: submit batch t+1 while "

@@ -142,8 +142,9 @@ class ServeEngine:
         per micro-batch.  ``mode='subgraph'`` is query-proportional
         (``docs/serving.md`` phase 2): each batch computes only the routed
         queries' L-hop receptive sets (``serve/subgraph.py``) with no
-        per-layer exchange — routed logits stay f32-bit-identical to
-        ``evaluate()`` either way."""
+        per-layer exchange.  Routed logits are f32-bit-identical to
+        ``evaluate()`` in full mode and equal to the ulp in sub-graph mode
+        (another compiled shape of the same op sequence)."""
         if halo_dtype is not None and model != "gcn":
             raise ValueError(
                 "halo_dtype is a GCN wire lever; the GAT exchange ships "
@@ -643,8 +644,7 @@ class ServeEngine:
         from ..obs.attribution import forward_flops
 
         # plan-derived per-chip residency (obs/memory.py) — `analytic: true`
-        # is the provenance flag scripts/validate_bench.py requires on any
-        # *_bytes residency claim in a bench block
+        # says the *_bytes figures are the model's, not the allocator's
         mem = {"analytic": True,
                "model_bytes": self.memory.total_bytes,
                **{f"{name}_bytes": int(v)
@@ -674,7 +674,7 @@ class ServeEngine:
                 # (Qb, nout) buffer — per query ~one logits row
                 "wire_rows_per_query": round(t["wire_rows"] / nq, 6),
                 # the full-forward figures a batch of this plan WOULD have
-                # paid — the A/B denominators (bench.py serve_subgraph_ab)
+                # paid — the denominators of the per-query cut
                 "full_rows_per_forward": int(self.plan.k * self.plan.b),
                 "full_forward_flops": forward_flops(
                     self.plan, self.fin, self.widths, model=self.model),

@@ -35,9 +35,14 @@ this module makes serving QUERY-proportional:
     the only collective in the program is the final logit-gather ``psum``
     (the audited contract of the ``serve_subgraph`` analysis mode).
 
-**Bit-identity contract.**  Routed logits are f32-bit-identical (``==``) to
-the trainer's ``evaluate()`` because every per-row reduction reproduces the
-full program's per-row addition sequence AND op structure exactly:
+**Parity contract.**  Routed logits match the trainer's ``evaluate()`` to
+the ulp (within 2 ulp of a row's largest logit;
+``tests/test_serve_subgraph.py::PARITY_ULPS``, 1 measured) because every
+per-row reduction reproduces the full program's per-row addition sequence
+AND op structure exactly — what is left is XLA:CPU's choice of FMA
+contraction, which it makes per compiled shape (under the JAX these
+mechanisms were tuned against the result was ``==``; under 0.9.0 a GCN logit
+differs by an ulp at some batch shapes):
 
   * the compact aggregations call the REAL kernels (``ops.pspmm.spmm_ell``
     / ``spmm_local``, ``models.gat._edge_pass`` slot passes) on compact

@@ -1793,7 +1793,7 @@ class FullBatchTrainer:
         from ..obs.tracing import measured_vs_model_block
 
         roofline = mvm = None
-        # same honesty gate as bench.py: the gather model describes the
+        # honesty gate: the gather model describes the
         # bucketed slot-pass aggregators (GCN ELL, GAT combined-edge) — for
         # the Pallas VMEM kernel it would describe a program that didn't
         # run, so omit it rather than mislead.  GAT attributes against its

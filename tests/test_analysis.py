@@ -33,7 +33,8 @@ from sgcn_tpu.analysis.ast_rules import (rule_consumer_registered,
                                          rule_sanctioned_sync_only,
                                          rule_traced_host_free,
                                          run_ast_pass)
-from sgcn_tpu.analysis.hlo_audit import audit_mode, audit_plan, run_audit
+from sgcn_tpu.analysis.hlo_audit import (BANDED_MODES, audit_mode,
+                                          audit_plan, run_audit)
 from sgcn_tpu.analysis.modes import (Mode, is_supported, supported_modes,
                                      train_matrix_verdicts)
 
@@ -53,13 +54,26 @@ def _rules_hit(entry):
 
 
 # ------------------------------------------------------------ matrix @ HEAD
-def test_full_matrix_clean_at_head(full_report):
-    """Acceptance criterion: the auditor covers the full supported mode
-    matrix and every census/dtype/shape/donation check passes at HEAD."""
-    bad = {mid: _violations(e) for mid, e in full_report["modes"].items()
-           if not e["ok"]}
-    assert full_report["ok"] and not bad, bad
+# a pure enumeration (no lowering at collection): every supported mode plus
+# the banded fixture's ragged modes — one case each, so a red mode is named
+AUDITED_MODE_IDS = ([m.mode_id for m in supported_modes()]
+                    + [m.mode_id + "@banded" for m in BANDED_MODES])
+
+
+@pytest.mark.parametrize("mode_id", AUDITED_MODE_IDS)
+def test_mode_clean_at_head(full_report, mode_id):
+    """Acceptance criterion, mode by mode: every census/dtype/shape/
+    donation check of this mode's programs passes at HEAD."""
+    entry = full_report["modes"][mode_id]
+    assert entry["ok"], _violations(entry)
+
+
+def test_full_matrix_covered_at_head(full_report):
+    """The auditor covers the full supported mode matrix — exactly the
+    enumerated ids — and its one verdict is green."""
+    assert set(full_report["modes"]) == set(AUDITED_MODE_IDS)
     assert full_report["n_modes"] == len(full_report["modes"])
+    assert full_report["ok"]
 
 
 def test_matrix_covers_the_advertised_axes(full_report):

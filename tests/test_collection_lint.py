@@ -44,10 +44,6 @@ SUBPROCESS_BUDGET_ALLOWLIST = {
                            "(--metrics-out + --profile telemetry smoke, and "
                            "the ragged-schedule wire-reconciliation smoke; "
                            "~50 s together)",
-    "test_validate_bench.py": "two validate_bench.py CLI children — pure "
-                              "stdlib JSON checks, sub-second, no jax",
-    "test_bench_trend.py": "three bench_trend.py CLI children — pure "
-                           "stdlib JSON trend checks, sub-second, no jax",
     "test_serve.py": "one serve-CLI child + one obs_report render on the "
                      "small cora fixture (closed-loop micro-batch smoke, "
                      "24 queries, one compiled bucket; ~1 min)",
@@ -59,8 +55,7 @@ SUBPROCESS_BUDGET_ALLOWLIST = {
                           "obs_report render — the bit-identity contract "
                           "is only provable by killing REAL subprocess "
                           "runs (docs/resilience.md); whole module "
-                          "measured 127 s at PR-13 (ROADMAP budget note "
-                          "re-measured accordingly)",
+                          "measured 127 s at PR-13",
     "test_scopes.py": "one python child that loads obs/tracing.py alone to "
                       "prove the module imports without jax (~1 s, no mesh)",
     "test_backend.py": "two pairs of CPU children sharing a temporary "

@@ -98,6 +98,7 @@ def test_gat_sym_backward_matches_autodiff(ahat):
     from sgcn_tpu.models.gat import (GAT_PLAN_FIELDS, gat_layer_local,
                                      gat_layer_sym)
     from sgcn_tpu.parallel import make_mesh_1d, shard_stacked
+    from sgcn_tpu.parallel.mesh import vary
     from sgcn_tpu.partition import balanced_random_partition
 
     n, k, fin, fout = ahat.shape[0], 4, 6, 5
@@ -124,8 +125,12 @@ def test_gat_sym_backward_matches_autodiff(ahat):
                 # exactly the trainer's contract (fullbatch psums them)
                 return jnp.sum(out * jnp.cos(out * 0.3))
 
+            # as gat_forward_local does: the replicated params are cast to
+            # varying first, so the custom VJP's per-chip PARTIAL cotangents
+            # carry the primals' type (and autodiff's are partials too)
+            pv = vary(params, "v")
             g = jax.grad(obj, argnums=(0, 1, 2, 3))(
-                params["w"], params["a1"], params["a2"], h[0])
+                pv["w"], pv["a1"], pv["a2"], h[0])
             return jax.tree.map(lambda x: x[None], g)
 
         fn = jax.jit(jax.shard_map(per_chip, mesh=mesh,

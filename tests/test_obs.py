@@ -301,7 +301,7 @@ def test_step_cost_model_and_roofline():
     cost = step_cost(plan, fin, widths)
     assert cost.nlayers == 2
     assert cost.widths == exchange_widths(fin, widths)
-    # the gather-byte model is THE bench.py roofline numerator (moved here)
+    # the gather-byte model is the roofline numerator
     assert cost.gather_bytes == gather_bytes_per_epoch(plan, fin, widths)
     # per-layer blocks reconcile with the totals
     assert sum(pl["spmm_flops"] for pl in cost.per_layer) == cost.spmm_flops

@@ -136,6 +136,14 @@ def test_replica_run_epochs_parity(cora):
     assert ra == rb
     assert ra["replica_exchanges"] == 2 * len(WIDTHS) * 3   # steps 1,2,4
     assert ra["halo_bytes_true_total"] < 5 * ra["halo_bytes_true_per_step"]
+    # rows shipped over the same five steps, against the no-replica arm's
+    # (every exchange at the full ring): replicated rows leave the wire on
+    # the three replica steps, so both totals are strictly lower
+    nl, full = len(WIDTHS), plan.wire_rows_per_exchange("ragged")
+    shrunk = plan.wire_rows_per_exchange("ragged", replica=True)
+    assert ra["wire_rows_total"] == 2 * nl * (2 * full + 3 * shrunk)
+    assert ra["wire_rows_total"] < 5 * 2 * nl * full
+    assert plan.replica_rows == BUDGET and plan.replica_send_saving > 0
 
 
 def test_replica_telemetry_books_and_reconciles(cora, tmp_path):

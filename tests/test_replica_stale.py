@@ -357,6 +357,23 @@ def test_controller_retunes_trainer_and_logs_manifest(cora, tmp_path):
         tr.step(d)
     rec.close()
     assert tr.sync_every > 2
+    # the controller's acceptance figure, as a count over the same seven
+    # steps: its exposed wire rows lie at or below every static setting's
+    # and strictly below the exact arms' — it widens the sync cadence from
+    # 2, so it syncs no more often than the static stale arms (steps 0, 2,
+    # 4, 6) and ships the full ring of its own schedule when it does
+    rep = tr.stats.report()
+    nl = len(WIDTHS)
+    wire = {s: plan.wire_rows_per_exchange(s) for s in ("a2a", "ragged")}
+    assert rep["exposed_wire_rows_total"] == \
+        rep["exposed_exchanges"] * wire[tr.comm_schedule]
+    assert rep["exposed_exchanges"] <= 4 * 2 * nl
+    statics = {"a2a_exact": 7 * 2 * nl * wire["a2a"],
+               "ragged_exact": 7 * 2 * nl * wire["ragged"],
+               "ragged_stale": 4 * 2 * nl * wire["ragged"],
+               "replica_stale": 4 * 2 * nl * wire["ragged"]}
+    assert all(rep["exposed_wire_rows_total"] <= v for v in statics.values())
+    assert rep["exposed_wire_rows_total"] < statics["ragged_exact"]
     ctl = tr.comm_decision["controller"]
     assert ctl["retunes"] and ctl["retunes"][0]["rule"] == "drift below band"
     m = load_run(str(tmp_path / "run")).manifest

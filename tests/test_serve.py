@@ -131,6 +131,14 @@ def test_forward_parity_bit_identical(cora, model, sched, tmp_path):
     # a shuffled sub-batch returns the same rows, in query order
     sel = np.random.default_rng(0).permutation(plan.n)[:17]
     np.testing.assert_array_equal(eng.query(sel), expected[sel])
+    # the forward exchange is serving's whole comm cost: the engine books
+    # its schedule's wire rows, and on this skewed hp partition the ring's
+    # per-round pads ship strictly fewer than the dense pad
+    wire = eng.gauges()["wire_rows_per_exchange"]
+    assert wire == plan.wire_rows_per_exchange(sched)
+    plan.ensure_ragged()
+    assert (plan.wire_rows_per_exchange("ragged")
+            < plan.wire_rows_per_exchange("a2a"))
 
 
 # ----------------------------------------------------- buckets / recompile

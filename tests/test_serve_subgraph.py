@@ -3,12 +3,17 @@ engine ``mode='subgraph'`` (docs/serving.md phase 2).
 
 The contracts pinned here:
 
-  * **routed-logit bit-parity** — the compact L-hop receptive-set forward's
-    logits are f32-BIT-identical (``==``) to the trainer's
-    ``evaluate()``/``predict`` path on the cora fixture, for GCN and GAT
-    under BOTH comm schedules (the per-row fold recipes reproduce each
-    owner chip's addition sequence exactly; the GAT stabilizers arrive
-    precomputed);
+  * **routed-logit parity to the ulp** — the compact L-hop receptive-set
+    forward's logits match the trainer's ``evaluate()``/``predict`` path
+    on the cora fixture within ``PARITY_ULPS`` ulps of a row's largest
+    logit, for GCN and GAT under BOTH comm schedules.  The per-row fold
+    recipes reproduce each owner chip's addition sequence exactly and the
+    GAT stabilizers arrive precomputed, but the two are different compiled
+    shapes, and XLA:CPU contracts multiply-add chains into FMAs per
+    compiled shape: under jax 0.9.0 a GCN logit rounds differently by one
+    ulp of its row's scale at some batch shapes (max |diff| 6e-8 on
+    logits of 0.5–0.9), so ``==`` is not a contract this backend keeps.
+    A wrong neighbour, weight or wire cast moves a logit by 1e-3 or more;
   * **no-recompile across growth** — the doubling-ladder shape keys mean a
     repeated traffic sweep (any query count, any receptive-set size seen
     before) never compiles again: ``compile_count`` pinned over a replayed
@@ -16,7 +21,7 @@ The contracts pinned here:
   * **weight hot-swap** — ``swap_weights`` verifies provenance (plan
     digest + model config) BEFORE touching engine state, swaps with ZERO
     re-compiles (``compile_count`` pinned), bumps ``weights_rev``, and the
-    served logits flip to the new checkpoint's bit-exact values;
+    served logits flip to the new checkpoint's values (same ulp contract);
   * **checkpoint watch** — ``--watch-checkpoint-dir``'s poller picks up
     the newest intact checkpoint from a PR-13 rotation directory once per
     flush window;
@@ -45,6 +50,21 @@ from sgcn_tpu.serve import (MicroBatcher, ServeEngine,  # noqa: E402
                             SubgraphIndex, run_loadgen)
 from sgcn_tpu.train import FullBatchTrainer, make_train_data  # noqa: E402
 from sgcn_tpu.utils.checkpoint import save_checkpoint  # noqa: E402
+
+
+# ulps of a row's largest logit a compact-shape forward may differ from
+# the full-shape one by (module docstring): 1 measured, 2 allowed
+PARITY_ULPS = 2
+
+
+def assert_logits_match(got, want, err_msg=""):
+    tol = PARITY_ULPS * np.spacing(
+        np.abs(want).max(axis=-1, keepdims=True).astype(np.float32))
+    diff = np.abs(got - want)
+    assert got.shape == want.shape and np.all(diff <= tol), (
+        f"{err_msg} max |diff| {diff.max()} = "
+        f"{(diff / (tol / PARITY_ULPS)).max():.1f} ulp of the row scale "
+        f"(allowed {PARITY_ULPS})")
 
 
 @pytest.fixture(scope="module")
@@ -78,11 +98,11 @@ def tiny():
     # placement exactly, or == breaks only in the narrowed configuration
     ("gcn", "a2a", "bfloat16"),
 ])
-def test_subgraph_parity_bit_identical(cora, model, sched, halo_dtype):
-    """The acceptance criterion: sub-graph routed logits ``==`` the
-    trainer's eval-path logits for every (model, schedule, wire-dtype)
-    combination — across several batch shapes, so multiple receptive-set
-    buckets are exercised."""
+def test_subgraph_parity_to_the_ulp(cora, model, sched, halo_dtype):
+    """The acceptance criterion: sub-graph routed logits match the
+    trainer's eval-path logits to ``PARITY_ULPS`` for every (model,
+    schedule, wire-dtype) combination — across several batch shapes, so
+    multiple receptive-set buckets are exercised."""
     import jax
 
     plan, feats, labels = cora["plan"], cora["feats"], cora["labels"]
@@ -105,15 +125,42 @@ def test_subgraph_parity_bit_identical(cora, model, sched, halo_dtype):
         sel = rng.permutation(plan.n)[:nq]
         got = eng.query(sel)
         assert got.dtype == np.float32
-        assert np.array_equal(got, expected[sel]), (
+        assert_logits_match(
+            got, expected[sel],
             f"{model}/{sched}: sub-graph logits differ from evaluate() at "
-            f"nq={nq} (max |diff| {np.abs(got - expected[sel]).max()})")
+            f"nq={nq}:")
     g = eng.gauges()
     assert g["serve_mode"] == "subgraph"
     # query-proportionality on the fixture itself: the receptive sets are
     # far below the k·B rows the full forward computes per batch
     assert 0 < g["touched_rows_per_query"] < g["full_rows_per_forward"]
     assert 0 < g["subgraph_flops_per_query"] < g["full_forward_flops"]
+
+
+def test_subgraph_per_query_cut_over_fixed_chunks(cora):
+    """Query-proportionality as counts, no clock and no engine: over a
+    fixed chunking of one seeded query trace (5 = the batch an open loop
+    at 50 queries/s fills in a 100 ms budget), the routed queries' 2-hop
+    receptive sets touch ≥ 10× fewer rows and cost ≥ 10× fewer analytic
+    FLOPs per query than the k·B rows a full forward computes per batch."""
+    from sgcn_tpu.obs.attribution import forward_flops, subgraph_batch_flops
+    from sgcn_tpu.serve import VertexRouter, synthetic_query_ids
+
+    plan, widths = cora["plan"], cora["widths"]
+    fin, nl = cora["feats"].shape[1], len(cora["widths"])
+    index, router = SubgraphIndex(plan, "gcn"), VertexRouter(plan)
+    qids = synthetic_query_ids(plan.n, 200, seed=0)
+    touched = edges = batches = 0
+    for i in range(0, len(qids), 5):
+        sets = [index.receptive(q, nl)
+                for q in router.route(qids[i: i + 5]).values()]
+        touched += sum(len(u) for u in sets)
+        edges += sum(index.edges_in(u) for u in sets)
+        batches += 1
+    assert touched == 1252                # 6.26 rows a query, this trace
+    assert plan.k * plan.b * batches >= 10 * touched
+    assert (forward_flops(plan, fin, widths) * batches
+            >= 10 * subgraph_batch_flops(touched, edges, fin, widths))
 
 
 # ----------------------------------------------------- buckets / recompile
@@ -180,7 +227,7 @@ def test_hot_swap_provenance_and_pinned_compiles(tiny, tmp_path):
                       checkpoint=ckpt_a, max_batch=8, mode="subgraph")
     eng.set_features(feats)
     sel = np.arange(0, plan.n, 5)[:8]
-    np.testing.assert_array_equal(eng.query(sel), exp_a[sel])
+    assert_logits_match(eng.query(sel), exp_a[sel])
     warm = eng.compile_count
     assert eng.weights_rev == 0
 
@@ -199,9 +246,9 @@ def test_hot_swap_provenance_and_pinned_compiles(tiny, tmp_path):
     with pytest.raises(ValueError, match="model config mismatch"):
         eng.swap_weights(ckpt_w)
     assert eng.weights_rev == 0 and eng.compile_count == warm
-    np.testing.assert_array_equal(eng.query(sel), exp_a[sel])
+    assert_logits_match(eng.query(sel), exp_a[sel])
 
-    # the real swap: zero recompiles, bumped rev, bit-exact new logits
+    # the real swap: zero recompiles, bumped rev, the new weights' logits
     meta = eng.swap_weights(ckpt_b)
     assert meta["step"] == 1
     assert eng.weights_rev == 1
@@ -209,7 +256,8 @@ def test_hot_swap_provenance_and_pinned_compiles(tiny, tmp_path):
     assert eng.compile_count == warm, (
         "swap_weights recompiled — params are AOT-program inputs and the "
         "swap must be zero re-lowering by contract")
-    np.testing.assert_array_equal(got, exp_b[sel])
+    assert_logits_match(got, exp_b[sel])
+    assert not np.allclose(exp_a[sel], exp_b[sel], atol=1e-4)
 
 
 def test_hot_swap_refreshes_gat_stabilizers(tiny, tmp_path):
@@ -265,7 +313,7 @@ def test_watch_checkpoint_dir_hot_swaps(tiny, tmp_path):
     exp1 = tr.predict(data).astype(np.float32)
     got = eng.query(sel)               # poll at this flush window swaps
     assert eng.weights_rev == 1
-    np.testing.assert_array_equal(got, exp1[sel])
+    assert_logits_match(got, exp1[sel])
 
     # a corrupt newest checkpoint is skipped with a warning; the engine
     # keeps serving the last intact revision

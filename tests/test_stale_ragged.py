@@ -34,6 +34,7 @@ from sgcn_tpu.parallel import build_comm_plan
 from sgcn_tpu.partition.emit import read_partvec
 from sgcn_tpu.prep import normalize_adjacency
 from sgcn_tpu.train import FullBatchTrainer, make_train_data
+from sgcn_tpu.utils.stats import CommStats
 
 FIX = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 
@@ -182,6 +183,20 @@ def test_composed_telemetry_tracks_books_and_reconciles(cora, tmp_path,
     assert rep["wire_rows_per_exchange"] == \
         plan.wire_rows_per_exchange("ragged")
     assert rep["wire_rows_per_exchange"] < plan.wire_rows_per_exchange("a2a")
+    # the composition's acceptance inequality, as counts over the SAME four
+    # steps: the composed arm's exposed wire rows lie strictly below both
+    # single levers' — below ragged+exact because half its steps are
+    # hidden, below a2a+stale because its sync steps ride the smaller ring
+    levers = {"ragged_exact": CommStats.from_plan(plan, "ragged"),
+              "a2a_stale": CommStats.from_plan(plan, "a2a")}
+    for i in range(4):
+        levers["ragged_exact"].count_step(nl)
+        levers["a2a_stale"].count_step(nl, hidden=i % 3 != 0)
+    assert rep["exposed_wire_rows_total"] == \
+        2 * 2 * nl * plan.wire_rows_per_exchange("ragged")
+    for st in levers.values():
+        assert rep["exposed_wire_rows_total"] < \
+            st.report()["exposed_wire_rows_total"]
 
     log = load_run(str(tmp_path))
     # the schedule-selection decision log landed in the manifest

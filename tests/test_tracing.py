@@ -5,7 +5,7 @@ and its schema/recorder integration:
     same-name entry no longer double-counts (the pre-fix corruption), and
     the inclusive side keeps the whole-region semantics ``fit()`` times with;
   * SpanTimer — nested spans over the shared timer, span events through the
-    recorder, ``emit_span``/``scoped_span`` env-gating;
+    recorder;
   * trace parser — op classification into the attribution vocabulary, the
     overlap/exposed/straggler math on a synthetic trace, and a real parse
     of the checked-in 8-vdev trace artifact;
@@ -120,35 +120,6 @@ def test_span_timer_nesting_and_events(tmp_path):
     assert spans[0]["step"] == 1
     # the span generalizes PhaseTimer: both names landed in the timer too
     assert st.timer.counts["step"] == st.timer.counts["train_step"] == 1
-
-
-def test_emit_span_env_gated(tmp_path, monkeypatch):
-    from sgcn_tpu.obs import RunRecorder, load_run
-    from sgcn_tpu.obs.tracing import emit_span, scoped_span
-
-    d = str(tmp_path / "bench_run")
-    monkeypatch.delenv("SGCN_METRICS_OUT", raising=False)
-    emit_span("no:dir", 0.1)
-    assert not os.path.exists(os.path.join(d, "events.jsonl"))
-    monkeypatch.setenv("SGCN_METRICS_OUT", d)
-    with scoped_span("bench:flagship", phase="flagship"):
-        pass
-    emit_span("bench:stale_ab", 0.25, phase="ab_child", detail="n=100")
-    # a KILLED bench leaves events.jsonl with no manifest — the completed
-    # measurements must still load (manifest {}), like heartbeat-only dirs
-    partial = load_run(d)
-    assert partial.manifest == {}
-    assert [e["name"] for e in partial.events] == ["bench:flagship",
-                                                   "bench:stale_ab"]
-    # the bench flow creates the manifest at emission time; the earlier
-    # span appends survive in the same stream
-    with RunRecorder(d, config={}, run_kind="bench") as rec:
-        rec.record_summary({"metric": "x", "value": 1})
-    log = load_run(d)
-    names = [e["name"] for e in log.events if e["kind"] == "span"]
-    assert names == ["bench:flagship", "bench:stale_ab"]
-    assert all(e["pid"] == os.getpid() for e in log.events
-               if e["kind"] == "span")
 
 
 # ------------------------------------------------------------- trace parser
