@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pspmm import (pspmm_ell_sym, pspmm_overlap, pspmm_ragged_sym,
+from ..ops.pspmm import (pspmm_ell_sym, pspmm_ell_sym_coo, pspmm_overlap,
+                         pspmm_ragged_sym,
                          pspmm_replica, pspmm_replica_partial,
                          pspmm_replica_ragged, pspmm_replica_stale,
                          pspmm_replica_stale_ragged, pspmm_stale,
@@ -38,6 +39,12 @@ from .activations import get_activation
 GCN_PLAN_FIELDS_SYM = ("send_idx", "halo_src", "ell_idx", "ell_w",
                        "ltail_dst", "ltail_src", "ltail_w",
                        "hedge_dst", "hedge_src", "hedge_w")
+# the exact full-batch step on symmetric Â: the same exchange and ELL, the
+# hub tail and the halo-source edges in slot form instead of the COO lists
+# (CommPlan.ensure_fold_slots; chosen by resolve_forward_setup)
+GCN_PLAN_FIELDS_SLOTS = ("send_idx", "halo_src", "ell_idx", "ell_w",
+                         "ft_idx", "ft_w", "ft_row",
+                         "fh_idx", "fh_w", "fh_row")
 GCN_PLAN_FIELDS_GEN = ("send_idx", "halo_src", "ledge_dst", "ledge_src",
                        "ledge_w", "hedge_dst", "hedge_src", "hedge_w")
 GCN_PLAN_FIELDS_RAGGED = ("rsend_idx", "ell_idx", "ell_w",
@@ -99,6 +106,7 @@ def _gcn_aggregator(
     comm_schedule: str = "a2a",
     rr_sizes: tuple | None = None,
     rr_edge_sizes: tuple | None = None,
+    fold_classes: tuple | None = None,
     axis_name: str = AXIS,
 ):
     """``agg(x) = Â·x`` (halo exchange + local fold) for one plan and one
@@ -165,12 +173,17 @@ def _gcn_aggregator(
             raise ValueError(
                 "symmetric GCN forward needs the plan's static ell_buckets")
 
-        def agg(x):
-            return pspmm_ell_sym(
-                x, pa["send_idx"], pa["halo_src"], pa["ell_idx"], pa["ell_w"],
-                pa["ltail_dst"], pa["ltail_src"], pa["ltail_w"],
-                pa["hedge_dst"], pa["hedge_src"], pa["hedge_w"],
-                ell_buckets, axis_name, halo_dtype)
+        if fold_classes is not None:
+            # the exact full-batch setup: both COO stores in slot form
+            def agg(x):
+                return pspmm_ell_sym(
+                    x, *(pa[f] for f in GCN_PLAN_FIELDS_SLOTS),
+                    ell_buckets, *fold_classes, axis_name, halo_dtype)
+        else:
+            def agg(x):
+                return pspmm_ell_sym_coo(
+                    x, *(pa[f] for f in GCN_PLAN_FIELDS_SYM),
+                    ell_buckets, axis_name, halo_dtype)
     else:
         def agg(x):
             return pspmm_overlap(
@@ -205,6 +218,10 @@ def gcn_forward_local(
                                         # ring, docs/comm_schedule.md)
     rr_sizes: tuple | None = None,      # static plan.rr_sizes (ragged)
     rr_edge_sizes: tuple | None = None,  # static plan.rr_edge_sizes (ragged)
+    fold_classes: tuple | None = None,  # static (tail, halo) width classes
+                                        # of the slot-form COO stores
+                                        # (GCN_PLAN_FIELDS_SLOTS); None: the
+                                        # COO lists of GCN_PLAN_FIELDS_SYM
     axis_name: str = AXIS,
     input_aggregated: bool = False,     # static: ``h`` is already Â·h0
                                         # (gcn_aggregate_local) — layer 0
@@ -247,7 +264,8 @@ def gcn_forward_local(
         pallas_tb=pallas_tb, pallas_emulate=pallas_emulate,
         pallas_lclasses=pallas_lclasses, pallas_hclasses=pallas_hclasses,
         halo_dtype=halo_dtype, comm_schedule=comm_schedule,
-        rr_sizes=rr_sizes, rr_edge_sizes=rr_edge_sizes, axis_name=axis_name)
+        rr_sizes=rr_sizes, rr_edge_sizes=rr_edge_sizes,
+        fold_classes=fold_classes, axis_name=axis_name)
 
     for i, w in enumerate(params):
         with scope("layer", i):

@@ -71,6 +71,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs.tracing import scope, subscope
 from ..ops.pspmm import bucketed_slot_reduce, halo_exchange_multi
@@ -228,13 +229,14 @@ def plan_virtual_rows(plan) -> tuple:
     that have edges, stacked per chip like the plan's, and ``{"tail_shape":
     (nv, W) | None, "halo_shape": (nv, W) | None}``."""
     arrays, statics = {}, {}
-    layouts = plan.virtual_rows()
+    layouts = plan.virtual_rows()       # one class, ``VROW_WIDTH`` wide
     for store, pre in (("tail", "vt"), ("halo", "vh")):
         lay = layouts[store]
-        statics[store + "_shape"] = None if lay is None else lay["shape"]
+        statics[store + "_shape"] = None if lay is None else lay["classes"][0]
         if lay is not None:
+            # attention reads Â's pattern only: the weights narrow to a mask
             arrays.update({f"{pre}_idx": lay["idx"],
-                           f"{pre}_mask": lay["mask"],
+                           f"{pre}_mask": (lay["w"] != 0).astype(np.int8),
                            f"{pre}_row": lay["row"]})
     return arrays, statics
 

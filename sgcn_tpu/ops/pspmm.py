@@ -46,9 +46,25 @@ _SCHED_OVERLAP_SLOTS = 16
 _SCAN_LIVE_LIMIT = 3 * 1024**3
 
 
+def _scan_unroll(wb: int, slot_bytes: int, live_limit: int,
+                 scanned: bool = False) -> int | None:
+    """How ``bucketed_slot_reduce`` runs a bucket of ``wb`` slots of
+    ``slot_bytes``: ``None`` for the unrolled branch, else the unroll factor
+    of its scan — the LARGEST (≤ 4) whose live temporaries fit
+    ``live_limit``."""
+    if wb <= 2 or (not scanned and min(wb, _SCHED_OVERLAP_SLOTS) * slot_bytes
+                   <= _CONCURRENT_TEMP_LIMIT):
+        return None
+    # cap 8 measured OOM at ogbn-products f32 (16.59/15.75 GB): the budget
+    # models only slot temps, and the rest of the epoch program leaves
+    # < _SCAN_LIVE_LIMIT of true headroom there
+    return max(1, min(4, live_limit // max(slot_bytes, 1)))
+
+
 def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
                          slot_bytes, scan_live_limit: int | None = None,
-                         combine=jnp.add, with_rows: bool = False):
+                         combine=jnp.add, with_rows: bool = False,
+                         scanned: bool = False):
     """Σ over width slots of ``contrib(idx_t, w_t)`` per bucket — THE shared
     memory policy for every bucketed width-major layout (GCN SpMM, GAT
     attention passes).
@@ -77,7 +93,9 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     ``init`` of its identity); ``with_rows=True`` hands ``contrib`` and
     ``init`` the bucket's first output row as a third / second argument
     (a static int: the attention passes slice their per-destination
-    scalars by it).  Returns the per-bucket reduced pytrees in bucket order.
+    scalars by it).  ``scanned=True`` takes the scan form for every bucket
+    wider than two slots, whatever its size (the fold passes of
+    ``fold_slots``).  Returns the per-bucket reduced pytrees in bucket order.
     """
     live_limit = (_SCAN_LIVE_LIMIT if scan_live_limit is None
                   else scan_live_limit)
@@ -85,8 +103,8 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     off = row = 0
     for nb, wb in buckets:
         at = (row,) if with_rows else ()
-        if (min(wb, _SCHED_OVERLAP_SLOTS) * slot_bytes(nb)
-                <= _CONCURRENT_TEMP_LIMIT) or wb <= 2:
+        unroll = _scan_unroll(wb, slot_bytes(nb), live_limit, scanned)
+        if unroll is None:
             acc = None
             for t in range(wb):
                 seg = slice(off + t * nb, off + (t + 1) * nb)
@@ -108,10 +126,6 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
 
             acc0 = jax.tree.map(lambda x: x + zero.astype(x.dtype),
                                 init(nb, *at))
-            # cap 8 measured OOM at ogbn-products f32 (16.59/15.75 GB): the
-            # budget models only slot temps, and the rest of the epoch
-            # program leaves < _SCAN_LIVE_LIMIT of true headroom there
-            unroll = max(1, min(4, live_limit // max(slot_bytes(nb), 1)))
             acc, _ = jax.lax.scan(body, acc0, (seg_i, seg_w), unroll=unroll)
         outs.append(acc)
         off += nb * wb
@@ -356,21 +370,9 @@ def pspmm_overlap(h, send_idx, halo_src,
     return local + remote
 
 
-def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
-    """Local SpMM in bucketed-ELL layout + COO overflow tail.
-
-    ``buckets = ((nb, wb), ...)`` is the plan's static degree-bucket
-    structure (``sgcn_tpu.parallel.plan``): the next ``nb`` output rows each
-    own ``wb`` flat slots of ``ell_idx``/``ell_w``, stored WIDTH-MAJOR (slot
-    t of the bucket's rows is one contiguous (nb,) run).  Per slot this is
-    one fused gather·weight + accumulate — no (nb, wb, f) intermediate
-    exists, which is the point: the row-major gather+reduce form paid
-    ~17 ms/epoch of XLA "data formatting" relayouts at ogbn-arxiv scale
-    (round-3 trace), and the unrolled per-slot form measured 444 vs 367
-    Mrows/s isolated.  The v5e gather is row-rate-bound (pattern/dtype-
-    independent), so the bucketed layout's ~1.1-1.2× padding vs
-    single-width ELL's ~1.7× is a direct time saving.
-    """
+def _ell_slots(ell_idx, ell_w, h, buckets):
+    """The bucketed-ELL slot passes of ``spmm_ell`` / ``_pspmm_ell_once``:
+    per slot one fused gather·weight + accumulate, under ``agg_slots``."""
     if sum(nb * wb for nb, wb in buckets) != ell_idx.shape[0]:
         raise ValueError(
             f"bucket structure {buckets} does not cover the flat ELL arrays "
@@ -389,23 +391,144 @@ def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
             contrib=lambda idx, w: jnp.take(h, idx, axis=0) * w[:, None],
             init=lambda nb: jnp.zeros((nb, f), h.dtype),
             slot_bytes=lambda nb: nb * f * 4)
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+
+
+def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
+    """Local SpMM in bucketed-ELL layout + COO overflow tail.
+
+    ``buckets = ((nb, wb), ...)`` is the plan's static degree-bucket
+    structure (``sgcn_tpu.parallel.plan``): the next ``nb`` output rows each
+    own ``wb`` flat slots of ``ell_idx``/``ell_w``, stored WIDTH-MAJOR (slot
+    t of the bucket's rows is one contiguous (nb,) run).  Per slot this is
+    one fused gather·weight + accumulate — no (nb, wb, f) intermediate
+    exists, which is the point: the row-major gather+reduce form makes XLA
+    relayout that intermediate.  The v5e gather is row-rate-bound (8.06 ns
+    an executed slot at 128 lanes f32 whatever the index pattern; ledger,
+    PR 29, ``products.fullbatch``), so executed slots are the time and the
+    bucketed layout's padding (4.5 % there) is what a single-width ELL
+    would multiply.
+
+    The tail is folded per edge by a sorted ``segment_sum``, which costs
+    about two slots an edge (15.2 ns; same ledger line): the form of the
+    callers that keep COO stores (the ragged, stale and replica ops, the
+    mini-batch envelope, sub-graph serving).  The exact full-batch step
+    folds the same edges as slot passes over virtual rows
+    (``_pspmm_ell_once``).
+    """
+    out = _ell_slots(ell_idx, ell_w, h, buckets)
     with scope("agg_tail"):
         tg = jnp.take(h, tail_src, axis=0) * tail_w[:, None]
         # the tail is dst-sorted by construction (plan edges are dst-sorted
-        # and padding appends dst=b-1), so a sorted segment_sum beats the
-        # scatter-add form: measured 58.6 -> 54.0 ms/epoch (-8%) at
-        # ogbn-arxiv shape on a power-law (BA) graph where hub spill puts 8%
-        # of edges in the tail (no-op on ER benches, whose tails are empty)
+        # and padding appends dst=b-1), so the segment_sum is told so
         tsum = jax.ops.segment_sum(tg, tail_dst, num_segments=out.shape[0],
                                    indices_are_sorted=True)
         return out + tsum
 
 
+# the fold passes run beside the main slot passes in one program.  Their
+# scans unroll only as far as this many bytes of live slot temporaries —
+# at the cells' shapes the large classes (0.5 GB a slot) do not unroll at
+# all — so that the folds never set the step's workspace: compiled for the
+# v5e, the step's temporaries read 3.97 GB (gp4) / 7.16 GB (one chip)
+# against the parent's 4.05 / 7.29, and 5.12 / 8.78 GB at twice this limit,
+# for 7–11 % of a fold pass (PERF.md §6, PR 30)
+_FOLD_SCAN_LIVE = 3 * 1024**3 // 4
+
+
+def fold_slots(out, table, idx, w, row, classes,
+               scan_live_limit: int = _FOLD_SCAN_LIVE):
+    """``out`` plus one COO edge store in slot form
+    (``parallel.plan._build_virtual_rows``): every class ``(nv, W)`` of
+    ``classes`` is a bucket of ``bucketed_slot_reduce`` (``take(table, idx)
+    · w`` summed over its W slots, per virtual row), and one sorted
+    scatter-add a class folds the ``nv`` row sums into their destinations
+    ``row``.  No classes, no pass.  Every class runs in the scan form: on
+    the v5e a scanned class of virtual rows cost 5–7 ns a slot where the
+    unrolled form of the same store cost 7–10 (PERF.md §6, PR 30, step 1:
+    gp4's tail at one width of 16, scanned, 18.5 ms a pass; at classes {8,
+    16}, fewer slots but unrolled, 29.9)."""
+    if not classes:
+        return out
+    f = table.shape[-1]
+    parts = bucketed_slot_reduce(
+        idx, w, classes,
+        contrib=lambda i, wt: jnp.take(table, i, axis=0) * wt[:, None],
+        init=lambda nv: jnp.zeros((nv, f), table.dtype),
+        slot_bytes=lambda nv: nv * f * 4, scan_live_limit=scan_live_limit,
+        scanned=True)
+    r0 = 0
+    for (nv, _), part in zip(classes, parts):
+        out = out.at[row[r0: r0 + nv]].add(part, indices_are_sorted=True)
+        r0 += nv
+    return out
+
+
 def _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
-                    ltail_dst, ltail_src, ltail_w,
-                    hedge_dst, hedge_src, hedge_w, buckets, axis_name,
+                    ft_idx, ft_w, ft_row, fh_idx, fh_w, fh_row,
+                    buckets, tail_classes, halo_classes, axis_name,
                     halo_dtype=None):
+    # no halo edge on any chip: nothing reads a halo table, nothing is sent
+    halo = (halo_exchange(h, send_idx, halo_src, axis_name, halo_dtype)
+            if halo_classes else None)
+    # local ELL aggregation has no data dependence on the exchange (overlap)
+    out = _ell_slots(ell_idx, ell_w, h, buckets)
+    with scope("agg_tail"):
+        out = fold_slots(out, h, ft_idx, ft_w, ft_row, tail_classes)
+    with scope("agg_halo_fold"):
+        return fold_slots(out, halo, fh_idx, fh_w, fh_row, halo_classes)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(11, 12, 13, 14, 15))
+def pspmm_ell_sym(h, send_idx, halo_src, ell_idx, ell_w,
+                  ft_idx, ft_w, ft_row, fh_idx, fh_w, fh_row,
+                  buckets, tail_classes, halo_classes,
+                  axis_name=AXIS, halo_dtype=None):
+    """``PSpMM`` for a SYMMETRIC Â — the exact full-batch aggregation: ELL
+    slot passes over the local edges, the hub tail (``ft_*``) and the
+    halo-source edges (``fh_*``) as slot passes over virtual rows in the
+    width classes the plan chose for each store
+    (``CommPlan.ensure_fold_slots``), and a custom backward that reuses the
+    forward form.
+
+    JAX's mechanical transpose of a gather is a scatter-add, which the v5e
+    runs at about half the rate of the gather form per entry; for symmetric
+    Â (the reference's standing assumption — its backward applies A, not
+    Aᵀ, ``Parallel-GCN/main.c:374-404``) the gradient is just ``Â·g``,
+    computed exactly like the forward, including the same halo exchange
+    (the symmetric pattern makes the reversed comm identical to the forward
+    comm).
+
+    Only valid when ``plan.symmetric``; callers must fall back to
+    ``pspmm_overlap`` otherwise.  ``pspmm_ell_sym_coo`` is the same
+    aggregation with both stores folded per edge.
+    """
+    return _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
+                           ft_idx, ft_w, ft_row, fh_idx, fh_w, fh_row,
+                           buckets, tail_classes, halo_classes, axis_name,
+                           halo_dtype)
+
+
+def _pspmm_ell_sym_fwd(h, *args):
+    return _pspmm_ell_once(h, *args), args[:10]
+
+
+def _pspmm_ell_sym_bwd(buckets, tail_classes, halo_classes, axis_name,
+                       halo_dtype, res, g):
+    # the gradient exchange rides the same narrow wire as the forward's —
+    # both directions of ICI traffic halve under halo_dtype='bfloat16'
+    gh = _pspmm_ell_once(g, *res, buckets, tail_classes, halo_classes,
+                         axis_name, halo_dtype)
+    return (gh, *[None] * 10)
+
+
+pspmm_ell_sym.defvjp(_pspmm_ell_sym_fwd, _pspmm_ell_sym_bwd)
+
+
+def _pspmm_ell_coo_once(h, send_idx, halo_src, ell_idx, ell_w,
+                        ltail_dst, ltail_src, ltail_w,
+                        hedge_dst, hedge_src, hedge_w, buckets, axis_name,
+                        halo_dtype=None):
     halo = halo_exchange(h, send_idx, halo_src, axis_name, halo_dtype)
     # local ELL aggregation has no data dependence on the exchange (overlap)
     local = spmm_ell(ell_idx, ell_w, ltail_dst, ltail_src, ltail_w, h, buckets)
@@ -415,58 +538,34 @@ def _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(11, 12, 13))
-def pspmm_ell_sym(h, send_idx, halo_src, ell_idx, ell_w,
-                  ltail_dst, ltail_src, ltail_w,
-                  hedge_dst, hedge_src, hedge_w, buckets,
-                  axis_name=AXIS, halo_dtype=None):
-    """``PSpMM`` for a SYMMETRIC Â: ELL local aggregation + overlap structure,
-    with a custom backward that reuses the forward form.
-
-    JAX's mechanical transpose of the gather is a scatter-add, ~3.6× slower
-    than the gather form on v5e; for symmetric Â (the reference's standing
-    assumption — its backward applies A, not Aᵀ,
-    ``Parallel-GCN/main.c:374-404``) the gradient is just ``Â·g``, computed
-    exactly like the forward, including the same halo exchange (the
-    symmetric pattern makes the reversed comm identical to the forward
-    comm).  Measured fwd+bwd at ogbn-arxiv scale: 20 ms vs 55 ms for the
-    COO pair, grads bit-identical in f32 tolerance.
-
-    Only valid when ``plan.symmetric``; callers must fall back to
-    ``pspmm_overlap`` otherwise.
-    """
-    return _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
-                           ltail_dst, ltail_src, ltail_w,
-                           hedge_dst, hedge_src, hedge_w, buckets, axis_name,
-                           halo_dtype)
+def pspmm_ell_sym_coo(h, send_idx, halo_src, ell_idx, ell_w,
+                      ltail_dst, ltail_src, ltail_w,
+                      hedge_dst, hedge_src, hedge_w, buckets,
+                      axis_name=AXIS, halo_dtype=None):
+    """``pspmm_ell_sym`` with the hub tail and the halo-source edges as the
+    plan's COO lists (``ltail_*``, ``hedge_*``), each folded per edge by a
+    sorted ``segment_sum`` — the form of the programs that cannot take a
+    plan's virtual rows: the mini-batch step (one compiled envelope serves
+    every batch's plan, and a virtual-row count has no envelope), and the
+    exact forward the stale and replica trainers evaluate with (they ship
+    the COO lists their step ops fold).  Same edges, weights and f32
+    accumulation; only the order of the additions differs."""
+    return _pspmm_ell_coo_once(h, send_idx, halo_src, ell_idx, ell_w,
+                               ltail_dst, ltail_src, ltail_w,
+                               hedge_dst, hedge_src, hedge_w, buckets,
+                               axis_name, halo_dtype)
 
 
-def _pspmm_ell_sym_fwd(h, send_idx, halo_src, ell_idx, ell_w,
-                       ltail_dst, ltail_src, ltail_w,
-                       hedge_dst, hedge_src, hedge_w, buckets, axis_name,
-                       halo_dtype):
-    out = _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
-                          ltail_dst, ltail_src, ltail_w,
-                          hedge_dst, hedge_src, hedge_w, buckets, axis_name,
-                          halo_dtype)
-    res = (send_idx, halo_src, ell_idx, ell_w, ltail_dst, ltail_src, ltail_w,
-           hedge_dst, hedge_src, hedge_w)
-    return out, res
+def _pspmm_ell_sym_coo_fwd(h, *args):
+    return _pspmm_ell_coo_once(h, *args), args[:10]
 
 
-def _pspmm_ell_sym_bwd(buckets, axis_name, halo_dtype, res, g):
-    (send_idx, halo_src, ell_idx, ell_w, ltail_dst, ltail_src, ltail_w,
-     hedge_dst, hedge_src, hedge_w) = res
-    # the gradient exchange rides the same narrow wire as the forward's —
-    # both directions of ICI traffic halve under halo_dtype='bfloat16'
-    gh = _pspmm_ell_once(g, send_idx, halo_src, ell_idx, ell_w,
-                         ltail_dst, ltail_src, ltail_w,
-                         hedge_dst, hedge_src, hedge_w, buckets, axis_name,
-                         halo_dtype)
-    zeros = [None] * 10
-    return (gh, *zeros)
+def _pspmm_ell_sym_coo_bwd(buckets, axis_name, halo_dtype, res, g):
+    gh = _pspmm_ell_coo_once(g, *res, buckets, axis_name, halo_dtype)
+    return (gh, *[None] * 10)
 
 
-pspmm_ell_sym.defvjp(_pspmm_ell_sym_fwd, _pspmm_ell_sym_bwd)
+pspmm_ell_sym_coo.defvjp(_pspmm_ell_sym_coo_fwd, _pspmm_ell_sym_coo_bwd)
 
 
 # -------------------------------------------------------------------- ragged

@@ -67,7 +67,21 @@ PER_CHIP_ARRAY_FIELDS = (
     "rep_ring_pos", "nrep_ring_dst",
     "rep_rows", "rep_row_counts", "ronly_send_idx", "ronly_send_counts",
     "ronly_base_pos", "rep_recv_src",
+    "ft_idx", "ft_w", "ft_row", "fh_idx", "fh_w", "fh_row",
 )
+
+# slots a virtual row of the attention layer holds: the one width that ran
+# at 512 lanes (PERF.md §6, PR 27: hub tail of the products-eighth graph,
+# 2.04 M edges as 92,344 rows; its own optimum is open, PERF.md §7)
+VROW_WIDTH = 32
+
+# the widths a fold store's virtual rows may take, and what one virtual row
+# costs beside one executed slot: the row's share of the sorted scatter-add
+# that folds the rows' sums into their destinations, in slots (measured at
+# 128 lanes f32 on the v5e: 7.8–8.1 ns a row of 0.65–2.26 M scattered into
+# 630,624, against 5.1–6.9 ns a scanned slot; PERF.md §6, PR 30, step 1)
+FOLD_WIDTHS = (4, 8, 16, 32)
+FOLD_ROW_COST = 1.4
 
 # Auto-selection threshold for SGCN_COMM_SCHEDULE=auto: below this dense-a2a
 # padding efficiency (Σ send_counts / (k²·S)) the per-round-sized ragged
@@ -243,6 +257,20 @@ class CommPlan:
     # reference makes the same assumption (backward uses A, not Aᵀ —
     # Parallel-GCN/main.c:374-404).
     symmetric: bool
+
+    # The two COO stores above — the hub tail and the halo-source edges —
+    # again in SLOT form (lazy, ``ensure_fold_slots``): virtual rows in
+    # width classes ``fold_*_classes = ((nv_c, W_c), ...)`` chosen from the
+    # store's run lengths, flat width-major like the ELL.  What the exact
+    # symmetric GCN step ships INSTEAD of ``ltail_*`` / ``hedge_*``.
+    fold_tail_classes: tuple | None = None
+    fold_halo_classes: tuple | None = None
+    ft_idx: np.ndarray | None = None    # (k, Σ nv·W) int32 local src
+    ft_w: np.ndarray | None = None      # (k, Σ nv·W) float32, 0 on padding
+    ft_row: np.ndarray | None = None    # (k, Σ nv) int32 destination row
+    fh_idx: np.ndarray | None = None    # (k, Σ nv·W) int32 halo rank
+    fh_w: np.ndarray | None = None      # (k, Σ nv·W) float32, 0 on padding
+    fh_row: np.ndarray | None = None    # (k, Σ nv) int32 destination row
 
     # The COMBINED edge list (src in [0, B+R), local ‖ halo) in the same
     # bucketed width-major layout — for ops that must see every in-edge of a
@@ -527,19 +555,72 @@ class CommPlan:
                 setattr(self, name, val)
         return self
 
-    def virtual_rows(self) -> dict:
+    def _fold_stores(self) -> dict:
+        """The two COO edge stores a slot-form fold covers, as
+        ``_build_virtual_rows`` takes them (the source table's height last)."""
+        return {
+            "tail": (self.ltail_dst, self.ltail_src, self.ltail_w,
+                     self.ltail_nnz, self.b, self.b),
+            "halo": (self.hedge_dst, self.hedge_src, self.hedge_w,
+                     self.hnnz, self.b, self.r)}
+
+    def virtual_rows(self, widths: tuple | None = (VROW_WIDTH,)) -> dict:
         """The slot form of the two COO edge stores (``_build_virtual_rows``):
         ``{"tail": layout | None, "halo": layout | None}`` from ``ltail_*``
-        and ``hedge_*``.  Built on each call, kept by the caller (today the
-        multi-head attention layer's setup; the GCN folds both stores by
-        scatter-add — ROADMAP A3)."""
-        return {
-            "tail": _build_virtual_rows(self.ltail_dst, self.ltail_src,
-                                        self.ltail_w, self.ltail_nnz, self.b,
-                                        height=self.b),
-            "halo": _build_virtual_rows(self.hedge_dst, self.hedge_src,
-                                        self.hedge_w, self.hnnz, self.b,
-                                        height=self.r)}
+        and ``hedge_*``, at the class ``widths`` given — the attention
+        layer's one width by default, ``None`` for the widths each store's
+        own run lengths choose.  Built on each call, kept by the caller (the
+        multi-head attention layer's setup; ``ensure_fold_slots`` for the
+        exact GCN step)."""
+        return {store: _build_virtual_rows(*args, widths=widths)
+                for store, args in self._fold_stores().items()}
+
+    def ensure_fold_slots(self) -> "CommPlan":
+        """Build, on first use, the slot form of the hub tail (``ft_*``) and
+        of the halo-source edges (``fh_*``) that the exact symmetric GCN
+        aggregation folds (``ops.pspmm.pspmm_ell_sym``), each store at the
+        class widths its own run-length histogram chooses
+        (``choose_fold_widths``).  A store without an edge on any chip has
+        no classes and arrays of length 0: its pass does not exist.
+        ``work_counts()`` then reports what these passes execute, and the
+        counter ``plan.work_counts`` is left anew."""
+        if self.fold_tail_classes is None:
+            from ..obs.tracing import set_counter, span
+            with span("plan.fold"):
+                layouts = self.virtual_rows(widths=None)
+            for store, pre in (("tail", "ft"), ("halo", "fh")):
+                lay = layouts[store] or {
+                    "idx": np.zeros((self.k, 0), np.int32),
+                    "w": np.zeros((self.k, 0), np.float32),
+                    "row": np.zeros((self.k, 0), np.int32), "classes": ()}
+                setattr(self, f"fold_{store}_classes", lay["classes"])
+                for name in ("idx", "w", "row"):
+                    setattr(self, f"{pre}_{name}", lay[name])
+            set_counter("plan.work_counts", self.work_counts())
+        return self
+
+    def fold_counts(self, slots: bool) -> dict:
+        """What the step folds its two COO stores as — the program counter
+        ``fold``: per store the ``form`` (``"slots"``, ``"coo"``, or ``None``
+        where no chip has an edge and the pass does not exist), the class
+        shapes, the virtual rows and slots every chip executes, and the true
+        edges per chip; ``row_cost`` is the constant the widths were chosen
+        by.  ``slots`` says whether the caller's program takes the
+        ``ensure_fold_slots`` layouts (the exact full-batch GCN setup) or the
+        COO lists."""
+        out = {"row_cost": FOLD_ROW_COST}
+        for store, true, coo in (("tail", self.ltail_nnz, self.tl),
+                                 ("halo", self.hnnz, self.eh)):
+            classes = getattr(self, f"fold_{store}_classes") if slots else ()
+            live = bool(np.asarray(true).sum())
+            out[store] = {
+                "form": ("slots" if slots else "coo") if live else None,
+                "classes": [list(c) for c in classes],
+                "virtual_rows": int(sum(nv for nv, _ in classes)),
+                "executed_slots": (int(sum(nv * w for nv, w in classes))
+                                   if slots else int(coo) * live),
+                "true_edges": np.asarray(true).tolist()}
+        return out
 
     # -------------------------------------------------------- ragged schedule
     def ragged_round_sizes(self) -> tuple:
@@ -571,7 +652,9 @@ class CommPlan:
         """Per chip, the true counts of one aggregation pass beside what
         EVERY chip executes for them (all chips run one program over the
         padded shapes): ``slot_edges`` in the ELL buckets (Σ nb·wb slots),
-        ``tail_edges`` (``tl``), ``halo_edges`` (``eh``), ``halo_rows``
+        ``tail_edges`` (``tl``) and ``halo_edges`` (``eh``) — or, once
+        ``ensure_fold_slots`` has built their slot form, the slots of the
+        virtual rows the exact step executes for them — ``halo_rows``
         received into the halo table (``r``) and ``rows_sent`` (``k·s`` send
         slots) — and what the difference is made of: ``padding``, the
         padding entries of the store's gathered index array (``ell_idx``,
@@ -585,12 +668,15 @@ class CommPlan:
         ``obs.tracing.counters()`` by ``build_comm_plan``."""
         rows = self.ell_idx.shape[0]
         unsent = _unsent_slots(self.send_counts, self.send_idx.shape[2])
+        folded = self.fold_tail_classes is not None
         pads = {
             "slot_edges": [self.ell_idx[p][self.ell_w[p] == 0]
                            for p in range(rows)],
-            "tail_edges": [self.ltail_src[p, int(self.ltail_nnz[p]):]
+            "tail_edges": [self.ft_idx[p][self.ft_w[p] == 0] if folded
+                           else self.ltail_src[p, int(self.ltail_nnz[p]):]
                            for p in range(rows)],
-            "halo_edges": [self.hedge_src[p, int(self.hnnz[p]):]
+            "halo_edges": [self.fh_idx[p][self.fh_w[p] == 0] if folded
+                           else self.hedge_src[p, int(self.hnnz[p]):]
                            for p in range(rows)],
             "halo_rows": [self.halo_src[p, int(self.halo_counts[p]):]
                           for p in range(rows)],
@@ -606,8 +692,10 @@ class CommPlan:
             },
             "executed": {
                 "slot_edges": int(sum(nb * wb for nb, wb in self.ell_buckets)),
-                "tail_edges": int(self.tl),
-                "halo_edges": int(self.eh),
+                "tail_edges": int(self.ft_idx.shape[1] if folded
+                                  else self.tl),
+                "halo_edges": int(self.fh_idx.shape[1] if folded
+                                  else self.eh),
                 "halo_rows": int(self.r),
                 "rows_sent": int(self.k * self.s),
             },
@@ -1521,7 +1609,11 @@ def _choose_buckets(profile: np.ndarray, max_buckets: int = 6,
     gather per width slot, so program size scales with Σ wb — a power-law
     hub (ogbn-arxiv hubs reach ~13k in-degree) must NOT set the width.
     Rows beyond the cap spill their overflow edges to the COO tail
-    (scatter-add; hubs are few, so the tail stays small)."""
+    (``ltail_*``) — not a small store: 13 % of the edges, 16.2 M, on the
+    products stand-in (391,884 hub rows; PERF.md §5).  The exact GCN step
+    and the attention layer fold it as slot passes over virtual rows
+    (``_build_virtual_rows``); the programs that keep the COO list fold it
+    by scatter-add at about two slots an edge."""
     b = len(profile)
     d = np.minimum(np.maximum(np.asarray(profile, dtype=np.int64), 0),
                    width_cap)
@@ -1664,57 +1756,121 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
                 ltail_nnz=ltail_nnz)
 
 
-# slots a virtual row holds.  The one width measured on the v5e (PERF.md §6,
-# PR 27: hub tail of the products-eighth graph, 2.04 M edges as 92,344 rows,
-# 0.651 s an epoch where the scatter-add fold took 3.218 s); other widths
-# were not measured against it (PERF.md §7)
-VROW_WIDTH = 32
+def _run_lengths(dst, w, counts, b: int) -> list:
+    """Per chip, the real (weight != 0) edges each of the ``b`` destinations
+    has in a dst-sorted COO store."""
+    return [np.bincount(dst[p, :int(c)][w[p, :int(c)] != 0], minlength=b)
+            for p, c in enumerate(counts)]
 
 
-def _build_virtual_rows(dst, src, w, counts, b: int,
-                        height: int) -> dict | None:
+def _class_rows(dg: np.ndarray, widths: tuple) -> tuple:
+    """How a destination's ``dg`` edges are cut into virtual rows of the
+    ascending class ``widths``: full runs of the widest class, the remainder
+    as one row of the narrowest class that holds it.  Returns ``(full,
+    cls)``: full runs per destination, and the class of its remainder (−1
+    where there is none)."""
+    wmax = widths[-1]
+    full, rem = dg // wmax, dg % wmax
+    cls = np.where(rem > 0, np.searchsorted(widths, rem), -1)
+    return full, cls
+
+
+def fold_class_shapes(degs: list, widths: tuple) -> tuple:
+    """``((nv_c, W_c), ...)`` a store of per-chip run lengths ``degs`` takes
+    at the class ``widths``: the rows of the fullest chip in each class (all
+    chips run one program), a multiple of 8; classes no chip uses are left
+    out."""
+    nv = np.zeros(len(widths), np.int64)
+    for dg in degs:
+        hist = np.bincount(dg)                     # by run length
+        full, cls = _class_rows(np.arange(len(hist)), widths)
+        rows = np.bincount(cls[cls >= 0], weights=hist[cls >= 0],
+                           minlength=len(widths)).astype(np.int64)
+        rows[-1] += int((full * hist).sum())
+        np.maximum(nv, rows, out=nv)
+    return tuple((int(-(-n // 8) * 8), w) for n, w in zip(nv, widths) if n)
+
+
+def choose_fold_widths(degs: list, row_cost: float = FOLD_ROW_COST) -> tuple:
+    """The class widths for a fold store, from the store's own run-length
+    histogram: the ascending subset of ``FOLD_WIDTHS`` with the least
+    ``executed slots + row_cost · virtual rows`` at the shapes every chip
+    executes.  A hub tail (long runs) takes wide rows, a store of short
+    runs over nearly every destination (the halo-source edges) narrow ones;
+    one width too wide pads, one too narrow pays a scatter row per few
+    slots."""
+    best = None
+    for bits in range(1, 1 << len(FOLD_WIDTHS)):
+        widths = tuple(w for i, w in enumerate(FOLD_WIDTHS) if bits >> i & 1)
+        cost = sum(nv * (w + row_cost)
+                   for nv, w in fold_class_shapes(degs, widths))
+        if best is None or cost < best[0]:
+            best = (cost, widths)
+    return best[1]
+
+
+def _build_virtual_rows(dst, src, w, counts, b: int, height: int,
+                        widths: tuple | None = None) -> dict | None:
     """The slot form of a dst-sorted COO edge store — the hub tail
     (``ltail_*``) or the halo-source edges (``hedge_*``) — beside
     ``_build_ell``'s: ``(k, E)`` lists with ``counts[p]`` real edges a chip
-    become **virtual rows**, a destination's edges cut into runs of
-    ``VROW_WIDTH``, each run one row of a single-bucket width-major slot
-    layout, so the store goes through ``ops.pspmm.bucketed_slot_reduce``
-    like every other slot and ONE sorted scatter a pass adds the virtual
-    rows' sums to their destinations (instead of one scatter-add per edge).
+    become **virtual rows**, a destination's edges cut into runs (full runs
+    of the widest class of ``widths``, the remainder one row of the
+    narrowest class that holds it), each class one bucket of a width-major
+    slot layout, so the store goes through
+    ``ops.pspmm.bucketed_slot_reduce`` like every other slot and ONE sorted
+    scatter a class and pass adds the virtual rows' sums to their
+    destinations (instead of one scatter-add per edge).  ``widths=None``
+    takes ``choose_fold_widths`` of the store's run lengths.
 
-    Returns ``idx`` / ``mask`` ``(k, W·nv)`` (slot t of virtual row v at
-    ``t·nv + v``; mask int8, 0 on padding, where ``idx`` holds the
-    ``padding_rows`` of the source table's ``height``), ``row`` ``(k, nv)``
-    the destination of each virtual row (ascending; padding rows point at
-    ``b − 1`` with an empty mask) and the static ``shape = (nv, W)`` — or
-    ``None`` where no chip has a real edge in the store (k = 1 has no halo
-    edges; a graph without hubs no tail), so the caller skips the pass."""
-    k, wd = dst.shape[0], VROW_WIDTH
-    degs = []
-    for p in range(k):
-        cnt = int(counts[p])
-        degs.append(np.bincount(dst[p, :cnt][w[p, :cnt] != 0], minlength=b))
-    nv = max((int((-(-dg // wd)).sum()) for dg in degs), default=0)
-    if nv == 0:
+    Returns ``classes = ((nv_c, W_c), ...)`` (static; ascending widths,
+    shapes the maximum over chips), ``idx`` / ``w`` ``(k, Σ nv_c·W_c)``
+    (class after class; slot t of a class's virtual row v at ``off_c + t·nv_c
+    + v``; ``w`` the edge weights, 0 on padding, where ``idx`` holds the
+    ``padding_rows`` of the source table's ``height``) and ``row`` ``(k, Σ
+    nv_c)`` the destination of each virtual row (ascending within a class;
+    padding rows point at ``b − 1`` with weights 0) — or ``None`` where no
+    chip has a real edge in the store (k = 1 has no halo edges; a graph
+    without hubs no tail), so the caller skips the pass."""
+    k = dst.shape[0]
+    degs = _run_lengths(dst, w, counts, b)
+    if widths is None:
+        widths = choose_fold_widths(degs)
+    classes = fold_class_shapes(degs, widths)
+    if not classes:
         return None
-    nv = -(-nv // 8) * 8
-    idx = np.empty((k, wd * nv), np.int32)
-    mask = np.zeros((k, wd * nv), np.int8)
-    row = np.full((k, nv), b - 1, np.int32)
+    widths = tuple(wd for _, wd in classes)
+    nvs = np.array([nv for nv, _ in classes], np.int64)
+    sizes = nvs * np.array(widths)                  # slots of each class
+    offs, roffs = np.cumsum(sizes) - sizes, np.cumsum(nvs) - nvs
+    total = int(sizes.sum())
+    idx = np.empty((k, total), np.int32)
+    wv = np.zeros((k, total), np.float32)
+    row = np.full((k, int(nvs.sum())), b - 1, np.int32)
+    last = len(widths) - 1
     for p, dg in enumerate(degs):
         cnt = int(counts[p])
         real = w[p, :cnt] != 0
-        d, s0 = dst[p, :cnt][real].astype(np.int64), src[p, :cnt][real]
-        nseg = -(-dg // wd)
-        vbase = np.cumsum(nseg) - nseg              # first virtual row
-        start = np.cumsum(dg) - dg                  # first edge of a row
-        pos = np.arange(len(d)) - start[d]
-        slot = (pos % wd) * nv + vbase[d] + pos // wd
-        mask[p, slot] = 1
-        idx[p, mask[p] == 0] = padding_rows(wd * nv - len(slot), height)
-        idx[p, slot] = s0
-        row[p, : int(nseg.sum())] = np.repeat(np.arange(b), nseg)
-    return {"idx": idx, "mask": mask, "row": row, "shape": (nv, wd)}
+        s0, wt = src[p, :cnt][real], w[p, :cnt][real]   # dst-sorted, as dg
+        full, cls = _class_rows(dg, widths)
+        first = np.cumsum(dg) - dg                  # first edge of a row
+        for c, wd in enumerate(widths):
+            # a virtual row is a contiguous run of its destination's edges:
+            # where it starts, how many it holds
+            per = (cls == c).astype(np.int64) + (full if c == last else 0)
+            dests = np.repeat(np.arange(b), per)
+            row[p, roffs[c]: roffs[c] + len(dests)] = dests
+            nth = np.arange(len(dests)) - np.repeat(np.cumsum(per) - per, per)
+            skip = nth * wd if c == last else full[dests] * widths[-1]
+            start, length = first[dests] + skip, dg[dests] - skip
+            seg = slice(offs[c], offs[c] + sizes[c])
+            iv, wvv = (x[p, seg].reshape(wd, nvs[c]) for x in (idx, wv))
+            for t in range(wd):
+                v = np.flatnonzero(length > t)
+                iv[t, v], wvv[t, v] = s0[start[v] + t], wt[start[v] + t]
+        pad = wv[p] == 0
+        idx[p, pad] = padding_rows(int(pad.sum()), height)
+    return {"idx": idx, "w": wv, "row": row, "classes": classes}
 
 
 def shared_ell_buckets(plans: list, b: int, combined: bool = False) -> tuple:

@@ -155,7 +155,8 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
                           refresh_band: float | None = None,
                           serve_subgraph: bool = False,
                           allow_pallas: bool = True,
-                          model_args: dict | None = None
+                          model_args: dict | None = None,
+                          shared_envelope: bool = False
                           ) -> ForwardSetup:
     """Resolve (schedule, shipped plan fields, static forward kwargs) for one
     plan — the selection logic that used to live inline in
@@ -173,6 +174,14 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
     VMEM-fit rule — the mini-batch trainer's ONE compiled step must serve
     every per-batch plan, and the Pallas tile layout (per-class Emax_c
     statics, tiles built per plan) has no shared-envelope form.
+    ``shared_envelope=True`` says the same of every layout DERIVED from one
+    plan's edges: the program is compiled once for many plans padded to one
+    envelope (the mini-batch trainer again), so the forward keeps the COO
+    hub tail and halo-edge lists, which have an envelope (``tl``, ``eh``).
+    Without it the exact GCN setup — symmetric Â, a2a schedule, no carried
+    halo, the full forward — ships both stores in slot form instead
+    (``CommPlan.ensure_fold_slots``, ``ops.pspmm.pspmm_ell_sym``): chosen
+    here from what the setup observes, never by an option.
     ``model_args`` is the configuration of a model that has one (``mhgat``:
     heads per layer, concat or mean, slope, skip, bias): validated by the
     registry entry's setup hook and bound into the init function and the
@@ -327,6 +336,21 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
                 # concat needs only rr_sizes — no redge fold, no halo_r)
                 fwd_static.update(comm_schedule="ragged",
                                   rr_sizes=plan.rr_sizes)
+    if (model == "gcn" and plan.symmetric and comm_schedule == "a2a"
+            and not halo_staleness and not replica_budget
+            and not serve_subgraph and not shared_envelope
+            and "pallas_tb" not in fwd_static):
+        # the exact step folds the hub tail and the halo-source edges as
+        # slot passes over virtual rows, and ships that form INSTEAD of the
+        # COO lists (a fold by scatter-add costs two slots an edge: PERF.md
+        # §6, PR 30).  The carried-halo families fold inside their own ops,
+        # sub-graph serving mirrors the COO chains row by row, and a shared
+        # envelope has no virtual-row count: they keep the lists.
+        from ..models.gcn import GCN_PLAN_FIELDS_SLOTS
+        plan.ensure_fold_slots()
+        plan_fields = GCN_PLAN_FIELDS_SLOTS
+        fwd_static = dict(fwd_static, fold_classes=(plan.fold_tail_classes,
+                                                    plan.fold_halo_classes))
     return ForwardSetup(model=model, comm_schedule=comm_schedule,
                         plan_fields=plan_fields, fwd_static=fwd_static,
                         forward_fn=forward_fn, init_fn=init_fn,
@@ -492,8 +516,14 @@ class FullBatchTrainer:
         allow_pallas: bool = True,
         memory_budget: int | None = None,
         model_args: dict | None = None,
+        shared_envelope: bool = False,
     ):
-        """``compute_dtype='bfloat16'`` runs forward/backward (including the
+        """``shared_envelope=True``: this trainer's step is compiled once for
+        many plans padded to one envelope (``MiniBatchTrainer``'s inner
+        trainer), so nothing derived from one plan's edges may shape it
+        (``resolve_forward_setup``).
+
+        ``compute_dtype='bfloat16'`` runs forward/backward (including the
         halo exchange — half the ICI bytes) in bf16 with f32 master params
         and f32 loss/grad reduction; the reference stacks are f32-only, this
         is the TPU-native mixed-precision option (MXU eats bf16).
@@ -659,7 +689,8 @@ class FullBatchTrainer:
             plan, fin, widths, model=model, comm_schedule=comm_schedule,
             compute_dtype=compute_dtype, halo_staleness=halo_staleness,
             replica_budget=replica_budget, refresh_band=refresh_band,
-            allow_pallas=allow_pallas, model_args=model_args)
+            allow_pallas=allow_pallas, model_args=model_args,
+            shared_envelope=shared_envelope)
         self.comm_decision = setup.decision   # selection → run manifest
         comm_schedule = setup.comm_schedule
         replica_budget = setup.replica_budget   # 'auto' -> the knee B
@@ -752,6 +783,11 @@ class FullBatchTrainer:
             for name, value in setup.custom.counters.items():
                 set_counter(name, value)
         self.model = model
+        # what this trainer's programs fold the hub tail and the halo-source
+        # edges as — beside ``agg0``, for a reader of counters()
+        fold_slots = "fold_classes" in setup.fwd_static
+        if fold_slots or "ltail_src" in setup.plan_fields:
+            set_counter("fold", plan.fold_counts(slots=fold_slots))
         # layer 0's Â·h0 is loop-invariant on the exact GCN path with an
         # aggregate-first layer 0 (class docstring): decided here from what
         # the trainer can observe, read by the programs when they are traced

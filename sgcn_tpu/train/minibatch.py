@@ -177,13 +177,17 @@ class MiniBatchTrainer:
         # per-plan (per-class Emax_c statics, ptile_* arrays built by
         # ensure_pallas_tiles) — plans[0]'s compiled step cannot serve the
         # other batches' plans, whose tile arrays would never be built, so
-        # the shared envelope stays on the slot-pass/ELL aggregators
+        # the shared envelope stays on the slot-pass/ELL aggregators.
+        # shared_envelope=True, for the same reason: the hub tail and the
+        # halo-source edges stay COO lists (padded to tl / eh); their slot
+        # form's virtual-row counts differ from plan to plan
         self.inner = FullBatchTrainer(
             self.plans[0], fin, widths, mesh=self.mesh, lr=lr,
             activation=activation, model=model, loss=loss,
             optimizer=optimizer, seed=seed,
             compute_dtype=compute_dtype, comm_schedule=comm_schedule,
-            allow_pallas=False, memory_budget=memory_budget)
+            allow_pallas=False, memory_budget=memory_budget,
+            shared_envelope=True)
         # every batch brings its own Â and h0, so Â·h0 is not loop-invariant
         # here: the inner trainer's programs keep layer 0's aggregation
         # (switched off before any of them is traced)

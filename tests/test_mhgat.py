@@ -175,7 +175,8 @@ def test_virtual_rows_hold_every_edge_of_a_coo_store_once(plans):
             (layouts["tail"],
              (plan.ltail_dst, plan.ltail_src, plan.ltail_nnz)),
             (layouts["halo"], (plan.hedge_dst, plan.hedge_src, plan.hnnz))):
-        nv, wd = vs["shape"]
+        (nv, wd), = vs["classes"]
+        mask = vs["w"] != 0
         assert wd == VROW_WIDTH and nv % 8 == 0
         assert vs["idx"].shape == (4, nv * wd) and vs["row"].shape == (4, nv)
         for p in range(4):
@@ -183,12 +184,12 @@ def test_virtual_rows_hold_every_edge_of_a_coo_store_once(plans):
             got = sorted(
                 (int(vs["row"][p, v]), int(vs["idx"][p, t * nv + v]))
                 for t in range(wd) for v in range(nv)
-                if vs["mask"][p, t * nv + v])
+                if mask[p, t * nv + v])
             want = sorted(zip(dst[p, :cnt].tolist(), src[p, :cnt].tolist()))
             assert got == want and len(got) == cnt
             assert np.all(np.diff(vs["row"][p]) >= 0)       # a sorted scatter
             # a destination's runs are full but for its last
-            per_row = vs["mask"][p].reshape(wd, nv).sum(axis=0)
+            per_row = mask[p].reshape(wd, nv).sum(axis=0)
             rows = vs["row"][p]
             for r in np.unique(rows[per_row > 0]):
                 runs = per_row[(rows == r) & (per_row > 0)]

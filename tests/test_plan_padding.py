@@ -111,8 +111,8 @@ def _local_edges(plan, p):
 
 def _vrow_edges(lay, p):
     """(dst, src) pairs a virtual-row layout holds on chip ``p``."""
-    nv, wd = lay["shape"]
-    real = lay["mask"][p] != 0
+    (nv, wd), = lay["classes"]          # the attention layer's one width
+    real = lay["w"][p] != 0
     return sorted(zip(np.tile(lay["row"][p], wd)[real].tolist(),
                       lay["idx"][p][real].tolist()))
 
@@ -203,7 +203,7 @@ def _vrow(store, counts, edges, height):
         c = int(getattr(plan, counts)[p])
         want = sorted(zip(getattr(plan, dst)[p, :c].tolist(),
                           getattr(plan, src)[p, :c].tolist()))
-        return (lay["idx"][p], lay["mask"][p] == 0, lay["mask"][p],
+        return (lay["idx"][p], lay["w"][p] == 0, lay["w"][p],
                 getattr(plan, height), _vrow_edges(lay, p) == want)
     return case
 
@@ -336,14 +336,17 @@ def test_nothing_recognises_padding_by_its_value():
 
 
 # ------------------------------------------------- the change is data only
-# sha256 of ``lower_step().as_text()`` at the parent commit 8eecc9a (PR 27),
-# made by this file's ``step_sha`` run against that tree (CHANGES.md, PR 28).
-# A later PR that changes the step program on purpose re-pins these.
+# sha256 of ``lower_step().as_text()``: the two ``mhgat`` pins at the commit
+# 8eecc9a (PR 27), made by this file's ``step_sha`` run against that tree
+# (CHANGES.md, PR 28); the two ``gcn`` pins re-made by PR 30, which changed
+# that step on purpose (its hub tail and halo-source edges fold as slot
+# passes) and left the attention step as it was.  A later PR that changes a
+# step program on purpose re-pins it.
 PARENT_STEP_SHA = {
     ("gcn", 1):
-        "a4e80fcacc33172930f5d009c3c20304138404448410b4f858749b540610c7bc",
+        "cf9c1918e2b939eaa2e59396c0bca0e08c3c180620b3caca452d5560a8ec66be",
     ("gcn", 4):
-        "f2d5782c68dc9a41d8fe9316dc2acf3c38e3aefa9f3549f87fa21447910cfd6e",
+        "66465023282b49fa5af441c46774d869f47a037a82163474eb6df7e5157be6eb",
     ("mhgat", 1):
         "cc4893a2ad634dc0cef3493785043d4c74776ce68a03bd2997c305bc211033df",
     ("mhgat", 4):
@@ -364,6 +367,6 @@ def step_sha(plan, model):
 @pytest.mark.parametrize("model", ["gcn", "mhgat"])
 def test_lowered_exact_step_is_the_parents(plans, model, k):
     assert step_sha(plans[k], model) == PARENT_STEP_SHA[model, k], (
-        "the lowered exact step differs from the one pinned at PR 27: where "
-        "padding points is data, so this PR must not move it; a later PR "
-        "that changes the program on purpose re-pins PARENT_STEP_SHA")
+        "the lowered exact step differs from the one pinned (mhgat: PR 27; "
+        "gcn: PR 30): a PR that does not mean to change the program must "
+        "not; one that changes it on purpose re-pins PARENT_STEP_SHA")

@@ -126,14 +126,25 @@ def test_overlap_backward_parity(ahat):
     np.testing.assert_allclose(got, ahat.T @ wgt, rtol=1e-4, atol=1e-5)
 
 
-def _sym_args(pa):
-    return tuple(pa[f] for f in SYM_FIELDS)
+def _sym_op(plan, form):
+    """``(shipped fields, agg(pa, h))`` of the exact symmetric aggregation
+    with the hub tail and the halo-source edges as slot passes (``slots``,
+    what the exact full-batch step runs) or as COO lists (``coo``)."""
+    from sgcn_tpu.models.gcn import GCN_PLAN_FIELDS_SLOTS
+    from sgcn_tpu.ops import pspmm_ell_sym, pspmm_ell_sym_coo
+    if form == "coo":
+        return SYM_FIELDS, lambda pa, h: pspmm_ell_sym_coo(
+            h, *(pa[f] for f in SYM_FIELDS), plan.ell_buckets)
+    plan.ensure_fold_slots()
+    return GCN_PLAN_FIELDS_SLOTS, lambda pa, h: pspmm_ell_sym(
+        h, *(pa[f] for f in GCN_PLAN_FIELDS_SLOTS), plan.ell_buckets,
+        plan.fold_tail_classes, plan.fold_halo_classes)
 
 
+@pytest.mark.parametrize("form", ["slots", "coo"])
 @pytest.mark.parametrize("k", [2, 4, 8])
-def test_ell_sym_forward_parity(ahat, k):
+def test_ell_sym_forward_parity(ahat, k, form):
     """The ELL + symmetric-backward fast path must also compute dense Â·H."""
-    from sgcn_tpu.ops import pspmm_ell_sym
     n = ahat.shape[0]
     f = 5
     plan = build_comm_plan(ahat, balanced_random_partition(n, k, seed=11), k)
@@ -144,11 +155,12 @@ def test_ell_sym_forward_parity(ahat, k):
     mesh = make_mesh_1d(k)
     h = np.random.default_rng(4).standard_normal((n, f)).astype(np.float32)
     hb = shard_stacked(mesh, plan.scatter_rows(h))
-    pa = shard_stacked(mesh, {f_: getattr(plan, f_) for f_ in SYM_FIELDS})
+    fields, agg = _sym_op(plan, form)
+    pa = shard_stacked(mesh, {f_: getattr(plan, f_) for f_ in fields})
 
     def per_chip(pa, h):
         pa = jax.tree.map(lambda x: x[0], pa)
-        return pspmm_ell_sym(h[0], *_sym_args(pa), plan.ell_buckets)[None]
+        return agg(pa, h[0])[None]
 
     fn = jax.jit(jax.shard_map(per_chip, mesh=mesh,
                                in_specs=(P("v"), P("v")), out_specs=P("v")))
@@ -156,10 +168,10 @@ def test_ell_sym_forward_parity(ahat, k):
     np.testing.assert_allclose(got, ahat @ h, rtol=1e-4, atol=1e-5)
 
 
-def test_ell_sym_backward_parity(ahat):
+@pytest.mark.parametrize("form", ["slots", "coo"])
+def test_ell_sym_backward_parity(ahat, form):
     """The symmetric custom VJP (bwd = forward applied to g) must equal
     Âᵀ·w = Â·w, including the exchange in the backward."""
-    from sgcn_tpu.ops import pspmm_ell_sym
     n = ahat.shape[0]
     k = 4
     f = 3
@@ -168,7 +180,8 @@ def test_ell_sym_backward_parity(ahat):
     rng = np.random.default_rng(7)
     h = rng.standard_normal((n, f)).astype(np.float32)
     wgt = rng.standard_normal((n, f)).astype(np.float32)
-    pa = shard_stacked(mesh, {f_: getattr(plan, f_) for f_ in SYM_FIELDS})
+    fields, agg = _sym_op(plan, form)
+    pa = shard_stacked(mesh, {f_: getattr(plan, f_) for f_ in fields})
     hb = shard_stacked(mesh, plan.scatter_rows(h))
     wb = shard_stacked(mesh, plan.scatter_rows(wgt))
 
@@ -176,7 +189,7 @@ def test_ell_sym_backward_parity(ahat):
         pa = jax.tree.map(lambda x: x[0], pa)
 
         def obj(hl):
-            out = pspmm_ell_sym(hl, *_sym_args(pa), plan.ell_buckets)
+            out = agg(pa, hl)
             # per-chip LOCAL objective: its grad is still the GLOBAL
             # d(sum over chips)/dh — every chip runs the same transposed
             # exchange, so cotangents for rows this chip owns arrive from

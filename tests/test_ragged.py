@@ -32,6 +32,13 @@ from sgcn_tpu.partition.emit import read_partvec
 from sgcn_tpu.prep import normalize_adjacency
 from sgcn_tpu.train import FullBatchTrainer, make_train_data
 
+# The exact step with its hub tail and halo-source edges as COO lists
+# (``pspmm_ell_sym_coo``): the addition order the carried-halo and ragged
+# programs reproduce bit for bit.  The exact full-batch step itself folds
+# both stores as slot passes since PR 30 — same edges, another order;
+# ``tests/test_fold_slots.py`` bounds the difference.
+COO_EXACT = {"shared_envelope": True}
+
 FIX = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 
 
@@ -132,7 +139,7 @@ def test_ensure_ragged_receive_layout(asymplan):
 
 
 def test_op_level_bit_parity_fwd_and_grad(asymplan):
-    """pspmm_ragged_sym vs pspmm_ell_sym on the asymmetric-count plan:
+    """pspmm_ragged_sym vs pspmm_ell_sym_coo on the asymmetric-count plan:
     forward AND gradients bitwise equal, and halo_exchange_ragged delivers
     the dense exchange's exact halo rows."""
     import jax
@@ -140,7 +147,7 @@ def test_op_level_bit_parity_fwd_and_grad(asymplan):
     from jax.sharding import PartitionSpec as P
 
     from sgcn_tpu.ops.pspmm import (halo_exchange, halo_exchange_ragged,
-                                    pspmm_ell_sym, pspmm_ragged_sym)
+                                    pspmm_ell_sym_coo, pspmm_ragged_sym)
 
     plan, *_ = asymplan
     plan.ensure_ragged()
@@ -158,10 +165,10 @@ def test_op_level_bit_parity_fwd_and_grad(asymplan):
 
     def dense_chip(pa, h):
         pa, h = jax.tree.map(lambda x: x[0], (pa, h))
-        out = pspmm_ell_sym(h, pa["send_idx"], pa["halo_src"], pa["ell_idx"],
-                            pa["ell_w"], pa["ltail_dst"], pa["ltail_src"],
-                            pa["ltail_w"], pa["hedge_dst"], pa["hedge_src"],
-                            pa["hedge_w"], bk)
+        out = pspmm_ell_sym_coo(
+            h, pa["send_idx"], pa["halo_src"], pa["ell_idx"], pa["ell_w"],
+            pa["ltail_dst"], pa["ltail_src"], pa["ltail_w"], pa["hedge_dst"],
+            pa["hedge_src"], pa["hedge_w"], bk)
         halo = halo_exchange(h, pa["send_idx"], pa["halo_src"])
         return out[None], halo[None]
 
@@ -197,7 +204,8 @@ def test_trainer_bit_identical_on_cora(cora):
     trained parameters are f32-BIT-identical to the dense a2a schedule's on
     the cora fixture (exact ELL path; stale composition is deferred)."""
     plan, feats, labels = cora
-    tr_a = FullBatchTrainer(plan, fin=feats.shape[1], widths=[16, 7], seed=3)
+    tr_a = FullBatchTrainer(plan, fin=feats.shape[1], widths=[16, 7], seed=3,
+                            **COO_EXACT)
     tr_r = FullBatchTrainer(plan, fin=feats.shape[1], widths=[16, 7], seed=3,
                             comm_schedule="ragged")
     assert tr_r.comm_schedule == "ragged"
@@ -207,6 +215,12 @@ def test_trainer_bit_identical_on_cora(cora):
     assert la == lr                                  # bitwise, not allclose
     for wa, wr in zip(tr_a.params, tr_r.params):
         np.testing.assert_array_equal(np.asarray(wa), np.asarray(wr))
+    # the a2a step as it ships (both stores as slot passes): the same sums
+    # in another order
+    tr_s = FullBatchTrainer(plan, fin=feats.shape[1], widths=[16, 7], seed=3)
+    assert "fold_classes" in tr_s._fwd_static
+    np.testing.assert_allclose([tr_s.step(d) for _ in range(3)], la,
+                               rtol=1e-5)
     # the two schedules agree on the TRUE volume and disagree on the wire
     ra, rr = tr_a.stats.report(), tr_r.stats.report()
     assert ra["true_rows_per_exchange"] == rr["true_rows_per_exchange"]

@@ -38,6 +38,13 @@ from sgcn_tpu.partition.emit import read_partvec
 from sgcn_tpu.prep import normalize_adjacency
 from sgcn_tpu.train import FullBatchTrainer, make_train_data
 
+# The exact step with its hub tail and halo-source edges as COO lists
+# (``pspmm_ell_sym_coo``): the addition order the carried-halo and ragged
+# programs reproduce bit for bit.  The exact full-batch step itself folds
+# both stores as slot passes since PR 30 — same edges, another order;
+# ``tests/test_fold_slots.py`` bounds the difference.
+COO_EXACT = {"shared_envelope": True}
+
 FIX = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 
 WIDTHS = [16, 7]
@@ -59,7 +66,8 @@ def exact_run(cora):
     """Exact no-replica reference: 4 losses + trained parameters, shared
     by both transports' bit-identity assertions (one compile)."""
     plan, feats, labels = cora
-    tr = FullBatchTrainer(plan, fin=feats.shape[1], widths=WIDTHS, seed=3)
+    tr = FullBatchTrainer(plan, fin=feats.shape[1], widths=WIDTHS, seed=3,
+                          **COO_EXACT)
     d = make_train_data(plan, feats, labels)
     losses = [tr.step(d) for _ in range(4)]
     return losses, [np.asarray(w) for w in tr.params]

@@ -122,7 +122,12 @@ def test_lowered_step_names_every_scope(ahat, k):
     _, tr = _trainer(ahat, k)
     text = tr.lower_step().as_text(debug_info=True)
     tokens = set(TOKEN.findall(text))
-    assert set(LEAVES) <= tokens, set(LEAVES) - tokens
+    # one chip has no halo-source edge: since PR 30 a store without edges has
+    # no pass, and nothing is sent for a table nobody reads
+    halo = {"agg_halo_fold", "xchg_pack", "xchg_a2a", "xchg_unpack"}
+    want = set(LEAVES) - (halo if k == 1 else set())
+    assert want <= tokens, want - tokens
+    assert k > 1 or not halo & tokens
     assert {"layer0", "layer1"} <= tokens
     assert tracing.SCOPES[0] == "layer"
     with pytest.raises(ValueError, match="unknown scope"):
