@@ -57,8 +57,12 @@ class Expectation:
     grad_shapes: list = field(default_factory=list)
     # scalar f32 add-allreduces (loss machinery)
     scalar_psums: int = 0
-    # max-allreduces (the GAT per-layer softmax stabilizer pmax): count
+    # max-allreduces (the GAT per-layer softmax stabilizer pmax; the deep
+    # stack's per-body one): count
     max_psums: int = 0
+    # statistics allreduces of a normalisation layer (add-reduce, f32):
+    # multiset of operand shapes, counted beside ``grad_shapes``
+    stat_shapes: list = field(default_factory=list)
     # serve logit gather: list of (shape,) add-allreduce operands
     gather_shapes: list = field(default_factory=list)
     # argument classification for the donation check, in flatten order:
@@ -191,6 +195,25 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
         for i in bwd_layers:
             exp.exchanges += _exchange_ops(plan, mode.schedule, fs[i], gdt,
                                            replica=rep_wire)
+    elif mode.model == "deepergcn":
+        # one scanned, per-layer-checkpointed body: the lowered program
+        # holds layer 0's block and ONE body for the L - 1 layers after it,
+        # each forward and backward — its collectives do not scale with L
+        st = trainer._fwd_static
+        hidden, bodies = st["hidden"], 1 + (st["layers"] > 1)
+        for _body in range(bodies):
+            # the 2·hidden-lane table forward (again in the backward where
+            # the checkpoint keeps the layer's input alone), hidden lanes
+            # of gradient backward
+            for lane in ((2 * hidden, hidden, 2 * hidden)
+                         if st["keep"] == "input" else (2 * hidden, hidden)):
+                exp.exchanges += _exchange_ops(plan, mode.schedule, lane,
+                                               "f32")
+        # BatchNorm's mean and variance forward, their two column sums
+        # backward: the scanned body's norm and the head's; the kept
+        # statistics mean a recomputed forward runs none
+        exp.stat_shapes = [(hidden,)] * (4 * bodies)
+        exp.max_psums = bodies                   # the stabiliser's pmax
     else:
         from ..models.gat import gat_table_form
         for i in range(L):

@@ -65,6 +65,9 @@ AUDIT_K = 8
 AUDIT_N = 96
 AUDIT_FIN = 8
 AUDIT_WIDTHS = (8, 4)
+# the deep stack's widths are [hidden] * layers + [classes]: four layers,
+# so the scanned body stands for three
+AUDIT_DEEP_WIDTHS = (8, 8, 8, 8, 4)
 # replica-mode audits run at this fixed budget: large enough that the
 # shrunken nrep pads differ from the full ones on the ER fixture (the
 # wire-shape rule sees real shrinkage), small enough that every chip
@@ -225,8 +228,8 @@ def check_program(text: str, exp: "expect.Expectation", k: int) -> tuple:
     # ---- census of reductions
     reduces = [op for op in ops if op.kind == "all_reduce"]
     grad_like, scalar_adds, maxes, other = [], 0, 0, []
-    tensor_expected = Counter(exp.grad_shapes) + Counter(
-        exp.gather_shapes)
+    tensor_expected = (Counter(exp.grad_shapes) + Counter(exp.gather_shapes)
+                       + Counter(exp.stat_shapes))
     for op in reduces:
         shape, _dt = op.wire
         if op.reducer == "maximum":
@@ -248,7 +251,7 @@ def check_program(text: str, exp: "expect.Expectation", k: int) -> tuple:
                                      grad_like)
         violations.append(_viol(
             "collective-census",
-            "grad-sync/logit-gather psum census: one full-mesh add-"
+            "grad-sync/logit-gather/statistics psum census: one full-mesh add-"
             f"allreduce per tensor expected; missing={miss} "
             f"unexpected={extra}"))
     if scalar_adds != exp.scalar_psums:
@@ -260,8 +263,8 @@ def check_program(text: str, exp: "expect.Expectation", k: int) -> tuple:
     if maxes != exp.max_psums:
         violations.append(_viol(
             "collective-census",
-            f"{maxes} max-allreduces, expected {exp.max_psums} (the GAT "
-            "per-layer softmax stabilizer pmax)"))
+            f"{maxes} max-allreduces, expected {exp.max_psums} (the "
+            "softmax stabilizer pmax: per GAT layer, per deep-stack body)"))
     if other:
         violations.append(_viol(
             "collective-census", f"unclassifiable all_reduce ops: {other}"))
@@ -402,12 +405,13 @@ def lower_mode_programs(mode: Mode, plan=None) -> tuple:
                       else 0,
                       replica_budget=AUDIT_REPLICA_B if mode.replica
                       else 0)
-        else:
+        elif mode.model == "gat":
             kw.update(compute_dtype=mode.compute_dtype)
+        widths = (AUDIT_DEEP_WIDTHS if mode.model == "deepergcn"
+                  else AUDIT_WIDTHS)
         with _gat_form_env(mode.gat_form), \
                 _pallas_env(getattr(mode, "pallas", False)):
-            tr = FullBatchTrainer(plan, fin=AUDIT_FIN,
-                                  widths=list(AUDIT_WIDTHS),
+            tr = FullBatchTrainer(plan, fin=AUDIT_FIN, widths=list(widths),
                                   model=mode.model, **kw)
             # the audit must never silently check the WRONG aggregator:
             # a pallas mode that fell back to the slot-pass path would

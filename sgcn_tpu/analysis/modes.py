@@ -7,6 +7,9 @@ A :class:`Mode` names one point of the support matrix:
     {train, serve} × {gcn, gat} × {a2a, ragged} × staleness {0, 1}
     × halo-dtype {f32, bf16} × delta {off, on} × GAT table form
 
+plus the one mode of the deep residual stack (``deepergcn``: exact
+full-batch training on the a2a schedule, float32).
+
 ``supported_modes()`` enumerates exactly the combinations the trainers and
 the serve engine accept — the same gates ``FullBatchTrainer.__init__`` and
 ``ServeEngine.__init__`` enforce at construction time, encoded ONCE more
@@ -59,7 +62,10 @@ class Mode:
     #                                query-proportional serving program
     #                                (docs/serving.md phase 2): no
     #                                per-layer exchange, one logit psum
-    model: str                     # 'gcn' | 'gat'
+    model: str                     # 'gcn' | 'gat' | 'deepergcn' (the
+    #                                deep residual stack: ONE mode — exact
+    #                                full-batch training on the a2a
+    #                                schedule, f32)
     schedule: str                  # 'a2a' | 'ragged'
     staleness: int = 0             # 0 exact | 1 pipelined
     halo_dtype: str | None = None  # None (f32 wire) | 'bfloat16'
@@ -107,8 +113,16 @@ def is_supported(mode: Mode) -> tuple[bool, str]:
     m = mode
     if m.workload not in ("train", "serve", "serve_subgraph", "minibatch"):
         return False, f"unknown workload {m.workload!r}"
-    if m.model not in ("gcn", "gat"):
+    if m.model not in ("gcn", "gat", "deepergcn"):
         return False, f"unknown model {m.model!r}"
+    if m.model == "deepergcn" and (
+            m.workload != "train" or m.schedule != "a2a" or m.staleness
+            or m.halo_dtype is not None or m.delta or m.replica or m.pallas
+            or m.gat_form is not None):
+        return False, ("deepergcn runs the dense a2a schedule and the "
+                       "full forward only, float32, exact, full-batch: "
+                       "its setup hook refuses every other mode "
+                       "(models/deepergcn.py::model_setup)")
     if m.schedule not in ("a2a", "ragged"):
         return False, f"unknown schedule {m.schedule!r}"
     if m.model == "gat":
@@ -209,6 +223,8 @@ def supported_modes() -> list[Mode]:
                                               (False, True)):
         modes.append(Mode("train", "gat", sched, gat_form=form,
                           pallas=pal))
+    # train / deep residual stack: its one mode
+    modes.append(Mode("train", "deepergcn", "a2a"))
     # serve: model × schedule (× halo-dtype for GCN, × form for GAT)
     for sched, hd in itertools.product(("a2a", "ragged"),
                                        (None, "bfloat16")):

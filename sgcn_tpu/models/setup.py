@@ -32,6 +32,8 @@ class ModelSetup:
     counters: dict              # program counters the trainer leaves
     #                             (obs.tracing.set_counter), name -> value
     allow_pallas: bool          # whether a Pallas aggregator may be selected
+    checkpointed: bool = False  # the forward checkpoints its own layers: the
+    #                             trainer's whole-forward ``remat`` is refused
 
 
 def check_memory(device, estimate: dict) -> None:

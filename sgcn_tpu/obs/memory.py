@@ -292,9 +292,13 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
                  + int(plan.ctl or 0)) if plan.cell_buckets is not None else 0
         workspace += npass * slots * max(lane_widths) * compute_isize
     if custom is not None:
-        # the model's own itemised estimate (mhgat: per-row state kept for
-        # the backward and whole-row slot temporaries) replaces the
-        # activation-mirror figure
+        # the model's own itemised estimate replaces the activation-mirror
+        # figure (mhgat: per-row state kept for the backward and whole-row
+        # slot temporaries; deepergcn: the rows its per-layer checkpoints
+        # keep under the configured ``keep``, ONE layer's recomputed and
+        # backward rows, the slot scans' budgets — the figure above would
+        # price every layer's rows live at once, which is what per-layer
+        # checkpointing exists to avoid)
         est = custom.estimate_memory(train=train)
         workspace = (est["rows_kept"] + est["rows_transient"]
                      + est["slot_temps"])

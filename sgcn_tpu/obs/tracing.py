@@ -83,6 +83,13 @@ SCOPES = ("layer", "dense", "xchg_pack", "xchg_a2a", "xchg_unpack",
 # to its leaf.  ``benchmark/scopes_att.json`` is the benchmark's own copy.
 SUBSCOPES = ("att_project", "att_max", "att_score", "att_norm")
 
+# Sub-scopes of the deep residual stack (``models/deepergcn.py``), likewise
+# legal for ``subscope``: both open inside ``sgcn.dense``, the layer's
+# row-wise work — ``norm`` (BatchNorm's statistics, their collectives, the
+# apply) and ``softmax_table`` (message, exp, the aggregated table, the
+# divide).  ``benchmark/scopes_deep.json`` is the benchmark's own copy.
+DEEP_SUBSCOPES = ("norm", "softmax_table")
+
 _spans: dict = {}           # name -> {count, total_s, parent, durations}
 _spans_lock = threading.Lock()  # spans close on more than one thread
 _open = threading.local()   # .stack: this thread's open span names;
@@ -113,12 +120,12 @@ def _named(full: str, leaf: bool):
 
 
 def subscope(name: str):
-    """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``, legal
-    only inside a leaf ``scope`` — a sub-scope on its own would leave its
-    ops unscoped for every reader of ``SCOPES``."""
-    if name not in SUBSCOPES:
+    """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES`` or
+    ``DEEP_SUBSCOPES``, legal only inside a leaf ``scope`` — a sub-scope on
+    its own would leave its ops unscoped for every reader of ``SCOPES``."""
+    if name not in SUBSCOPES + DEEP_SUBSCOPES:
         raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
-                         f"{SUBSCOPES}")
+                         f"{SUBSCOPES + DEEP_SUBSCOPES}")
     if not getattr(_open, "leaves", 0):
         raise ValueError(f"sub-scope {name!r} opened outside a leaf scope "
                          f"of {SCOPES[1:]}")

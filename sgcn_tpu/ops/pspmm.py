@@ -525,6 +525,41 @@ def _pspmm_ell_sym_bwd(buckets, tail_classes, halo_classes, axis_name,
 pspmm_ell_sym.defvjp(_pspmm_ell_sym_fwd, _pspmm_ell_sym_bwd)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(11, 12, 13, 14, 15))
+def pspmm_ell_sym_detached(h, send_idx, halo_src, ell_idx, ell_w,
+                           ft_idx, ft_w, ft_row, fh_idx, fh_w, fh_row,
+                           buckets, tail_classes, halo_classes,
+                           grad_lanes: int, axis_name=AXIS):
+    """``pspmm_ell_sym`` of a table whose lanes past ``grad_lanes`` the
+    caller has DETACHED (they carry no cotangent): the forward aggregates
+    every lane of ``h``, the backward only the first ``grad_lanes`` of the
+    cotangent and returns zeros for the rest — different widths forward and
+    backward through the same slot passes and the same exchange.  The deep
+    stack's softmax aggregation ships ``[u m ‖ u]`` forward (2f lanes: the
+    numerator's table and the detached denominator's) and ``g / S`` backward
+    (f lanes) this way (``models/deepergcn.py``)."""
+    return _pspmm_ell_once(h, send_idx, halo_src, ell_idx, ell_w,
+                           ft_idx, ft_w, ft_row, fh_idx, fh_w, fh_row,
+                           buckets, tail_classes, halo_classes, axis_name)
+
+
+def _pspmm_ell_sym_detached_fwd(h, *args):
+    *plan, _grad_lanes, axis_name = args    # ten arrays, three statics
+    return _pspmm_ell_once(h, *plan, axis_name), args[:10]
+
+
+def _pspmm_ell_sym_detached_bwd(buckets, tail_classes, halo_classes,
+                                grad_lanes, axis_name, res, g):
+    gh = _pspmm_ell_once(g[:, :grad_lanes], *res, buckets, tail_classes,
+                         halo_classes, axis_name)
+    pad = jnp.zeros((g.shape[0], g.shape[1] - grad_lanes), g.dtype)
+    return (jnp.concatenate([gh, pad], axis=1), *[None] * 10)
+
+
+pspmm_ell_sym_detached.defvjp(_pspmm_ell_sym_detached_fwd,
+                              _pspmm_ell_sym_detached_bwd)
+
+
 def _pspmm_ell_coo_once(h, send_idx, halo_src, ell_idx, ell_w,
                         ltail_dst, ltail_src, ltail_w,
                         hedge_dst, hedge_src, hedge_w, buckets, axis_name,

@@ -108,13 +108,18 @@ def main() -> None:
     p.add_argument("-f", "--nfeatures", type=int, default=16)
     p.add_argument("-n", "--batch-size", type=int, default=None,
                    help="enable the mini-batch trainer")
-    p.add_argument("--model", default="gcn", choices=["gcn", "gat", "mhgat"],
+    p.add_argument("--model", default="gcn",
+                   choices=["gcn", "gat", "mhgat", "deepergcn"],
                    help="gat = the reference's single-head PGAT layer; "
                         "mhgat = multi-head graph attention as published "
                         "(LeakyReLU scores, per-edge softmax, bias, linear "
                         "skips; full-batch, a2a, f32 only): --hidden is the "
                         "width PER HEAD, hidden layers concatenate --heads "
-                        "heads, the last layer averages them")
+                        "heads, the last layer averages them; deepergcn = "
+                        "DeeperGCN as published (softmax_sg GENConv, res+ "
+                        "blocks, BatchNorm; full-batch, a2a, f32 only): an "
+                        "encoder, -l layers of --hidden as one scanned, "
+                        "per-layer-checkpointed body, and a head")
     p.add_argument("--heads", type=int, default=1,
                    help="attention heads per layer (--model mhgat)")
     p.add_argument("--activation", default=None,
@@ -428,8 +433,11 @@ def main() -> None:
         # hidden layers concatenate their heads, the last averages them
         widths = [args.heads * hidden] * (args.nlayers - 1) + [nclasses]
         model_args = {"heads": (args.heads,) * args.nlayers}
-        if args.batch_size is not None:
-            raise SystemExit("--model mhgat is full-batch only; drop -n")
+    if args.model == "deepergcn":
+        # -l counts the GENConv layers; the head is one more width
+        widths = [hidden] * args.nlayers + [nclasses]
+    if args.model in ("mhgat", "deepergcn") and args.batch_size is not None:
+        raise SystemExit(f"--model {args.model} is full-batch only; drop -n")
 
     prof = (jax.profiler.trace(args.profile) if args.profile
             else contextlib.nullcontext())

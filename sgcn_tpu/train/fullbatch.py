@@ -35,7 +35,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..models import mhgat
+from ..models import deepergcn, mhgat
 from ..models import setup as model_setup
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
@@ -81,6 +81,14 @@ MODELS = {
               lambda plan: mhgat.MHGAT_PLAN_FIELDS,
               lambda plan: {"ell_buckets": plan.ell_buckets},
               mhgat.model_setup),
+    # the deep residual stack (models/deepergcn.py): the exact GCN step's
+    # slot-form plan arrays with unit weights; one scanned, per-layer-
+    # checkpointed body whatever the depth
+    "deepergcn": (deepergcn.init_deepergcn_params,
+                  deepergcn.deepergcn_forward_local,
+                  lambda plan: deepergcn.DEEPERGCN_PLAN_FIELDS,
+                  lambda plan: {"ell_buckets": plan.ell_buckets},
+                  deepergcn.model_setup),
 }
 
 
@@ -538,11 +546,17 @@ class FullBatchTrainer:
         GAT exchange ships its attention tables, which narrow via
         ``compute_dtype='bfloat16'`` (the packed one-gather path).
 
-        ``remat=True`` wraps the forward in ``jax.checkpoint`` so layer
-        activations are recomputed in the backward pass instead of stored —
-        the HBM-for-FLOPs trade for deep stacks / huge vertex counts (no
-        reference analogue; the MPI code stores every layer's H and Z,
-        ``Parallel-GCN/main.c:553-607``).
+        ``remat=True`` wraps the WHOLE forward in ONE ``jax.checkpoint``:
+        the forward pass keeps nothing but its inputs, and the backward
+        first re-runs all of it — so while the backward runs, every layer's
+        rows are live again, exactly as without it.  It frees the
+        activations only for as long as the loss is computed; it does not
+        bound what a deep stack holds (no reference analogue; the MPI code
+        stores every layer's H and Z, ``Parallel-GCN/main.c:553-607``).  The
+        form that does is a checkpoint PER LAYER around a scanned body,
+        which keeps one layer's rows live at a time: a model that brings it
+        (``models/deepergcn.py``, ``ModelSetup.checkpointed``) refuses
+        ``remat=True``.
 
         ``halo_staleness=1`` selects the PIPELINED exchange (the
         PipeGCN-style bounded-staleness mode, ``ops/pspmm.py::pspmm_stale``):
@@ -773,6 +787,11 @@ class FullBatchTrainer:
                 else 0,
                 dtype=compute_dtype)
         self.model_memory = None
+        if remat and setup.custom is not None and setup.custom.checkpointed:
+            raise ValueError(
+                f"model {model!r} checkpoints each of its layers itself; "
+                "remat=True (one checkpoint around the whole forward) has "
+                "nothing left to free — drop it")
         if setup.custom is not None:
             # the model's own estimate, from its per-row and per-table
             # arrays; the old fence above is the factorised layer's and is
@@ -2117,7 +2136,8 @@ class FullBatchTrainer:
 
     @property
     def nlayers(self) -> int:
-        return len(self.params)
+        """Aggregating layers = exchanges of one sweep (``CommStats``)."""
+        return len(self.stats.lane_widths)
 
     def fit(
         self,

@@ -279,3 +279,21 @@ def test_chip_smoke_refuses_without_a_chip(tmp_path):
     assert r.returncode != 0
     assert r.stdout == ""
     assert "No module named 'sgcn_tpu'" in r.stderr
+
+
+def test_train_cli_deepergcn(pipeline):
+    """``--model deepergcn``: -l GENConv layers of --hidden as one scanned
+    body, an encoder and a head; full-batch only."""
+    d = pipeline
+    base = ["sgcn_tpu.train", "-a", str(d / "g.A.mtx"),
+            "-p", str(d / "g.A.mtx.4.hp"), "-b", "cpu", "-s", "4",
+            "-l", "3", "--hidden", "8", "-f", "6", "--model", "deepergcn"]
+    r = run_cli(base + ["--epochs", "2"])
+    assert r.returncode == 0, r.stderr
+    report = json.loads(r.stdout.strip().splitlines()[-1])
+    assert report["model"] == "deepergcn" and report["epochs"] == 2
+    assert report["activation"] == "relu"
+    # warm-up + two epochs, three layers, both directions
+    assert report["exchanges"] == 3 * 2 * 3
+    r = run_cli(base + ["-n", "40"])
+    assert r.returncode != 0 and "full-batch only" in r.stderr
