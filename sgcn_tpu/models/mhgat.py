@@ -39,7 +39,14 @@ K·C-lane row costs 19.6 ns an edge, four gathers of 128-lane per-head rows
 44.0 ns, so the table is ONE ``(rows, K·C)`` array and the per-head
 coefficients are spread over the gathered row's lanes by a product with a
 0/1 matrix (``_scale_heads``); the K scalars of a row ride a narrow table
-of their own (5.7 ns an edge).
+of their own (5.7 ns an edge).  A slot's time is its fusions' HBM traffic,
+not its MXU passes (PERF.md §6 PR 32): a forward slot runs ONE spread where
+its two accumulators would take two — of the coefficient signed by
+[s_i + t_j > 0], whose magnitude and positive part are the two factors —
+as one bfloat16 pass over the coefficient's three exact pieces (``split3``;
+the 0/1 side is exact in one piece, every output is one input times one, so
+the result is the f32 broadcast to the bit).  The backward slot's spread and
+its sum over a head's lanes (``_dot_heads``) stay ``HIGHEST`` products.
 
 **Backward** (``attention_aggregate``'s custom rule; a symmetric pattern is
 required, as for the GCN's): with ``g = ∂L/∂O`` and ``c_i = g_i·O_i`` per head,
@@ -193,12 +200,47 @@ def _head_lanes(k: int, f: int):
     return jnp.repeat(jnp.eye(k, dtype=jnp.float32), f // k, axis=1)
 
 
+def split3(a):
+    """``a`` (f32) cut into three bfloat16 pieces ``hi, mid, lo`` with
+    ``hi + mid + lo == a`` to the bit (8 + 8 + 8 significand bits) wherever
+    the pieces stay normal, |a| ≥ 2⁻¹⁰² — the pieces ``Precision.HIGHEST``
+    cuts an operand into; below that ``lo`` flushes to zero and the sum is
+    ``a`` to 2⁻¹⁶ of a number under 2⁻¹⁰², as ``HIGHEST``'s on this chip.
+    The cuts are ``reduce_precision``, not a cast there and back: a compiler
+    may drop an f32 → bf16 → f32 round trip as excess precision, and ``mid``
+    and ``lo`` would be zero."""
+    hi = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+    rest = a - hi
+    mid = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+    return tuple(x.astype(jnp.bfloat16) for x in (hi, mid, rest - mid))
+
+
+def _spread_heads(p, f: int):
+    """``p`` (n, K) → (n, K·C), column k copied to head k's lanes, as ONE
+    bfloat16 MXU pass: every output is one input times one, so the stacked
+    pieces ``[hi ‖ mid ‖ lo]`` (n, 3K) against the 0/1 matrix stacked three
+    times (3K, K·C), f32 out, give the f32 broadcast to the bit — the
+    accumulator adds ``hi + mid + lo``, whose partial sums are all f32
+    numbers.  ``Precision.HIGHEST`` runs six passes for the same bits, three
+    of them against the zero pieces of the 0/1 side.  The forward slot's
+    spread (``_aggregate_fwd``); never differentiated, it sits inside the
+    aggregation's own rule (a transposed ``split3`` would round a cotangent
+    to bfloat16)."""
+    lanes = _head_lanes(p.shape[1], f).astype(jnp.bfloat16)
+    return jnp.dot(jnp.concatenate(split3(p), axis=1),
+                   jnp.concatenate([lanes] * 3, axis=0),
+                   preferred_element_type=jnp.float32)
+
+
 def _scale_heads(p, rows):
     """``rows`` (n, K·C) with head k's lanes multiplied by ``p[:, k]``.  The
     K coefficients of a row are spread over its lanes by a product with a
     0/1 matrix at ``HIGHEST`` precision (exact: each output is one input),
     which the v5e runs at 39.0 ns an edge where per-head lane slices ran
-    45.3 and ``jnp.repeat`` 67.3 (forward pass, C = 128; PERF.md §6 PR 27)."""
+    45.3 and ``jnp.repeat`` 67.3 (forward pass, C = 128; PERF.md §6 PR 27).
+    The backward slot's spread and the rows' normalisation; in the backward
+    slot the one-pass form of ``_spread_heads`` costs the same alone and
+    more in the step (PERF.md §6 PR 32)."""
     k = p.shape[1]
     if k == 1:
         return rows * p
@@ -209,12 +251,29 @@ def _scale_heads(p, rows):
 def _dot_heads(a, b, k: int):
     """Per-head inner products of two (·, K·C) arrays → (n, K): the
     products summed by the transposed 0/1 matrix, likewise at ``HIGHEST``
-    (34.8 ns an edge against 44.2 for sums of lane slices, backward pass)."""
+    (34.8 ns an edge against 44.2 for sums of lane slices, backward pass;
+    its exact three-piece split costs 45.7 against 31.4: the pieces are
+    K·C-lane arrays, read back from HBM by a product each; PR 32)."""
     ab = a * b
     if k == 1:
         return ab.sum(axis=1, keepdims=True)
     return jnp.dot(ab, _head_lanes(k, ab.shape[1]).T,
                    precision=jax.lax.Precision.HIGHEST)
+
+
+def head_products() -> dict:
+    """What ``att.work`` says of the products by the 0/1 head matrix, in
+    layers of K > 1 (one head multiplies and sums without a product): which
+    products a slot of the forward and of the backward aggregation runs and
+    a layer's row-wise work runs once, in what form and how many MXU passes
+    each.  The row-wise ones: the two score projections' sums and the
+    normalisation's two spreads forward; the sums of ``c`` and ``∂L/∂s`` and
+    the projections' transposes (spreads, by autodiff) backward."""
+    split, highest = ({"form": "split3", "passes": 1},
+                      {"form": "highest", "passes": 6})
+    return {"forward_slot": {"spread": 1, **split},
+            "backward_slot": {"spread": 1, "sum": 1, **highest},
+            "layer_rows": {"spread": 4, "sum": 4, **highest}}
 
 
 def _concat_buckets(outs):
@@ -335,7 +394,14 @@ def _aggregate_fwd(z, s, t, send_idx, halo_src, ell_idx, ell_w,
                           jnp.exp(_leaky(x, slope) - m_i), 0.0)
             q = jnp.where(x > 0, p, 0.0)
         rows = jnp.take(tab_z, src, axis=0)
-        return _scale_heads(p, rows), p, _scale_heads(q, rows), q
+        # ONE spread a slot for both accumulators: the coefficient signed
+        # by [x > 0] — its magnitude scales every edge's row, its positive
+        # part the positive-score edges' (±0 where p is 0: nothing added)
+        signed = jnp.where(x > 0, p, -p)
+        if k > 1:
+            signed = _spread_heads(signed, f)
+        return (rows * jnp.abs(signed), p,
+                rows * jnp.maximum(signed, 0.0), q)
 
     num, den, pnum, pden = _all_stores(
         (z, t), (zh, th), (s, m), *shapes, contrib=edge,
@@ -563,7 +629,8 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                            "backward": list(lanes_b)},
         # none where no chip has a halo edge (k = 1)
         "exchanges_per_step": (2 * len(widths)
-                               if vshapes["halo_shape"] is not None else 0)}
+                               if vshapes["halo_shape"] is not None else 0),
+        "head_products": head_products()}
     return ModelSetup(
         fwd_static={**args, **vshapes},
         init_static=args,

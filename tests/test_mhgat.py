@@ -13,12 +13,20 @@ skip, head mean — on the normal path (``build_comm_plan`` →
   * (d) PR 26's hoist is off, and the lowered step holds one exchange per
     layer and pass — layer 0's backward included;
   * (e) the modes that refuse the model do so loudly;
-  * (f) the configuration's counts, the sub-scopes, the memory estimate.
+  * (f) the configuration's counts, the sub-scopes, the memory estimate;
+  * (g) the products by the 0/1 head matrix (PR 32): the forward slot's
+    spread as ONE pass over exact bfloat16 splits, to the bit, and its two
+    factors off one signed spread; the ``HIGHEST`` spread to the bit and
+    the sum within 4 ulp of the f64 sum; and ``att.work["head_products"]``
+    says what the lowered step holds.
 
 CPU, tiny graphs, one to eight virtual devices.
 """
 
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
@@ -369,3 +377,144 @@ def test_the_memory_estimate_is_itemised_and_guards_a_small_device(plans):
             return None
 
     check_memory(NoStats(), est)        # nothing to guard
+
+
+# ----------------------------------- (g) the head products as exact splits
+def _coefficients(rng, n, k):
+    """Coefficients 1 … 2⁻¹⁰⁰, exact zeros (masked slots) and whole masked
+    rows among them."""
+    p = (rng.uniform(0.5, 1.0, (n, k))
+         * np.exp2(-rng.integers(0, 101, (n, k)))).astype(np.float32)
+    p[rng.uniform(size=(n, k)) < 0.1] = 0.0
+    p[::7] = 0.0
+    return p
+
+
+def test_split3_recombines_to_the_bit():
+    rng = np.random.default_rng(0)
+    a = (rng.standard_normal(1 << 16)
+         * np.exp2(rng.integers(-100, 30, 1 << 16))).astype(np.float32)
+    a[:64] = 0.0
+    a = a[(np.abs(a) >= 2.0 ** -100) | (a == 0)]    # the pieces stay normal
+    pieces = jax.jit(mhgat.split3)(a)
+    assert all(x.dtype == jnp.bfloat16 for x in pieces)
+    hi, mid, lo = (np.asarray(x, np.float32) for x in pieces)
+    assert np.array_equal((hi + mid) + lo, a)
+    assert np.array_equal(hi + (mid + lo), a)       # exact in any order
+    assert np.abs(mid).max() > 0 and np.abs(lo).max() > 0
+
+
+@pytest.mark.parametrize("k,f", [(2, 188), (4, 188), (2, 512), (4, 512)])
+def test_both_spreads_are_the_f32_broadcast_to_the_bit(k, f):
+    rng = np.random.default_rng(f + k)
+    p = _coefficients(rng, 1024, k)
+    rows = rng.standard_normal((1024, f)).astype(np.float32)
+    want = np.asarray(jax.jit(
+        lambda p, r: r * jnp.repeat(p, f // k, axis=1))(p, rows))
+    for spread in (mhgat._scale_heads,          # HIGHEST, six passes
+                   lambda p, r: r * mhgat._spread_heads(p, f)):     # one
+        got = np.asarray(jax.jit(spread)(p, rows))
+        assert np.array_equal(got.view(np.int32), want.view(np.int32))
+    assert np.count_nonzero(want) > want.size // 2          # not vacuous
+
+
+@pytest.mark.parametrize("k,f", [(2, 188), (4, 188), (2, 512), (4, 512)])
+def test_dot_heads_is_as_near_the_f64_sum_as_the_highest_product(k, f):
+    rng = np.random.default_rng(f - k)
+    a = rng.standard_normal((1024, f)).astype(np.float32)
+    b = rng.standard_normal((1024, f)).astype(np.float32)
+    dot = jax.jit(lambda a, b: mhgat._dot_heads(a, b, k))
+    highest = jax.jit(lambda x: jnp.dot(
+        x, mhgat._head_lanes(k, f).T, precision=jax.lax.Precision.HIGHEST))
+
+    def f64(x):
+        return x.astype(np.float64).reshape(len(x), k, f // k).sum(-1)
+
+    # positive terms: an ulp of the sum is an ulp of its terms' size
+    pos = np.abs(a * b)
+    ulp = np.spacing(f64(pos).astype(np.float32))
+    err = np.abs(np.asarray(dot(np.abs(a), np.abs(b))) - f64(pos))
+    assert (err / ulp).max() <= 4.0
+    # terms of both signs: no further from the f64 sum than HIGHEST is
+    err = np.abs(np.asarray(dot(a, b)) - f64(a * b)).max()
+    assert err <= max(np.abs(np.asarray(highest(a * b)) - f64(a * b)).max(),
+                      4.0 * np.spacing(np.float32(np.abs(a * b).max())))
+
+
+def test_one_head_takes_no_product():
+    rows = np.arange(12, dtype=np.float32).reshape(3, 4)
+    text = jax.jit(lambda p, r: (mhgat._scale_heads(p, r),
+                                 mhgat._dot_heads(r, r, 1))).lower(
+        rows[:, :1], rows).as_text()
+    assert "dot_general" not in text
+
+
+def test_a_forward_slot_reads_both_factors_off_one_signed_spread():
+    """``_aggregate_fwd``'s slot spreads ±p once, the sign saying
+    [x > 0]: the magnitude is p's spread and the positive part q's, to the
+    bit (a −0 for a +0 apart, which adds nothing)."""
+    rng = np.random.default_rng(5)
+    k, f = 4, 188
+    p = _coefficients(rng, 1024, k)
+    x = rng.standard_normal((1024, k)).astype(np.float32)
+    rows = rng.standard_normal((1024, f)).astype(np.float32)
+    q = np.where(x > 0, p, 0.0).astype(np.float32)
+
+    @jax.jit
+    def slot(p, x, rows):
+        signed = mhgat._spread_heads(jnp.where(x > 0, p, -p), f)
+        return rows * jnp.abs(signed), rows * jnp.maximum(signed, 0.0)
+
+    num, pnum = (np.asarray(a) for a in slot(p, x, rows))
+    assert np.array_equal(num, np.asarray(mhgat._scale_heads(p, rows)))
+    assert np.array_equal(pnum, np.asarray(mhgat._scale_heads(q, rows)))
+    assert np.count_nonzero(pnum) > 0 and np.count_nonzero(num != pnum) > 0
+
+
+_DOT = re.compile(r"stablehlo\.dot_general.*precision = \[(\w+), (\w+)\]"
+                  r".*: \(tensor<(\d+)x(\d+)x(\w+)>, "
+                  r"tensor<(\d+)x(\d+)x(\w+)>\)")
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_the_lowered_step_holds_the_products_the_counter_names(plans, k):
+    """Every bfloat16 product of the lowered step is a forward slot's
+    spread (contraction 3K deep: the stacked pieces, ONE pass, default
+    precision); every ``HIGHEST`` product is the backward slot's spread or
+    sum, or a layer's row-wise one — as many of each as
+    ``att.work["head_products"]`` says (slots unrolled at this size);
+    nothing else runs at ``HIGHEST``, nothing at a six-pass algorithm."""
+    plan = plans[k]
+    tr = _trainer(plan)
+    work = tracing.counters()["att.work"]["head_products"]
+    assert work["forward_slot"] == {"spread": 1, "form": "split3",
+                                    "passes": 1}
+    assert work["backward_slot"]["form"] == "highest"
+    text = tr.lower_step().as_text()
+    assert "algorithm" not in text
+    found = {"split3": {}, "highest": {}}
+    for m in _DOT.finditer(text):
+        p0, p1, _, _, lhs, deep, wide, rhs = m.groups()
+        assert p0 == p1 and lhs == rhs
+        if lhs == "bf16":                       # (3K, K·C) stacked 0/1 rows
+            assert p0 == "DEFAULT" and int(deep) % 3 == 0
+            lanes = int(wide)
+            found["split3"][lanes] = found["split3"].get(lanes, 0) + 1
+        elif p0 == "HIGHEST":                   # (K, K·C) or its transpose
+            lanes = max(int(deep), int(wide))
+            found["highest"][lanes] = found["highest"].get(lanes, 0) + 1
+        else:
+            assert p0 == "DEFAULT"              # the dense layer's own
+    assert text.count("HIGHEST") == 2 * sum(found["highest"].values())
+    # slot bodies in the program, each store unrolled: Σ bucket widths
+    slots = sum(wb for _, wb in plan.ell_buckets) + sum(
+        sh[1] for sh in (tr._fwd_static["tail_shape"],
+                         tr._fwd_static["halo_shape"]) if sh is not None)
+    want = {"split3": {}, "highest": {}}
+    for _, kh, c, _ in mhgat.layer_shapes(FIN, WIDTHS, **ARGS):
+        for where, count in (("forward_slot", slots),
+                             ("backward_slot", slots), ("layer_rows", 1)):
+            w = work[where]
+            want[w["form"]][kh * c] = want[w["form"]].get(kh * c, 0) + (
+                count * (w["spread"] + w.get("sum", 0)))
+    assert found == want

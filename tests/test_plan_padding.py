@@ -336,21 +336,22 @@ def test_nothing_recognises_padding_by_its_value():
 
 
 # ------------------------------------------------- the change is data only
-# sha256 of ``lower_step().as_text()``: the two ``mhgat`` pins at the commit
-# 8eecc9a (PR 27), made by this file's ``step_sha`` run against that tree
-# (CHANGES.md, PR 28); the two ``gcn`` pins re-made by PR 30, which changed
-# that step on purpose (its hub tail and halo-source edges fold as slot
-# passes) and left the attention step as it was.  A later PR that changes a
-# step program on purpose re-pins it.
+# sha256 of ``lower_step().as_text()``, made by this file's ``step_sha``.
+# The two ``gcn`` pins are PR 30's, which changed that step on purpose (its
+# hub tail and halo-source edges fold as slot passes); the two ``mhgat`` pins
+# stood from the commit 8eecc9a (PR 27) until PR 32 re-made them, which
+# changed THAT step on purpose (a forward slot spreads its head coefficients
+# once, signed, as one bfloat16 pass over exact splits) and left the GCN's as
+# it was.  A later PR that changes a step program on purpose re-pins it.
 PARENT_STEP_SHA = {
     ("gcn", 1):
         "cf9c1918e2b939eaa2e59396c0bca0e08c3c180620b3caca452d5560a8ec66be",
     ("gcn", 4):
         "66465023282b49fa5af441c46774d869f47a037a82163474eb6df7e5157be6eb",
     ("mhgat", 1):
-        "cc4893a2ad634dc0cef3493785043d4c74776ce68a03bd2997c305bc211033df",
+        "c34b240991b16c9697cc662850e9e172df9b9a9a5840008ee5e3a0e5b672053b",
     ("mhgat", 4):
-        "5b4ac8aeb3899ace97f46b918ffb97e46d5120615e1f891038a4332c1582f349",
+        "79975ecbcb8bd4970cb90a9ace575f21f026e234f45ea96fb71742b24778749b",
 }
 MODEL_KW = {"gcn": {},
             "mhgat": {"model_args": {"heads": (4, 2), "concat": (True, False)},
@@ -367,6 +368,6 @@ def step_sha(plan, model):
 @pytest.mark.parametrize("model", ["gcn", "mhgat"])
 def test_lowered_exact_step_is_the_parents(plans, model, k):
     assert step_sha(plans[k], model) == PARENT_STEP_SHA[model, k], (
-        "the lowered exact step differs from the one pinned (mhgat: PR 27; "
+        "the lowered exact step differs from the one pinned (mhgat: PR 32; "
         "gcn: PR 30): a PR that does not mean to change the program must "
         "not; one that changes it on purpose re-pins PARENT_STEP_SHA")
