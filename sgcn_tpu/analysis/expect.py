@@ -214,6 +214,16 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
         # statistics mean a recomputed forward runs none
         exp.stat_shapes = [(hidden,)] * (4 * bodies)
         exp.max_psums = bodies                   # the stabiliser's pmax
+    elif mode.model == "rgcn":
+        # per layer a row's input forward, and backward the cotangent
+        # blocks its gradient types want, side by side — layer 0's too:
+        # the embeddings are trainable, so a halo copy's gradient goes home
+        for lanes in (trainer.stats.lane_widths,
+                      trainer.stats.lane_widths_bwd):
+            for lane in lanes:
+                if lane:
+                    exp.exchanges += _exchange_ops(plan, mode.schedule,
+                                                   lane, "f32")
     else:
         from ..models.gat import gat_table_form
         for i in range(L):
@@ -238,8 +248,12 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
                         plan, "ragged", fout + 1, "f32")
         exp.max_psums = L                        # per-layer softmax pmax
 
-    exp.grad_shapes = [tuple(np.shape(x))
-                       for x in jax.tree.leaves(trainer.params)]
+    # one all-reduce a REPLICATED leaf: a leaf owned with the rows
+    # (``ModelSetup.row_owned``) keeps its gradient where its rows are
+    exp.grad_shapes = [
+        tuple(np.shape(x)) for path, x in
+        jax.tree_util.tree_flatten_with_path(trainer.params)[0]
+        if trainer._owned_rows(path) is None]
     exp.scalar_psums = XENT_SCALAR_PSUMS
     exp.forbidden_scatters = pallas_ragged_forbidden_scatters(trainer, mode)
 

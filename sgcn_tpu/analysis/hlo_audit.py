@@ -73,6 +73,14 @@ AUDIT_DEEP_WIDTHS = (8, 8, 8, 8, 4)
 # wire-shape rule sees real shrinkage), small enough that every chip
 # keeps non-replica traffic (all rounds stay live)
 AUDIT_REPLICA_B = 12
+# the relational model's configuration over the fixture's rows: a featured,
+# labelled type and an embedded one, related both ways, the first to itself
+AUDIT_REL_ARGS = {
+    "types": [{"name": "a", "count": AUDIT_N // 2, "input": "features"},
+              {"name": "b", "count": AUDIT_N - AUDIT_N // 2,
+               "input": "embedding"}],
+    "relations": [("a", "aa", "a"), ("a", "ab", "b"), ("b", "ba", "a")],
+    "label_type": "a"}
 
 
 @lru_cache(maxsize=None)
@@ -407,6 +415,8 @@ def lower_mode_programs(mode: Mode, plan=None) -> tuple:
                       else 0)
         elif mode.model == "gat":
             kw.update(compute_dtype=mode.compute_dtype)
+        elif mode.model == "rgcn":
+            kw.update(model_args=AUDIT_REL_ARGS)
         widths = (AUDIT_DEEP_WIDTHS if mode.model == "deepergcn"
                   else AUDIT_WIDTHS)
         with _gat_form_env(mode.gat_form), \

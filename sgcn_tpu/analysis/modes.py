@@ -62,9 +62,10 @@ class Mode:
     #                                query-proportional serving program
     #                                (docs/serving.md phase 2): no
     #                                per-layer exchange, one logit psum
-    model: str                     # 'gcn' | 'gat' | 'deepergcn' (the
-    #                                deep residual stack: ONE mode — exact
-    #                                full-batch training on the a2a
+    model: str                     # 'gcn' | 'gat' | 'deepergcn' | 'rgcn'
+    #                                (the deep residual stack and the
+    #                                relational model: ONE mode each —
+    #                                exact full-batch training on the a2a
     #                                schedule, f32)
     schedule: str                  # 'a2a' | 'ragged'
     staleness: int = 0             # 0 exact | 1 pipelined
@@ -113,8 +114,16 @@ def is_supported(mode: Mode) -> tuple[bool, str]:
     m = mode
     if m.workload not in ("train", "serve", "serve_subgraph", "minibatch"):
         return False, f"unknown workload {m.workload!r}"
-    if m.model not in ("gcn", "gat", "deepergcn"):
+    if m.model not in ("gcn", "gat", "deepergcn", "rgcn"):
         return False, f"unknown model {m.model!r}"
+    if m.model == "rgcn" and (
+            m.workload != "train" or m.schedule != "a2a" or m.staleness
+            or m.halo_dtype is not None or m.delta or m.replica or m.pallas
+            or m.gat_form is not None):
+        return False, ("rgcn runs the dense a2a schedule and the full "
+                       "forward only, float32, exact, full-batch: its "
+                       "setup hook refuses every other mode "
+                       "(models/rgcn.py::model_setup)")
     if m.model == "deepergcn" and (
             m.workload != "train" or m.schedule != "a2a" or m.staleness
             or m.halo_dtype is not None or m.delta or m.replica or m.pallas
@@ -225,6 +234,8 @@ def supported_modes() -> list[Mode]:
                           pallas=pal))
     # train / deep residual stack: its one mode
     modes.append(Mode("train", "deepergcn", "a2a"))
+    # train / relational model (typed rows, row-owned embeddings): its one
+    modes.append(Mode("train", "rgcn", "a2a"))
     # serve: model × schedule (× halo-dtype for GCN, × form for GAT)
     for sched, hd in itertools.product(("a2a", "ragged"),
                                        (None, "bfloat16")):

@@ -40,6 +40,7 @@ CONSUMER_TUPLE_SOURCES = {
     "MHGAT_PLAN_FIELDS": "sgcn_tpu.models.mhgat:MHGAT_PLAN_FIELDS",
     "DEEPERGCN_PLAN_FIELDS":
         "sgcn_tpu.models.deepergcn:DEEPERGCN_PLAN_FIELDS",
+    "RGCN_PLAN_FIELDS": "sgcn_tpu.models.rgcn:RGCN_PLAN_FIELDS",
     "GCN_PLAN_FIELDS_SYM": "sgcn_tpu.models.gcn:GCN_PLAN_FIELDS_SYM",
     "GCN_PLAN_FIELDS_SLOTS": "sgcn_tpu.models.gcn:GCN_PLAN_FIELDS_SLOTS",
     "GCN_PLAN_FIELDS_GEN": "sgcn_tpu.models.gcn:GCN_PLAN_FIELDS_GEN",

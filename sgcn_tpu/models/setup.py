@@ -29,11 +29,28 @@ class ModelSetup:
     estimate_memory: Callable   # (train: bool) -> itemised per-chip HBM bytes,
     #                             with "total", "rows_kept", "rows_transient",
     #                             "slot_temps" (obs/memory.py's workspace)
+    #                             and, where leaves are row-owned,
+    #                             "param_bytes": the tree's bytes on ONE chip
     counters: dict              # program counters the trainer leaves
     #                             (obs.tracing.set_counter), name -> value
     allow_pallas: bool          # whether a Pallas aggregator may be selected
     checkpointed: bool = False  # the forward checkpoints its own layers: the
     #                             trainer's whole-forward ``remat`` is refused
+    row_owned: dict = dataclasses.field(default_factory=dict)
+    #                             parameters owned WITH the rows: top-level
+    #                             key of the parameter tree -> a tree, shaped
+    #                             like that subtree, of (k, height) int
+    #                             arrays: the row of the leaf in global row
+    #                             order that each per-chip row holds (-1:
+    #                             padding).  Such a leaf is stacked per chip
+    #                             and sharded like ``h0``; its gradient is not
+    #                             ``psum``med; optimiser state follows it;
+    #                             checkpoints hold it in global row order
+    out_rows: tuple | None = None  # (rows, valid): names of two shipped
+    #                             arrays — the plan row of every row the
+    #                             forward returns, and a 0/1 mask of the real
+    #                             ones — where the forward returns other rows
+    #                             than the chip's ``plan.b``
 
 
 def check_memory(device, estimate: dict) -> None:

@@ -219,7 +219,10 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
     families["params"] = model_param_bytes(fin, widths, model=model)
     custom = setup.custom       # a model with a setup hook prices itself
     if custom is not None:
-        families["params"] = 4 * custom.param_count
+        # (a model with leaves owned with the rows says what ONE chip holds
+        # of its tree: ``param_bytes`` of its estimate)
+        families["params"] = custom.estimate_memory(train=train).get(
+            "param_bytes", 4 * custom.param_count)
     # Adam: count scalar + one mu and one nu tree (optax.adam — the only
     # optimizer the CLIs construct); inference carries no optimizer state
     families["opt_state"] = (2 * families["params"] + 4) if train else 0

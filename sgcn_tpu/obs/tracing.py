@@ -90,6 +90,15 @@ SUBSCOPES = ("att_project", "att_max", "att_score", "att_norm")
 # divide).  ``benchmark/scopes_deep.json`` is the benchmark's own copy.
 DEEP_SUBSCOPES = ("norm", "softmax_table")
 
+# Sub-scopes of the relational model (``models/rgcn.py``) and of the
+# trainer's row-owned parameters: ``rel_project`` (the per-relation and
+# per-type products, forward and backward) and ``rel_table`` (building a
+# layer's gather tables: the feature ‖ embedding input, the stacked
+# cotangent) open inside ``sgcn.dense``; ``row_update`` (the optimiser on
+# the leaves sharded with the rows) inside ``sgcn.optimizer``.
+# ``benchmark/scopes_rel.json`` is the benchmark's own copy.
+REL_SUBSCOPES = ("rel_project", "rel_table", "row_update")
+
 _spans: dict = {}           # name -> {count, total_s, parent, durations}
 _spans_lock = threading.Lock()  # spans close on more than one thread
 _open = threading.local()   # .stack: this thread's open span names;
@@ -120,12 +129,14 @@ def _named(full: str, leaf: bool):
 
 
 def subscope(name: str):
-    """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES`` or
-    ``DEEP_SUBSCOPES``, legal only inside a leaf ``scope`` — a sub-scope on
-    its own would leave its ops unscoped for every reader of ``SCOPES``."""
-    if name not in SUBSCOPES + DEEP_SUBSCOPES:
+    """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``,
+    ``DEEP_SUBSCOPES`` or ``REL_SUBSCOPES``, legal only inside a leaf
+    ``scope`` — a sub-scope on its own would leave its ops unscoped for
+    every reader of ``SCOPES``."""
+    known = SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES
+    if name not in known:
         raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
-                         f"{SUBSCOPES + DEEP_SUBSCOPES}")
+                         f"{known}")
     if not getattr(_open, "leaves", 0):
         raise ValueError(f"sub-scope {name!r} opened outside a leaf scope "
                          f"of {SCOPES[1:]}")

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..obs.tracing import scope
+from ..obs.tracing import scope, subscope
 from ..parallel.mesh import AXIS
 
 # bound on the gather temps XLA's latency-hiding scheduler can keep live
@@ -437,7 +437,7 @@ _FOLD_SCAN_LIVE = 3 * 1024**3 // 4
 
 
 def fold_slots(out, table, idx, w, row, classes,
-               scan_live_limit: int = _FOLD_SCAN_LIVE):
+               scan_live_limit: int = _FOLD_SCAN_LIVE, contrib=None):
     """``out`` plus one COO edge store in slot form
     (``parallel.plan._build_virtual_rows``): every class ``(nv, W)`` of
     ``classes`` is a bucket of ``bucketed_slot_reduce`` (``take(table, idx)
@@ -447,19 +447,28 @@ def fold_slots(out, table, idx, w, row, classes,
     the v5e a scanned class of virtual rows cost 5–7 ns a slot where the
     unrolled form of the same store cost 7–10 (PERF.md §6, PR 30, step 1:
     gp4's tail at one width of 16, scanned, 18.5 ms a pass; at classes {8,
-    16}, fewer slots but unrolled, 29.9)."""
+    16}, fewer slots but unrolled, 29.9).  ``contrib(idx, w) -> rows shaped
+    like out's`` replaces the default ``take(table, idx) · w`` (the typed
+    aggregation decodes its slots itself; ``table`` is then unused), and
+    ``out`` may then be a pytree of row arrays."""
     if not classes:
         return out
-    f = table.shape[-1]
+    if contrib is None:
+        def contrib(i, wt):
+            return jnp.take(table, i, axis=0) * wt[:, None]
+    f = sum(x.shape[-1] for x in jax.tree.leaves(out))
     parts = bucketed_slot_reduce(
-        idx, w, classes,
-        contrib=lambda i, wt: jnp.take(table, i, axis=0) * wt[:, None],
-        init=lambda nv: jnp.zeros((nv, f), table.dtype),
+        idx, w, classes, contrib=contrib,
+        init=lambda nv: jax.tree.map(
+            lambda x: jnp.zeros((nv, x.shape[-1]), x.dtype), out),
         slot_bytes=lambda nv: nv * f * 4, scan_live_limit=scan_live_limit,
         scanned=True)
     r0 = 0
     for (nv, _), part in zip(classes, parts):
-        out = out.at[row[r0: r0 + nv]].add(part, indices_are_sorted=True)
+        rows = row[r0: r0 + nv]
+        out = jax.tree.map(
+            lambda x, y, rows=rows: x.at[rows].add(y, indices_are_sorted=True),
+            out, part)
         r0 += nv
     return out
 
@@ -1683,3 +1692,191 @@ def _pspmm_stale_ragged_bwd(buckets, rr_sizes, rr_edge_sizes, axis_name,
 
 
 pspmm_stale_ragged.defvjp(_pspmm_stale_ragged_fwd, _pspmm_stale_ragged_bwd)
+
+
+# ----------------------------------------------------- typed (relational)
+# A slot of the typed aggregation names its source row AND the source's node
+# type in one int32: the row in the low bits, the type above them.
+TYPE_SHIFT = 28
+_ROW_MASK = (1 << TYPE_SHIFT) - 1
+MAX_NODE_TYPES = 8          # three bits above TYPE_SHIFT, the sign bit clear
+
+
+# the typed passes keep several accumulators a row (one per source type), so
+# their scans unroll only as far as this many bytes of live slot
+# temporaries: compiled for the v5e at the ogbn-mag shape the step's
+# temporaries read 13.8 GB at the default 3 GB (PERF.md §6, PR 33)
+_TYPED_SCAN_LIVE = 1024**3
+
+
+def _typed_group(arrays, layout, local, remote, blocks: int, f: int,
+                 weight: str):
+    """One destination type's rows: the ELL slots, the hub tail and the
+    halo-source edges of ITS rows (the sub-layout ``models/rgcn.py`` derives
+    from the plan's), each slot decoded by ``local`` / ``remote``
+    ``(code, w) -> blocks arrays (rows, f)``; ``weight`` picks the forward
+    (``"wf"``) or the transposed (``"wb"``) weight array of the same
+    slots.  Returns a tuple of ``blocks`` arrays."""
+    buckets, tail_classes, halo_classes = layout
+    with scope("agg_slots"):
+        outs = bucketed_slot_reduce(
+            arrays["e_code"], arrays["e_" + weight], buckets, contrib=local,
+            init=lambda nb: tuple(jnp.zeros((nb, f), jnp.float32)
+                                  for _ in range(blocks)),
+            slot_bytes=lambda nb: nb * blocks * f * 4,
+            scan_live_limit=_TYPED_SCAN_LIVE)
+        out = tuple(x[0] if len(x) == 1 else jnp.concatenate(x, axis=0)
+                    for x in zip(*outs))
+    with scope("agg_tail"):
+        out = fold_slots(out, None, arrays["t_code"], arrays["t_" + weight],
+                         arrays["t_row"], tail_classes, contrib=local)
+    with scope("agg_halo_fold"):
+        return fold_slots(out, None, arrays["h_code"],
+                          arrays["h_" + weight], arrays["h_row"],
+                          halo_classes, contrib=remote)
+
+
+def _typed_exchange(table, arrays, spec, axis_name):
+    """The halo copy of ``table``'s rows (the plan's exchange, its send rows
+    renamed into the table's order), or ``None`` where no chip has a
+    halo-source edge."""
+    if not spec.exchange:
+        return None
+    return halo_exchange(table, arrays["send_rows"], arrays["halo_src"],
+                         axis_name)
+
+
+def _typed_forward(blocks, arrays, spec, axis_name):
+    f = next(b.shape[-1] for b in blocks if b is not None)
+    with scope("dense"), subscope("rel_table"):
+        table = jnp.concatenate(
+            [jnp.zeros((h, f), jnp.float32) if b is None else b
+             for b, h in zip(blocks, spec.heights)], axis=0)
+    halo = _typed_exchange(table, arrays, spec, axis_name)
+    outs = []
+    for d in spec.dst:
+        srcs = spec.sources[d]
+
+        def place(rows, code, srcs=srcs):
+            # each slot's row into the accumulator of its source's type
+            u = (code >> TYPE_SHIFT)[:, None]
+            return tuple(jnp.where(u == t, rows, 0.0) for t in srcs)
+
+        def local(code, w, place=place):
+            rows = jnp.take(table, code & _ROW_MASK, axis=0) * w[:, None]
+            return place(rows, code)
+
+        def remote(code, w, place=place):
+            rows = jnp.take(halo, code & _ROW_MASK, axis=0) * w[:, None]
+            return place(rows, code)
+
+        outs.append(_typed_group(arrays["types"][d], spec.layouts[d], local,
+                                 remote, len(srcs), f, "wf"))
+    return tuple(outs)
+
+
+def _typed_backward(cts, arrays, spec, axis_name):
+    """The transposition on the same slots: a slot (i <- j) of the forward
+    is read from j's side, gathering i's cotangent block for j's type at
+    the OTHER endpoint's weight.  One pass per source type that needs a
+    gradient; the blocks no such type reads are never formed."""
+    ct_of = dict(zip(spec.dst, cts))
+    f = cts[0][0].shape[-1]
+    first = [sum(spec.heights[:t]) for t in range(len(spec.heights))]
+
+    def block(d, s):
+        return ct_of[d][spec.sources[d].index(s)]
+
+    halo, per_row = None, 0
+    if spec.exchange:
+        # a row's wanted blocks side by side, every type padded to the most
+        wanted = {t: [u for u in spec.sources[t] if u in spec.grad]
+                  if t in ct_of else [] for t in range(len(spec.heights))}
+        per_row = max(len(w) for w in wanted.values())
+        with scope("dense"), subscope("rel_table"):
+            wide = jnp.concatenate([
+                jnp.concatenate(
+                    [block(t, u) for u in wanted[t]]
+                    + [jnp.zeros((h, f), jnp.float32)]
+                    * (per_row - len(wanted[t])), axis=1)
+                for t, h in enumerate(spec.heights)], axis=0)
+        halo = _typed_exchange(wide, arrays, spec, axis_name)
+        halo = halo.reshape(-1, f)
+    grads = []
+    for s in range(len(spec.heights)):
+        # the destination types whose block for s exists: relation s -> d
+        live = [d for d in spec.dst if s in spec.sources[d]]
+        if s not in spec.grad or not live:
+            grads.append(None)
+            continue
+        # what s's rows gather from: one block, or the few stacked
+        with scope("dense"), subscope("rel_table"):
+            table = (block(live[0], s) if len(live) == 1 else
+                     jnp.concatenate([block(d, s) for d in live], axis=0))
+        start, off = {}, 0
+        for d in live:
+            start[d] = off - first[d]
+            off += spec.heights[d]
+        pos = {d: [u for u in spec.sources[d] if u in spec.grad].index(s)
+               for d in live}
+
+        def decode(code, w, where, live=live):
+            u, row = code >> TYPE_SHIFT, code & _ROW_MASK
+            idx, on = row, jnp.zeros(row.shape, bool)
+            for d in live:
+                idx = jnp.where(u == d, where(row, d), idx)
+                on = on | (u == d)
+            return idx, jnp.where(on, w, 0.0)
+
+        def local(code, w, decode=decode, table=table, start=start):
+            idx, w = decode(code, w, lambda row, d: row + start[d])
+            # a slot no relation reads keeps its own (distinct) row
+            return (jnp.take(table, idx % table.shape[0], axis=0)
+                    * w[:, None],)
+
+        def remote(code, w, decode=decode, pos=pos):
+            idx, w = decode(code, w, lambda row, d: row * per_row + pos[d])
+            return (jnp.take(halo, idx % halo.shape[0], axis=0)
+                    * w[:, None],)
+
+        grads.append(_typed_group(arrays["types"][s], spec.layouts[s], local,
+                                  remote, 1, f, "wb")[0])
+    return tuple(grads)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def typed_aggregate(blocks, arrays, spec, axis_name=AXIS):
+    """Per-relation MEAN aggregation over one symmetric pattern whose rows
+    have node types, a relation being an ordered pair of types (``D_r⁻¹ A_r``
+    for every relation at once; ``models/rgcn.py``).
+
+    ``blocks`` holds one ``(height_t, f)`` table per node type (``None``: a
+    type the layer does not read), rows in the typed order of the model's
+    sub-layouts; the result one tuple per destination type ``d`` of
+    ``spec.dst``: array q, ``(height_d, f)``, the mean over d's neighbours of
+    type ``sources[d][q]``.  Every edge goes through the slot
+    passes of the plan's own layout — ELL buckets, the hub tail and the
+    halo-source edges as virtual rows — restricted to the rows of one type
+    (``arrays["types"][t]``: ``e_*`` / ``t_*`` / ``h_*`` codes, weights and
+    fold rows).
+
+    The operator is symmetric in PATTERN and not in values: ``(D_r⁻¹ A_r)ᵀ
+    = A_rᵀ D_r⁻¹`` walks the same slots from the other side with the other
+    endpoint's degree (``*_wb`` beside ``*_wf``) and gathers the cotangent
+    of the block its own type fills — a custom VJP on the same index
+    arrays, gathers only, no scatter-add over edges.  Only the
+    types of ``spec.grad`` get a gradient (the others' tables are data, or
+    no relation out of them reaches ``spec.dst``); at k > 1 the backward
+    exchange ships each row's wanted blocks side by side."""
+    return _typed_forward(blocks, arrays, spec, axis_name)
+
+
+def _typed_aggregate_fwd(blocks, arrays, spec, axis_name):
+    return _typed_forward(blocks, arrays, spec, axis_name), arrays
+
+
+def _typed_aggregate_bwd(spec, axis_name, arrays, cts):
+    return _typed_backward(cts, arrays, spec, axis_name), None
+
+
+typed_aggregate.defvjp(_typed_aggregate_fwd, _typed_aggregate_bwd)

@@ -109,7 +109,7 @@ def main() -> None:
     p.add_argument("-n", "--batch-size", type=int, default=None,
                    help="enable the mini-batch trainer")
     p.add_argument("--model", default="gcn",
-                   choices=["gcn", "gat", "mhgat", "deepergcn"],
+                   choices=["gcn", "gat", "mhgat", "deepergcn", "rgcn"],
                    help="gat = the reference's single-head PGAT layer; "
                         "mhgat = multi-head graph attention as published "
                         "(LeakyReLU scores, per-edge softmax, bias, linear "
@@ -119,7 +119,22 @@ def main() -> None:
                         "DeeperGCN as published (softmax_sg GENConv, res+ "
                         "blocks, BatchNorm; full-batch, a2a, f32 only): an "
                         "encoder, -l layers of --hidden as one scanned, "
-                        "per-layer-checkpointed body, and a head")
+                        "per-layer-checkpointed body, and a head; rgcn = "
+                        "R-GCN as published for typed graphs (a mean and a "
+                        "weight per relation, a weight per node type on the "
+                        "row itself, per-node embeddings for types without "
+                        "features; full-batch, a2a, f32 only): give "
+                        "--node-types, --relations and --label-type")
+    p.add_argument("--node-types", default=None,
+                   help="--model rgcn: the node types in id order, "
+                        "name:count:features|embedding, comma-separated "
+                        "(ids are contiguous per type; the counts sum to n)")
+    p.add_argument("--relations", default=None,
+                   help="--model rgcn: source:name:destination, "
+                        "comma-separated; at most one per ordered pair of "
+                        "types; a reverse relation is listed like any other")
+    p.add_argument("--label-type", default=None,
+                   help="--model rgcn: the node type whose rows have labels")
     p.add_argument("--heads", type=int, default=1,
                    help="attention heads per layer (--model mhgat)")
     p.add_argument("--activation", default=None,
@@ -436,7 +451,20 @@ def main() -> None:
     if args.model == "deepergcn":
         # -l counts the GENConv layers; the head is one more width
         widths = [hidden] * args.nlayers + [nclasses]
-    if args.model in ("mhgat", "deepergcn") and args.batch_size is not None:
+    if args.model == "rgcn":
+        if not (args.node_types and args.relations and args.label_type):
+            raise SystemExit("--model rgcn needs --node-types, --relations "
+                             "and --label-type")
+        model_args = {
+            "types": [dict(zip(("name", "count", "input"), t.split(":")))
+                      for t in args.node_types.split(",")],
+            "relations": [tuple(r.split(":"))
+                          for r in args.relations.split(",")],
+            "label_type": args.label_type}
+        for t in model_args["types"]:
+            t["count"] = int(t["count"])
+    if (args.model in ("mhgat", "deepergcn", "rgcn")
+            and args.batch_size is not None):
         raise SystemExit(f"--model {args.model} is full-batch only; drop -n")
 
     prof = (jax.profiler.trace(args.profile) if args.profile

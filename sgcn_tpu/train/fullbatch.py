@@ -35,7 +35,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..models import deepergcn, mhgat
+from ..models import deepergcn, mhgat, rgcn
 from ..models import setup as model_setup
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
@@ -49,7 +49,7 @@ from ..models.gcn import (
     masked_sigmoid_bce_local,
     masked_softmax_xent_local,
 )
-from ..obs.tracing import scope, set_counter, span
+from ..obs.tracing import scope, set_counter, span, subscope
 from ..parallel.mesh import (AXIS, make_mesh_1d, replicate, shard_stacked,
                              vary)
 from ..parallel.plan import CommPlan
@@ -89,6 +89,12 @@ MODELS = {
                   lambda plan: deepergcn.DEEPERGCN_PLAN_FIELDS,
                   lambda plan: {"ell_buckets": plan.ell_buckets},
                   deepergcn.model_setup),
+    # typed rows and relations (models/rgcn.py): the plan's slots regrouped
+    # by the type of their destination, arrays its hook derives; per-node
+    # embeddings owned with the rows (``ModelSetup.row_owned``)
+    "rgcn": (rgcn.init_rgcn_params, rgcn.rgcn_forward_local,
+             lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
+             rgcn.model_setup),
 }
 
 
@@ -819,11 +825,18 @@ class FullBatchTrainer:
         self.loss_name = loss
         self._loss_fn = LOSSES[loss]
         dims = list(zip([fin] + widths[:-1], widths))
+        # what the model's hook says of its parameters and its output rows
+        # (models/setup.py): leaves owned with the rows, rows the forward
+        # returns — empty / None for every model without such a thing
+        self._row_owned = (setup.custom.row_owned
+                           if setup.custom is not None else {})
+        self._out_rows = (setup.custom.out_rows
+                          if setup.custom is not None else None)
         self.params = init_fn(jax.random.PRNGKey(seed), dims)
         self.opt = optimizer if optimizer is not None else optax.adam(lr)
-        self.opt_state = self.opt.init(self.params)
-        self.params = replicate(self.mesh, self.params)
-        self.opt_state = replicate(self.mesh, self.opt_state)
+        self.opt_state = self._init_opt_state(self.params)
+        self.params = self._place(self.params)
+        self.opt_state = self._place(self.opt_state)
         self.last_err = None
         self.pa = shard_stacked(self.mesh, setup.ship_arrays(plan))
         # per-exchange wire lane widths (f32-lane equivalents) — the real
@@ -935,6 +948,126 @@ class FullBatchTrainer:
                     fresh=False, partial=True)
             self._multi_rep = {}     # epochs -> compiled replica epoch loop
 
+    # ------------------------------------------------- row-owned parameters
+    def _owned_rows(self, path):
+        """Where the leaf at ``path`` (of the parameters or the optimiser
+        state) keeps its rows — the ``(k, height)`` map of
+        ``ModelSetup.row_owned`` — or ``None`` for a replicated leaf.  A
+        leaf is row-owned if its path passes a dict key the hook names;
+        Adam's moments mirror the parameter tree, so they follow."""
+        for i, key in enumerate(path):
+            node = self._row_owned.get(getattr(key, "key", None))
+            if node is not None:
+                for sub in path[i + 1:]:
+                    node = node[sub.key]
+                return node
+        return None
+
+    def _by_owner(self, tree, owned, shared=lambda x: x):
+        """``tree`` with ``owned(leaf)`` on the row-owned leaves and
+        ``shared(leaf)`` on the others."""
+        if not self._row_owned:
+            return jax.tree.map(shared, tree)
+        return jax.tree_util.tree_map_with_path(
+            lambda p, x: (owned(x) if self._owned_rows(p) is not None
+                          else shared(x)), tree)
+
+    def _place(self, tree):
+        """Row-owned leaves stacked per chip and sharded like ``h0``, the
+        rest replicated."""
+        return self._by_owner(tree,
+                              lambda x: shard_stacked(self.mesh, x),
+                              lambda x: replicate(self.mesh, x))
+
+    def _specs(self, tree):
+        """The ``shard_map`` specs of a parameter / optimiser-state tree."""
+        if not self._row_owned:
+            return P()
+        return self._by_owner(tree, lambda x: P(AXIS), lambda x: P())
+
+    def _split(self, tree: dict) -> tuple:
+        """``(shared, owned)`` halves of a parameter-shaped dict."""
+        return ({k: v for k, v in tree.items() if k not in self._row_owned},
+                {k: v for k, v in tree.items() if k in self._row_owned})
+
+    def _init_opt_state(self, params):
+        """One optimiser state — or, with row-owned leaves, one for the
+        replicated half and one for the owned half, so that the owned
+        update is an op group of its own (``sgcn.row_update``)."""
+        if not self._row_owned:
+            return self.opt.init(params)
+        shared, owned = self._split(params)
+        return {"shared": self.opt.init(shared),
+                "owned": self.opt.init(owned)}
+
+    def _update(self, grads, opt_state, params):
+        """Complete the replicated leaves' gradients with one ``psum`` (a
+        row-owned leaf's gradient is whole where its rows are) and apply the
+        optimiser.  ``params`` and ``grads`` hold the row-owned leaves as a
+        chip sees them, ``opt_state`` — and the parameters returned — with
+        the leading block axis ``shard_map`` hands over and takes back: the
+        owned half strips and restores it INSIDE its sub-scope, so that the
+        update's fusion is rooted there and not in a reshape outside every
+        scope."""
+        with scope("grad_psum"):
+            grads = self._by_owner(grads, lambda g: g,
+                                   lambda g: lax.psum(g, AXIS))
+        with scope("optimizer"):
+            if not self._row_owned:
+                updates, opt_state = self.opt.update(grads, opt_state,
+                                                     params)
+                return optax.apply_updates(params, updates), opt_state, grads
+            new, state = {}, {}
+            for half, g, p in zip(("shared", "owned"), self._split(grads),
+                                  self._split(params)):
+                with (subscope("row_update") if half == "owned"
+                      else contextlib.nullcontext()):
+                    updates, st = self.opt.update(
+                        g, self._by_owner(opt_state[half], lambda x: x[0]),
+                        p)
+                    state[half] = self._by_owner(st, lambda x: x[None])
+                    new.update(self._by_owner(
+                        optax.apply_updates(p, updates), lambda x: x[None]))
+            return new, state, grads
+
+    def _select_out(self, pa, labels, valid):
+        """Labels and mask of the rows the forward returns."""
+        if self._out_rows is None:
+            return labels, valid
+        rows, real = (pa[name] for name in self._out_rows)
+        with scope("loss"):
+            return labels[rows], valid[rows] * real
+
+    def host_state(self) -> tuple:
+        """``(params, opt_state)`` on the host, every row-owned leaf
+        gathered into its global row order — what a checkpoint holds, and
+        what a reader outside the mesh (a reference) can follow."""
+        def gather(path, x):
+            rows = self._owned_rows(path)
+            x = np.asarray(x)
+            if rows is None:
+                return x
+            ok = rows >= 0
+            out = np.zeros((int(ok.sum()),) + x.shape[2:], x.dtype)
+            out[rows[ok]] = x[ok]
+            return out
+
+        return jax.tree_util.tree_map_with_path(
+            gather, (self.params, self.opt_state))
+
+    def load_host_state(self, params, opt_state) -> None:
+        """Place ``host_state()``'s form back on the mesh."""
+        def scatter(path, x):
+            rows = self._owned_rows(path)
+            if rows is None:
+                return x
+            x = np.asarray(x)
+            return np.where((rows >= 0).reshape(rows.shape + (1,) * (
+                x.ndim - 1)), x[np.maximum(rows, 0)], 0).astype(x.dtype)
+
+        self.params, self.opt_state = self._place(
+            jax.tree_util.tree_map_with_path(scatter, (params, opt_state)))
+
     # ------------------------------------------------------------------ build
     def _cast(self, params, pa, h0):
         """``compute_dtype``'s narrowing of everything the forward reads."""
@@ -1017,6 +1150,10 @@ class FullBatchTrainer:
         fwd = (jax.checkpoint(self._forward, static_argnums=())
                if self.remat else self._forward)
 
+        labels, valid = self._select_out(pa, labels, valid)
+        # (row-owned leaves arrive with shard_map's block axis: _update)
+        params = self._by_owner(params, lambda x: x[0])
+
         def loss_fn(ps):
             logits = fwd(ps, pa, h0)
             with scope("loss"):
@@ -1035,13 +1172,12 @@ class FullBatchTrainer:
             vary(params))
         # dense weight-grad allreduce — GPU/PGCN.py:150-154 /
         # Parallel-GCN/main.c:422-425 (psum of local partials = full grad)
-        with scope("grad_psum"):
-            grads = jax.tree.map(lambda g: lax.psum(g, AXIS), grads)
-        with scope("optimizer"):
-            updates, opt_state = self.opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+        params, opt_state, grads = self._update(grads, opt_state, params)
         if telemetry:
-            gnorm = _global_grad_norm(grads)
+            # (a row-owned leaf's squares are summed over its owners)
+            gnorm = _global_grad_norm(self._by_owner(
+                grads, lambda g: jnp.sqrt(lax.psum(jnp.sum(jnp.square(g)),
+                                                   AXIS))))
             return params, opt_state, loss, err, gnorm
         return params, opt_state, loss, err
 
@@ -1547,11 +1683,12 @@ class FullBatchTrainer:
             return self._one_step(params, opt_state, pa, h0, labels, valid,
                                   telemetry=telemetry)
 
+        ps, os = self._specs(self.params), self._specs(self.opt_state)
         smapped = jax.shard_map(
             per_chip,
             mesh=mesh if mesh is not None else self.mesh,
-            in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
-            out_specs=(P(), P(), P(), P()) + ((P(),) if telemetry else ()),
+            in_specs=(ps, os, P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
+            out_specs=(ps, os, P(), P()) + ((P(),) if telemetry else ()),
         )
         return jax.jit(smapped, donate_argnums=(0, 1))
 
@@ -1619,8 +1756,9 @@ class FullBatchTrainer:
         def sds(x, sharding):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
-        params = jax.tree.map(lambda x: sds(x, rep), self.params)
-        opt_state = jax.tree.map(lambda x: sds(x, rep), self.opt_state)
+        params, opt_state = self._by_owner(
+            (self.params, self.opt_state), lambda x: sds(x, shd),
+            lambda x: sds(x, rep))
         pa = jax.tree.map(lambda x: sds(x, shd), self.pa)
         h0 = jax.ShapeDtypeStruct((k, b, fin), np.float32, sharding=shd)
         labels = jax.ShapeDtypeStruct((k, b), np.int32, sharding=shd)
@@ -1665,11 +1803,12 @@ class FullBatchTrainer:
                 0, epochs, body, (params, opt_state, z, z))
             return params, opt_state, losses, errs
 
+        ps, os = self._specs(self.params), self._specs(self.opt_state)
         smapped = jax.shard_map(
             per_chip,
             mesh=self.mesh,
-            in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
-            out_specs=(P(), P(), P(), P()),
+            in_specs=(ps, os, P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
+            out_specs=(ps, os, P(), P()),
         )
         return jax.jit(smapped, donate_argnums=(0, 1))
 
@@ -1765,6 +1904,8 @@ class FullBatchTrainer:
     def _build_eval(self):
         def per_chip(params, pa, h0, labels, valid):
             pa, h0, labels, valid = _unblock((pa, h0, labels, valid))
+            params = self._by_owner(params, lambda x: x[0])
+            labels, valid = self._select_out(pa, labels, valid)
             logits = self._forward(params, pa, h0)
             # eval loss uses the SAME objective as training, so train/eval
             # losses are comparable under --loss bce too (the MPI stack
@@ -1777,7 +1918,8 @@ class FullBatchTrainer:
         smapped = jax.shard_map(
             per_chip,
             mesh=self.mesh,
-            in_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
+            in_specs=(self._specs(self.params), P(AXIS), P(AXIS), P(AXIS),
+                      P(AXIS)),
             out_specs=(P(), P(), P(AXIS)),
         )
         return jax.jit(smapped)
@@ -2132,7 +2274,18 @@ class FullBatchTrainer:
             data.eval_valid
         )
         self.stats.count_forward(nlayers=self.nlayers)
-        return self.plan.gather_rows(np.asarray(logits))
+        logits = np.asarray(logits)
+        if self._out_rows is not None:
+            # the forward returned some rows only: the others read 0
+            rows, real = (np.asarray(self.pa[name])
+                          for name in self._out_rows)
+            ids = self.plan.global_row_ids()
+            out = np.zeros((self.plan.n, logits.shape[-1]), logits.dtype)
+            for c in range(self.plan.k):
+                ok = real[c] > 0
+                out[ids[c][rows[c][ok]]] = logits[c][ok]
+            return out
+        return self.plan.gather_rows(logits)
 
     @property
     def nlayers(self) -> int:

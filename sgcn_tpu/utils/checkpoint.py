@@ -169,7 +169,11 @@ def save_checkpoint(trainer, path: str, step: int = 0) -> str:
     exposes one, provenance, the format version, and a per-array CRC map —
     committed via temp + fsync + rename so a kill at ANY byte leaves
     either the previous checkpoint or the complete new one."""
-    leaves = jax.tree.leaves((trainer.params, trainer.opt_state))
+    # (a trainer with parameters owned with the rows hands them over in
+    # global row order: ``FullBatchTrainer.host_state``)
+    leaves = jax.tree.leaves(trainer.host_state()
+                             if hasattr(trainer, "host_state")
+                             else (trainer.params, trainer.opt_state))
     arrays = {f"leaf_{i}": np.asarray(x) for i, x in enumerate(leaves)}
     arrays[_META_STEP] = np.asarray(step, dtype=np.int64)
     # ``checkpoint_plan`` (may be explicitly None) overrides ``plan``: the
@@ -397,7 +401,9 @@ def load_checkpoint(trainer, path: str, verify: bool = True) -> int:
             activation=getattr(trainer, "activation", None),
             final_activation=getattr(trainer, "final_activation", None),
             what=f"load_checkpoint({path!r})")
-    cur = jax.tree.leaves((trainer.params, trainer.opt_state))
+    cur = jax.tree.leaves(trainer.host_state()
+                          if hasattr(trainer, "host_state")
+                          else (trainer.params, trainer.opt_state))
     if len(cur) != len(leaves):
         raise ValueError(
             f"checkpoint has {len(leaves)} leaves, trainer expects {len(cur)}")
@@ -472,8 +478,11 @@ def load_checkpoint(trainer, path: str, verify: bool = True) -> int:
             "full-state resume", RuntimeWarning, stacklevel=2)
     treedef = jax.tree.structure((trainer.params, trainer.opt_state))
     params, opt_state = jax.tree.unflatten(treedef, leaves)
-    trainer.params = replicate(trainer.mesh, params)
-    trainer.opt_state = replicate(trainer.mesh, opt_state)
+    if hasattr(trainer, "load_host_state"):
+        trainer.load_host_state(params, opt_state)
+    else:
+        trainer.params = replicate(trainer.mesh, params)
+        trainer.opt_state = replicate(trainer.mesh, opt_state)
     if restore_state:
         trainer.restore_resume_state(state, carry_leaves)
     # expose the restore OUTCOME so callers (the CLI's resume event, run

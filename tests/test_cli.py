@@ -297,3 +297,35 @@ def test_train_cli_deepergcn(pipeline):
     assert report["exchanges"] == 3 * 2 * 3
     r = run_cli(base + ["-n", "40"])
     assert r.returncode != 0 and "full-batch only" in r.stderr
+
+
+def test_train_cli_rgcn(pipeline):
+    """``--model rgcn``: the type table and the relations on the command
+    line; typed rows train end to end on 4 virtual devices; full-batch
+    only."""
+    d = pipeline
+    import scipy.io
+
+    n = scipy.io.mmread(str(d / "g.A.mtx")).shape[0]
+    first = n // 2
+    base = ["sgcn_tpu.train", "-a", str(d / "g.A.mtx"),
+            "-p", str(d / "g.A.mtx.4.hp"), "-b", "cpu", "-s", "4",
+            "-l", "2", "--hidden", "8", "-f", "6", "--model", "rgcn",
+            "--node-types",
+            f"doc:{first}:features,tag:{n - first}:embedding",
+            "--relations", "doc:links:doc,doc:has:tag,tag:of:doc",
+            "--label-type", "doc"]
+    r = run_cli(base + ["--epochs", "2"])
+    assert r.returncode == 0, r.stderr
+    report = json.loads(r.stdout.strip().splitlines()[-1])
+    assert report["model"] == "rgcn" and report["epochs"] == 2
+    history = [float(ln.split("loss")[1]) for ln in r.stdout.splitlines()
+               if ln.startswith("epoch ")]
+    assert len(history) == 2 and history[1] < history[0]
+    # warm-up + two epochs, two layers, both directions (layer 0's backward
+    # too: the tags' embeddings are trainable)
+    assert report["exchanges"] == 3 * 2 * 2
+    r = run_cli(base + ["-n", "40"])
+    assert r.returncode != 0 and "full-batch only" in r.stderr
+    r = run_cli(base[:-6] + ["--epochs", "1"])
+    assert r.returncode != 0 and "--node-types" in r.stderr
