@@ -20,24 +20,34 @@ i's neighbours of type s in that graph (a self-loop is no edge; Â's values
 are not read).
 
 **Typed order.**  Every array of the model lives in the plan's row order
-restricted to one type: ELL bucket after bucket, within a bucket the rows of
-that type in plan order, padded to the fullest chip's count.  In that order
-a type's rows are a static slice, so a weight per TYPE is a product on a
-slice; the aggregation's slots are the plan's own, regrouped by the type of
-their destination (``build_typed_layout``: sub-buckets of ``ell_buckets``,
-sub-classes of the tail's and the halo store's virtual rows), and a slot
-carries its source's typed row and type in one int32, the mean's weight
-``1 / deg_r(i)`` and the transposed weight ``1 / deg_r'(j)`` of the same
-slot read from the other side (``ops.pspmm.typed_aggregate``).
+restricted to one type: the rows of that type in plan order, padded to the
+fullest chip's count.  In that order a type's rows are a static slice, so a
+weight per TYPE is a product on a slice.
 
-**Every layer aggregates first**: per destination type the mean over each
-source type into an accumulator of its own (``d_in`` lanes gathered, one
-``d_in``-lane array per source type accumulated), then per type the products
-``x_d W_root + sum_s A_ds W_(s->d)``.  The backward gathers, into the rows of
-each source type that needs a gradient — at layer 0 the embedded types only
-(features are data) — the cotangent of the block that type fills; the blocks
-nobody reads are never formed, and the weights' gradients are dense products
-on what the forward kept.  The v5e gathers rows at one rate
+**One slot layout per relation.**  The unit of the aggregation is the
+ordered pair of types (s -> d): the rows of d, their slots that pair's edges
+only (``build_typed_layout``), every directed edge of the graph in exactly
+one layout, once.  A layout is what the plan's own builders make of the
+pair's edges: ELL buckets over d's rows in typed order (``_build_ell``, at
+the widths ``_relation_buckets`` chooses by count), what lies past a bucket's
+width as virtual rows (``_build_virtual_rows``: the typed order follows a
+row's TOTAL degree, a relation's own degree may not), the halo-source edges
+as virtual rows too.  A slot names its source's row in the SOURCE type's own
+table and carries the mean's weight ``1 / deg_r(i)`` and the transposed
+weight ``1 / deg_r'(j)`` of the same slot read from the other side: the
+forward of s -> d and the backward of d -> s walk the same layout
+(``ops.pspmm.typed_aggregate``), which is why the pattern must be symmetric.
+
+**Every layer aggregates first**: per relation into a destination type the
+mean over its sources (``d_in`` lanes gathered, one ``d_in``-lane array
+accumulated), then per type the products ``x_d W_root + sum_s A_ds
+W_(s->d)``.  The backward runs, per relation whose source needs a gradient —
+at layer 0 the embedded types only (features are data) — the reverse pair's
+layout over the cotangent of the block that relation fills; a relation dead
+in a pass contributes no slot to it, the blocks nobody reads are never
+formed, and the weights' gradients are dense products on what the forward
+kept (``typed_passes`` lists what runs; the counter ``rel.work`` reports it).
+The v5e gathers rows at one rate
 from 64 to 128 lanes, so projecting layer 0 first would buy nothing per
 slot and cost a pass into every source row for the weights' gradient.
 
@@ -70,23 +80,27 @@ import numpy as np
 from jax import lax
 
 from ..obs.tracing import scope, subscope
-from ..ops.pspmm import (_FOLD_SCAN_LIVE, _TYPED_SCAN_LIVE, MAX_NODE_TYPES,
-                         TYPE_SHIFT, typed_aggregate)
+from ..ops.pspmm import _FOLD_SCAN_LIVE, _TYPED_SCAN_LIVE, typed_aggregate
 from ..parallel.mesh import AXIS
-from ..parallel.plan import padding_rows
+from ..parallel.plan import (FOLD_ROW_COST, _build_ell, _build_virtual_rows,
+                             _choose_buckets, choose_fold_widths,
+                             fold_class_shapes, padding_rows)
 from .activations import get_activation
 from .setup import ModelSetup
 
 RGCN_PLAN_FIELDS = ("halo_src",)
 INPUTS = ("features", "embedding")
-STORES = ("e", "t", "h")            # ELL slots, hub tail, halo-source edges
+STORES = ("e", "t", "h")    # ELL slots, the runs past them, halo-source edges
+# a relation's ELL width cap: none (virtual rows only) up to the plan's own
+ELL_CAPS = (0, 1, 2, 4, 8, 16, 32, 64)
+PROFILE_POINTS = 64
 
 
 class TypedSpec(NamedTuple):
     """Statics of one layer's ``typed_aggregate``."""
     heights: tuple      # rows of each type's table, in type order
     sources: tuple      # per type: the types with a relation INTO it
-    layouts: tuple      # per type: (ell buckets, tail classes, halo classes)
+    layouts: tuple      # ((s, d), (ell buckets, tail classes, halo classes))
     dst: tuple          # destination types this layer computes
     grad: tuple         # source types whose table gets a gradient
     exchange: bool      # some chip has a halo-source edge
@@ -115,9 +129,6 @@ def resolve_args(fin: int, widths, model_args: dict | None) -> dict:
     names = [t["name"] for t in types]
     if len(set(names)) != len(names) or not names:
         raise ValueError(f"rgcn: type names {names} are not distinct")
-    if len(names) > MAX_NODE_TYPES:
-        raise ValueError(f"rgcn: {len(names)} node types; a slot's code "
-                         f"holds {MAX_NODE_TYPES}")
     for t in types:
         if t.get("input") not in INPUTS or int(t["count"]) < 1:
             raise ValueError(f"rgcn: type {t} needs a count >= 1 and an "
@@ -194,44 +205,169 @@ def layer_specs(args: dict, layout: dict) -> tuple:
     return tuple(specs)
 
 
+def typed_passes(specs, relations) -> list:
+    """What each layer's ``typed_aggregate`` runs, read off its spec as
+    ``ops.pspmm`` reads it: per layer the forward and the backward pass,
+    each a list of ``(relation, the pair whose layout is walked, weight)``
+    — forward every relation into ``spec.dst``; backward those of them
+    whose source is in ``spec.grad``, on the REVERSE pair's slots."""
+    rel_of = {(s, d): r for r, (s, _, d) in enumerate(relations)}
+    return [([(rel_of[s, d], (s, d), "wf")
+              for d in spec.dst for s in spec.sources[d]],
+             [(rel_of[s, d], (d, s), "wb") for s in spec.grad
+              for d in spec.dst if s in spec.sources[d]]) for spec in specs]
+
+
+def shipped_layouts(layout: dict, specs, relations) -> dict:
+    """The relation layouts' arrays a step reads, by the name they ship
+    under (``rel_<s>_<d>_<array>``): the pairs some pass walks, and of their
+    two weights the ones a pass picks."""
+    weights = {}
+    for passes in typed_passes(specs, relations):
+        for _, pair, weight in passes[0] + passes[1]:
+            weights.setdefault(pair, set()).add(weight)
+    return {f"rel_{s}_{d}_{name}": x
+            for (s, d), picked in sorted(weights.items())
+            for name, x in layout["arrays"]["rels"][s, d].items()
+            if name[2:] not in {"wf", "wb"} - picked}
+
+
+def pass_counts(args: dict, layout: dict, specs, lanes) -> list:
+    """The ``rel.work`` counter's ``passes``: per layer and direction the
+    types whose rows the pass fills, the relations it runs with the edges
+    (the fullest chip's), slots, virtual rows and buckets + classes of the
+    layout each walks, and the relations it leaves out."""
+    names = [name for name, _, _ in args["types"]]
+    rels = args["relations"]
+    passes = []
+    for layer, (spec, a, both) in enumerate(zip(
+            specs, lanes, typed_passes(specs, rels))):
+        for direction, into, end, run in (("forward", spec.dst, 2, both[0]),
+                                          ("backward", spec.grad, 0, both[1])):
+            ran = [r for r, _, _ in run]
+            counts = [layout["counts"][pair] for _, pair, _ in run]
+            passes.append({
+                "layer": layer, "direction": direction, "lanes": a,
+                # the types whose rows the pass fills: backward, the sources
+                "into": [names[t] for t in into],
+                "relations": [rels[r][1] for r in ran],
+                # what a pass over these types' WHOLE rows would also walk:
+                # the relations that end (backward: start) there and are
+                # dead in this pass
+                "left_out": [rel[1] for r, rel in enumerate(rels)
+                             if rel[end] in into and r not in ran],
+                "run": [{"relation": rels[r][1], **c}
+                        for r, c in zip(ran, counts)],
+                "edges": sum(c["edges"] for c in counts),
+                "slots": sum(c["slots"] for c in counts)})
+    return passes
+
+
 # ------------------------------------------------------------------ layout
-def _sub_layout(buckets, keep):
-    """``buckets = ((n, w), ...)`` of a width-major slot layout (slot t of a
-    bucket's row v at ``off + t·n + v``) restricted, per chip, to the rows
-    ``keep[c]`` (a mask over Σ n rows) marks: the sub-buckets (``n`` the
-    fullest chip's count, empty ones dropped), per chip the position each
-    slot of the sub-layout had (−1: padding) and the row each of its rows
-    was (−1: padding)."""
-    k = len(keep)
-    sub, takes, rows = [], [[] for _ in range(k)], [[] for _ in range(k)]
-    off = r0 = 0
-    for n, w in buckets:
-        sel = [np.flatnonzero(m[r0:r0 + n]) for m in keep]
-        n_sub = max(len(s) for s in sel)
-        if n_sub:
-            sub.append((n_sub, w))
-            for c, s in enumerate(sel):
-                pos = np.full((w, n_sub), -1, np.int64)
-                pos[:, :len(s)] = off + np.arange(w)[:, None] * n + s[None]
-                takes[c].append(pos.ravel())
-                row = np.full(n_sub, -1, np.int64)
-                row[:len(s)] = r0 + s
-                rows[c].append(row)
-        off += n * w
-        r0 += n
-    cat = lambda parts: (np.stack([np.concatenate(p) for p in parts])  # noqa: E731
-                         if sub else np.zeros((k, 0), np.int64))
-    return tuple(sub), cat(takes), cat(rows)
+def _relation_buckets(degs: list, height: int) -> tuple:
+    """ELL buckets over a destination type's ``height`` rows for ONE
+    relation's local edges (``degs``: per chip, the edges each row has in
+    it).  The typed order follows a row's TOTAL degree and a relation's own
+    degree may not, so what lies past a bucket's width runs as virtual rows:
+    per width cap of ``ELL_CAPS`` the plan's own rules give the buckets
+    (``_choose_buckets``) and the classes of the rest
+    (``choose_fold_widths``), and the cap with the least ``executed slots +
+    FOLD_ROW_COST · virtual rows`` wins — cap 0 is the pure virtual-row
+    form, the widest the plan's own ELL with a hub tail.  The profile is the
+    maximum over blocks of rows (``_choose_buckets`` walks it in Python)."""
+    block = -(-height // PROFILE_POINTS)
+    profile = np.max(degs, axis=0)
+    profile = np.pad(profile, (0, -height % block)).reshape(-1, block).max(1)
+    best = None
+    for cap in ELL_CAPS:
+        buckets, width = (), 0              # a row's width in its bucket
+        if cap:
+            found = _choose_buckets(profile, width_cap=cap)
+            rows = [n * block for n, _ in found]
+            rows[-1] -= -height % block
+            buckets = tuple(zip(rows, (w for _, w in found)))
+            width = np.repeat([w for _, w in found], rows)
+        rest = [np.maximum(dg - width, 0) for dg in degs]
+        cost = sum(n * w for n, w in buckets) + sum(
+            nv * (w + FOLD_ROW_COST)
+            for nv, w in fold_class_shapes(rest, choose_fold_widths(rest)))
+        if best is None or cost < best[0]:
+            best = (cost, buckets)
+    return best[1]
+
+
+def _relation_layout(local: list, halo: list, height: int, tables: tuple,
+                     ) -> tuple:
+    """The slot layout of ONE ordered pair of types from its edges per chip
+    (``local`` / ``halo``: ``(destination, source, wf, wb)``, destinations
+    ascending typed rows, sources rows of ``tables`` = the source type's
+    height / the halo table's): the plan's builders laid over the edge
+    NUMBERS — ``_build_ell`` at ``_relation_buckets``' widths, what it spills
+    and the halo-source edges through ``_build_virtual_rows`` — then every
+    real slot given its edge's source and weights, every padding slot a row
+    of ``padding_rows``.  Returns arrays, ``(buckets, tail classes, halo
+    classes)`` and counts."""
+    k = len(local)
+    none = {"idx": np.zeros((k, 0), np.int32), "w": np.zeros((k, 0), np.float32),
+            "row": np.zeros((k, 0), np.int32), "classes": ()}
+
+    def numbered(edges):
+        # (dst, edge number, 1 / 0, count): a store as the builders take it
+        cnt = np.array([len(e[0]) for e in edges])
+        eno = np.arange(cnt.max(), dtype=np.int32)[None].repeat(k, 0)
+        dst = np.zeros(eno.shape, np.int32)
+        for c, e in enumerate(edges):
+            dst[c, :cnt[c]] = e[0]
+        return dst, eno, (eno < cnt[:, None]).astype(np.float32), cnt
+
+    def fill(pre, idx, w, edges, table):
+        # a built store's slots: edge number -> source row and weights
+        out = {f"{pre}_idx": np.empty(idx.shape, np.int32),
+               f"{pre}_wf": np.zeros(idx.shape, np.float32),
+               f"{pre}_wb": np.zeros(idx.shape, np.float32)}
+        for c, (_, src, wf, wb) in enumerate(edges):
+            real = w[c] != 0
+            out[f"{pre}_idx"][c][~real] = padding_rows(int((~real).sum()),
+                                                       table)
+            for name, val in (("idx", src), ("wf", wf), ("wb", wb)):
+                out[f"{pre}_{name}"][c][real] = val[idx[c][real]]
+        return out
+
+    def virtual(pre, stored, edges, table):
+        lay = _build_virtual_rows(*stored, height, table) or none
+        return ({**fill(pre, lay["idx"], lay["w"], edges, table),
+                 f"{pre}_row": lay["row"]}, lay["classes"])
+
+    stored = numbered(local)
+    buckets = _relation_buckets(
+        [np.bincount(e[0], minlength=height) for e in local], height)
+    ell = {"ell_idx": none["idx"], "ell_w": none["w"]}
+    if buckets:
+        ell = _build_ell(*stored, height, buckets=buckets)
+        stored = (ell["ltail_dst"], ell["ltail_src"], ell["ltail_w"],
+                  ell["ltail_nnz"])
+    tail, tail_classes = virtual("t", stored, local, tables[0])
+    over, halo_classes = virtual("h", numbered(halo), halo, tables[1])
+    arrays = {**fill("e", ell["ell_idx"], ell["ell_w"], local, tables[0]),
+              **tail, **over}
+    counts = {
+        # the fullest chip's edges; what every chip executes
+        "edges": max(len(a[0]) + len(b[0]) for a, b in zip(local, halo)),
+        "slots": sum(arrays[f"{s}_idx"].shape[1] for s in STORES),
+        "rows": arrays["t_row"].shape[1] + arrays["h_row"].shape[1],
+        "classes": len(buckets) + len(tail_classes) + len(halo_classes)}
+    return arrays, (buckets, tail_classes, halo_classes), counts
 
 
 def build_typed_layout(plan, args: dict) -> dict:
     """Everything the model derives from the plan, per chip: the typed
-    order, and per destination type the plan's slots regrouped with their
-    codes and both weights (module docstring).  Returns ``arrays`` (shipped:
-    ``ModelSetup.extra_arrays``), the statics (``heights``, ``layouts``,
-    ``exchange``), ``rows`` per type ``(k, height)`` the table row of every
-    typed row in the type's global id order (−1 padding) and ``edges`` per
-    relation."""
+    order, and per ordered pair of types with a relation (either way round:
+    a relation's backward walks the reverse pair's slots) that pair's slot
+    layout with both weights (module docstring).  Returns ``arrays``
+    (shipped: ``ModelSetup.extra_arrays``), the statics (``heights``,
+    ``layouts``, ``exchange``), ``counts`` per pair, ``rows`` per type ``(k,
+    height)`` the table row of every typed row in the type's global id order
+    (−1 padding) and ``edges`` per relation."""
     types, rels = args["types"], args["relations"]
     nt, k, b = len(types), plan.k, plan.b
     counts = np.array([c for _, c, _ in types], np.int64)
@@ -239,122 +375,76 @@ def build_typed_layout(plan, args: dict) -> dict:
         raise ValueError(f"rgcn: the type table counts {int(counts.sum())} "
                          f"rows, the plan {plan.n}")
     starts = np.concatenate([[0], np.cumsum(counts)])
-    rel_of = -np.ones((nt, nt), np.int64)       # [source, destination]
-    for r, (s, _, d) in enumerate(rels):
-        rel_of[s, d] = r
-    plan.ensure_fold_slots()
+    is_rel = np.zeros((nt, nt), bool)           # [source, destination]
+    for s, _, d in rels:
+        is_rel[s, d] = True
     gid = plan.global_row_ids()                             # (k, B), -1 pad
     typ = np.where(gid >= 0, np.searchsorted(starts, gid, "right") - 1, -1)
     hgid = plan.halo_global_rows()                          # (k, R)
     htyp = np.where(hgid >= 0, np.searchsorted(starts, hgid, "right") - 1,
                     -1)
-    exchange = bool(plan.fold_halo_classes)
 
-    # neighbours of every row by type, a self-loop being no edge
-    deg = np.zeros((k, b, nt), np.int64)
+    # the typed order: a type's rows in plan order, padded to the fullest
+    # chip's count
+    rows = []
+    for t in range(nt):
+        per = [np.flatnonzero(typ[c] == t) for c in range(k)]
+        height = max(len(r) for r in per)
+        rows.append(np.stack([np.pad(r, (0, height - len(r)),
+                                     constant_values=-1) for r in per]))
+    heights = tuple(int(r.shape[1]) for r in rows)
+    first = np.concatenate([[0], np.cumsum(heights)])
+    pos = np.zeros((k, b), np.int64)            # typed row WITHIN its type
+    for r in rows:
+        for c in range(k):
+            pos[c, r[c][r[c] >= 0]] = np.flatnonzero(r[c] >= 0)
+
+    # every real edge of a chip (a self-loop is none), local and halo-source:
+    # destination row, source row / halo rank, their pair of types (int8:
+    # these lists are the build's memory traffic)
+    stores = [[], []]
+    deg = np.zeros((k, b, nt), np.int64)    # a row's neighbours by type
     for c in range(k):
-        for dst, src, cnt, tsrc, loop in (
-                (plan.ledge_dst, plan.ledge_src, plan.lnnz, typ, True),
-                (plan.hedge_dst, plan.hedge_src, plan.hnnz, htyp, False)):
+        for halo, (dst, src, cnt, tsrc) in enumerate((
+                (plan.ledge_dst, plan.ledge_src, plan.lnnz, typ),
+                (plan.hedge_dst, plan.hedge_src, plan.hnnz, htyp))):
             d_, s_ = dst[c, :int(cnt[c])], src[c, :int(cnt[c])]
-            ok = (s_ != d_) if loop else np.ones(len(d_), bool)
-            ok &= tsrc[c][s_] >= 0
-            deg[c] += np.bincount(
-                d_[ok].astype(np.int64) * nt + tsrc[c][s_[ok]],
-                minlength=b * nt).reshape(b, nt)
+            ts = tsrc[c].astype(np.int8)[s_]
+            ok = ts >= 0
+            if not halo:
+                ok &= s_ != d_
+            d_, s_, ts = d_[ok], s_[ok], ts[ok]
+            stores[halo].append((d_, s_, typ[c].astype(np.int8)[d_], ts))
+            deg[c] += np.bincount(d_.astype(np.int64) * nt + ts,
+                                  minlength=b * nt).reshape(b, nt)
     gdeg = plan.gather_rows(deg)                            # (n, nt)
     edges = {name: int(gdeg[starts[d]:starts[d + 1], s].sum())
              for s, name, d in rels}
 
-    # the typed order: per type the ELL's sub-buckets
-    ell_rows = sum(n for n, _ in plan.ell_buckets)
-    subs = [_sub_layout(plan.ell_buckets,
-                        [np.pad(typ[c] == t, (0, ell_rows - b))
-                         for c in range(k)]) for t in range(nt)]
-    heights = tuple(int(rows.shape[1]) for _, _, rows in subs)
-    first = np.concatenate([[0], np.cumsum(heights)])
-    pos = np.zeros((k, b), np.int64)            # typed row WITHIN its type
-    for t, (_, _, rows) in enumerate(subs):
-        for c in range(k):
-            ok = rows[c] >= 0
-            pos[c, rows[c][ok]] = np.flatnonzero(ok)
-
     def inv(x):
-        return np.where(x > 0, 1.0 / np.maximum(x, 1), 0.0)
+        return (1.0 / np.maximum(x, 1)).astype(np.float32)
 
-    def codes(c, t, dst_row, src, real, halo: bool):
-        """Code and both weights of the slots of chip c whose destination
-        rows ``dst_row`` have type t; ``src`` a local row or a halo rank."""
-        u = np.where(real, (htyp if halo else typ)[c][src], 0)
-        real = real & (u >= 0)
-        if not halo:
-            real = real & (src != dst_row)
-        u = np.where(real, u, 0)
-        row = src if halo else first[u] + pos[c][src]
-        g_src = (hgid if halo else gid)[c][src]
-        wf = np.where(real & (rel_of[u, t] >= 0),
-                      inv(deg[c][np.where(real, dst_row, 0), u]), 0.0)
-        wb = np.where(real & (rel_of[t, u] >= 0),
-                      inv(gdeg[np.where(real, g_src, 0), t]), 0.0)
-        n_pad = int((~real).sum())
-        height = plan.r if halo else int(first[-1])
-        code = (row + (u << TYPE_SHIFT)).astype(np.int64)
-        code[~real] = padding_rows(n_pad, height)
-        return (code.astype(np.int32), wf.astype(np.float32),
-                wb.astype(np.float32))
+    def pair_edges(c, halo, s, d):
+        """Chip c's edges of the pair (s -> d) in one store: typed
+        destination row, source (typed row / halo rank), the mean's weight
+        of s -> d and the transposed weight of d -> s (0: no such
+        relation)."""
+        d_, s_, td, ts = stores[halo][c]
+        at = np.flatnonzero((td == d) & (ts == s))
+        d_, s_ = d_[at], s_[at]
+        wf = inv(deg[c][d_, s]) * is_rel[s, d]
+        wb = inv(gdeg[(hgid if halo else gid)[c][s_], d]) * is_rel[d, s]
+        return pos[c][d_], s_ if halo else pos[c][s_], wf, wb
 
-    def store(t, sub, take, dst_rows, idx, w, halo: bool) -> dict:
-        """One store's slots regrouped for type t: code and both weights of
-        every slot of the sub-layout (``take``: its position in the plan's
-        arrays ``idx`` / ``w``; ``dst_rows``: its destination, per chip)."""
-        out = []
-        for c in range(k):
-            ok, at = take[c] >= 0, np.maximum(take[c], 0)
-            out.append(codes(c, t, dst_rows[c], np.where(ok, idx[c][at], 0),
-                             ok & (w[c][at] != 0), halo))
-        return dict(zip(("code", "wf", "wb"), (np.stack(x)
-                                               for x in zip(*out))))
+    arrays = {"rels": {}}
+    layouts, slot_counts = {}, {}
+    for s, d in np.argwhere(is_rel | is_rel.T).tolist():
+        arrays["rels"][s, d], layouts[s, d], slot_counts[s, d] = \
+            _relation_layout(*[[pair_edges(c, halo, s, d) for c in range(k)]
+                               for halo in (0, 1)], heights[d],
+                             (heights[s], plan.r))
 
-    # the two fold stores: which virtual rows hold an edge at all
-    folds = []
-    for pre, classes, idx, w, vrow, halo in (
-            ("t", plan.fold_tail_classes, plan.ft_idx, plan.ft_w,
-             plan.ft_row, False),
-            ("h", plan.fold_halo_classes, plan.fh_idx, plan.fh_w,
-             plan.fh_row, True)):
-        slot_row = _slot_rows(classes, np.arange(sum(n for n, _ in classes)))
-        real = [np.bincount(slot_row[w[c] != 0], minlength=vrow.shape[1]) > 0
-                for c in range(k)]
-        folds.append((pre, classes, idx, w, vrow, halo, real))
-    arrays = {"types": {}}
-    layouts = []
-    for t in range(nt):
-        buckets, take, rows = subs[t]
-        # ELL: a slot's destination is its row of the sub-bucket
-        per = {f"e_{name}": x for name, x in store(
-            t, buckets, take,
-            [np.maximum(_slot_rows(buckets, rows[c]), 0) for c in range(k)],
-            plan.ell_idx, plan.ell_w, False).items()}
-        lay = [buckets]
-        for pre, classes, idx, w, vrow, halo, real in folds:
-            sub, vtake, vrows = _sub_layout(
-                classes, [real[c] & (typ[c][vrow[c]] == t) for c in range(k)])
-            # a virtual row's destination, and its typed row for the fold
-            # (padding rows last)
-            dest = [vrow[c][np.maximum(vrows[c], 0)] for c in range(k)]
-            per.update({f"{pre}_{name}": x for name, x in store(
-                t, sub, vtake,
-                [_slot_rows(sub, dest[c]) for c in range(k)], idx, w,
-                halo).items()})
-            per[f"{pre}_row"] = np.stack([
-                np.where(vrows[c] >= 0, pos[c][dest[c]],
-                         max(heights[t] - 1, 0)) for c in range(k)
-            ]).astype(np.int32)
-            lay.append(sub)
-        arrays["types"][t] = per
-        layouts.append(tuple(lay))
-
-    # the exchange's send rows, in the table's order
+    # the exchange's send rows, in the stacked table's order
     send = np.asarray(plan.send_idx)
     arrays["send_rows"] = np.stack([
         (first[np.maximum(typ[c][send[c]], 0)] + pos[c][send[c]])
@@ -362,25 +452,16 @@ def build_typed_layout(plan, args: dict) -> dict:
     # typed row -> plan row (features are gathered through it), and ->
     # the row of the type's table in global id order
     plan_rows, table_rows = [], []
-    for t, (_, _, rows) in enumerate(subs):
-        ok = rows >= 0
-        filler = np.stack([padding_rows(rows.shape[1], b)] * k)
-        plan_rows.append(np.where(ok, rows, filler).astype(np.int32))
-        g = np.stack([gid[c][np.maximum(rows[c], 0)] for c in range(k)])
-        table_rows.append(np.where(ok, g - starts[t], -1))
-    return {"arrays": arrays, "heights": heights, "layouts": tuple(layouts),
-            "exchange": exchange, "plan_rows": plan_rows,
+    for t, r in enumerate(rows):
+        filler = np.stack([padding_rows(r.shape[1], b)] * k)
+        plan_rows.append(np.where(r >= 0, r, filler).astype(np.int32))
+        g = np.stack([gid[c][np.maximum(r[c], 0)] for c in range(k)])
+        table_rows.append(np.where(r >= 0, g - starts[t], -1))
+    return {"arrays": arrays, "heights": heights,
+            "layouts": tuple(sorted(layouts.items())),
+            "exchange": bool(np.asarray(plan.hnnz).any()),
+            "counts": slot_counts, "plan_rows": plan_rows,
             "table_rows": table_rows, "edges": edges}
-
-
-def _slot_rows(buckets, rows) -> np.ndarray:
-    """The entry of ``rows`` (one per row of a width-major layout) every
-    slot of the layout belongs to."""
-    out, r0 = [], 0
-    for n, w in buckets:
-        out.append(np.tile(rows[r0:r0 + n], w))
-        r0 += n
-    return np.concatenate(out) if out else np.zeros(0, np.int64)
 
 
 # ------------------------------------------------------------------ params
@@ -461,12 +542,11 @@ def rgcn_forward_local(
         raise ValueError("rgcn ships its tables over the dense all_to_all "
                          f"only, not comm_schedule={comm_schedule!r}")
     act, last = get_activation(activation), get_activation(final_activation)
-    arrays = {"types": {t: {f"{s}_{n}": pa[f"rel_{t}_{s}_{n}"]
-                            for s in STORES
-                            for n in ("code", "wf", "wb", "row")
-                            if f"rel_{t}_{s}_{n}" in pa}
-                        for t in range(len(types))
-                        if f"rel_{t}_e_code" in pa},
+    arrays = {"rels": {(s, d): {f"{st}_{n}": pa[f"rel_{s}_{d}_{st}_{n}"]
+                                for st in STORES
+                                for n in ("idx", "wf", "wb", "row")
+                                if f"rel_{s}_{d}_{st}_{n}" in pa}
+                       for (s, d), _ in specs[0].layouts},
               "send_rows": pa["rel_send_rows"], "halo_src": pa["halo_src"]}
     x = []
     with scope("dense"), subscope("rel_table"):
@@ -509,16 +589,18 @@ def estimate_rgcn_hbm_bytes(plan, fin: int, widths, args: dict, layout: dict,
     * ``rows_kept``: what the forward holds for the backward — per layer the
       featured types' gathered rows, the aggregated blocks ``A_ds`` and the
       layer's output (the last layer's: the labelled rows' logits);
-    * ``rows_transient``: the most of — a layer's gather table beside the
-      accumulators being filled; at the loss, the logits' gradient and the
+    * ``rows_transient``: the most of — a layer's means being filled
+      beside, at k > 1, the stacked table its exchange ships; at the loss,
+      the logits' gradient and the
       softmax; in a layer's backward, its output's cotangent, the cotangent
       blocks the gradient types read and the rows gathered into them — and
       the row-owned tables' gradient beside any of these;
     * ``slot_temps``: the slot passes' gathered rows and accumulators,
       bounded by the scan-unroll budgets of the typed passes and the folds
       and by the unrolled buckets' concurrent temporaries;
-    * ``plan``: code (int32) and two weights (f32) per slot of every type's
-      sub-layout, a destination per virtual row;
+    * ``plan``: the relation layouts a step reads (``shipped_layouts``):
+      per slot an index and the weights its passes pick, a destination per
+      virtual row;
     * ``features``: the trainer's ``h0``, labels and masks;
     * ``param_bytes`` (not in the total: it is inside ``row_owned`` and
       ``params``): the parameter tree's bytes on ONE chip, which is what a
@@ -535,7 +617,8 @@ def estimate_rgcn_hbm_bytes(plan, fin: int, widths, args: dict, layout: dict,
     owned = emb_rows * fin * (12 if train else 4)
     kept, transient = 0, 2 * heights[args["label"]] * widths[-1] * 4
     for layer, (spec, (a, b)) in enumerate(zip(specs, dims)):
-        table = sum(heights[t] for t in _read(spec)) * a * 4
+        table = sum(heights[t] for t in _read(spec)) * a * 4 \
+            * layout["exchange"]
         agg = sum(heights[d] * len(spec.sources[d]) for d in spec.dst) * a * 4
         out = sum(heights[d] for d in spec.dst) * b * 4
         gathered = sum(heights[t] for t in _read(spec)
@@ -550,25 +633,20 @@ def estimate_rgcn_hbm_bytes(plan, fin: int, widths, args: dict, layout: dict,
                             out + wanted + into)
         else:
             transient = max(transient, table + agg + out)
-    slots = sum(int(np.prod(x.shape[1:])) for per in
-                layout["arrays"]["types"].values()
-                for name, x in per.items() if name.endswith("_code"))
-    vrows = sum(int(x.shape[1]) for per in layout["arrays"]["types"].values()
-                for name, x in per.items() if name.endswith("_row"))
     shared = (param_count(fin, widths, args["types"], args["relations"])
               - sum(c for _, c, kd in args["types"] if kd == "embedding")
               * fin)
-    # a row of the widest pass: its gathered lanes and an accumulator per
-    # source type
-    widest = max((len(spec.sources[d]) + 1) * a * 4
-                 for spec, (a, _) in zip(specs, dims) for d in spec.dst)
-    big = max((n for lay in layout["layouts"] for n, _ in lay[0]), default=0)
+    # a row of a pass: its gathered lanes and its accumulator
+    widest = 2 * max(a for a, _ in dims) * 4
+    big = max((n for _, lay in layout["layouts"] for n, _ in lay[0]),
+              default=0)
     parts = {"row_owned": owned, "rows_kept": kept,
              "rows_transient": transient
              + (emb_rows * fin * 4 if train else 0),
              "slot_temps": min(_TYPED_SCAN_LIVE + _FOLD_SCAN_LIVE,
                                16 * big * widest),
-             "plan": 12 * slots + 4 * vrows,
+             "plan": sum(x[0].nbytes for x in shipped_layouts(
+                 layout, specs, args["relations"]).values()),
              "features": int(plan.b) * 4 * (fin + 3),
              "params": (16 if train else 4) * shared}
     parts["total"] = sum(parts.values())
@@ -604,12 +682,8 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
     types, rels = args["types"], args["relations"]
     heights = layout["heights"]
     label = args["label"]
-    used = sorted({t for spec in specs for t in spec.dst}
-                  | {t for spec in specs for t in spec.grad})
-    extra = {"rel_send_rows": layout["arrays"]["send_rows"]}
-    for t in used:
-        for name, x in layout["arrays"]["types"][t].items():
-            extra[f"rel_{t}_{name}"] = x
+    extra = {"rel_send_rows": layout["arrays"]["send_rows"],
+             **shipped_layouts(layout, specs, rels)}
     for t, (_, _, kind) in enumerate(types):
         if kind == "features" and t in _read(specs[0]):
             extra[f"rel_{t}_rows"] = layout["plan_rows"][t]
@@ -626,28 +700,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                                  list(widths), args, layout)
     names = [name for name, _, _ in types]
 
-    def slots_of(ts):
-        return int(sum(np.prod(layout["arrays"]["types"][t][f"{s}_code"]
-                               .shape[1:]) for t in ts for s in STORES))
-
-    passes = []
-    for layer, (spec, (a, _)) in enumerate(zip(specs, dims)):
-        passes.append({
-            "layer": layer, "direction": "forward",
-            "into": [names[d] for d in spec.dst],
-            "relations": [n for s, n, d in rels if d in spec.dst],
-            "slots": slots_of(spec.dst), "lanes": a,
-            "table_rows": int(sum(heights[t] for t in _read(spec)))})
-        live = list(spec.grad)
-        passes.append({
-            "layer": layer, "direction": "backward",
-            "into": [names[s] for s in live],
-            "relations": [n for s, n, d in rels
-                          if d in spec.dst and s in spec.grad],
-            "slots": slots_of(live), "lanes": a,
-            "table_rows": int(sum(
-                heights[d] for d in spec.dst for u in spec.sources[d]
-                if u in spec.grad))})
+    passes = pass_counts(args, layout, specs, [a for a, _ in dims])
     left_out = [{"layer": layer, "relations": [
         n for s, n, d in rels if d not in spec.dst]}
         for layer, spec in enumerate(specs)]
@@ -663,6 +716,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                             "optimizer_state": 2 * est["row_owned"] // 3,
                             "gradient": est["row_owned"] // 3},
         "executed_slots_per_step": sum(p["slots"] for p in passes),
+        "live_edges_per_step": sum(p["edges"] for p in passes),
     }
     statics = {"types": types, "relations": rels, "label": label,
                "specs": specs}

@@ -1695,152 +1695,103 @@ pspmm_stale_ragged.defvjp(_pspmm_stale_ragged_fwd, _pspmm_stale_ragged_bwd)
 
 
 # ----------------------------------------------------- typed (relational)
-# A slot of the typed aggregation names its source row AND the source's node
-# type in one int32: the row in the low bits, the type above them.
-TYPE_SHIFT = 28
-_ROW_MASK = (1 << TYPE_SHIFT) - 1
-MAX_NODE_TYPES = 8          # three bits above TYPE_SHIFT, the sign bit clear
-
-
-# the typed passes keep several accumulators a row (one per source type), so
-# their scans unroll only as far as this many bytes of live slot
-# temporaries: compiled for the v5e at the ogbn-mag shape the step's
-# temporaries read 13.8 GB at the default 3 GB (PERF.md §6, PR 33)
+# the typed passes run beside each other in one program, so their scans
+# unroll only as far as this many bytes of live slot temporaries: compiled
+# for the v5e at the ogbn-mag shape the step's temporaries read 13.8 GB at
+# the default 3 GB (PERF.md §6, PR 33)
 _TYPED_SCAN_LIVE = 1024**3
 
 
-def _typed_group(arrays, layout, local, remote, blocks: int, f: int,
-                 weight: str):
-    """One destination type's rows: the ELL slots, the hub tail and the
-    halo-source edges of ITS rows (the sub-layout ``models/rgcn.py`` derives
-    from the plan's), each slot decoded by ``local`` / ``remote``
-    ``(code, w) -> blocks arrays (rows, f)``; ``weight`` picks the forward
-    (``"wf"``) or the transposed (``"wb"``) weight array of the same
-    slots.  Returns a tuple of ``blocks`` arrays."""
+def _typed_pass(out, arrays, layout, height: int, table, halo, weight: str):
+    """``out`` (or nothing yet) plus ONE relation's slots — the layout
+    ``models/rgcn.py`` builds for an ordered pair of node types: ELL buckets
+    over the destination type's ``height`` rows, what lies past a bucket's
+    width as virtual rows, the halo-source edges likewise.  Local slots
+    gather ``table`` (the source type's own rows), halo slots ``halo``;
+    ``weight`` picks the forward (``"wf"``) or the transposed (``"wb"``)
+    weight array of the same slots."""
     buckets, tail_classes, halo_classes = layout
+    f = table.shape[-1]
     with scope("agg_slots"):
-        outs = bucketed_slot_reduce(
-            arrays["e_code"], arrays["e_" + weight], buckets, contrib=local,
-            init=lambda nb: tuple(jnp.zeros((nb, f), jnp.float32)
-                                  for _ in range(blocks)),
-            slot_bytes=lambda nb: nb * blocks * f * 4,
+        parts = bucketed_slot_reduce(
+            arrays["e_idx"], arrays["e_" + weight], buckets,
+            contrib=lambda idx, w: jnp.take(table, idx, axis=0) * w[:, None],
+            init=lambda nb: jnp.zeros((nb, f), jnp.float32),
+            slot_bytes=lambda nb: nb * f * 4,
             scan_live_limit=_TYPED_SCAN_LIVE)
-        out = tuple(x[0] if len(x) == 1 else jnp.concatenate(x, axis=0)
-                    for x in zip(*outs))
+        # buckets cover every row of the type, or there are none
+        parts = parts or [jnp.zeros((height, f), jnp.float32)]
+        ell = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+        out = ell if out is None else out + ell
     with scope("agg_tail"):
-        out = fold_slots(out, None, arrays["t_code"], arrays["t_" + weight],
-                         arrays["t_row"], tail_classes, contrib=local)
+        out = fold_slots(out, table, arrays["t_idx"], arrays["t_" + weight],
+                         arrays["t_row"], tail_classes)
     with scope("agg_halo_fold"):
-        return fold_slots(out, None, arrays["h_code"],
-                          arrays["h_" + weight], arrays["h_row"],
-                          halo_classes, contrib=remote)
+        return fold_slots(out, halo, arrays["h_idx"], arrays["h_" + weight],
+                          arrays["h_row"], halo_classes)
 
 
-def _typed_exchange(table, arrays, spec, axis_name):
-    """The halo copy of ``table``'s rows (the plan's exchange, its send rows
-    renamed into the table's order), or ``None`` where no chip has a
-    halo-source edge."""
-    if not spec.exchange:
-        return None
+def _typed_exchange(parts, arrays, spec, axis_name):
+    """The halo copy of one table a row — ``parts[t]`` the lanes of type t's
+    rows (``None``: zeros), stacked in type order, the plan's exchange with
+    its send rows renamed into that order.  Not called where no chip has a
+    halo-source edge (k = 1: no table is stacked, nothing is sent)."""
+    f = next(p.shape[-1] for p in parts if p is not None)
+    with scope("dense"), subscope("rel_table"):
+        table = jnp.concatenate(
+            [jnp.zeros((h, f), jnp.float32) if p is None else p
+             for p, h in zip(parts, spec.heights)], axis=0)
     return halo_exchange(table, arrays["send_rows"], arrays["halo_src"],
                          axis_name)
 
 
 def _typed_forward(blocks, arrays, spec, axis_name):
-    f = next(b.shape[-1] for b in blocks if b is not None)
-    with scope("dense"), subscope("rel_table"):
-        table = jnp.concatenate(
-            [jnp.zeros((h, f), jnp.float32) if b is None else b
-             for b, h in zip(blocks, spec.heights)], axis=0)
-    halo = _typed_exchange(table, arrays, spec, axis_name)
-    outs = []
-    for d in spec.dst:
-        srcs = spec.sources[d]
-
-        def place(rows, code, srcs=srcs):
-            # each slot's row into the accumulator of its source's type
-            u = (code >> TYPE_SHIFT)[:, None]
-            return tuple(jnp.where(u == t, rows, 0.0) for t in srcs)
-
-        def local(code, w, place=place):
-            rows = jnp.take(table, code & _ROW_MASK, axis=0) * w[:, None]
-            return place(rows, code)
-
-        def remote(code, w, place=place):
-            rows = jnp.take(halo, code & _ROW_MASK, axis=0) * w[:, None]
-            return place(rows, code)
-
-        outs.append(_typed_group(arrays["types"][d], spec.layouts[d], local,
-                                 remote, len(srcs), f, "wf"))
-    return tuple(outs)
+    halo = (_typed_exchange(blocks, arrays, spec, axis_name)
+            if spec.exchange else None)
+    layouts = dict(spec.layouts)
+    return tuple(
+        tuple(_typed_pass(None, arrays["rels"][s, d], layouts[s, d],
+                          spec.heights[d], blocks[s], halo, "wf")
+              for s in spec.sources[d]) for d in spec.dst)
 
 
 def _typed_backward(cts, arrays, spec, axis_name):
-    """The transposition on the same slots: a slot (i <- j) of the forward
-    is read from j's side, gathering i's cotangent block for j's type at
-    the OTHER endpoint's weight.  One pass per source type that needs a
-    gradient; the blocks no such type reads are never formed."""
+    """The transposition: the backward of a relation s -> d walks the
+    layout of the pair (d -> s) — the rows of s, their slots the same edges
+    read from the other side — gathering d's cotangent block for s at the
+    OTHER endpoint's weight.  One pass per relation whose forward ran and
+    whose source needs a gradient; the blocks nobody reads are never
+    formed."""
     ct_of = dict(zip(spec.dst, cts))
+    layouts = dict(spec.layouts)
     f = cts[0][0].shape[-1]
-    first = [sum(spec.heights[:t]) for t in range(len(spec.heights))]
+    # per type the blocks some gradient type reads: side by side they are
+    # what a row ships, every type padded to the most
+    wanted = [[u for u in spec.sources[t] if u in spec.grad]
+              if t in ct_of else [] for t in range(len(spec.heights))]
+    most = max(map(len, wanted))
 
     def block(d, s):
         return ct_of[d][spec.sources[d].index(s)]
 
-    halo, per_row = None, 0
-    if spec.exchange:
-        # a row's wanted blocks side by side, every type padded to the most
-        wanted = {t: [u for u in spec.sources[t] if u in spec.grad]
-                  if t in ct_of else [] for t in range(len(spec.heights))}
-        per_row = max(len(w) for w in wanted.values())
-        with scope("dense"), subscope("rel_table"):
-            wide = jnp.concatenate([
-                jnp.concatenate(
-                    [block(t, u) for u in wanted[t]]
-                    + [jnp.zeros((h, f), jnp.float32)]
-                    * (per_row - len(wanted[t])), axis=1)
-                for t, h in enumerate(spec.heights)], axis=0)
-        halo = _typed_exchange(wide, arrays, spec, axis_name)
-        halo = halo.reshape(-1, f)
-    grads = []
-    for s in range(len(spec.heights)):
-        # the destination types whose block for s exists: relation s -> d
-        live = [d for d in spec.dst if s in spec.sources[d]]
-        if s not in spec.grad or not live:
-            grads.append(None)
-            continue
-        # what s's rows gather from: one block, or the few stacked
-        with scope("dense"), subscope("rel_table"):
-            table = (block(live[0], s) if len(live) == 1 else
-                     jnp.concatenate([block(d, s) for d in live], axis=0))
-        start, off = {}, 0
-        for d in live:
-            start[d] = off - first[d]
-            off += spec.heights[d]
-        pos = {d: [u for u in spec.sources[d] if u in spec.grad].index(s)
-               for d in live}
-
-        def decode(code, w, where, live=live):
-            u, row = code >> TYPE_SHIFT, code & _ROW_MASK
-            idx, on = row, jnp.zeros(row.shape, bool)
-            for d in live:
-                idx = jnp.where(u == d, where(row, d), idx)
-                on = on | (u == d)
-            return idx, jnp.where(on, w, 0.0)
-
-        def local(code, w, decode=decode, table=table, start=start):
-            idx, w = decode(code, w, lambda row, d: row + start[d])
-            # a slot no relation reads keeps its own (distinct) row
-            return (jnp.take(table, idx % table.shape[0], axis=0)
-                    * w[:, None],)
-
-        def remote(code, w, decode=decode, pos=pos):
-            idx, w = decode(code, w, lambda row, d: row * per_row + pos[d])
-            return (jnp.take(halo, idx % halo.shape[0], axis=0)
-                    * w[:, None],)
-
-        grads.append(_typed_group(arrays["types"][s], spec.layouts[s], local,
-                                  remote, 1, f, "wb")[0])
+    halo = None
+    if spec.exchange and most:
+        halo = _typed_exchange(
+            [jnp.concatenate([block(t, u) for u in us]
+                             + [jnp.zeros((h, f), jnp.float32)]
+                             * (most - len(us)), axis=1) if us else None
+             for t, (us, h) in enumerate(zip(wanted, spec.heights))],
+            arrays, spec, axis_name)
+    grads = [None] * len(spec.heights)
+    for s in spec.grad:
+        for d in spec.dst:
+            if s not in spec.sources[d]:
+                continue
+            at = wanted[d].index(s) * f
+            grads[s] = _typed_pass(
+                grads[s], arrays["rels"][d, s], layouts[d, s],
+                spec.heights[s], block(d, s),
+                None if halo is None else halo[:, at: at + f], "wb")
     return tuple(grads)
 
 
@@ -1848,26 +1799,25 @@ def _typed_backward(cts, arrays, spec, axis_name):
 def typed_aggregate(blocks, arrays, spec, axis_name=AXIS):
     """Per-relation MEAN aggregation over one symmetric pattern whose rows
     have node types, a relation being an ordered pair of types (``D_r⁻¹ A_r``
-    for every relation at once; ``models/rgcn.py``).
+    for every relation of a layer; ``models/rgcn.py``).
 
     ``blocks`` holds one ``(height_t, f)`` table per node type (``None``: a
-    type the layer does not read), rows in the typed order of the model's
-    sub-layouts; the result one tuple per destination type ``d`` of
-    ``spec.dst``: array q, ``(height_d, f)``, the mean over d's neighbours of
-    type ``sources[d][q]``.  Every edge goes through the slot
-    passes of the plan's own layout — ELL buckets, the hub tail and the
-    halo-source edges as virtual rows — restricted to the rows of one type
-    (``arrays["types"][t]``: ``e_*`` / ``t_*`` / ``h_*`` codes, weights and
-    fold rows).
+    type the layer does not read), rows in the model's typed order; the
+    result one tuple per destination type ``d`` of ``spec.dst``: array q,
+    ``(height_d, f)``, the mean over d's neighbours of type
+    ``sources[d][q]``.  Every relation has a slot layout of its own
+    (``arrays["rels"][s, d]``: ``e_*`` / ``t_*`` / ``h_*`` indices, weights
+    and fold rows; ``_typed_pass``), every directed edge lies in exactly one,
+    and a pass runs the layouts of the relations live in it and no other.
 
     The operator is symmetric in PATTERN and not in values: ``(D_r⁻¹ A_r)ᵀ
-    = A_rᵀ D_r⁻¹`` walks the same slots from the other side with the other
-    endpoint's degree (``*_wb`` beside ``*_wf``) and gathers the cotangent
-    of the block its own type fills — a custom VJP on the same index
-    arrays, gathers only, no scatter-add over edges.  Only the
-    types of ``spec.grad`` get a gradient (the others' tables are data, or
-    no relation out of them reaches ``spec.dst``); at k > 1 the backward
-    exchange ships each row's wanted blocks side by side."""
+    = A_rᵀ D_r⁻¹`` walks the reverse pair's slots with the other endpoint's
+    degree (``*_wb`` beside ``*_wf``) and gathers the cotangent of the block
+    its own type fills — a custom VJP on the same index arrays, gathers
+    only, no scatter-add over edges.  Only the types of ``spec.grad`` get a
+    gradient (the others' tables are data, or no relation out of them
+    reaches ``spec.dst``); at k > 1 the backward exchange ships each row's
+    wanted blocks side by side."""
     return _typed_forward(blocks, arrays, spec, axis_name)
 
 

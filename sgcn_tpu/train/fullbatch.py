@@ -89,8 +89,8 @@ MODELS = {
                   lambda plan: deepergcn.DEEPERGCN_PLAN_FIELDS,
                   lambda plan: {"ell_buckets": plan.ell_buckets},
                   deepergcn.model_setup),
-    # typed rows and relations (models/rgcn.py): the plan's slots regrouped
-    # by the type of their destination, arrays its hook derives; per-node
+    # typed rows and relations (models/rgcn.py): one slot layout per
+    # relation, arrays its hook derives from the plan's edges; per-node
     # embeddings owned with the rows (``ModelSetup.row_owned``)
     "rgcn": (rgcn.init_rgcn_params, rgcn.rgcn_forward_local,
              lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},

@@ -19,12 +19,17 @@ with the rows.
     form for are refused loudly;
   * (g) save -> restore of the row-owned leaves at k = 4 resumes the
     trajectory, the file holding them in global row order;
-  * (h) ``analysis``' census passes for ``train/rgcn/a2a/s0/f32``.
+  * (h) ``analysis``' census passes for ``train/rgcn/a2a/s0/f32``;
+  * (i) the slot layouts, one per ordered pair of types with a relation
+    (PR 34): every directed edge in exactly one, once, with both weights;
+    padding on distinct in-bounds rows; a pass runs the relations live in
+    it and ships nothing else.
 
 CPU, tiny graphs, one to four virtual devices.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +44,7 @@ from sgcn_tpu.obs import tracing
 from sgcn_tpu.ops.pspmm import typed_aggregate
 from sgcn_tpu.parallel import build_comm_plan, make_mesh_1d, shard_stacked
 from sgcn_tpu.parallel.mesh import AXIS
+from sgcn_tpu.parallel.plan import padding_fanin, padding_fanin_bound
 from sgcn_tpu.partition import balanced_random_partition
 from sgcn_tpu.prep import normalize_adjacency
 from sgcn_tpu.train import FullBatchTrainer, TrainData, make_train_data
@@ -348,7 +354,7 @@ def test_the_custom_backward_is_the_forwards_transposition(plans, k):
     spec = rgcn.layer_specs(args, layout)[0]._replace(grad=(0, 1, 2, 3))
     mesh = make_mesh_1d(k)
     arrays = shard_stacked(mesh, {
-        "types": layout["arrays"]["types"],
+        "rels": layout["arrays"]["rels"],
         "send_rows": layout["arrays"]["send_rows"],
         "halo_src": plan.halo_src})
     rng = np.random.default_rng(2)
@@ -481,6 +487,144 @@ def test_save_and_restore_of_row_owned_leaves_at_k4(plans, inputs, tmp_path):
                                rtol=1e-6)
 
 
+# ------------------------------------------------------------------- (i)
+PAIR_ARRAY = re.compile(r"rel_\d+_\d+_[eth]_\w+")   # rel_<s>_<d>_<array>
+
+
+@pytest.fixture(scope="module")
+def layouts(plans):
+    args = rgcn.resolve_args(FIN, WIDTHS, MODEL)
+    return {k: rgcn.build_typed_layout(plans[k], args) for k in plans}
+
+
+def _slot_rows(stores, arrays, store):
+    """Per slot of one store of a layout, the typed destination row: a
+    width-major bucket's own rows (ELL), or its virtual rows' ``row``."""
+    out, r0 = [], 0
+    for n, w in stores:
+        rows = (np.arange(r0, r0 + n) if store == "e"
+                else arrays[f"{store}_row"][r0:r0 + n])
+        out.append(np.tile(rows, w))
+        r0 += n
+    return np.concatenate(out) if out else np.zeros(0, np.int64)
+
+
+def _layout_edges(plan, layout):
+    """Every real slot of every layout as (destination, source, wf, wb) in
+    global ids, and every padding slot's (index, table height) per store."""
+    first = [int(START[n]) for n in NAMES]
+    halo_ids = plan.halo_global_rows()
+    edges, padding = [], []
+    for (s, d), stores in layout["layouts"]:
+        per = layout["arrays"]["rels"][s, d]
+        for c in range(plan.k):
+            for store, shapes in zip("eth", stores):
+                arrays = {name: x[c] for name, x in per.items()}
+                idx, wf, wb = (arrays[f"{store}_{n}"]
+                               for n in ("idx", "wf", "wb"))
+                assert sum(n * w for n, w in shapes) == len(idx)
+                real = (wf != 0) | (wb != 0)
+                rows = _slot_rows(shapes, arrays, store)[real]
+                dst = first[d] + layout["table_rows"][d][c][rows]
+                src = (halo_ids[c][idx[real]] if store == "h" else
+                       first[s] + layout["table_rows"][s][c][idx[real]])
+                assert (layout["table_rows"][d][c][rows] >= 0).all()
+                edges.append(np.stack([dst, src, wf[real], wb[real]], 1))
+                padding.append((idx[~real],
+                                plan.r if store == "h"
+                                else layout["heights"][s]))
+    return np.concatenate(edges), padding
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_every_edge_lies_in_one_layout_once_with_both_weights(
+        plans, layouts, adjacency, k):
+    got, _ = _layout_edges(plans[k], layouts[k])
+    coo = adjacency.tocoo()
+    type_of = np.searchsorted(list(START.values()), np.arange(N),
+                              "right") - 1
+    # a row's neighbours by type, and which ordered pairs are relations
+    deg = np.zeros((N, len(NAMES)))
+    np.add.at(deg, (coo.row, type_of[coo.col]), 1)
+    is_rel = np.zeros((len(NAMES),) * 2, bool)
+    for src, _, dst in RELS:
+        is_rel[NAMES.index(src), NAMES.index(dst)] = True
+    ts, td = type_of[coo.col], type_of[coo.row]
+    held = is_rel[ts, td] | is_rel[td, ts]
+    assert held.sum() == adjacency.nnz      # the fixture: every edge typed
+    want = np.stack([
+        coo.row, coo.col,
+        is_rel[ts, td] / deg[coo.row, ts],       # the mean of s -> d at i
+        is_rel[td, ts] / deg[coo.col, td]], 1)[held]   # of d -> s at j
+    assert len(got) == len(want)                 # ... exactly once
+    order = lambda e: e[np.lexsort((e[:, 1], e[:, 0]))]  # noqa: E731
+    got, want = order(got), order(want)
+    np.testing.assert_array_equal(got[:, :2], want[:, :2])
+    np.testing.assert_allclose(got[:, 2:], want[:, 2:], rtol=1e-6)
+    # one layout per ordered pair with a relation, here the seven
+    pairs = [pair for pair, _ in layouts[k]["layouts"]]
+    assert sorted(pairs) == sorted(
+        (NAMES.index(src), NAMES.index(dst)) for src, _, dst in RELS)
+    # a relation's edges, counted in its layout (k = 1: one chip has all)
+    if k == 1:
+        for src, name, dst in RELS:
+            pair = (NAMES.index(src), NAMES.index(dst))
+            assert layouts[1]["counts"][pair]["edges"] \
+                == layouts[1]["edges"][name] > 0
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_layout_padding_names_distinct_rows_in_bounds(plans, layouts, k):
+    _, padding = _layout_edges(plans[k], layouts[k])
+    assert sum(len(idx) for idx, _ in padding) > 0
+    for idx, height in padding:
+        assert idx.min(initial=0) >= 0 and idx.max(initial=0) < height
+        assert padding_fanin(idx) <= padding_fanin_bound(len(idx), height)
+    # a virtual row's destination is a row of the type, padding included
+    for (s, d), _ in layouts[k]["layouts"]:
+        for store in "th":
+            rows = layouts[k]["arrays"]["rels"][s, d][f"{store}_row"]
+            assert rows.min(initial=0) >= 0
+            assert rows.max(initial=0) < layouts[k]["heights"][d]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_a_pass_runs_the_live_relations_and_ships_nothing_else(
+        plans, layouts, k):
+    from sgcn_tpu.train.fullbatch import resolve_forward_setup
+
+    args = rgcn.resolve_args(FIN, WIDTHS, MODEL)
+    specs = rgcn.layer_specs(args, layouts[k])
+    rel = {name: r for r, (_, name, _) in enumerate(args["relations"])}
+    pair = {name: (s, d) for s, name, d in args["relations"]}
+    (fwd0, bwd0), (fwd1, bwd1) = rgcn.typed_passes(specs, args["relations"])
+    # forward: a relation walks its own pair's slots at the mean's weight
+    assert sorted(fwd0) == sorted(
+        (rel[n], pair[n], "wf") for n in rel if n != "affiliated_with")
+    assert sorted(fwd1) == sorted(
+        (rel[n], pair[n], "wf") for n in ("cites", "writes", "rev_has_topic"))
+    # backward: the REVERSE pair's slots, and only where the source's table
+    # is trainable (layer 0: papers are data) and the forward ran
+    assert sorted(bwd0) == sorted(
+        (rel[n], pair[n][::-1], "wb")
+        for n in ("writes", "rev_affiliated_with", "rev_has_topic"))
+    assert sorted(bwd1) == sorted(
+        (rel[n], pair[n][::-1], "wb")
+        for n in ("cites", "writes", "rev_has_topic"))
+    # what ships: the pairs some pass walks, with the weights it picks — the
+    # authors' institution slots (pair institution -> author) run forward
+    # for rev_affiliated_with and never backward for affiliated_with
+    extra = resolve_forward_setup(plans[k], FIN, WIDTHS, model="rgcn",
+                                  model_args=MODEL).custom.extra_arrays
+    inst, author = NAMES.index("inst"), NAMES.index("author")
+    assert f"rel_{inst}_{author}_e_wf" in extra
+    assert f"rel_{inst}_{author}_e_wb" not in extra
+    assert f"rel_{author}_{inst}_t_wb" in extra
+    assert f"rel_{author}_{inst}_t_wf" not in extra
+    shipped = rgcn.shipped_layouts(layouts[k], specs, args["relations"])
+    assert {n for n in extra if PAIR_ARRAY.fullmatch(n)} == set(shipped)
+
+
 # ------------------------------------------------------------------- (h)
 def test_counters_scopes_and_memory(plans, inputs):
     tr = _trainer(plans[4])         # (the counter is the newest trainer's)
@@ -497,10 +641,33 @@ def test_counters_scopes_and_memory(plans, inputs):
     assert work["passes"][2]["into"] == ["paper"]
     assert work["left_out"][0]["relations"] == ["affiliated_with"]
     assert len(work["left_out"][1]["relations"]) == 4
+    # per pass: the relations run, those of its types left out as dead, and
+    # per relation run the edges, slots, virtual rows, buckets + classes
+    l0f, l0b, l1f, l1b = work["passes"]
+    assert l0f["left_out"] == l1f["left_out"] == []
+    assert l0b["relations"] == ["writes", "rev_affiliated_with",
+                                "rev_has_topic"]
+    assert l0b["left_out"] == ["affiliated_with"]
+    assert l1f["relations"] == ["cites", "writes", "rev_has_topic"]
+    assert sorted(l1b["relations"]) == sorted(l1f["relations"])
+    assert sorted(l1b["left_out"]) == ["affiliated_with", "has_topic",
+                                       "rev_writes"]
+    for p in work["passes"]:
+        assert [r["relation"] for r in p["run"]] == p["relations"]
+        assert p["slots"] == sum(r["slots"] for r in p["run"])
+        assert p["edges"] == sum(r["edges"] for r in p["run"])
+        for r in p["run"]:
+            assert 0 < r["edges"] <= r["slots"]
+            assert r["rows"] >= 0 and r["classes"] >= 1
+    assert 0 < work["live_edges_per_step"] == sum(
+        p["edges"] for p in work["passes"]) \
+        <= work["executed_slots_per_step"] == sum(
+            p["slots"] for p in work["passes"])
     owned = work["row_owned_bytes"]
     assert owned["optimizer_state"] == 2 * owned["parameters"] > 0
     hlo = tr.lower_step().as_text(debug_info=True)
-    for sub in tracing.REL_SUBSCOPES:
+    for sub in tracing.REL_SUBSCOPES + ("agg_slots", "agg_tail",
+                                        "agg_halo_fold"):
         assert f"sgcn.{sub}" in hlo, sub
     assert not set(tracing.REL_SUBSCOPES) & set(
         tracing.SCOPES + tracing.SUBSCOPES + tracing.DEEP_SUBSCOPES)
@@ -509,6 +676,12 @@ def test_counters_scopes_and_memory(plans, inputs):
         "row_owned", "rows_kept", "rows_transient", "slot_temps", "plan",
         "features", "params"))
     assert est["row_owned"] == owned["parameters"] * 3
+    # the layout's line is the bytes of the relation arrays a chip is sent
+    from sgcn_tpu.train.fullbatch import resolve_forward_setup
+    extra = resolve_forward_setup(plans[4], FIN, WIDTHS, model="rgcn",
+                                  model_args=MODEL).custom.extra_arrays
+    assert est["plan"] == sum(x[0].nbytes for name, x in extra.items()
+                              if PAIR_ARRAY.fullmatch(name)) > 0
     assert owned["parameters"] == sum(
         x.shape[1] for x in tr.params["emb"].values()) * FIN * 4
     # what one chip holds of the tree is what its step donates
