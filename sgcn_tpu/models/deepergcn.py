@@ -74,9 +74,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.tracing import scope, subscope
-from ..ops.pspmm import _FOLD_SCAN_LIVE, _SCAN_LIVE_LIMIT, pspmm_ell_sym_detached
+from ..ops.pspmm import (_FOLD_SCAN_LIVE, _SCAN_LIVE_LIMIT, pass_store_forms,
+                         pspmm_ell_sym_detached)
 from ..parallel.mesh import AXIS, vary
-from .setup import ModelSetup
+from .setup import ModelSetup, plan_true_edges, slot_pass, slot_work
 
 # the exact GCN step's slot-form arrays (``GCN_PLAN_FIELDS_SLOTS``), every
 # weight array narrowed to a 0/1 mask (``ModelSetup.mask_fields``), and the
@@ -384,6 +385,29 @@ def estimate_deepergcn_hbm_bytes(plan, fin: int, hidden: int, layers: int,
     return parts
 
 
+def slot_passes(plan, layers: int, hidden: int, keep: str,
+                fold_classes) -> list:
+    """The step's pass list for the counter ``slots.work``
+    (``models/setup.py::slot_pass``): the token ``sgcn.layer0`` covers the
+    first layer and ``sgcn.layer1`` the scanned body's ``layers − 1``
+    (``times_per_epoch``); each aggregates ``[u m ‖ u]`` forward (2 ·
+    ``hidden`` lanes) and the cotangent backward (``hidden``), and under
+    ``keep="input"`` the backward runs the forward's pass again first."""
+    true = plan_true_edges(plan)
+    wide = pass_store_forms(plan.ell_buckets, *fold_classes, 2 * hidden)
+    narrow = pass_store_forms(plan.ell_buckets, *fold_classes, hidden)
+    passes = []
+    for layer, times in ((0, 1), (1, layers - 1)):
+        if not times:
+            continue
+        kw = {"times_per_epoch": times, "true_edges": true}
+        passes.append(slot_pass(layer, "fwd", 2 * hidden, wide, **kw))
+        if keep == "input":
+            passes.append(slot_pass(layer, "bwd", 2 * hidden, wide, **kw))
+        passes.append(slot_pass(layer, "bwd", hidden, narrow, **kw))
+    return passes
+
+
 # -------------------------------------------------------------- the registry
 def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                 comm_schedule: str, compute_dtype, serve_subgraph: bool
@@ -442,6 +466,8 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         lane_widths_bwd=(hidden,) * layers,
         param_count=param_count(fin, hidden, layers, classes),
         estimate_memory=estimate,
-        counters={"deep.work": counter},
+        counters={"deep.work": counter,
+                  "slots.work": slot_work(slot_passes(
+                      plan, layers, hidden, keep, fold_classes))},
         allow_pallas=False,         # no VMEM form of the two-width rule
         checkpointed=True)          # per layer, always: remat=True refused

@@ -21,8 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pspmm import (pspmm_ell_sym, pspmm_ell_sym_coo, pspmm_overlap,
-                         pspmm_ragged_sym,
+from ..ops.pspmm import (pass_store_forms, pspmm_ell_sym, pspmm_ell_sym_coo,
+                         pspmm_overlap, pspmm_ragged_sym,
                          pspmm_replica, pspmm_replica_partial,
                          pspmm_replica_ragged, pspmm_replica_stale,
                          pspmm_replica_stale_ragged, pspmm_stale,
@@ -30,6 +30,7 @@ from ..ops.pspmm import (pspmm_ell_sym, pspmm_ell_sym_coo, pspmm_overlap,
 from ..obs.tracing import scope
 from ..parallel.mesh import AXIS
 from .activations import get_activation
+from .setup import plan_true_edges, slot_pass
 
 # plan arrays the GCN forward consumes (fullbatch ships exactly these).
 # Symmetric Â takes the ELL + symmetric-backward fast path; general Â the
@@ -78,6 +79,30 @@ def exchange_widths(fin: int, widths) -> list[int]:
         out.append(w if (w < f and f >= PROJECT_FIRST_MIN_FIN) else f)
         f = w
     return out
+
+
+def gcn_slot_passes(plan, fin: int, widths, fold_classes, *,
+                    hoisted: bool, remat: bool = False) -> list:
+    """The aggregation passes of the exact slot-form step
+    (``pspmm_ell_sym`` with ``fold_classes``), for the counter
+    ``slots.work`` (``models/setup.py::slot_pass``): per layer one forward
+    pass at ``exchange_widths``' lanes — none in layer 0 where ``Â·h0`` is
+    ``hoisted`` — and one backward pass of the same stores — none in an
+    aggregate-first layer 0, whose input is data.  Under ``remat`` the
+    backward runs the forward passes again, under the backward's names."""
+    true = plan_true_edges(plan)
+    passes, f_in = [], fin
+    for layer, lanes in enumerate(exchange_widths(fin, widths)):
+        fwd = not (layer == 0 and hoisted)
+        bwd = (layer > 0 or lanes != f_in) + (fwd and remat)
+        stores = pass_store_forms(plan.ell_buckets, *fold_classes, lanes)
+        for way, times in (("fwd", int(fwd)), ("bwd", int(bwd))):
+            if times:
+                passes.append(slot_pass(layer, way, lanes, stores,
+                                        times_per_epoch=times,
+                                        true_edges=true))
+        f_in = widths[layer]
+    return passes
 
 
 def init_gcn_params(rng: jax.Array, dims: list[tuple[int, int]]):

@@ -81,10 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.tracing import scope, subscope
-from ..ops.pspmm import bucketed_slot_reduce, halo_exchange_multi
+from ..ops.pspmm import (bucket_forms, bucketed_slot_reduce,
+                         fold_rows_scope, halo_exchange_multi)
 from ..parallel.mesh import AXIS
 from .activations import get_activation
-from .setup import ModelSetup
+from .setup import ModelSetup, plan_true_edges, slot_pass, slot_work
 
 # plan arrays shipped as they are (``ell_w`` narrowed to a 0/1 mask by
 # ``ForwardSetup.ship_arrays``), and the virtual-row layouts of the hub
@@ -300,6 +301,49 @@ def plan_virtual_rows(plan) -> tuple:
     return arrays, statics
 
 
+def _max_slot_bytes(nb: int) -> int:
+    """A slot's temporaries in the max pass: one tile of lanes a row."""
+    return nb * 128 * 4
+
+
+def _agg_slot_bytes(f: int):
+    """... and in an aggregation pass over ``f`` = K·C lanes: the gathered
+    rows and two products of their size."""
+    return lambda nb: 3 * nb * f * 4
+
+
+def slot_passes(plan, fin: int, widths, heads, concat, tail_shape,
+                halo_shape) -> list:
+    """The step's pass list for the counter ``slots.work``
+    (``models/setup.py::slot_pass``): per layer the narrow max pass (tag
+    ``att_max``, the sub-scope its ops carry) and the forward and the
+    backward aggregation, each over the ELL buckets and the one class of
+    the tail's and of the halo edges' virtual rows, in the form
+    ``_store_reduce`` runs them."""
+    true = plan_true_edges(plan)
+    stores = {"ell": plan.ell_buckets,
+              "tail": () if tail_shape is None else (tail_shape,),
+              "halo": () if halo_shape is None else (halo_shape,)}
+
+    def forms(slot_bytes):
+        return {name: list(zip(b, bucket_forms(b, slot_bytes, _SCAN_LIVE)))
+                for name, b in stores.items()}
+
+    passes = []
+    for layer, (_, k, c, _) in enumerate(layer_shapes(fin, widths, heads,
+                                                      concat)):
+        f = k * c
+        passes += [
+            slot_pass(layer, "fwd", k, forms(_max_slot_bytes),
+                      tags=("att_max",), true_edges=true),
+            # [Z ‖ t] gathered forward, [g ‖ s, m, 1/D, c] backward
+            slot_pass(layer, "fwd", f + k, forms(_agg_slot_bytes(f)),
+                      true_edges=true),
+            slot_pass(layer, "bwd", f + 4 * k, forms(_agg_slot_bytes(f)),
+                      true_edges=true)]
+    return passes
+
+
 def _store_reduce(idx, mask, buckets, vrow, dst_side, contrib, init,
                   slot_bytes, combine=jnp.add):
     """One edge store through ``bucketed_slot_reduce``: ``contrib(idx, mask,
@@ -308,7 +352,8 @@ def _store_reduce(idx, mask, buckets, vrow, dst_side, contrib, init,
     (``vrow`` given) reads them at its rows' destinations, and its result is
     per virtual row."""
     if vrow is not None:
-        dst_side = tuple(jnp.take(x, vrow, axis=0) for x in dst_side)
+        with fold_rows_scope():
+            dst_side = tuple(jnp.take(x, vrow, axis=0) for x in dst_side)
     return _concat_buckets(bucketed_slot_reduce(
         idx, mask, buckets,
         contrib=lambda i, w, row: contrib(
@@ -344,7 +389,9 @@ def _all_stores(tables, halo_tables, dst_side, pa, buckets, tail_shape,
             part = _store_reduce(idx, mask, (shape,), vrow, dst_side,
                                  partial(contrib, tabs), init, slot_bytes,
                                  combine)
-            acc = jax.tree.map(lambda a, v: scatter(a, vrow, v), acc, part)
+            with fold_rows_scope():
+                acc = jax.tree.map(lambda a, v: scatter(a, vrow, v), acc,
+                                   part)
     return acc
 
 
@@ -379,8 +426,7 @@ def _aggregate_fwd(z, s, t, send_idx, halo_src, ell_idx, ell_w,
         contrib=lambda tabs, idx, w, _dst: jnp.where(
             (w != 0)[:, None], jnp.take(tabs[0], idx, axis=0), _NEG),
         init=lambda nb: jnp.full((nb, k), _NEG, jnp.float32),
-        slot_bytes=lambda nb: nb * 128 * 4, combine=jnp.maximum,
-        sub="att_max")
+        slot_bytes=_max_slot_bytes, combine=jnp.maximum, sub="att_max")
     with scope("agg_slots"), subscope("att_max"):
         m = _leaky(s + tmax, slope)
 
@@ -409,7 +455,7 @@ def _aggregate_fwd(z, s, t, send_idx, halo_src, ell_idx, ell_w,
                          jnp.zeros((nb, k), jnp.float32),
                          jnp.zeros((nb, f), jnp.float32),
                          jnp.zeros((nb, k), jnp.float32)),
-        slot_bytes=lambda nb: 3 * nb * f * 4)
+        slot_bytes=_agg_slot_bytes(f))
     with scope("agg_slots"), subscope("att_norm"):
         dinv = 1.0 / jnp.maximum(den, _TINY)
         out = _scale_heads(dinv, num)
@@ -450,7 +496,7 @@ def _aggregate_bwd(heads, buckets, tail_shape, halo_shape, slope, axis_name,
         halo_shape, contrib=edge,
         init=lambda nb: (jnp.zeros((nb, f), jnp.float32),
                          jnp.zeros((nb, k), jnp.float32)),
-        slot_bytes=lambda nb: 3 * nb * f * 4)
+        slot_bytes=_agg_slot_bytes(f))
     return (dz, ds, dt) + (None,) * 10
 
 
@@ -641,5 +687,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                                 bias=args["bias"]),
         estimate_memory=functools.partial(
             estimate_mhgat_hbm_bytes, plan, fin, widths, **hc, **vshapes),
-        counters={"att.work": counter},
+        counters={"att.work": counter,
+                  "slots.work": slot_work(slot_passes(
+                      plan, fin, widths, **hc, **vshapes))},
         allow_pallas=False)         # no VMEM form of the per-edge softmax

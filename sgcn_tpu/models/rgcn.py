@@ -80,13 +80,14 @@ import numpy as np
 from jax import lax
 
 from ..obs.tracing import scope, subscope
-from ..ops.pspmm import _FOLD_SCAN_LIVE, _TYPED_SCAN_LIVE, typed_aggregate
+from ..ops.pspmm import (_FOLD_SCAN_LIVE, _TYPED_SCAN_LIVE, pass_store_forms,
+                         typed_aggregate)
 from ..parallel.mesh import AXIS
 from ..parallel.plan import (FOLD_ROW_COST, _build_ell, _build_virtual_rows,
                              _choose_buckets, choose_fold_widths,
                              fold_class_shapes, padding_rows)
 from .activations import get_activation
-from .setup import ModelSetup
+from .setup import ModelSetup, slot_pass, slot_work
 
 RGCN_PLAN_FIELDS = ("halo_src",)
 INPUTS = ("features", "embedding")
@@ -263,6 +264,32 @@ def pass_counts(args: dict, layout: dict, specs, lanes) -> list:
     return passes
 
 
+def slot_passes(args: dict, layout: dict, specs, lanes) -> tuple:
+    """``(passes, relations)`` for the counter ``slots.work``
+    (``models/setup.py::slot_pass`` / ``slot_work``): one pass a relation run
+    — per layer and direction those of ``typed_passes`` — tagged with the
+    pair whose layout it walks (``pair_<source type>_<row type>``, the token
+    ``ops.pspmm`` names the pass by: backward, the REVERSE pair of the
+    relation), and the name of every walked pair: the relation whose forward
+    walks it."""
+    rels = args["relations"]
+    name_of = {(s, d): n for s, n, d in rels}
+    layouts = dict(layout["layouts"])
+    passes, walked = [], set()
+    for layer, (a, both) in enumerate(zip(lanes, typed_passes(specs, rels))):
+        for way, run in zip(("fwd", "bwd"), both):
+            for _, (s, d), _ in run:
+                walked.add((s, d))
+                passes.append(slot_pass(
+                    layer, way, a,
+                    pass_store_forms(*layouts[s, d], a, _TYPED_SCAN_LIVE),
+                    tags=(f"pair_{s}_{d}",),
+                    true_edges=layout["counts"][s, d]["chip_edges"]))
+    return passes, {
+        f"pair_{s}_{d}": name_of.get((s, d), f"{name_of.get((d, s))}^T")
+        for s, d in sorted(walked)}
+
+
 # ------------------------------------------------------------------ layout
 def _relation_buckets(degs: list, height: int) -> tuple:
     """ELL buckets over a destination type's ``height`` rows for ONE
@@ -350,9 +377,11 @@ def _relation_layout(local: list, halo: list, height: int, tables: tuple,
     over, halo_classes = virtual("h", numbered(halo), halo, tables[1])
     arrays = {**fill("e", ell["ell_idx"], ell["ell_w"], local, tables[0]),
               **tail, **over}
+    chip_edges = [len(a[0]) + len(b[0]) for a, b in zip(local, halo)]
     counts = {
-        # the fullest chip's edges; what every chip executes
-        "edges": max(len(a[0]) + len(b[0]) for a, b in zip(local, halo)),
+        # the fullest chip's edges (and each chip's); what every chip
+        # executes
+        "edges": max(chip_edges), "chip_edges": chip_edges,
         "slots": sum(arrays[f"{s}_idx"].shape[1] for s in STORES),
         "rows": arrays["t_row"].shape[1] + arrays["h_row"].shape[1],
         "classes": len(buckets) + len(tail_classes) + len(halo_classes)}
@@ -730,7 +759,9 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         lane_widths_bwd=lanes_bwd,
         param_count=param_count(fin, widths, types, rels),
         estimate_memory=estimate,
-        counters={"rel.work": counter},
+        counters={"rel.work": counter,
+                  "slots.work": slot_work(*slot_passes(
+                      args, layout, specs, [a for a, _ in dims]))},
         allow_pallas=False,
         row_owned={"emb": {n: layout["table_rows"][t]
                            for t, (n, _, kind) in enumerate(types)

@@ -12,8 +12,11 @@ it keeps a process-wide in-memory table (count, total seconds, parent, the
 last 256 durations) that the benchmark's ``program_span`` readers read.
 ``scope`` is its compiled-program counterpart: ``jax.named_scope`` over the
 fixed vocabulary ``SCOPES``, which reaches the device trace through each
-op's HLO metadata.  ``set_counter`` / ``counters`` hold plan-time counts
-(``CommPlan.work_counts``) the same way.
+op's HLO metadata; ``subscope``, ``bucket_scope`` and ``pair_scope`` name
+what lies below and beside the leaf scopes (a model's own work, every bucket
+of the slot passes, a relation's pass).  ``set_counter`` / ``counters`` hold
+plan-time counts (``CommPlan.work_counts``, the step's pass list
+``slots.work``) the same way.
 
 **Span API** (``SpanTimer``) — named,
 optionally nested wall-clock spans with ``block_until_ready`` sync points;
@@ -99,6 +102,25 @@ DEEP_SUBSCOPES = ("norm", "softmax_table")
 # ``benchmark/scopes_rel.json`` is the benchmark's own copy.
 REL_SUBSCOPES = ("rel_project", "rel_table", "row_update")
 
+# What the slot passes name of themselves (``ops/pspmm.py``), BELOW the three
+# aggregation leaf scopes — three token families, all outside ``SCOPES`` so
+# that every op still books to its leaf (``benchmark/scopes_slots.json`` is
+# the benchmark's own copy of the three patterns):
+#   * ``sgcn.bkt_<rows>x<width>_u`` / ``..._s<unroll>``: one bucket or width
+#     class of ``bucketed_slot_reduce``, with the form that ran (unrolled, or
+#     scanned at that unroll) — ``bucket_scope``, inside a leaf scope;
+#   * ``sgcn.fold_rows``: the sorted row scatter of a class of virtual rows
+#     (and the gather of its destination-side rows) — ``subscope``;
+#   * ``sgcn.pair_<s>_<d>``: one relation's pass of the typed aggregation,
+#     named after the layout it walks (source type -> row type) —
+#     ``pair_scope``, OUTSIDE the leaf scopes like ``sgcn.layer<i>``.
+# The counter ``slots.work`` (``models/setup.py::leave_slot_work``) lists
+# the same buckets, forms and pairs per pass, so a trace's seconds under a
+# token divide by the slots the token names.
+SLOT_SUBSCOPES = ("fold_rows",)
+BUCKET_TOKEN = r"bkt_(\d+)x(\d+)_(u|s\d+)"
+PAIR_TOKEN = r"pair_(\d+)_(\d+)"
+
 _spans: dict = {}           # name -> {count, total_s, parent, durations}
 _spans_lock = threading.Lock()  # spans close on more than one thread
 _open = threading.local()   # .stack: this thread's open span names;
@@ -130,19 +152,63 @@ def _named(full: str, leaf: bool):
 
 def subscope(name: str):
     """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``,
-    ``DEEP_SUBSCOPES`` or ``REL_SUBSCOPES``, legal only inside a leaf
+    ``DEEP_SUBSCOPES``, ``REL_SUBSCOPES`` or ``SLOT_SUBSCOPES``, legal only
+    inside a leaf
     ``scope`` — a sub-scope on its own would leave its ops unscoped for
     every reader of ``SCOPES``."""
-    known = SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES
+    known = SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES + SLOT_SUBSCOPES
     if name not in known:
         raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
                          f"{known}")
-    if not getattr(_open, "leaves", 0):
+    if not in_leaf_scope():
         raise ValueError(f"sub-scope {name!r} opened outside a leaf scope "
                          f"of {SCOPES[1:]}")
     import jax
 
     return jax.named_scope(PREFIX + name)
+
+
+def form_token(unroll: int | None) -> str:
+    """``"u"`` for an unrolled bucket, ``"s<unroll>"`` for a scanned one —
+    the ``form`` of the counter ``slots.work`` and the tail of a bucket's
+    token."""
+    return "u" if unroll is None else f"s{int(unroll)}"
+
+
+def bucket_token(nb: int, wb: int, unroll: int | None) -> str:
+    return f"bkt_{int(nb)}x{int(wb)}_{form_token(unroll)}"
+
+
+def parse_bucket_token(token: str) -> tuple | None:
+    """``(rows, width, form)`` of a bucket token (``PREFIX`` or not), else
+    ``None``."""
+    m = re.fullmatch(BUCKET_TOKEN, token.removeprefix(PREFIX))
+    return (int(m.group(1)), int(m.group(2)), m.group(3)) if m else None
+
+
+def in_leaf_scope() -> bool:
+    """Whether a leaf ``scope`` is open on this thread (while tracing)."""
+    return bool(getattr(_open, "leaves", 0))
+
+
+def bucket_scope(nb: int, wb: int, unroll: int | None):
+    """``jax.named_scope("sgcn.bkt_<nb>x<wb>_u")`` around an unrolled bucket
+    of ``nb`` rows × ``wb`` slots, ``..._s<unroll>`` around a scanned one;
+    legal only inside a leaf ``scope``, like ``subscope``."""
+    if not in_leaf_scope():
+        raise ValueError(f"bucket scope {bucket_token(nb, wb, unroll)!r} "
+                         f"opened outside a leaf scope of {SCOPES[1:]}")
+    import jax
+
+    return jax.named_scope(PREFIX + bucket_token(nb, wb, unroll))
+
+
+def pair_scope(s: int, d: int):
+    """``jax.named_scope("sgcn.pair_<s>_<d>")`` around one relation's pass
+    of the typed aggregation — the layout of the ordered pair (source type
+    ``s`` -> the type ``d`` whose rows it fills); like ``sgcn.layer<i>`` it
+    is no leaf and opens outside them."""
+    return _named(f"{PREFIX}pair_{int(s)}_{int(d)}", leaf=False)
 
 
 @contextlib.contextmanager

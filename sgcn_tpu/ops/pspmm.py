@@ -27,13 +27,15 @@ TPU design:
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..obs.tracing import scope, subscope
+from ..obs.tracing import (bucket_scope, in_leaf_scope, pair_scope, scope,
+                           subscope)
 from ..parallel.mesh import AXIS
 
 # bound on the gather temps XLA's latency-hiding scheduler can keep live
@@ -61,6 +63,34 @@ def _scan_unroll(wb: int, slot_bytes: int, live_limit: int,
     return max(1, min(4, live_limit // max(slot_bytes, 1)))
 
 
+def _in_leaf(named, *args):
+    """``named(*args)`` inside a leaf scope, nothing outside one: a caller
+    outside every leaf scope (the factorised GAT, the micro-benchmarks)
+    names nothing — its ops read ``unscoped`` anyway."""
+    return named(*args) if in_leaf_scope() else contextlib.nullcontext()
+
+
+def bucket_forms(buckets, slot_bytes, scan_live_limit: int | None = None,
+                 scanned: bool = False) -> list:
+    """The form ``bucketed_slot_reduce`` runs each bucket of ``buckets`` in
+    under the same arguments: ``None`` for the unrolled branch, else the
+    unroll factor of its scan.  THE one decision — the reduce runs it, the
+    bucket's scope is named by it (``obs.tracing.bucket_scope``) and the
+    counter ``slots.work`` lists it (``models/setup.py``)."""
+    live_limit = (_SCAN_LIVE_LIMIT if scan_live_limit is None
+                  else scan_live_limit)
+    return [_scan_unroll(wb, slot_bytes(nb), live_limit, scanned)
+            for nb, wb in buckets]
+
+
+def ell_policy(lanes: int, scan_live_limit: int | None = None) -> dict:
+    """How an ELL store of ``lanes``-wide rows goes through
+    ``bucketed_slot_reduce`` (and ``bucket_forms``): a slot's temporaries
+    are its gathered rows at 4 B a lane, unrolled while they fit."""
+    return {"slot_bytes": lambda nb: nb * lanes * 4,
+            "scan_live_limit": scan_live_limit}
+
+
 def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
                          slot_bytes, scan_live_limit: int | None = None,
                          combine=jnp.add, with_rows: bool = False,
@@ -75,12 +105,21 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     buckets: tens of multi-hundred-MB temps measured as 17+ GB of HLO temps
     on a 16 GB chip) a ``lax.scan`` serializes the slots.  The scan body is
     software-pipelined with the LARGEST unroll whose live temps still fit
-    ``_SCAN_LIVE_LIMIT`` (≤4; measured 2.75 → 2.24 s/epoch at products
-    scale going 1 → 4), so liveness stays provably bounded for every
-    bucket shape.  The width-major flat layout makes each slot a
-    contiguous ``(nb,)`` run, so the ``(wb, nb)`` reshape moves nothing
-    row-major; under the TPU's tiled layout the compiler still copies it,
-    every step (~0.04 s an epoch at products scale, PERF.md §6, PR 25).
+    ``_SCAN_LIVE_LIMIT`` (≤4), so liveness stays provably bounded for every
+    bucket shape; what a slot costs in either form, by bucket width, is the
+    benchmark's ``ell_slot_ns`` / ``fold_slot_ns`` and its ``slot_prices``
+    table, per cell (PERF.md §5).  The width-major flat layout makes each
+    slot a contiguous ``(nb,)`` run, so the ``(wb, nb)`` reshape moves
+    nothing row-major; under the TPU's tiled layout the compiler still
+    copies it, every step (~0.04 s an epoch at products scale, PERF.md §6,
+    PR 25).
+
+    Inside a leaf scope every bucket is named in the compiled step:
+    ``sgcn.bkt_<nb>x<wb>_u`` around the unrolled slots and their
+    accumulates, ``..._s<unroll>`` around the reshapes, the carry and the
+    scan (``obs.tracing.bucket_scope``; metadata only) — with the unroll
+    ``bucket_forms`` returned, so the name cannot disagree with the form
+    that ran.
 
     ``contrib(idx (nb,), w (nb,)) -> pytree of (nb, ...) f32 arrays``;
     ``init(nb)`` builds the matching zero pytree; ``slot_bytes(nb)``
@@ -97,36 +136,36 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     wider than two slots, whatever its size (the fold passes of
     ``fold_slots``).  Returns the per-bucket reduced pytrees in bucket order.
     """
-    live_limit = (_SCAN_LIVE_LIMIT if scan_live_limit is None
-                  else scan_live_limit)
+    forms = bucket_forms(buckets, slot_bytes, scan_live_limit, scanned)
     outs = []
     off = row = 0
-    for nb, wb in buckets:
+    for (nb, wb), unroll in zip(buckets, forms):
         at = (row,) if with_rows else ()
-        unroll = _scan_unroll(wb, slot_bytes(nb), live_limit, scanned)
-        if unroll is None:
-            acc = None
-            for t in range(wb):
-                seg = slice(off + t * nb, off + (t + 1) * nb)
-                c = contrib(flat_idx[seg], flat_w[seg], *at)
-                acc = c if acc is None else jax.tree.map(combine, acc, c)
-        else:
-            seg_i = flat_idx[off: off + nb * wb].reshape(wb, nb)
-            seg_w = flat_w[off: off + nb * wb].reshape(wb, nb)
-            # carry must match the body output's varying-axes type under
-            # shard_map; adding 0·(an int32 element of the sharded index
-            # array) marks the zeros varying — integer 0·x is exactly 0,
-            # so (unlike 0·h[0,0]) an inf/NaN activation cannot poison it
-            zero = seg_i[0, 0] * 0
+        with _in_leaf(bucket_scope, nb, wb, unroll):
+            if unroll is None:
+                acc = None
+                for t in range(wb):
+                    seg = slice(off + t * nb, off + (t + 1) * nb)
+                    c = contrib(flat_idx[seg], flat_w[seg], *at)
+                    acc = c if acc is None else jax.tree.map(combine, acc, c)
+            else:
+                seg_i = flat_idx[off: off + nb * wb].reshape(wb, nb)
+                seg_w = flat_w[off: off + nb * wb].reshape(wb, nb)
+                # carry must match the body output's varying-axes type under
+                # shard_map; adding 0·(an int32 element of the sharded index
+                # array) marks the zeros varying — integer 0·x is exactly 0,
+                # so (unlike 0·h[0,0]) an inf/NaN activation cannot poison it
+                zero = seg_i[0, 0] * 0
 
-            def body(carry, iw, at=at):
-                i_t, w_t = iw
-                return jax.tree.map(combine, carry,
-                                    contrib(i_t, w_t, *at)), None
+                def body(carry, iw, at=at):
+                    i_t, w_t = iw
+                    return jax.tree.map(combine, carry,
+                                        contrib(i_t, w_t, *at)), None
 
-            acc0 = jax.tree.map(lambda x: x + zero.astype(x.dtype),
-                                init(nb, *at))
-            acc, _ = jax.lax.scan(body, acc0, (seg_i, seg_w), unroll=unroll)
+                acc0 = jax.tree.map(lambda x: x + zero.astype(x.dtype),
+                                    init(nb, *at))
+                acc, _ = jax.lax.scan(body, acc0, (seg_i, seg_w),
+                                      unroll=unroll)
         outs.append(acc)
         off += nb * wb
         row += nb
@@ -389,8 +428,7 @@ def _ell_slots(ell_idx, ell_w, h, buckets):
         outs = bucketed_slot_reduce(
             ell_idx, ell_w, buckets,
             contrib=lambda idx, w: jnp.take(h, idx, axis=0) * w[:, None],
-            init=lambda nb: jnp.zeros((nb, f), h.dtype),
-            slot_bytes=lambda nb: nb * f * 4)
+            init=lambda nb: jnp.zeros((nb, f), h.dtype), **ell_policy(f))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
@@ -403,11 +441,12 @@ def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
     t of the bucket's rows is one contiguous (nb,) run).  Per slot this is
     one fused gather·weight + accumulate — no (nb, wb, f) intermediate
     exists, which is the point: the row-major gather+reduce form makes XLA
-    relayout that intermediate.  The v5e gather is row-rate-bound (8.06 ns
-    an executed slot at 128 lanes f32 whatever the index pattern; ledger,
-    PR 29, ``products.fullbatch``), so executed slots are the time and the
-    bucketed layout's padding (4.5 % there) is what a single-width ELL
-    would multiply.
+    relayout that intermediate.  The v5e gather is row-rate-bound (8.0 ns
+    an executed slot at 128 lanes f32 on average, whatever the index
+    pattern — by bucket 5.1 or 11.0: the benchmark's ``ell_slot_ns`` and
+    its ``slot_prices`` line, PERF.md §5), so executed slots are the time
+    and the bucketed layout's padding (4.5 % in ``products.fullbatch``) is
+    what a single-width ELL would multiply.
 
     The tail is folded per edge by a sorted ``segment_sum``, which costs
     about two slots an edge (15.2 ns; same ledger line): the form of the
@@ -436,6 +475,31 @@ def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
 _FOLD_SCAN_LIVE = 3 * 1024**3 // 4
 
 
+def fold_policy(lanes: int, scan_live_limit: int = _FOLD_SCAN_LIVE) -> dict:
+    """``ell_policy`` of a store of virtual rows: every class wider than two
+    slots scans (``fold_slots``)."""
+    return dict(ell_policy(lanes, scan_live_limit), scanned=True)
+
+
+def pass_store_forms(buckets, tail_classes, halo_classes, lanes: int,
+                     ell_live_limit: int | None = None) -> dict:
+    """``{"ell" | "tail" | "halo": [((rows, width), unroll), ...]}`` of one
+    pass of ``_pspmm_ell_once`` / ``_typed_pass`` over ``lanes``-wide rows:
+    per store its buckets or classes with the form each runs in, by the
+    policies those passes hand ``bucketed_slot_reduce`` — what the counter
+    ``slots.work`` lists (``models/setup.py::slot_pass``)."""
+    return {name: list(zip(shapes, bucket_forms(shapes, **policy)))
+            for name, shapes, policy in (
+                ("ell", buckets, ell_policy(lanes, ell_live_limit)),
+                ("tail", tail_classes, fold_policy(lanes)),
+                ("halo", halo_classes, fold_policy(lanes)))}
+
+
+def fold_rows_scope():
+    """The sub-scope of a class's sorted row scatter (``sgcn.fold_rows``)."""
+    return _in_leaf(subscope, "fold_rows")
+
+
 def fold_slots(out, table, idx, w, row, classes,
                scan_live_limit: int = _FOLD_SCAN_LIVE, contrib=None):
     """``out`` plus one COO edge store in slot form
@@ -461,14 +525,14 @@ def fold_slots(out, table, idx, w, row, classes,
         idx, w, classes, contrib=contrib,
         init=lambda nv: jax.tree.map(
             lambda x: jnp.zeros((nv, x.shape[-1]), x.dtype), out),
-        slot_bytes=lambda nv: nv * f * 4, scan_live_limit=scan_live_limit,
-        scanned=True)
+        **fold_policy(f, scan_live_limit))
     r0 = 0
     for (nv, _), part in zip(classes, parts):
         rows = row[r0: r0 + nv]
-        out = jax.tree.map(
-            lambda x, y, rows=rows: x.at[rows].add(y, indices_are_sorted=True),
-            out, part)
+        with fold_rows_scope():
+            out = jax.tree.map(
+                lambda x, y, rows=rows: x.at[rows].add(
+                    y, indices_are_sorted=True), out, part)
         r0 += nv
     return out
 
@@ -1717,8 +1781,7 @@ def _typed_pass(out, arrays, layout, height: int, table, halo, weight: str):
             arrays["e_idx"], arrays["e_" + weight], buckets,
             contrib=lambda idx, w: jnp.take(table, idx, axis=0) * w[:, None],
             init=lambda nb: jnp.zeros((nb, f), jnp.float32),
-            slot_bytes=lambda nb: nb * f * 4,
-            scan_live_limit=_TYPED_SCAN_LIVE)
+            **ell_policy(f, _TYPED_SCAN_LIVE))
         # buckets cover every row of the type, or there are none
         parts = parts or [jnp.zeros((height, f), jnp.float32)]
         ell = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
@@ -1749,10 +1812,13 @@ def _typed_forward(blocks, arrays, spec, axis_name):
     halo = (_typed_exchange(blocks, arrays, spec, axis_name)
             if spec.exchange else None)
     layouts = dict(spec.layouts)
-    return tuple(
-        tuple(_typed_pass(None, arrays["rels"][s, d], layouts[s, d],
-                          spec.heights[d], blocks[s], halo, "wf")
-              for s in spec.sources[d]) for d in spec.dst)
+
+    def one(s, d):
+        with pair_scope(s, d):
+            return _typed_pass(None, arrays["rels"][s, d], layouts[s, d],
+                               spec.heights[d], blocks[s], halo, "wf")
+
+    return tuple(tuple(one(s, d) for s in spec.sources[d]) for d in spec.dst)
 
 
 def _typed_backward(cts, arrays, spec, axis_name):
@@ -1788,10 +1854,12 @@ def _typed_backward(cts, arrays, spec, axis_name):
             if s not in spec.sources[d]:
                 continue
             at = wanted[d].index(s) * f
-            grads[s] = _typed_pass(
-                grads[s], arrays["rels"][d, s], layouts[d, s],
-                spec.heights[s], block(d, s),
-                None if halo is None else halo[:, at: at + f], "wb")
+            # named after the layout walked: the pair (d -> s), rows of s
+            with pair_scope(d, s):
+                grads[s] = _typed_pass(
+                    grads[s], arrays["rels"][d, s], layouts[d, s],
+                    spec.heights[s], block(d, s),
+                    None if halo is None else halo[:, at: at + f], "wb")
     return tuple(grads)
 
 

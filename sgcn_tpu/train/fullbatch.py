@@ -43,6 +43,7 @@ from ..models.gcn import (
     gcn_aggregate_local,
     gcn_forward_local,
     gcn_plan_fields,
+    gcn_slot_passes,
     init_gcn_params,
     masked_accuracy_local,
     masked_err_local,
@@ -819,6 +820,14 @@ class FullBatchTrainer:
         self.agg0_hoisted = (model == "gcn" and not halo_staleness
                              and not replica_budget
                              and exchange_widths(fin, widths)[0] == fin)
+        # the slot passes this trainer's step runs, by bucket and form (the
+        # hooks of the models with one leave theirs among their counters);
+        # a program with no such list leaves no stale one
+        if setup.custom is None:
+            set_counter("slots.work", model_setup.slot_work(gcn_slot_passes(
+                plan, fin, widths, setup.fwd_static["fold_classes"],
+                hoisted=self.agg0_hoisted, remat=remat))
+                if fold_slots and model == "gcn" else None)
         self._agg0 = None           # (k, B, fin) f32 Â·h0, on the device
         self._agg0_src = None       # weakref to the data.h0 it was made from
         self._agg0_builds = self._agg0_served = 0
