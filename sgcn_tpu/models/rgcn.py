@@ -83,9 +83,10 @@ from ..obs.tracing import scope, subscope
 from ..ops.pspmm import (_FOLD_SCAN_LIVE, _TYPED_SCAN_LIVE, pass_store_forms,
                          typed_aggregate)
 from ..parallel.mesh import AXIS
-from ..parallel.plan import (FOLD_ROW_COST, _build_ell, _build_virtual_rows,
-                             _choose_buckets, choose_fold_widths,
-                             fold_class_shapes, padding_rows)
+from ..parallel.plan import (FOLD_ROW_COST, UNSNAPPED, _build_ell,
+                             _build_virtual_rows, _choose_buckets,
+                             choose_fold_widths, fold_class_shapes,
+                             padding_rows, snap_rows)
 from .activations import get_activation
 from .setup import ModelSetup, slot_pass, slot_work
 
@@ -301,26 +302,32 @@ def _relation_buckets(degs: list, height: int) -> tuple:
     (``choose_fold_widths``), and the cap with the least ``executed slots +
     FOLD_ROW_COST · virtual rows`` wins — cap 0 is the pure virtual-row
     form, the widest the plan's own ELL with a hub tail.  The profile is the
-    maximum over blocks of rows (``_choose_buckets`` walks it in Python)."""
+    maximum over blocks of rows (``_choose_buckets`` walks it in Python), so
+    the plan's ``snap_rows`` — buckets of row counts the slot gather runs
+    cheaply — is applied to the ROWS, not to the profile.  Returns the
+    buckets and what the snap moved in them."""
     block = -(-height // PROFILE_POINTS)
     profile = np.max(degs, axis=0)
     profile = np.pad(profile, (0, -height % block)).reshape(-1, block).max(1)
     best = None
     for cap in ELL_CAPS:
-        buckets, width = (), 0              # a row's width in its bucket
+        # a row's width in its bucket; what snap_rows moved
+        buckets, width, snapped = (), 0, dict(UNSNAPPED)
         if cap:
             found = _choose_buckets(profile, width_cap=cap)
             rows = [n * block for n, _ in found]
             rows[-1] -= -height % block
-            buckets = tuple(zip(rows, (w for _, w in found)))
-            width = np.repeat([w for _, w in found], rows)
+            buckets, snapped = snap_rows(
+                tuple(zip(rows, (w for _, w in found))), cover=True)
+            width = np.repeat([w for _, w in buckets],
+                              [n for n, _ in buckets])
         rest = [np.maximum(dg - width, 0) for dg in degs]
         cost = sum(n * w for n, w in buckets) + sum(
             nv * (w + FOLD_ROW_COST)
             for nv, w in fold_class_shapes(rest, choose_fold_widths(rest)))
         if best is None or cost < best[0]:
-            best = (cost, buckets)
-    return best[1]
+            best = (cost, buckets, snapped)
+    return best[1:]
 
 
 def _relation_layout(local: list, halo: list, height: int, tables: tuple,
@@ -336,7 +343,8 @@ def _relation_layout(local: list, halo: list, height: int, tables: tuple,
     classes)`` and counts."""
     k = len(local)
     none = {"idx": np.zeros((k, 0), np.int32), "w": np.zeros((k, 0), np.float32),
-            "row": np.zeros((k, 0), np.int32), "classes": ()}
+            "row": np.zeros((k, 0), np.int32), "classes": (),
+            "snapped": dict(UNSNAPPED)}
 
     def numbered(edges):
         # (dst, edge number, 1 / 0, count): a store as the builders take it
@@ -363,18 +371,19 @@ def _relation_layout(local: list, halo: list, height: int, tables: tuple,
     def virtual(pre, stored, edges, table):
         lay = _build_virtual_rows(*stored, height, table) or none
         return ({**fill(pre, lay["idx"], lay["w"], edges, table),
-                 f"{pre}_row": lay["row"]}, lay["classes"])
+                 f"{pre}_row": lay["row"]}, lay["classes"], lay["snapped"])
 
     stored = numbered(local)
-    buckets = _relation_buckets(
+    buckets, snapped = _relation_buckets(
         [np.bincount(e[0], minlength=height) for e in local], height)
     ell = {"ell_idx": none["idx"], "ell_w": none["w"]}
     if buckets:
         ell = _build_ell(*stored, height, buckets=buckets)
         stored = (ell["ltail_dst"], ell["ltail_src"], ell["ltail_w"],
                   ell["ltail_nnz"])
-    tail, tail_classes = virtual("t", stored, local, tables[0])
-    over, halo_classes = virtual("h", numbered(halo), halo, tables[1])
+    tail, tail_classes, tail_snapped = virtual("t", stored, local, tables[0])
+    over, halo_classes, halo_snapped = virtual("h", numbered(halo), halo,
+                                               tables[1])
     arrays = {**fill("e", ell["ell_idx"], ell["ell_w"], local, tables[0]),
               **tail, **over}
     chip_edges = [len(a[0]) + len(b[0]) for a, b in zip(local, halo)]
@@ -384,7 +393,10 @@ def _relation_layout(local: list, halo: list, height: int, tables: tuple,
         "edges": max(chip_edges), "chip_edges": chip_edges,
         "slots": sum(arrays[f"{s}_idx"].shape[1] for s in STORES),
         "rows": arrays["t_row"].shape[1] + arrays["h_row"].shape[1],
-        "classes": len(buckets) + len(tail_classes) + len(halo_classes)}
+        "classes": len(buckets) + len(tail_classes) + len(halo_classes),
+        # what the plan's snap_rows moved, as ``work_counts()["snapped"]``
+        "snapped": {"slot_edges": snapped, "tail_edges": tail_snapped,
+                    "halo_edges": halo_snapped}}
     return arrays, (buckets, tail_classes, halo_classes), counts
 
 

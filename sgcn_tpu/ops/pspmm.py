@@ -108,11 +108,14 @@ def bucketed_slot_reduce(flat_idx, flat_w, buckets, contrib, init,
     ``_SCAN_LIVE_LIMIT`` (≤4), so liveness stays provably bounded for every
     bucket shape; what a slot costs in either form, by bucket width, is the
     benchmark's ``ell_slot_ns`` / ``fold_slot_ns`` and its ``slot_prices``
-    table, per cell (PERF.md §5).  The width-major flat layout makes each
-    slot a contiguous ``(nb,)`` run, so the ``(wb, nb)`` reshape moves
-    nothing row-major; under the TPU's tiled layout the compiler still
-    copies it, every step (~0.04 s an epoch at products scale, PERF.md §6,
-    PR 25).
+    table, per cell (PERF.md §5) — and a bucket's ROW COUNT decides more
+    than its form: modulo 1,024, a count of 0 or past 896 doubles the price
+    of every slot (``parallel.plan.snap_rows`` chooses the plan's shapes
+    off those residues; forced shapes run as given).  The width-major flat
+    layout makes each slot a contiguous ``(nb,)`` run, so the ``(wb, nb)``
+    reshape moves nothing row-major; under the TPU's tiled layout the
+    compiler still copies it, every step (~0.04 s an epoch at products
+    scale, PERF.md §6, PR 25).
 
     Inside a leaf scope every bucket is named in the compiled step:
     ``sgcn.bkt_<nb>x<wb>_u`` around the unrolled slots and their
@@ -441,12 +444,13 @@ def spmm_ell(ell_idx, ell_w, tail_dst, tail_src, tail_w, h, buckets):
     t of the bucket's rows is one contiguous (nb,) run).  Per slot this is
     one fused gather·weight + accumulate — no (nb, wb, f) intermediate
     exists, which is the point: the row-major gather+reduce form makes XLA
-    relayout that intermediate.  The v5e gather is row-rate-bound (8.0 ns
-    an executed slot at 128 lanes f32 on average, whatever the index
-    pattern — by bucket 5.1 or 11.0: the benchmark's ``ell_slot_ns`` and
-    its ``slot_prices`` line, PERF.md §5), so executed slots are the time
-    and the bucketed layout's padding (4.5 % in ``products.fullbatch``) is
-    what a single-width ELL would multiply.
+    relayout that intermediate.  The v5e gather is row-rate-bound (5.1 ns
+    an executed slot at 128 lanes f32 whatever the index pattern, and 11.0
+    in a bucket whose row count modulo 1,024 is 0 or past 896 — shapes the
+    plan's ``snap_rows`` keeps its buckets off: the benchmark's
+    ``ell_slot_ns`` and its ``slot_prices`` line, PERF.md §5, §6 PR 36), so
+    executed slots are the time and the bucketed layout's padding (4.5 % in
+    ``products.fullbatch``) is what a single-width ELL would multiply.
 
     The tail is folded per edge by a sorted ``segment_sum``, which costs
     about two slots an edge (15.2 ns; same ledger line): the form of the

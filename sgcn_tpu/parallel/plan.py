@@ -36,7 +36,7 @@ Everything here is offline numpy; nothing is traced.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 import scipy.sparse as sp
@@ -82,6 +82,24 @@ VROW_WIDTH = 32
 # 630,624, against 5.1–6.9 ns a scanned slot; PERF.md §6, PR 30, step 1)
 FOLD_WIDTHS = (4, 8, 16, 32)
 FOLD_ROW_COST = 1.4
+
+# the row counts a bucket or fold class of ROW_PERIOD rows and more may take,
+# as residues modulo ROW_PERIOD, both ends included: the v5e's slot gather
+# prices a slot by its bucket's row count modulo 1,024 (``snap_rows``).
+# Set by ``scripts/row_residue_micro.py`` (my chip run, PR 36;
+# ``bench_artifacts/row_residue_micro.json``; PERF.md §6): one bucket of
+# width 32 at 128 lanes, 100 and 600 periods of rows, scanned and unrolled —
+# residues 8 … 768 read 4.9–5.4 ns a slot, 832 … 896 a fixed ~0.17 ms more a
+# slot pass (6.7–7.0 ns at 100 periods, 5.5–5.7 at 600), and 897 … 1,023 AND
+# 0 read 10.9–11.3: a bucket is dear when its count of 128-row tiles is a
+# multiple of 8, so rounding up to a multiple of 1,024 lands on the dear
+# side.  The upper end stays a tile below the bare bucket's 768: in the
+# deep-stack cell's step an unrolled bucket at residue 768 (``48896x31``,
+# its slots starting mid-tile in the flat array) read the 832 … 896 price,
+# and at 640 its neighbours' (same PR, calls 2 and 3).  The lower end is a
+# multiple of 8 so that a fold class stays one.
+ROW_PERIOD = 1024
+ROW_WINDOW = (8, 640)
 
 # Auto-selection threshold for SGCN_COMM_SCHEDULE=auto: below this dense-a2a
 # padding efficiency (Σ send_counts / (k²·S)) the per-round-sized ragged
@@ -271,6 +289,12 @@ class CommPlan:
     fh_idx: np.ndarray | None = None    # (k, Σ nv·W) int32 halo rank
     fh_w: np.ndarray | None = None      # (k, Σ nv·W) float32, 0 on padding
     fh_row: np.ndarray | None = None    # (k, Σ nv) int32 destination row
+
+    # What ``snap_rows`` moved where this plan's shapes were chosen, per
+    # store (``slot_edges`` from ``_build_ell``; ``tail_edges`` /
+    # ``halo_edges`` once ``ensure_fold_slots`` has run): ``shapes`` changed,
+    # ``rows`` moved or added, ``slots`` added — ``work_counts()["snapped"]``.
+    snapped: dict = field(default_factory=dict)
 
     # The COMBINED edge list (src in [0, B+R), local ‖ halo) in the same
     # bucketed width-major layout — for ops that must see every in-edge of a
@@ -592,8 +616,10 @@ class CommPlan:
                 lay = layouts[store] or {
                     "idx": np.zeros((self.k, 0), np.int32),
                     "w": np.zeros((self.k, 0), np.float32),
-                    "row": np.zeros((self.k, 0), np.int32), "classes": ()}
+                    "row": np.zeros((self.k, 0), np.int32), "classes": (),
+                    "snapped": dict(UNSNAPPED)}
                 setattr(self, f"fold_{store}_classes", lay["classes"])
+                self.snapped[f"{store}_edges"] = lay["snapped"]
                 for name in ("idx", "w", "row"):
                     setattr(self, f"{pre}_{name}", lay[name])
             set_counter("plan.work_counts", self.work_counts())
@@ -664,8 +690,14 @@ class CommPlan:
         does (``padding_rows``), so the fan-in is ⌈padding ÷ table height⌉
         by construction, and it is COUNTED here from the arrays, with
         padding told by the counts (by weight 0 in the ELL, whose padding is
-        not a suffix).  Plain ints and lists: it is left in
-        ``obs.tracing.counters()`` by ``build_comm_plan``."""
+        not a suffix).  ``snapped`` says how far the executed row counts
+        were moved off the residues modulo 1,024 the v5e runs dear
+        (``snap_rows``; window measured in PERF.md §6, PR 36): per slot
+        store the ``shapes`` changed, the ``rows`` moved across bucket
+        boundaries (ELL) or added (fold classes), and the padding ``slots``
+        that added — all 0 where every chosen shape was cheap already, under
+        ``ROW_PERIOD`` rows, or forced by the caller.  Plain ints and lists:
+        it is left in ``obs.tracing.counters()`` by ``build_comm_plan``."""
         rows = self.ell_idx.shape[0]
         unsent = _unsent_slots(self.send_counts, self.send_idx.shape[2])
         folded = self.fold_tail_classes is not None
@@ -703,6 +735,9 @@ class CommPlan:
                         for store, per in pads.items()},
             "padding_fanin": {store: [padding_fanin(x) for x in per]
                               for store, per in pads.items()},
+            "snapped": {store: dict(self.snapped.get(store, UNSNAPPED))
+                        for store in ("slot_edges", "tail_edges",
+                                      "halo_edges")},
         }
 
     def wire_rows_per_exchange(self, schedule: str = "a2a",
@@ -1463,7 +1498,11 @@ def padding_rows(count: int, height: int) -> np.ndarray:
     height)`` = ⌈count ÷ height⌉ of them — the least any rule can reach.
     Nothing may recognise padding by its index: the counts (``lnnz``,
     ``ltail_nnz``, ``hnnz``, ``send_counts``, ``halo_counts``) and the zero
-    weights / masks say what is padding."""
+    weights / masks say what is padding.  The slots ``snap_rows`` adds —
+    rows that join a wider bucket, virtual rows that bring a fold class onto
+    a row count the gather runs cheaply (residues modulo 1,024 measured on
+    the v5e: PERF.md §6, PR 36) — are padding of this kind and follow this
+    rule."""
     return (np.arange(count, dtype=np.int64) % max(height, 1)).astype(np.int32)
 
 
@@ -1475,6 +1514,81 @@ def padding_fanin_bound(count: int, height: int) -> int:
 def padding_fanin(idx: np.ndarray) -> int:
     """The largest number of the given (padding) entries naming one row."""
     return int(np.bincount(idx).max()) if idx.size else 0
+
+
+def rows_cheap(rows):
+    """Whether a bucket or fold class of ``rows`` rows (an int or an array)
+    runs at the cheap price of a slot: under ``ROW_PERIOD`` rows, or with a
+    residue modulo ``ROW_PERIOD`` inside ``ROW_WINDOW`` (``snap_rows``)."""
+    res = rows % ROW_PERIOD
+    return (rows < ROW_PERIOD) | ((res >= ROW_WINDOW[0])
+                                  & (res <= ROW_WINDOW[1]))
+
+
+UNSNAPPED = {"shapes": 0, "rows": 0, "slots": 0}
+
+
+def snap_rows(shapes: tuple, cover: bool) -> tuple:
+    """Move the row counts of ``shapes = ((rows, width), ...)`` that the plan
+    CHOSE onto residues modulo ``ROW_PERIOD`` the v5e runs cheaply; returns
+    ``(shapes, {"shapes": changed, "rows": moved or added, "slots": added})``.
+
+    Why: ``bucketed_slot_reduce`` gathers a bucket's slot as one ``(rows,)``
+    run, and what the gather costs a slot follows ``rows mod 1,024``, not
+    width, form, lanes or the indices — ~5 ns inside ``ROW_WINDOW``, ~11 ns
+    from residue 897 up to AND INCLUDING the next multiple of 1,024
+    (PERF.md §5's per-bucket tables, PR 35, in the cells; the curve over
+    the residue that set the window: ``scripts/row_residue_micro.py``,
+    ``bench_artifacts/row_residue_micro.json``, PERF.md §6, PR 36).
+    A shape under ``ROW_PERIOD`` rows is left alone, and so is every shape
+    a caller FORCES (``buckets=`` / ``widths=``: the mini-batch envelope) —
+    this is applied where shapes are chosen, nowhere else.
+
+    ``cover=True``: ELL buckets, which must go on covering exactly Σ rows in
+    descending degree order.  A boundary may move LATER at no cost in
+    correctness: δ rows of bucket i+1 join bucket i at width w_i ≥ their
+    degree (no new tail edge; the slots of every row keep their order),
+    adding δ · (w_i − w_{i+1}) padding slots.  The moves (each under
+    ``ROW_PERIOD`` rows and within the next bucket) that leave the fewest
+    buckets outside the window, then add the fewest slots, are found
+    exactly, boundary by boundary; ``rows`` counts the rows moved.
+    ``cover=False``: fold classes, whose virtual rows may simply grow
+    (padding rows point at ``b − 1`` with weight 0): each count goes up to
+    the window; ``rows`` counts the rows added."""
+    if all(rows_cheap(n) for n, _ in shapes):
+        return tuple(shapes), dict(UNSNAPPED)
+    rows = np.array([n for n, _ in shapes], np.int64)
+    widths = np.array([w for _, w in shapes], np.int64)
+    if not cover:
+        res = rows % ROW_PERIOD
+        up = np.where(rows_cheap(rows), rows, rows - res + ROW_WINDOW[0]
+                      + ROW_PERIOD * (res > ROW_WINDOW[1]))
+        moved = up - rows
+    else:
+        m, d = len(shapes), np.arange(ROW_PERIOD)
+        dear, never = 1 << 40, 1 << 60      # a shape left dear; not allowed
+        grow = d[None] - d[:, None] + ROW_PERIOD - 1    # [previous, this]
+        cost = np.where(d == 0, 0, never)   # by the previous boundary's move
+        back = []
+        for i in range(m):
+            by = rows[i] + np.arange(1 - ROW_PERIOD, ROW_PERIOD)
+            price = np.where(by < 1, never,
+                             np.where(rows_cheap(by), 0, dear))
+            # this boundary's move: within the next bucket, and only down
+            # the widths; the last bucket ends at Σ rows
+            gap = widths[i] - widths[i + 1] if i < m - 1 else 0
+            room = rows[i + 1] if i < m - 1 and gap >= 0 else 0
+            total = (cost[:, None] + price[grow]
+                     + np.where(d <= room, d * gap, never)[None])
+            back.append(total.argmin(0))
+            cost = np.minimum(never, total[back[-1], d])
+        moved = np.zeros(m, np.int64)       # boundary i moves by moved[i]
+        for i in range(m - 1, 0, -1):
+            moved[i - 1] = back[i][moved[i]]
+        up = rows + moved - np.concatenate([[0], moved[:-1]])
+    return (tuple(zip(up.tolist(), widths.tolist())),
+            {"shapes": int((up != rows).sum()), "rows": int(moved.sum()),
+             "slots": int(((up - rows) * widths).sum())})
 
 
 def _unsent_slots(send_counts, s: int) -> np.ndarray:
@@ -1613,7 +1727,15 @@ def _choose_buckets(profile: np.ndarray, max_buckets: int = 6,
     products stand-in (391,884 hub rows; PERF.md §5).  The exact GCN step
     and the attention layer fold it as slot passes over virtual rows
     (``_build_virtual_rows``); the programs that keep the COO list fold it
-    by scatter-add at about two slots an edge."""
+    by scatter-add at about two slots an edge.
+
+    The row counts returned are the DP's, not yet the ones executed: every
+    caller that CHOOSES a layout by this function (``_build_ell``,
+    ``shared_ell_buckets``, the typed layouts' ``_relation_buckets``) passes
+    them through ``snap_rows``, which moves the boundaries of buckets of
+    ``ROW_PERIOD`` rows and more off the residues modulo 1,024 that the
+    v5e's gather runs at twice the price (window measured by
+    ``scripts/row_residue_micro.py``; PERF.md §6, PR 36)."""
     b = len(profile)
     d = np.minimum(np.maximum(np.asarray(profile, dtype=np.int64), 0),
                    width_cap)
@@ -1688,12 +1810,14 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
     k = ledge_dst.shape[0]
     degs = [np.bincount(ledge_dst[p, : int(lnnz[p])], minlength=b)
             for p in range(k)]
+    snapped = dict(UNSNAPPED)
     if buckets is None:
         if row_order == "degree":
             prof = np.zeros(b, dtype=np.int64)
             for dg in degs:
                 np.maximum(prof, dg, out=prof)
-            buckets = _choose_buckets(prof, max_buckets=max_buckets)
+            buckets, snapped = snap_rows(
+                _choose_buckets(prof, max_buckets=max_buckets), cover=True)
         else:
             alldeg = (np.concatenate(degs) if k else np.zeros(1, np.int64))
             buckets = ((b, _single_bucket_width(alldeg, tail_frac)),)
@@ -1753,7 +1877,7 @@ def _build_ell(ledge_dst, ledge_src, ledge_w, lnnz, b,
     return dict(ell_k=max(wb for _, wb in buckets), tl=tl,
                 ell_buckets=buckets, ell_idx=ell_idx, ell_w=ell_wv,
                 ltail_dst=ltail_dst, ltail_src=ltail_src, ltail_w=ltail_w,
-                ltail_nnz=ltail_nnz)
+                ltail_nnz=ltail_nnz, snapped={"slot_edges": snapped})
 
 
 def _run_lengths(dst, w, counts, b: int) -> list:
@@ -1775,11 +1899,10 @@ def _class_rows(dg: np.ndarray, widths: tuple) -> tuple:
     return full, cls
 
 
-def fold_class_shapes(degs: list, widths: tuple) -> tuple:
-    """``((nv_c, W_c), ...)`` a store of per-chip run lengths ``degs`` takes
-    at the class ``widths``: the rows of the fullest chip in each class (all
-    chips run one program), a multiple of 8; classes no chip uses are left
-    out."""
+def _fold_class_rows(degs: list, widths: tuple) -> tuple:
+    """``((nv_c, W_c), ...)`` as the run lengths alone give them: per class
+    of ``widths`` the virtual rows of the fullest chip (all chips run one
+    program), a multiple of 8; classes no chip uses are left out."""
     nv = np.zeros(len(widths), np.int64)
     for dg in degs:
         hist = np.bincount(dg)                     # by run length
@@ -1789,6 +1912,16 @@ def fold_class_shapes(degs: list, widths: tuple) -> tuple:
         rows[-1] += int((full * hist).sum())
         np.maximum(nv, rows, out=nv)
     return tuple((int(-(-n // 8) * 8), w) for n, w in zip(nv, widths) if n)
+
+
+def fold_class_shapes(degs: list, widths: tuple) -> tuple:
+    """``((nv_c, W_c), ...)`` a store of per-chip run lengths ``degs`` takes
+    at the class ``widths``: the rows of the fullest chip in each class, a
+    multiple of 8 (``_fold_class_rows``), and then, from ``ROW_PERIOD`` rows
+    up, raised onto a row count the slot gather runs cheaply (``snap_rows``:
+    the residues measured dear on the v5e are avoided, at under
+    ``ROW_PERIOD`` more virtual rows a class)."""
+    return snap_rows(_fold_class_rows(degs, widths), cover=False)[0]
 
 
 def choose_fold_widths(degs: list, row_cost: float = FOLD_ROW_COST) -> tuple:
@@ -1824,7 +1957,8 @@ def _build_virtual_rows(dst, src, w, counts, b: int, height: int,
     takes ``choose_fold_widths`` of the store's run lengths.
 
     Returns ``classes = ((nv_c, W_c), ...)`` (static; ascending widths,
-    shapes the maximum over chips), ``idx`` / ``w`` ``(k, Σ nv_c·W_c)``
+    shapes the maximum over chips, moved onto cheap row counts by
+    ``snap_rows``, which ``snapped`` counts), ``idx`` / ``w`` ``(k, Σ nv_c·W_c)``
     (class after class; slot t of a class's virtual row v at ``off_c + t·nv_c
     + v``; ``w`` the edge weights, 0 on padding, where ``idx`` holds the
     ``padding_rows`` of the source table's ``height``) and ``row`` ``(k, Σ
@@ -1836,7 +1970,7 @@ def _build_virtual_rows(dst, src, w, counts, b: int, height: int,
     degs = _run_lengths(dst, w, counts, b)
     if widths is None:
         widths = choose_fold_widths(degs)
-    classes = fold_class_shapes(degs, widths)
+    classes, snapped = snap_rows(_fold_class_rows(degs, widths), cover=False)
     if not classes:
         return None
     widths = tuple(wd for _, wd in classes)
@@ -1870,7 +2004,8 @@ def _build_virtual_rows(dst, src, w, counts, b: int, height: int,
                 iv[t, v], wvv[t, v] = s0[start[v] + t], wt[start[v] + t]
         pad = wv[p] == 0
         idx[p, pad] = padding_rows(int(pad.sum()), height)
-    return {"idx": idx, "w": wv, "row": row, "classes": classes}
+    return {"idx": idx, "w": wv, "row": row, "classes": classes,
+            "snapped": snapped}
 
 
 def shared_ell_buckets(plans: list, b: int, combined: bool = False) -> tuple:
@@ -1884,7 +2019,7 @@ def shared_ell_buckets(plans: list, b: int, combined: bool = False) -> tuple:
              else ell_degree_profile(pl.ledge_dst, pl.lnnz, pl.b))
         np.maximum(prof[: pl.b], q, out=prof[: pl.b])
     if all(pl.row_order == "degree" for pl in plans):
-        return _choose_buckets(prof)
+        return snap_rows(_choose_buckets(prof), cover=True)[0]
     # id-ordered rows: one classic tail-bounded width shared by all.
     # Derive each plan's natural combined width from its degree counts
     # directly — materializing the full cell layout just to read the width

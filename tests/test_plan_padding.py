@@ -247,7 +247,8 @@ def test_work_counts_count_the_padding(plans, k):
     heights = {"slot_edges": plan.b, "tail_edges": plan.b,
                "halo_edges": plan.r, "halo_rows": plan.k * plan.s,
                "rows_sent": plan.b}
-    assert set(work) == {"true", "executed", "padding", "padding_fanin"}
+    assert set(work) == {"true", "executed", "padding", "padding_fanin",
+                         "snapped"}
     for store, height in heights.items():
         for p in range(k):
             pad = work["executed"][store] - work["true"][store][p]
