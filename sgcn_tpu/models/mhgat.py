@@ -75,6 +75,7 @@ from __future__ import annotations
 import contextlib
 import functools
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -321,13 +322,11 @@ def slot_passes(plan, fin: int, widths, heads, concat, tail_shape,
     the tail's and of the halo edges' virtual rows, in the form
     ``_store_reduce`` runs them."""
     true = plan_true_edges(plan)
-    stores = {"ell": plan.ell_buckets,
-              "tail": () if tail_shape is None else (tail_shape,),
-              "halo": () if halo_shape is None else (halo_shape,)}
+    stores = plan_stores((None,) * 8, plan.ell_buckets, tail_shape,
+                         halo_shape)
 
     def forms(slot_bytes):
-        return {name: list(zip(b, bucket_forms(b, slot_bytes, _SCAN_LIVE)))
-                for name, b in stores.items()}
+        return store_forms(stores, slot_bytes)
 
     passes = []
     for layer, (_, k, c, _) in enumerate(layer_shapes(fin, widths, heads,
@@ -345,57 +344,195 @@ def slot_passes(plan, fin: int, widths, heads, concat, tail_shape,
 
 
 def _store_reduce(idx, mask, buckets, vrow, dst_side, contrib, init,
-                  slot_bytes, combine=jnp.add):
+                  slot_bytes, combine=jnp.add, scanned=False):
     """One edge store through ``bucketed_slot_reduce``: ``contrib(idx, mask,
     dst_side rows of the slot)`` combined over the slots of every bucket.
     ``dst_side`` are per-destination arrays ``(B, ·)``; a virtual-row store
     (``vrow`` given) reads them at its rows' destinations, and its result is
-    per virtual row."""
+    one array a class, per virtual row."""
     if vrow is not None:
         with fold_rows_scope():
             dst_side = tuple(jnp.take(x, vrow, axis=0) for x in dst_side)
-    return _concat_buckets(bucketed_slot_reduce(
+    outs = bucketed_slot_reduce(
         idx, mask, buckets,
         contrib=lambda i, w, row: contrib(
             i, w, tuple(x[row:row + i.shape[0]] for x in dst_side)),
         init=lambda nb, row: init(nb), slot_bytes=slot_bytes,
-        scan_live_limit=_SCAN_LIVE, combine=combine, with_rows=True))
+        scan_live_limit=_SCAN_LIVE, combine=combine, with_rows=True,
+        scanned=scanned)
+    return outs if vrow is not None else _concat_buckets(outs)
 
 
-def _all_stores(tables, halo_tables, dst_side, pa, buckets, tail_shape,
-                halo_shape, contrib, init, slot_bytes, combine=jnp.add,
-                sub=None):
-    """``contrib`` over the three edge stores — ELL slots and hub tail
-    reading ``tables`` (local rows), halo edges ``halo_tables`` — combined
-    per destination; a store without a layout (shape ``None``) has no pass.
-    ``sub`` names a sub-scope for the whole pass."""
+class Store(NamedTuple):
+    """One edge store of a pass: its leaf scope, its buckets or width
+    classes, the slots' sources and 0/1 mask, the virtual rows' destinations
+    (``None``: ELL slots over every destination row, in order), whether its
+    sources are halo rows, and whether every class wider than two slots
+    scans (``bucketed_slot_reduce``'s ``scanned``)."""
+    scope: str
+    shapes: tuple
+    idx: object
+    mask: object
+    row: object = None
+    halo: bool = False
+    scanned: bool = False
+
+
+def plan_stores(pa, buckets, tail_shape, halo_shape) -> tuple:
+    """The homogeneous layer's store set: the plan's ELL slots, and the one
+    class of the tail's and of the halo edges' virtual rows (none where a
+    store has no edges)."""
     (ell_idx, ell_w, vt_idx, vt_mask, vt_row, vh_idx, vh_mask, vh_row) = pa
+    return (Store("agg_slots", tuple(buckets), ell_idx, ell_w),
+            Store("agg_tail", () if tail_shape is None else (tail_shape,),
+                  vt_idx, vt_mask, vt_row),
+            Store("agg_halo_fold", () if halo_shape is None else (halo_shape,),
+                  vh_idx, vh_mask, vh_row, halo=True))
+
+
+def store_forms(stores, slot_bytes) -> dict:
+    """``{"ell" | "tail" | "halo": [((rows, width), unroll), ...]}`` of one
+    pass over ``stores`` (``Store``, or anything with its ``shapes`` and
+    ``scanned``, in the order ELL, tail, halo): the forms ``_store_reduce``
+    runs them in — what ``slot_pass`` lists."""
+    return {name: list(zip(st.shapes, bucket_forms(
+        st.shapes, slot_bytes, _SCAN_LIVE, st.scanned)))
+        for name, st in zip(("ell", "tail", "halo"), stores)}
+
+
+def _all_stores(tables, halo_tables, dst_side, stores, contrib, init,
+                slot_bytes, combine=jnp.add, sub=None, rows=None):
+    """``contrib`` over a pass's store set (``Store``: the ELL slots first,
+    then virtual rows) — local stores reading ``tables``, halo stores
+    ``halo_tables`` — combined per destination; a store without buckets or
+    classes has no pass.  ``rows`` is the destination count where the ELL
+    store may have no buckets; ``sub`` names a sub-scope for the whole
+    pass."""
     inner = (lambda: subscope(sub)) if sub else contextlib.nullcontext
     scatter = {jnp.add: lambda a, r, v: a.at[r].add(
                    v, indices_are_sorted=True),
                jnp.maximum: lambda a, r, v: a.at[r].max(
                    v, indices_are_sorted=True)}[combine]
-    with scope("agg_slots"), inner():
-        acc = _store_reduce(ell_idx, ell_w, buckets, None, dst_side,
-                            partial(contrib, tables), init, slot_bytes,
-                            combine)
-    for name, shape, idx, mask, vrow, tabs in (
-            ("agg_tail", tail_shape, vt_idx, vt_mask, vt_row, tables),
-            ("agg_halo_fold", halo_shape, vh_idx, vh_mask, vh_row,
-             halo_tables)):
-        if shape is None:
+    acc = None
+    for st in stores:
+        if not st.shapes:
             continue
-        with scope(name), inner():
-            part = _store_reduce(idx, mask, (shape,), vrow, dst_side,
-                                 partial(contrib, tabs), init, slot_bytes,
-                                 combine)
-            with fold_rows_scope():
-                acc = jax.tree.map(lambda a, v: scatter(a, vrow, v), acc,
-                                   part)
-    return acc
+        tabs = halo_tables if st.halo else tables
+        with scope(st.scope), inner():
+            part = _store_reduce(st.idx, st.mask, st.shapes, st.row,
+                                 dst_side, partial(contrib, tabs), init,
+                                 slot_bytes, combine, st.scanned)
+            if st.row is None:
+                acc = part
+                continue
+            if acc is None:
+                acc = init(rows)
+            # one sorted scatter a class: a class's destinations ascend,
+            # the classes' concatenation need not (the sort flag is a
+            # promise the TPU's scatter holds a program to)
+            r0 = 0
+            for (nv, _), cls in zip(st.shapes, part):
+                at = st.row if len(st.shapes) == 1 else st.row[r0:r0 + nv]
+                with fold_rows_scope():
+                    acc = jax.tree.map(
+                        lambda a, v, at=at: scatter(a, at, v), acc, cls)
+                r0 += nv
+    return init(rows) if acc is None else acc
 
 
 # ------------------------------------------------------------- aggregation
+def attend(z, s, t, zh, th, stores, heads, slope, rows=None):
+    """The forward slot bodies over one store set: ``O_i = Σ_{j∈N(i)}
+    softmax_j(LeakyReLU(s_i + t_j)) Z_j`` per head, with ``z`` (·, K·C) and
+    ``t`` (·, K) the sources' table (``zh``, ``th``: its halo rows; ``None``
+    where no store reads them) and ``s`` (B, K) the destinations' scores.
+    Returns ``out`` and what the backward reads beside the inputs: ``(m,
+    1/D, P, p)``."""
+    f, k = z.shape[1], heads
+
+    # ---- max pass: LeakyReLU is monotone, so only t is gathered
+    tmax = _all_stores(
+        (t,), (th,), (), stores,
+        contrib=lambda tabs, idx, w, _dst: jnp.where(
+            (w != 0)[:, None], jnp.take(tabs[0], idx, axis=0), _NEG),
+        init=lambda nb: jnp.full((nb, k), _NEG, jnp.float32),
+        slot_bytes=_max_slot_bytes, combine=jnp.maximum, sub="att_max",
+        rows=rows)
+    with scope("agg_slots"), subscope("att_max"):
+        m = _leaky(s + tmax, slope)
+
+    # ---- aggregation pass: un-normalised sums, all edges and the
+    # positive-score part of them (the backward's ∂L/∂s reads the latter)
+    def edge(tabs, src, mask, dst_side):
+        (tab_z, tab_t), (s_i, m_i) = tabs, dst_side
+        with subscope("att_score"):
+            x = s_i + jnp.take(tab_t, src, axis=0)
+            p = jnp.where((mask != 0)[:, None],
+                          jnp.exp(_leaky(x, slope) - m_i), 0.0)
+            q = jnp.where(x > 0, p, 0.0)
+        rows_ = jnp.take(tab_z, src, axis=0)
+        # ONE spread a slot for both accumulators: the coefficient signed
+        # by [x > 0] — its magnitude scales every edge's row, its positive
+        # part the positive-score edges' (±0 where p is 0: nothing added)
+        signed = jnp.where(x > 0, p, -p)
+        if k > 1:
+            signed = _spread_heads(signed, f)
+        return (rows_ * jnp.abs(signed), p,
+                rows_ * jnp.maximum(signed, 0.0), q)
+
+    num, den, pnum, pden = _all_stores(
+        (z, t), (zh, th), (s, m), stores, contrib=edge,
+        init=lambda nb: (jnp.zeros((nb, f), jnp.float32),
+                         jnp.zeros((nb, k), jnp.float32),
+                         jnp.zeros((nb, f), jnp.float32),
+                         jnp.zeros((nb, k), jnp.float32)),
+        slot_bytes=_agg_slot_bytes(f), rows=rows)
+    with scope("agg_slots"), subscope("att_norm"):
+        dinv = 1.0 / jnp.maximum(den, _TINY)
+        out = _scale_heads(dinv, num)
+        pos = _scale_heads(dinv, pnum)
+        ppos = pden * dinv
+    return out, (m, dinv, pos, ppos)
+
+
+def attend_bwd(g, z, s, t, m, dinv, out, pos, ppos, stores, exchange,
+               heads, slope, rows=None):
+    """The gather-only backward of ``attend`` (module docstring): ``∂L/∂s``
+    from the forward's second accumulator, then ONE pass over ``stores`` —
+    the REVERSE walk of the forward's (rows: the sources, slots: the
+    destinations that aggregate them) — reading ``[g ‖ s, m, 1/D, c]`` of
+    the destinations, halo rows through ``exchange((g, scal)) -> (gh,
+    scalh)`` (``None`` where no store reads them).  Returns ``(∂Z, ∂s,
+    ∂t)``."""
+    f, k = z.shape[1], heads
+    with scope("agg_slots"), subscope("att_norm"):
+        c = _dot_heads(g, out, k)
+        ds = (1.0 - slope) * (_dot_heads(g, pos, k) - c * ppos)
+        scal = jnp.concatenate([s, m, dinv, c], axis=1)         # (B, 4K)
+    gh, scalh = exchange((g, scal)) if exchange else (None, None)
+
+    # row j collects from every i that aggregates it
+    def edge(tabs, src, mask, dst_side):
+        (tab_g, tab_scal), (t_j, z_j) = tabs, dst_side
+        si = jnp.take(tab_scal, src, axis=0)
+        with subscope("att_score"):
+            x = si[:, :k] + t_j
+            alpha = jnp.where(
+                (mask != 0)[:, None],
+                jnp.exp(_leaky(x, slope) - si[:, k:2 * k]) * si[:, 2 * k:3 * k],
+                0.0)
+        gi = jnp.take(tab_g, src, axis=0)
+        de = alpha * (_dot_heads(gi, z_j, k) - si[:, 3 * k:])
+        return _scale_heads(alpha, gi), jnp.where(x > 0, de, slope * de)
+
+    dz, dt = _all_stores(
+        (g, scal), (gh, scalh), (t, z), stores, contrib=edge,
+        init=lambda nb: (jnp.zeros((nb, f), jnp.float32),
+                         jnp.zeros((nb, k), jnp.float32)),
+        slot_bytes=_agg_slot_bytes(f), rows=rows)
+    return dz, ds, dt
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(13, 14, 15, 16, 17, 18))
 def attention_aggregate(z, s, t, send_idx, halo_src, ell_idx, ell_w,
                         vt_idx, vt_mask, vt_row, vh_idx, vh_mask, vh_row,
@@ -413,54 +550,13 @@ def attention_aggregate(z, s, t, send_idx, halo_src, ell_idx, ell_w,
 def _aggregate_fwd(z, s, t, send_idx, halo_src, ell_idx, ell_w,
                    vt_idx, vt_mask, vt_row, vh_idx, vh_mask, vh_row,
                    heads, buckets, tail_shape, halo_shape, slope, axis_name):
-    f, k = z.shape[1], heads
     pa = (ell_idx, ell_w, vt_idx, vt_mask, vt_row, vh_idx, vh_mask, vh_row)
-    shapes = (pa, buckets, tail_shape, halo_shape)
     # no halo edge on any chip: nothing reads a halo table, nothing is sent
     zh, th = (halo_exchange_multi((z, t), send_idx, halo_src, axis_name)
               if halo_shape is not None else (None, None))
-
-    # ---- max pass: LeakyReLU is monotone, so only t is gathered
-    tmax = _all_stores(
-        (t,), (th,), (), *shapes,
-        contrib=lambda tabs, idx, w, _dst: jnp.where(
-            (w != 0)[:, None], jnp.take(tabs[0], idx, axis=0), _NEG),
-        init=lambda nb: jnp.full((nb, k), _NEG, jnp.float32),
-        slot_bytes=_max_slot_bytes, combine=jnp.maximum, sub="att_max")
-    with scope("agg_slots"), subscope("att_max"):
-        m = _leaky(s + tmax, slope)
-
-    # ---- aggregation pass: un-normalised sums, all edges and the
-    # positive-score part of them (the backward's ∂L/∂s reads the latter)
-    def edge(tabs, src, mask, dst_side):
-        (tab_z, tab_t), (s_i, m_i) = tabs, dst_side
-        with subscope("att_score"):
-            x = s_i + jnp.take(tab_t, src, axis=0)
-            p = jnp.where((mask != 0)[:, None],
-                          jnp.exp(_leaky(x, slope) - m_i), 0.0)
-            q = jnp.where(x > 0, p, 0.0)
-        rows = jnp.take(tab_z, src, axis=0)
-        # ONE spread a slot for both accumulators: the coefficient signed
-        # by [x > 0] — its magnitude scales every edge's row, its positive
-        # part the positive-score edges' (±0 where p is 0: nothing added)
-        signed = jnp.where(x > 0, p, -p)
-        if k > 1:
-            signed = _spread_heads(signed, f)
-        return (rows * jnp.abs(signed), p,
-                rows * jnp.maximum(signed, 0.0), q)
-
-    num, den, pnum, pden = _all_stores(
-        (z, t), (zh, th), (s, m), *shapes, contrib=edge,
-        init=lambda nb: (jnp.zeros((nb, f), jnp.float32),
-                         jnp.zeros((nb, k), jnp.float32),
-                         jnp.zeros((nb, f), jnp.float32),
-                         jnp.zeros((nb, k), jnp.float32)),
-        slot_bytes=_agg_slot_bytes(f))
-    with scope("agg_slots"), subscope("att_norm"):
-        dinv = 1.0 / jnp.maximum(den, _TINY)
-        out = _scale_heads(dinv, num)
-        pos = _scale_heads(dinv, pnum)
-        ppos = pden * dinv
+    out, (m, dinv, pos, ppos) = attend(
+        z, s, t, zh, th, plan_stores(pa, buckets, tail_shape, halo_shape),
+        heads, slope)
     res = (z, s, t, m, dinv, out, pos, ppos, send_idx, halo_src) + pa
     return out, res
 
@@ -468,35 +564,13 @@ def _aggregate_fwd(z, s, t, send_idx, halo_src, ell_idx, ell_w,
 def _aggregate_bwd(heads, buckets, tail_shape, halo_shape, slope, axis_name,
                    res, g):
     z, s, t, m, dinv, out, pos, ppos, send_idx, halo_src, *pa = res
-    f, k = z.shape[1], heads
-    with scope("agg_slots"), subscope("att_norm"):
-        c = _dot_heads(g, out, k)
-        ds = (1.0 - slope) * (_dot_heads(g, pos, k) - c * ppos)
-        scal = jnp.concatenate([s, m, dinv, c], axis=1)         # (B, 4K)
-    gh, scalh = (halo_exchange_multi((g, scal), send_idx, halo_src, axis_name)
-                 if halo_shape is not None else (None, None))
-
-    # row j collects from every i that aggregates it: the same stores,
-    # read the other way round (symmetric pattern)
-    def edge(tabs, src, mask, dst_side):
-        (tab_g, tab_scal), (t_j, z_j) = tabs, dst_side
-        si = jnp.take(tab_scal, src, axis=0)
-        with subscope("att_score"):
-            x = si[:, :k] + t_j
-            alpha = jnp.where(
-                (mask != 0)[:, None],
-                jnp.exp(_leaky(x, slope) - si[:, k:2 * k]) * si[:, 2 * k:3 * k],
-                0.0)
-        gi = jnp.take(tab_g, src, axis=0)
-        de = alpha * (_dot_heads(gi, z_j, k) - si[:, 3 * k:])
-        return _scale_heads(alpha, gi), jnp.where(x > 0, de, slope * de)
-
-    dz, dt = _all_stores(
-        (g, scal), (gh, scalh), (t, z), tuple(pa), buckets, tail_shape,
-        halo_shape, contrib=edge,
-        init=lambda nb: (jnp.zeros((nb, f), jnp.float32),
-                         jnp.zeros((nb, k), jnp.float32)),
-        slot_bytes=_agg_slot_bytes(f))
+    # the same stores, read the other way round (symmetric pattern)
+    dz, ds, dt = attend_bwd(
+        g, z, s, t, m, dinv, out, pos, ppos,
+        plan_stores(tuple(pa), buckets, tail_shape, halo_shape),
+        (lambda parts: halo_exchange_multi(parts, send_idx, halo_src,
+                                           axis_name))
+        if halo_shape is not None else None, heads, slope)
     return (dz, ds, dt) + (None,) * 10
 
 

@@ -102,6 +102,14 @@ DEEP_SUBSCOPES = ("norm", "softmax_table")
 # ``benchmark/scopes_rel.json`` is the benchmark's own copy.
 REL_SUBSCOPES = ("rel_project", "rel_table", "row_update")
 
+# Sub-scopes of attention inside the typed layouts (``models/rgat.py``), both
+# inside ``sgcn.dense``: ``ratt_project`` (the per-relation projections, the
+# folded destination scores and the skip, forward and backward) and
+# ``ratt_norm`` (BatchNorm's statistics with their ``psum``, ELU and the
+# head).  Its slot passes keep ``agg_*`` and the attention sub-scopes above.
+# ``benchmark/scopes_ratt.json`` is the benchmark's own copy.
+RATT_SUBSCOPES = ("ratt_project", "ratt_norm")
+
 # What the slot passes name of themselves (``ops/pspmm.py``), BELOW the three
 # aggregation leaf scopes — three token families, all outside ``SCOPES`` so
 # that every op still books to its leaf (``benchmark/scopes_slots.json`` is
@@ -152,11 +160,12 @@ def _named(full: str, leaf: bool):
 
 def subscope(name: str):
     """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``,
-    ``DEEP_SUBSCOPES``, ``REL_SUBSCOPES`` or ``SLOT_SUBSCOPES``, legal only
-    inside a leaf
+    ``DEEP_SUBSCOPES``, ``REL_SUBSCOPES``, ``RATT_SUBSCOPES`` or
+    ``SLOT_SUBSCOPES``, legal only inside a leaf
     ``scope`` — a sub-scope on its own would leave its ops unscoped for
     every reader of ``SCOPES``."""
-    known = SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES + SLOT_SUBSCOPES
+    known = (SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES + RATT_SUBSCOPES
+             + SLOT_SUBSCOPES)
     if name not in known:
         raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
                          f"{known}")

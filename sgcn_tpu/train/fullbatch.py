@@ -35,7 +35,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..models import deepergcn, mhgat, rgcn
+from ..models import deepergcn, mhgat, rgat, rgcn
 from ..models import setup as model_setup
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
@@ -96,6 +96,11 @@ MODELS = {
     "rgcn": (rgcn.init_rgcn_params, rgcn.rgcn_forward_local,
              lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
              rgcn.model_setup),
+    # attention inside the typed layouts (models/rgat.py): rgcn's layout per
+    # relation, mhgat's slot bodies over it
+    "rgat": (rgat.init_rgat_params, rgat.rgat_forward_local,
+             lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
+             rgat.model_setup),
 }
 
 

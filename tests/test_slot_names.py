@@ -62,7 +62,14 @@ MODELS = {
                    "input": "features" if n == "paper" else "embedding"}
                   for n, c in COUNTS.items()],
         "relations": RELS, "label_type": "paper", "hidden": 5, "layers": 2}},
+    # attention inside the typed layouts: every type brings features
+    "rgat": {"widths": [8, 8, 5], "activation": "elu", "model_args": {
+        "types": [{"name": n, "count": c, "input": "features"}
+                  for n, c in COUNTS.items()],
+        "relations": RELS, "label_type": "paper", "hidden": 8, "layers": 2,
+        "heads": 2}},
 }
+TYPED = ("rgcn", "rgat")
 STORE_OF = {"agg_slots": "ell", "agg_tail": "tail", "agg_halo_fold": "halo"}
 NAME = re.compile(r'"([^"]*sgcn\.[^"]*)"')
 TOKEN = re.compile(r"sgcn\.([A-Za-z0-9_]+)")
@@ -107,7 +114,7 @@ def plans():
 
 def _trainer(plans, model, k, **kw):
     spec = dict(MODELS[model])
-    plan = plans["typed" if model == "rgcn" else "plain", k]
+    plan = plans["typed" if model in TYPED else "plain", k]
     tr = FullBatchTrainer(plan, fin=spec.pop("fin", FIN), seed=3,
                           model=model.split("-")[0], mesh=make_mesh_1d(k),
                           **spec, **kw)
@@ -176,8 +183,8 @@ def test_the_lowered_steps_tokens_are_the_counters_buckets(plans, model, k):
         assert not any(p["stores"]["halo"] for p in work["passes"])
     else:
         assert all(p["stores"]["halo"] for p in work["passes"])
-    if model == "rgcn":
-        pairs = {t for p in work["passes"] for t in p["tags"]}
+    if model in TYPED:
+        pairs = {t for p in work["passes"] for t in p["tags"]} - {"att_max"}
         assert pairs == set(work["relations"]) and len(pairs) >= 6
         # a backward pass walks the REVERSE pair's layout: writes
         # (author -> paper) backward fills authors from papers
