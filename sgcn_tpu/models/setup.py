@@ -72,6 +72,11 @@ class ModelSetup:
     #                             than the chip's ``plan.b``
     hoist: Hoist | None = None  # loop-invariant work over data, made once
     #                             per ``h0`` outside the step (``Hoist``)
+    label_input: str | None = None  # name of a shipped (k, B) 0/1 array,
+    #                             the rows whose label the forward reads: the
+    #                             trainer hands the forward ``label_ids`` (the
+    #                             label there, -1 elsewhere) and leaves those
+    #                             rows out of the loss
 
 
 def slot_pass(layer: int, way: str, lanes: int, stores: dict, *, tags=(),

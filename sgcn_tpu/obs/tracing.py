@@ -110,6 +110,18 @@ REL_SUBSCOPES = ("rel_project", "rel_table", "row_update")
 # ``benchmark/scopes_ratt.json`` is the benchmark's own copy.
 RATT_SUBSCOPES = ("ratt_project", "ratt_norm")
 
+# Sub-scopes of the graph-transformer layer (``models/unimp.py``), both inside
+# ``sgcn.dense``: ``xatt_project`` (the query, key, value and skip products,
+# the label embedding, the mean over heads and the gate, forward and
+# backward) and ``xatt_norm`` (LayerNorm and the activation).  Its slot passes
+# keep ``agg_*``; each names itself by one of ``XATT_PASSES`` inside them
+# (the max pass, the forward aggregation, the backward over a destination's
+# slots and the backward over a source's) — the tags of its passes in
+# ``slots.work``.  ``benchmark/scopes_xatt.json`` is the benchmark's own copy
+# of both lists.
+XATT_SUBSCOPES = ("xatt_project", "xatt_norm")
+XATT_PASSES = ("xatt_max", "xatt_fwd", "xatt_dst", "xatt_src")
+
 # What the slot passes name of themselves (``ops/pspmm.py``), BELOW the three
 # aggregation leaf scopes — three token families, all outside ``SCOPES`` so
 # that every op still books to its leaf (``benchmark/scopes_slots.json`` is
@@ -160,12 +172,12 @@ def _named(full: str, leaf: bool):
 
 def subscope(name: str):
     """``jax.named_scope("sgcn.<name>")`` for a name of ``SUBSCOPES``,
-    ``DEEP_SUBSCOPES``, ``REL_SUBSCOPES``, ``RATT_SUBSCOPES`` or
-    ``SLOT_SUBSCOPES``, legal only inside a leaf
-    ``scope`` — a sub-scope on its own would leave its ops unscoped for
-    every reader of ``SCOPES``."""
+    ``DEEP_SUBSCOPES``, ``REL_SUBSCOPES``, ``RATT_SUBSCOPES``,
+    ``XATT_SUBSCOPES``, ``XATT_PASSES`` or ``SLOT_SUBSCOPES``, legal only
+    inside a leaf ``scope`` — a sub-scope on its own would leave its ops
+    unscoped for every reader of ``SCOPES``."""
     known = (SUBSCOPES + DEEP_SUBSCOPES + REL_SUBSCOPES + RATT_SUBSCOPES
-             + SLOT_SUBSCOPES)
+             + XATT_SUBSCOPES + XATT_PASSES + SLOT_SUBSCOPES)
     if name not in known:
         raise ValueError(f"unknown sub-scope {name!r}; the vocabulary is "
                          f"{known}")

@@ -109,7 +109,8 @@ def main() -> None:
     p.add_argument("-n", "--batch-size", type=int, default=None,
                    help="enable the mini-batch trainer")
     p.add_argument("--model", default="gcn",
-                   choices=["gcn", "gat", "mhgat", "deepergcn", "rgcn"],
+                   choices=["gcn", "gat", "mhgat", "deepergcn", "rgcn",
+                            "unimp"],
                    help="gat = the reference's single-head PGAT layer; "
                         "mhgat = multi-head graph attention as published "
                         "(LeakyReLU scores, per-edge softmax, bias, linear "
@@ -124,7 +125,14 @@ def main() -> None:
                         "weight per relation, a weight per node type on the "
                         "row itself, per-node embeddings for types without "
                         "features; full-batch, a2a, f32 only): give "
-                        "--node-types, --relations and --label-type")
+                        "--node-types, --relations and --label-type; "
+                        "unimp = UniMP's graph transformer as published "
+                        "(dot-product attention over a row's neighbours, a "
+                        "gated residual, LayerNorm, a label input on a "
+                        "seeded 62.5 %% of the rows, left out of the loss; "
+                        "full-batch, a2a, f32 only): hidden layers of "
+                        "--hidden concatenate --heads heads, the last "
+                        "averages them")
     p.add_argument("--node-types", default=None,
                    help="--model rgcn: the node types in id order, "
                         "name:count:features|embedding, comma-separated "
@@ -136,7 +144,8 @@ def main() -> None:
     p.add_argument("--label-type", default=None,
                    help="--model rgcn: the node type whose rows have labels")
     p.add_argument("--heads", type=int, default=1,
-                   help="attention heads per layer (--model mhgat)")
+                   help="attention heads per layer (--model mhgat, "
+                        "unimp)")
     p.add_argument("--activation", default=None,
                    choices=["relu", "sigmoid", "elu", "none"],
                    help="inter-layer activation; defaults to relu for gcn "
@@ -448,6 +457,8 @@ def main() -> None:
         # hidden layers concatenate their heads, the last averages them
         widths = [args.heads * hidden] * (args.nlayers - 1) + [nclasses]
         model_args = {"heads": (args.heads,) * args.nlayers}
+    if args.model == "unimp":
+        model_args = {"heads": args.heads}
     if args.model == "deepergcn":
         # -l counts the GENConv layers; the head is one more width
         widths = [hidden] * args.nlayers + [nclasses]
@@ -463,7 +474,7 @@ def main() -> None:
             "label_type": args.label_type}
         for t in model_args["types"]:
             t["count"] = int(t["count"])
-    if (args.model in ("mhgat", "deepergcn", "rgcn")
+    if (args.model in ("mhgat", "deepergcn", "rgcn", "unimp")
             and args.batch_size is not None):
         raise SystemExit(f"--model {args.model} is full-batch only; drop -n")
 

@@ -35,7 +35,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..models import deepergcn, mhgat, rgat, rgcn
+from ..models import deepergcn, mhgat, rgat, rgcn, unimp
 from ..models import setup as model_setup
 from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
@@ -101,6 +101,12 @@ MODELS = {
     "rgat": (rgat.init_rgat_params, rgat.rgat_forward_local,
              lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
              rgat.model_setup),
+    # dot-product attention with a gated residual and a label input
+    # (models/unimp.py): mhgat's plan arrays and store machinery
+    "unimp": (unimp.init_unimp_params, unimp.unimp_forward_local,
+              lambda plan: unimp.UNIMP_PLAN_FIELDS,
+              lambda plan: {"ell_buckets": plan.ell_buckets},
+              unimp.model_setup),
 }
 
 
@@ -852,6 +858,10 @@ class FullBatchTrainer:
                            if setup.custom is not None else {})
         self._out_rows = (setup.custom.out_rows
                           if setup.custom is not None else None)
+        # rows whose label the forward reads (models/setup.py): None for
+        # every model without a label input
+        self._label_input = (setup.custom.label_input
+                             if setup.custom is not None else None)
         # loop-invariant work over data the model makes outside its step
         # (models/setup.py::Hoist; _agg0_for builds it)
         self._hoist = setup.custom.hoist if setup.custom is not None else None
@@ -1061,6 +1071,16 @@ class FullBatchTrainer:
         with scope("loss"):
             return labels[rows], valid[rows] * real
 
+    def _label_feed(self, pa, labels, valid) -> tuple:
+        """``(extra forward arguments, loss rows)``: with a model's label
+        input the label ids of its rows (-1 elsewhere), and those rows out of
+        the loss; ``((), valid)`` for every other model."""
+        if self._label_input is None:
+            return (), valid
+        with scope("loss"):
+            on = pa[self._label_input] != 0
+            return (jnp.where(on, labels, -1),), jnp.where(on, 0.0, valid)
+
     def host_state(self) -> tuple:
         """``(params, opt_state)`` on the host, every row-owned leaf
         gathered into its global row order — what a checkpoint holds, and
@@ -1109,13 +1129,16 @@ class FullBatchTrainer:
         return dict(symmetric=self.plan.symmetric, **self._fwd_static,
                     **extra)
 
-    def _forward(self, params, pa, h0):
+    def _forward(self, params, pa, h0, label_ids=None):
         """Per-chip logits.  On an ``agg0_hoisted`` trainer ``h0`` is
         ``_agg0_for(data.h0)`` and layer 0 starts at its dense product; with
-        a model's ``Hoist`` it is ``(h0, what the hoist built)``."""
+        a model's ``Hoist`` it is ``(h0, what the hoist built)``; with its
+        label input ``label_ids`` are ``_label_feed``'s."""
         hoisted = ({"input_aggregated": True} if self.agg0_hoisted else {})
         if self._hoist is not None:
             h0, hoisted["hoisted"] = h0
+        if label_ids is not None:
+            hoisted["label_ids"] = label_ids
         params, pa, h0 = self._cast(params, pa, h0)
         out = self._forward_fn(
             params, h0, pa,
@@ -1187,12 +1210,13 @@ class FullBatchTrainer:
         fwd = (jax.checkpoint(self._forward, static_argnums=())
                if self.remat else self._forward)
 
+        feed, valid = self._label_feed(pa, labels, valid)
         labels, valid = self._select_out(pa, labels, valid)
         # (row-owned leaves arrive with shard_map's block axis: _update)
         params = self._by_owner(params, lambda x: x[0])
 
         def loss_fn(ps):
-            logits = fwd(ps, pa, h0)
+            logits = fwd(ps, pa, h0, *feed)
             with scope("loss"):
                 loss = self._loss_fn(logits, labels, valid)
                 err = (masked_err_local(logits, labels, valid)
@@ -1947,8 +1971,9 @@ class FullBatchTrainer:
         def per_chip(params, pa, h0, labels, valid):
             pa, h0, labels, valid = _unblock((pa, h0, labels, valid))
             params = self._by_owner(params, lambda x: x[0])
+            feed, valid = self._label_feed(pa, labels, valid)
             labels, valid = self._select_out(pa, labels, valid)
-            logits = self._forward(params, pa, h0)
+            logits = self._forward(params, pa, h0, *feed)
             # eval loss uses the SAME objective as training, so train/eval
             # losses are comparable under --loss bce too (the MPI stack
             # reports the one flavor it trains with,

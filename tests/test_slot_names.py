@@ -62,6 +62,9 @@ MODELS = {
                    "input": "features" if n == "paper" else "embedding"}
                   for n, c in COUNTS.items()],
         "relations": RELS, "label_type": "paper", "hidden": 5, "layers": 2}},
+    # dot-product attention, a gated residual, a label input
+    "unimp": {"widths": [8, 8, 5],
+              "model_args": {"heads": 2, "label_rate": 0.5}},
     # attention inside the typed layouts: every type brings features
     "rgat": {"widths": [8, 8, 5], "activation": "elu", "model_args": {
         "types": [{"name": n, "count": c, "input": "features"}
@@ -73,7 +76,8 @@ TYPED = ("rgcn", "rgat")
 STORE_OF = {"agg_slots": "ell", "agg_tail": "tail", "agg_halo_fold": "halo"}
 NAME = re.compile(r'"([^"]*sgcn\.[^"]*)"')
 TOKEN = re.compile(r"sgcn\.([A-Za-z0-9_]+)")
-TAG = re.compile(r"att_max|" + tracing.PAIR_TOKEN)
+TAG = re.compile("|".join(("att_max",) + tracing.XATT_PASSES
+                          + (tracing.PAIR_TOKEN,)))
 
 
 @pytest.fixture(scope="module")
@@ -221,7 +225,7 @@ def _three(plan):
 @pytest.mark.parametrize("model,hoist", [
     ("gcn", True), ("gcn", False), ("gcn-project-first", False),
     ("mhgat", None), ("deepergcn", None), ("deepergcn-keep-input", None),
-    ("rgcn", None)])
+    ("rgcn", None), ("unimp", None)])
 def test_the_counters_sums_are_the_older_counters(plans, model, hoist, k):
     tr, work = _trainer(plans, model, k)
     if hoist is False and model == "gcn":
@@ -255,6 +259,15 @@ def test_the_counters_sums_are_the_older_counters(plans, model, hoist, k):
         assert slots == npass * att["executed_slots_per_pass"]
         assert per["true_edges"] == [npass * x
                                      for x in att["true_edges_per_pass"]]
+    elif model == "unimp":
+        xatt = c["xatt.work"]
+        npass = sum(xatt["passes_per_step"].values())
+        assert len(work["passes"]) == npass == 4 * 3
+        assert slots == npass * xatt["executed_slots_per_pass"]
+        assert per["true_edges"] == [npass * x
+                                     for x in xatt["true_edges_per_pass"]]
+        assert {tuple(p["tags"]) for p in work["passes"]} == {
+            ("xatt_max",), ("xatt_fwd",), ("xatt_dst",), ("xatt_src",)}
     elif model.startswith("deepergcn"):
         deep = c["deep.work"]
         npass = sum(deep["agg_passes_per_step"].values())
