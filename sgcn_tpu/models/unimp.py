@@ -41,12 +41,13 @@ required), with ``g = ∂L/∂o`` and ``c_i[h] = g_i[h]·o_i[h]``::
 ``∂q`` is a pass over the DESTINATION's slots gathering ``[k ‖ v]``
 (2·K·C lanes); ``∂k`` and ``∂v`` one pass over the SOURCE's own slots — on a
 symmetric pattern row j's slots enumerate exactly the i that aggregate j —
-gathering ``[q/√C ‖ g]`` and ``[m, 1/D, c]`` (2·K·C + 3K lanes).  α is
-recomputed per slot; residuals are per row (``q/√C, [k ‖ v], m, 1/D, o``),
-never per edge.  At k > 1 one ``all_to_all`` a layer ships ``[k ‖ v]``
-forward (read by the max pass, the forward and the destination backward)
-and one ships ``[q/√C ‖ g ‖ m, 1/D, c]`` backward.  The max stays a pass
-of its own: the forward's sums are exact once m is known, with no rescale.
+gathering ONE table ``[q/√C ‖ g ‖ m, 1/D, c]`` (2·K·C + 3K lanes): every
+pass gathers one table a slot.  α is recomputed per slot; residuals are per
+row (``q/√C, [k ‖ v], m, 1/D, o``), never per edge.  At k > 1 one
+``all_to_all`` a layer ships ``[k ‖ v]`` forward (read by the max pass, the
+forward and the destination backward) and one ships that table backward.
+The max stays a pass of its own: the forward's sums are exact once m is
+known, with no rescale.
 
 Every dense product, and every product by the 0/1 head matrix outside the
 forward slot's one-pass spread (``mhgat._spread_heads``: exact), runs at
@@ -267,14 +268,14 @@ def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
                scale: float, rows=None):
     """The backward of ``attend`` (module docstring): the destination pass
     for ``∂q`` over ``[k ‖ v]``, then the source pass for ``∂k``, ``∂v`` over
-    ``[q/√C ‖ g]`` and ``[m, 1/D, c]``, halo rows through ``exchange(parts)
-    -> parts`` (``None`` where no store reads them).  Returns ``(∂q, ∂k,
-    ∂v)``."""
+    one table ``[q/√C ‖ g ‖ m, 1/D, c]`` (one gather a slot), its halo rows
+    through ``exchange(parts) -> parts`` (``None`` where no store reads
+    them).  Returns ``(∂q, ∂k, ∂v)``."""
     f, k = qs.shape[1], heads
     with scope("agg_slots"), subscope("xatt_src"):
         c = mhgat._dot_heads(g, out, k)
-        qg = jnp.concatenate([qs, g], axis=1)                   # (B, 2F)
-        scal = jnp.concatenate([m, dinv, c], axis=1)            # (B, 3K)
+        # the source slot's one table: (B, 2F + 3K)
+        qgs = jnp.concatenate([qs, g, m, dinv, c], axis=1)
 
     def alpha(q_i, k_j, m_i, dinv_i, w):
         return jnp.where((w != 0)[:, None],
@@ -299,12 +300,16 @@ def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
 
     # ---- over the source's own slots: row j collects from every i that
     # aggregates it
-    qgh, scalh = exchange((qg, scal)) if exchange else (None, None)
+    (qgsh,) = exchange((qgs,)) if exchange else (None,)
 
     def edge_src(tabs, idx, w, dst):
-        (tab_qg, tab_s), (kv_j,) = tabs, dst
-        qg_i = jnp.take(tab_qg, idx, axis=0)
-        s_i = jnp.take(tab_s, idx, axis=0)
+        (tab,), (kv_j,) = tabs, dst
+        row = jnp.take(tab, idx, axis=0)
+        # the scalars by an exact 0/1 product, not a slice: a slice ties the
+        # whole gathered row to the narrow arrays' transposed layout
+        qg_i = row[:, :2 * f]
+        s_i = jnp.dot(row[:, 2 * f:], jnp.eye(3 * k, dtype=row.dtype),
+                      precision=_HIGHEST)
         q_i, g_i = qg_i[:, :f], qg_i[:, f:]
         a = alpha(q_i, kv_j[:, :f], s_i[:, :k], s_i[:, k:2 * k], w)
         de = a * (mhgat._dot_heads(g_i, kv_j[:, f:], k) - s_i[:, 2 * k:])
@@ -312,7 +317,7 @@ def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
         return qg_i * _spread_pair(de, a, f)
 
     dkv = mhgat._all_stores(
-        (qg, scal), (qgh, scalh), (kv,), stores, contrib=edge_src,
+        (qgs,), (qgsh,), (kv,), stores, contrib=edge_src,
         init=lambda nb: jnp.zeros((nb, 2 * f), jnp.float32),
         slot_bytes=mhgat._agg_slot_bytes(2 * f + 3 * k), sub="xatt_src",
         rows=rows)
@@ -474,8 +479,8 @@ def estimate_unimp_hbm_bytes(plan, fin: int, widths, heads: int,
       ``m``, ``1/D``, the skip, the gate, ``y``, LayerNorm's statistics and
       its output;
     * ``rows_transient``: the widest layer's backward — ``g``, ``∂q``,
-      ``∂[k ‖ v]``, ``[q/√C ‖ g]`` and the three scalars — and the virtual
-      rows' accumulators and destination-side rows;
+      ``∂[k ‖ v]`` and the source pass's one table ``[q/√C ‖ g ‖ m, 1/D,
+      c]`` — and the virtual rows' accumulators and destination-side rows;
     * ``halo``: the received tables of the widest layer, both directions;
     * ``slot_temps``: the slot passes' gathered rows, bounded by the
       scan-unroll budget or by the widest bucket;
@@ -529,11 +534,12 @@ def slot_passes(plan, fin: int, widths, heads: int, tail_shape, halo_shape,
 
 def _work(plan, fin, widths, heads, vshapes, true, diag) -> dict:
     """The program counter ``xatt.work``: per layer heads, channels and the
-    lanes a slot of each pass gathers; per chip the true edges of a pass
-    (the diagonal masked out) and the diagonal slots masked; the executed
-    slots of a pass, the passes a step, the exchange's lanes, and the lanes
-    gathered an epoch over the true edges (``lanes_per_epoch`` ÷
-    ``true_edges_per_pass`` = Σ of every pass's lanes)."""
+    lanes a slot of each pass gathers; the tables each pass gathers a slot;
+    per chip the true edges of a pass (the diagonal masked out) and the
+    diagonal slots masked; the executed slots of a pass, the passes a step,
+    the exchange's lanes, and the lanes gathered an epoch over the true
+    edges (``lanes_per_epoch`` ÷ ``true_edges_per_pass`` = Σ of every pass's
+    lanes)."""
     shapes = layer_shapes(fin, widths, heads)
     lanes = [pass_lanes(k, c) for _, k, c, _ in shapes]
     per_edge = sum(sum(x.values()) for x in lanes)
@@ -542,6 +548,7 @@ def _work(plan, fin, widths, heads, vshapes, true, diag) -> dict:
         "heads": [k for _, k, _, _ in shapes],
         "channels": [c for _, _, c, _ in shapes],
         "lanes_per_pass": lanes,
+        "gathers_per_slot": {"max": 1, "fwd": 1, "dst": 1, "src": 1},
         "true_edges_per_pass": list(true),
         "masked_diagonal_slots": list(diag),
         "executed_slots_per_pass": sum(mhgat.executed_slots(

@@ -4,7 +4,8 @@ residual, LayerNorm, a label input.
 
   * (a) logits, loss and EVERY gradient leaf equal a dense-matrix reference
     at k = 1 and on 4 virtual devices with a real partition — where the
-    exchange runs and a halo row's cotangent reaches its owner;
+    exchange runs and a halo row's cotangent reaches its owner; every pass
+    gathers one table a slot, the source pass included;
   * (b) the diagonal is excluded (a row whose only entry is its own gives
     o = 0: the skip alone), the label input reads P's rows only and the loss
     skips them; the benchmark's plain reference agrees with the dense one;
@@ -15,9 +16,11 @@ residual, LayerNorm, a label input.
 CPU, tiny graphs, one to four virtual devices.
 """
 
+import collections
 import importlib
 import importlib.util
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ import numpy as np
 import optax
 import pytest
 import scipy.sparse as sp
+from jax.core import jaxprs_in_params
 
 from sgcn_tpu.io.datasets import dcsbm_graph
 from sgcn_tpu.models import unimp
@@ -203,6 +207,53 @@ def test_every_gradient_equals_the_dense_reference(plans, inputs, reference,
         # softmax is blind to a key's bias: q_i·b_k is the same for every j
         moved = np.abs(g).max() > 1e-6
         assert moved != path_is(path, 'b_key'), jax.tree_util.keystr(path)
+
+
+STORES = ("sgcn.agg_slots", "sgcn.agg_tail", "sgcn.agg_halo_fold")
+
+
+def slot_gathers(tr, monkeypatch) -> collections.Counter:
+    """``{(pass tag, store scope): gather ops}`` of the traced training step
+    over the slot bodies: a gather under ``sgcn.fold_rows`` reads a virtual
+    row's destination-side rows, not a slot's source row, and is left out.
+    A nested jaxpr's names are relative to the equation that holds it."""
+    build = tr._build_step
+    monkeypatch.setattr(tr, "_build_step", lambda mesh=None: SimpleNamespace(
+        lower=build(mesh=mesh).trace))
+    found = collections.Counter()
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            name = f"{prefix}/{eqn.source_info.name_stack}"
+            tokens = name.split("/")
+            tags = [t for t in tracing.XATT_PASSES if f"sgcn.{t}" in tokens]
+            stores = [t[5:] for t in tokens if t in STORES]
+            if (eqn.primitive.name == "gather" and tags
+                    and "sgcn.fold_rows" not in tokens):
+                found[tags[-1], stores[-1]] += 1
+            for sub in jaxprs_in_params(eqn.params):
+                walk(sub, name)
+
+    walk(tr.lower_step().jaxpr.jaxpr, "")
+    return found
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_every_pass_gathers_one_table_a_slot(plans, inputs, monkeypatch, k):
+    """The source pass gathers ``[q/√C ‖ g ‖ m, 1/D, c]`` as ONE table: in
+    every store it holds as many slot gathers as the destination pass over
+    the same slots (two tables a slot would double them), and the counter
+    says so."""
+    run = _run(plans, inputs, k)
+    found = slot_gathers(run["tr"], monkeypatch)
+    want = {"agg_slots", "agg_tail"} | ({"agg_halo_fold"} if k > 1 else set())
+    for tag in tracing.XATT_PASSES:
+        assert {s for t, s in found if t == tag} == want, tag
+    for store in want:
+        assert (found["xatt_src", store] == found["xatt_dst", store]
+                == found["xatt_fwd", store] == found["xatt_max", store] > 0)
+    assert run["work"]["gathers_per_slot"] == {
+        "max": 1, "fwd": 1, "dst": 1, "src": 1}
 
 
 def test_halo_cotangents_reach_their_owners_at_k4(plans, inputs):
