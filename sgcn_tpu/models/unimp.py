@@ -38,16 +38,23 @@ required), with ``g = ∂L/∂o`` and ``c_i[h] = g_i[h]·o_i[h]``::
     ∂v_j = Σ_i α_ij g_i                 ∂e_ij = α_ij (g_i·v_j − c_i)
     ∂q_i = Σ_j ∂e_ij k_j / √C           ∂k_j = Σ_i ∂e_ij q_i / √C
 
-``∂q`` is a pass over the DESTINATION's slots gathering ``[k ‖ v]``
-(2·K·C lanes); ``∂k`` and ``∂v`` one pass over the SOURCE's own slots — on a
-symmetric pattern row j's slots enumerate exactly the i that aggregate j —
-gathering ONE table ``[q/√C ‖ g ‖ m, 1/D, c]`` (2·K·C + 3K lanes): every
-pass gathers one table a slot.  α is recomputed per slot; residuals are per
-row (``q/√C, [k ‖ v], m, 1/D, o``), never per edge.  At k > 1 one
-``all_to_all`` a layer ships ``[k ‖ v]`` forward (read by the max pass, the
-forward and the destination backward) and one ships that table backward.
-The max stays a pass of its own: the forward's sums are exact once m is
-known, with no rescale.
+On a symmetric pattern row r's slots enumerate exactly the s that aggregate
+r, so ONE walk over the slots serves both sums: slot (r, s) adds
+``∂e_rs k_s`` to ``∂q_r`` (r as the destination) and ``∂e_sr q_s/√C``,
+``α_sr g_s`` to ``∂k_r``, ``∂v_r`` (r as the source).  Each slot gathers one
+row of ONE table ``[k ‖ q/√C ‖ g ‖ v ‖ m, 1/D, c]`` (4·K·C + 3K lanes) and
+reads row r of the same table; one accumulator ``[∂q ‖ ∂k ‖ ∂v]`` is
+``[k_s ‖ q_s/√C ‖ g_s]`` times one spread of ``[∂e_rs ‖ ∂e_sr ‖ α_sr]``, and
+each class of virtual rows folds it in one sorted scatter.  A layer whose
+K·C is not a whole number of lane tiles keeps two walks over the same slots
+(``fused_backward``): the destination's for ``∂q`` gathering ``[k ‖ v]``,
+the source's for ``∂k``, ``∂v`` gathering ``[q/√C ‖ g ‖ m, 1/D, c]``.
+Every pass gathers one table a slot.  α is recomputed per slot; residuals
+are per row (``q/√C, [k ‖ v], m, 1/D, o``), never per edge.  At k > 1 one
+``all_to_all`` a layer ships ``[k ‖ v]`` forward (read by the max pass and
+the forward, and kept for the backward's halo table) and one ships ``[q/√C
+‖ g ‖ m, 1/D, c]`` backward.  The max stays a pass of its own: the
+forward's sums are exact once m is known, with no rescale.
 
 Every dense product, and every product by the 0/1 head matrix outside the
 forward slot's one-pass spread (``mhgat._spread_heads``: exact), runs at
@@ -82,6 +89,7 @@ LABEL_INPUT = "label_in"        # shipped (k, B) int8: 1 on the rows of P
 LN_EPS = 1e-5                   # LayerNorm's, torch's default
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+LANE_TILE = 128                 # the lanes of a TPU vector register
 
 
 # ----------------------------------------------------------- configuration
@@ -181,15 +189,33 @@ def param_count(fin: int, widths, heads: int) -> int:
 
 def exchange_lane_widths(fin: int, widths, heads: int) -> tuple:
     """``(forward, backward)`` f32 lanes of each layer's exchange:
-    ``[k ‖ v]`` 2·K·C, ``[q/√C ‖ g ‖ m, 1/D, c]`` 2·K·C + 3K."""
+    ``[k ‖ v]`` 2·K·C, ``[q/√C ‖ g ‖ m, 1/D, c]`` 2·K·C + 3K (the backward
+    walk's halo table joins the two)."""
     shapes = layer_shapes(fin, widths, heads)
     return (tuple(2 * k * c for _, k, c, _ in shapes),
             tuple(2 * k * c + 3 * k for _, k, c, _ in shapes))
 
 
+def fused_backward(k: int, c: int) -> bool:
+    """Whether a layer of K heads of C channels walks its backward ONCE:
+    where every block of its row ``[k ‖ q/√C ‖ g ‖ v ‖ m, 1/D, c]`` starts
+    on a lane tile (K·C a multiple of ``LANE_TILE``).  Otherwise the v5e's
+    compiler lays the table out transposed and copies it back whole inside
+    every scanned bucket, whatever the row's width (K·C = 8, 136 and 188
+    alike; 128 and 256 not: compiled steps, ``PERF.md`` §6) — +0.55 GB of
+    temporaries for the products cell's last layer (C = 47) — so such a
+    layer keeps the two walks."""
+    return (k * c) % LANE_TILE == 0
+
+
 def pass_lanes(k: int, c: int) -> dict:
-    """f32 lanes a slot of each pass of one layer gathers."""
+    """f32 lanes a slot of each pass of one layer gathers: the max pass
+    ``k``, the forward ``[k ‖ v]``, and the backward's one walk ``bwd``
+    ``[k ‖ q/√C ‖ g ‖ v ‖ m, 1/D, c]`` (``fused_backward``) or its two,
+    ``dst`` ``[k ‖ v]`` and ``src`` ``[q/√C ‖ g ‖ m, 1/D, c]``."""
     f = k * c
+    if fused_backward(k, c):
+        return {"max": f, "fwd": 2 * f, "bwd": 4 * f + 3 * k}
     return {"max": f, "fwd": 2 * f, "dst": 2 * f, "src": 2 * f + 3 * k}
 
 
@@ -266,28 +292,93 @@ def attend(qs, k_, kv, kvh, stores, heads, rows=None):
 
 def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
                scale: float, rows=None):
-    """The backward of ``attend`` (module docstring): the destination pass
-    for ``∂q`` over ``[k ‖ v]``, then the source pass for ``∂k``, ``∂v`` over
-    one table ``[q/√C ‖ g ‖ m, 1/D, c]`` (one gather a slot), its halo rows
-    through ``exchange(parts) -> parts`` (``None`` where no store reads
-    them).  Returns ``(∂q, ∂k, ∂v)``."""
+    """The backward of ``attend`` (module docstring): ONE walk over the
+    slots where the layer's shape fuses (``fused_backward``), else the
+    destination pass for ``∂q`` and the source pass for ``∂k``, ``∂v``.  At
+    k > 1 the halo rows of ``[q/√C ‖ g ‖ m, 1/D, c]`` come through
+    ``exchange(parts) -> parts`` (``None`` where no store reads them).
+    Returns ``(∂q, ∂k, ∂v)``."""
     f, k = qs.shape[1], heads
     with scope("agg_slots"), subscope("xatt_src"):
         c = mhgat._dot_heads(g, out, k)
-        # the source slot's one table: (B, 2F + 3K)
-        qgs = jnp.concatenate([qs, g, m, dinv, c], axis=1)
+    walk = _walk_once if fused_backward(k, f // k) else _walk_twice
+    return walk(g, qs, kv, kvh, m, dinv, c, stores, exchange, k, scale, rows)
 
-    def alpha(q_i, k_j, m_i, dinv_i, w):
-        return jnp.where((w != 0)[:, None],
-                         jnp.exp(mhgat._dot_heads(q_i, k_j, k) - m_i)
-                         * dinv_i, 0.0)
 
-    # ---- over the destination's slots: ∂q_i = Σ_j ∂e_ij k_j / √C
+def _alpha(q_i, k_j, m_i, dinv_i, w, k: int):
+    return jnp.where((w != 0)[:, None],
+                     jnp.exp(mhgat._dot_heads(q_i, k_j, k) - m_i) * dinv_i,
+                     0.0)
+
+
+def _scalars(t, lo: int, k: int):
+    """The 3K scalars ``[m, 1/D, c]`` at lanes ``lo:`` of a table's rows, by
+    an exact 0/1 product, not a slice: a slice ties a whole gathered row to
+    the narrow arrays' transposed layout."""
+    s = jnp.dot(t[:, lo:], jnp.eye(3 * k, dtype=t.dtype), precision=_HIGHEST)
+    return s[:, :k], s[:, k:2 * k], s[:, 2 * k:]
+
+
+def _walk_once(g, qs, kv, kvh, m, dinv, c, stores, exchange, k: int,
+               scale: float, rows):
+    """One walk: slot (r, s) gathers row s of ``[k ‖ q/√C ‖ g ‖ v ‖ m, 1/D,
+    c]`` and reads row r of the same table, its scalars from ``m``, ``1/D``
+    and ``c`` themselves (no product a slot); ``[∂q ‖ ∂k ‖ ∂v]`` is ``[k_s
+    ‖ q_s/√C ‖ g_s]`` times one spread of ``[∂e_rs ‖ ∂e_sr ‖ α_sr]``."""
+    f = qs.shape[1]
+    with scope("agg_slots"), subscope("xatt_src"):
+        # (B, 4F + 3K): the last use of ``kv``
+        tab = jnp.concatenate([kv[:, :f], qs, g, kv[:, f:], m, dinv, c],
+                              axis=1)
+        qgs = jnp.concatenate([qs, g, m, dinv, c], axis=1) if exchange \
+            else None
+    tabh = None
+    if exchange:
+        (qgsh,) = exchange((qgs,))
+        with scope("agg_slots"), subscope("xatt_src"):
+            tabh = jnp.concatenate([kvh[:, :f], qgsh[:, :2 * f], kvh[:, f:],
+                                    qgsh[:, 2 * f:]], axis=1)
+
+    def edge(tabs, idx, w, dst):
+        (tab_,), (tab_r, m_r, dinv_r, c_r) = tabs, dst
+        # the plan's indices are in bounds, padding included: "clip" gathers
+        # without "fill"'s select over every gathered row
+        row = jnp.take(tab_, idx, axis=0, mode="clip")
+        k_s, q_s, g_s, v_s = (row[:, i * f:(i + 1) * f] for i in range(4))
+        k_r, q_r, g_r, v_r = (tab_r[:, i * f:(i + 1) * f] for i in range(4))
+        m_s, dinv_s, c_s = _scalars(row, 4 * f, k)
+        # r as the destination: ∂q_r = Σ_s ∂e_rs k_s / √C
+        a_rs = _alpha(q_r, k_s, m_r, dinv_r, w, k)
+        de_rs = a_rs * (mhgat._dot_heads(g_r, v_s, k) - c_r)
+        # r as the source, s aggregating it: ∂k_r = Σ_s ∂e_sr q_s / √C,
+        # ∂v_r = Σ_s α_sr g_s
+        a_sr = _alpha(q_s, k_r, m_s, dinv_s, w, k)
+        de_sr = a_sr * (mhgat._dot_heads(g_s, v_r, k) - c_s)
+        return row[:, :3 * f] * _spread_blocks((de_rs, de_sr, a_sr), f)
+
+    acc = mhgat._all_stores(
+        (tab,), (tabh,), (tab, m, dinv, c), stores, contrib=edge,
+        init=lambda nb: jnp.zeros((nb, 3 * f), jnp.float32),
+        slot_bytes=mhgat._agg_slot_bytes(4 * f + 3 * k), sub="xatt_src",
+        rows=rows)
+    with scope("agg_slots"), subscope("xatt_src"):
+        return acc[:, :f] * scale, acc[:, f:2 * f], acc[:, 2 * f:]
+
+
+def _walk_twice(g, qs, kv, kvh, m, dinv, c, stores, exchange, k: int,
+                scale: float, rows):
+    """Two walks: over the destination's slots for ``∂q``, gathering ``[k ‖
+    v]``; over the source's own for ``∂k``, ``∂v``, gathering ONE table
+    ``[q/√C ‖ g ‖ m, 1/D, c]``."""
+    f = qs.shape[1]
+    with scope("agg_slots"), subscope("xatt_src"):
+        qgs = jnp.concatenate([qs, g, m, dinv, c], axis=1)   # (B, 2F + 3K)
+
     def edge_dst(tabs, idx, w, dst):
         (tab,), (q_i, m_i, dinv_i, g_i, c_i) = tabs, dst
         kv_j = jnp.take(tab, idx, axis=0)
         k_j, v_j = kv_j[:, :f], kv_j[:, f:]
-        a = alpha(q_i, k_j, m_i, dinv_i, w)
+        a = _alpha(q_i, k_j, m_i, dinv_i, w, k)
         de = a * (mhgat._dot_heads(g_i, v_j, k) - c_i)
         return mhgat._scale_heads(de, k_j)
 
@@ -297,24 +388,17 @@ def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
         slot_bytes=mhgat._agg_slot_bytes(2 * f), sub="xatt_dst", rows=rows)
     with scope("agg_slots"), subscope("xatt_dst"):
         dq = dq * scale
-
-    # ---- over the source's own slots: row j collects from every i that
-    # aggregates it
     (qgsh,) = exchange((qgs,)) if exchange else (None,)
 
     def edge_src(tabs, idx, w, dst):
         (tab,), (kv_j,) = tabs, dst
         row = jnp.take(tab, idx, axis=0)
-        # the scalars by an exact 0/1 product, not a slice: a slice ties the
-        # whole gathered row to the narrow arrays' transposed layout
         qg_i = row[:, :2 * f]
-        s_i = jnp.dot(row[:, 2 * f:], jnp.eye(3 * k, dtype=row.dtype),
-                      precision=_HIGHEST)
-        q_i, g_i = qg_i[:, :f], qg_i[:, f:]
-        a = alpha(q_i, kv_j[:, :f], s_i[:, :k], s_i[:, k:2 * k], w)
-        de = a * (mhgat._dot_heads(g_i, kv_j[:, f:], k) - s_i[:, 2 * k:])
+        m_i, dinv_i, c_i = _scalars(row, 2 * f, k)
+        a = _alpha(qg_i[:, :f], kv_j[:, :f], m_i, dinv_i, w, k)
+        de = a * (mhgat._dot_heads(qg_i[:, f:], kv_j[:, f:], k) - c_i)
         # [∂e ‖ α] spread over [q ‖ g]'s lanes in ONE product
-        return qg_i * _spread_pair(de, a, f)
+        return qg_i * _spread_blocks((de, a), f)
 
     dkv = mhgat._all_stores(
         (qgs,), (qgsh,), (kv,), stores, contrib=edge_src,
@@ -324,18 +408,13 @@ def attend_bwd(g, qs, kv, kvh, m, dinv, out, stores, exchange, heads,
     return dq, dkv[:, :f], dkv[:, f:]
 
 
-def _spread_pair(a, b, f: int):
-    """``[a ‖ b]`` (n, 2K) → (n, 2·K·C): column h of ``a`` over head h's
-    lanes of the first K·C, of ``b`` over the second's — one ``HIGHEST``
-    product by a block-diagonal 0/1 matrix (exact: each output is one
-    input)."""
-    k = a.shape[1]
-    lanes = mhgat._head_lanes(k, f)
-    zero = jnp.zeros_like(lanes)
-    block = jnp.concatenate([jnp.concatenate([lanes, zero], axis=1),
-                             jnp.concatenate([zero, lanes], axis=1)], axis=0)
-    return jnp.dot(jnp.concatenate([a, b], axis=1), block,
-                   precision=_HIGHEST)
+def _spread_blocks(cols, f: int):
+    """``[a ‖ b ‖ …]`` (n, J·K) → (n, J·K·C): column h of the j-th block
+    over head h's lanes of the j-th K·C — one ``HIGHEST`` product by a
+    block-diagonal 0/1 matrix (exact: each output is one input)."""
+    lanes = mhgat._head_lanes(cols[0].shape[1], f)
+    block = jnp.kron(jnp.eye(len(cols), dtype=lanes.dtype), lanes)
+    return jnp.dot(jnp.concatenate(cols, axis=1), block, precision=_HIGHEST)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(13, 14, 15, 16, 17))
@@ -478,10 +557,13 @@ def estimate_unimp_hbm_bytes(plan, fin: int, widths, heads: int,
     * ``rows_kept``: per layer the input, ``q/√C``, ``[k ‖ v]``, ``o``,
       ``m``, ``1/D``, the skip, the gate, ``y``, LayerNorm's statistics and
       its output;
-    * ``rows_transient``: the widest layer's backward — ``g``, ``∂q``,
-      ``∂[k ‖ v]`` and the source pass's one table ``[q/√C ‖ g ‖ m, 1/D,
-      c]`` — and the virtual rows' accumulators and destination-side rows;
-    * ``halo``: the received tables of the widest layer, both directions;
+    * ``rows_transient``: the widest layer's backward — ``g``, the
+      accumulators ``[∂q ‖ ∂k ‖ ∂v]`` and the table its walk gathers, ``[k
+      ‖ q/√C ‖ g ‖ v ‖ m, 1/D, c]`` where it walks once, else ``[q/√C ‖ g
+      ‖ m, 1/D, c]`` — and the virtual rows' accumulators and their
+      destination-side rows;
+    * ``halo``: the received tables of the widest layer, both directions,
+      and a fused walk's halo table;
     * ``slot_temps``: the slot passes' gathered rows, bounded by the
       scan-unroll budget or by the widest bucket;
     * ``plan``: index and mask arrays.
@@ -494,11 +576,18 @@ def estimate_unimp_hbm_bytes(plan, fin: int, widths, heads: int,
     kept = sum(b * 4 * (f + 4 * k * c + 2 * k + 3 * out + 3)
                for f, k, c, out in shapes) if train else 0
     fmax = max(k * c for _, k, c, _ in shapes)
-    kmax = max(k for _, k, _, _ in shapes)
-    transient = (b * 4 * ((6 * fmax + 3 * kmax) if train else 3 * fmax)
-                 + vrows * 4 * 3 * 2 * fmax)
-    halo = r * 4 * (2 * fmax + ((2 * fmax + 3 * kmax) if train else 0))
-    lanes = 2 * fmax + 3 * kmax
+    # per layer the widest row its backward gathers, and the one table of a
+    # fused walk (its halo rows too)
+    walks = [(max(pass_lanes(k, c).values()), k * c, k, fused_backward(k, c))
+             for _, k, c, _ in shapes]
+    lanes = max(row for row, _, _, _ in walks)
+    if train:
+        transient = max(b * 4 * (4 * f + row) + vrows * 4 * (3 * f + row)
+                        for row, f, _, _ in walks)
+        halo = max(r * 4 * (4 * f + 3 * k + (row if fused else 0))
+                   for row, f, k, fused in walks)
+    else:
+        transient, halo = b * 4 * 3 * fmax, r * 4 * 2 * fmax
     slot_temps = min(mhgat._SCAN_LIVE + 3 * 4 * lanes * b // 4,
                      3 * 4 * lanes * b)
     parts = {"rows_kept": kept, "rows_transient": transient, "halo": halo,
@@ -509,36 +598,42 @@ def estimate_unimp_hbm_bytes(plan, fin: int, widths, heads: int,
 
 
 # ------------------------------------------------------------ the counters
+# ``pass_lanes``' keys: the pass's direction and its sub-scope
+_PASS_TAGS = {"max": ("fwd", "xatt_max"), "fwd": ("fwd", "xatt_fwd"),
+              "bwd": ("bwd", "xatt_src"), "dst": ("bwd", "xatt_dst"),
+              "src": ("bwd", "xatt_src")}
+
+
 def slot_passes(plan, fin: int, widths, heads: int, tail_shape, halo_shape,
                 true_edges) -> list:
     """The step's pass list for ``slots.work``: per layer the max pass (tag
-    ``xatt_max``), the forward (``xatt_fwd``) and the two backward passes
-    (``xatt_dst``, ``xatt_src``), each over the ELL buckets and the one class
-    of the tail's and of the halo edges' virtual rows, in the form
+    ``xatt_max``), the forward (``xatt_fwd``) and the backward's one walk
+    (``xatt_src``, 4·K·C + 3K lanes) or, in a layer that does not fuse, its
+    two (``xatt_dst``, ``xatt_src``), each over the ELL buckets and the one
+    class of the tail's and of the halo edges' virtual rows, in the form
     ``mhgat._store_reduce`` runs them."""
     stores = mhgat.plan_stores((None,) * 8, plan.ell_buckets, tail_shape,
                                halo_shape)
     passes = []
     for layer, (_, k, c, _) in enumerate(layer_shapes(fin, widths, heads)):
-        lanes = pass_lanes(k, c)
-        for way, tag, key in (("fwd", "xatt_max", "max"),
-                              ("fwd", "xatt_fwd", "fwd"),
-                              ("bwd", "xatt_dst", "dst"),
-                              ("bwd", "xatt_src", "src")):
+        for key, lanes in pass_lanes(k, c).items():
+            way, tag = _PASS_TAGS[key]
             passes.append(slot_pass(
-                layer, way, lanes[key],
-                mhgat.store_forms(stores, mhgat._agg_slot_bytes(lanes[key])),
+                layer, way, lanes,
+                mhgat.store_forms(stores, mhgat._agg_slot_bytes(lanes)),
                 tags=(tag,), true_edges=true_edges))
     return passes
 
 
 def _work(plan, fin, widths, heads, vshapes, true, diag) -> dict:
-    """The program counter ``xatt.work``: per layer heads, channels and the
-    lanes a slot of each pass gathers; the tables each pass gathers a slot;
-    per chip the true edges of a pass (the diagonal masked out) and the
-    diagonal slots masked; the executed slots of a pass, the passes a step,
-    the exchange's lanes, and the lanes gathered an epoch over the true
-    edges (``lanes_per_epoch`` ÷ ``true_edges_per_pass`` = Σ of every pass's
+    """The program counter ``xatt.work``: per layer heads, channels, the
+    lanes a slot of each pass gathers (the backward's one walk under
+    ``bwd``) and whether the backward walks the slots once
+    (``fused_backward``); the tables each pass gathers a slot; per chip the
+    true edges of a pass (the diagonal masked out) and the diagonal slots
+    masked; the executed slots of a pass, the passes a step, the exchange's
+    lanes, and the lanes gathered an epoch over the true edges
+    (``lanes_per_epoch`` ÷ ``true_edges_per_pass`` = Σ of every pass's
     lanes)."""
     shapes = layer_shapes(fin, widths, heads)
     lanes = [pass_lanes(k, c) for _, k, c, _ in shapes]
@@ -548,13 +643,14 @@ def _work(plan, fin, widths, heads, vshapes, true, diag) -> dict:
         "heads": [k for _, k, _, _ in shapes],
         "channels": [c for _, _, c, _ in shapes],
         "lanes_per_pass": lanes,
-        "gathers_per_slot": {"max": 1, "fwd": 1, "dst": 1, "src": 1},
+        "fused_backward": [fused_backward(k, c) for _, k, c, _ in shapes],
+        "gathers_per_slot": {key: 1 for x in lanes for key in x},
         "true_edges_per_pass": list(true),
         "masked_diagonal_slots": list(diag),
         "executed_slots_per_pass": sum(mhgat.executed_slots(
             plan, **vshapes).values()),
         "passes_per_step": {"max": len(shapes), "aggregate": len(shapes),
-                            "backward": 2 * len(shapes)},
+                            "backward": sum(len(x) - 2 for x in lanes)},
         "lanes_per_edge": per_edge,
         "lanes_per_epoch": [per_edge * t for t in true],
         "exchange_lanes": {"forward": list(lanes_f),

@@ -115,10 +115,11 @@ RATT_SUBSCOPES = ("ratt_project", "ratt_norm")
 # the label embedding, the mean over heads and the gate, forward and
 # backward) and ``xatt_norm`` (LayerNorm and the activation).  Its slot passes
 # keep ``agg_*``; each names itself by one of ``XATT_PASSES`` inside them
-# (the max pass, the forward aggregation, the backward over a destination's
-# slots and the backward over a source's) — the tags of its passes in
-# ``slots.work``.  ``benchmark/scopes_xatt.json`` is the benchmark's own copy
-# of both lists.
+# (the max pass, the forward aggregation, and ``xatt_src``, the backward's one
+# walk over the slots, or its walk over a source's slots in a layer that keeps
+# two walks, whose walk over a destination's slots is ``xatt_dst``) — the tags
+# of its passes in ``slots.work``.
+# ``benchmark/scopes_xatt.json`` is the benchmark's own copy of both lists.
 XATT_SUBSCOPES = ("xatt_project", "xatt_norm")
 XATT_PASSES = ("xatt_max", "xatt_fwd", "xatt_dst", "xatt_src")
 

@@ -62,8 +62,10 @@ MODELS = {
                    "input": "features" if n == "paper" else "embedding"}
                   for n, c in COUNTS.items()],
         "relations": RELS, "label_type": "paper", "hidden": 5, "layers": 2}},
-    # dot-product attention, a gated residual, a label input
-    "unimp": {"widths": [8, 8, 5],
+    # dot-product attention, a gated residual, a label input; the hidden
+    # layers' K·C is one lane tile, so their backward walks once and the
+    # last layer's twice
+    "unimp": {"widths": [128, 128, 5],
               "model_args": {"heads": 2, "label_rate": 0.5}},
     # attention inside the typed layouts: every type brings features
     "rgat": {"widths": [8, 8, 5], "activation": "elu", "model_args": {
@@ -262,12 +264,25 @@ def test_the_counters_sums_are_the_older_counters(plans, model, hoist, k):
     elif model == "unimp":
         xatt = c["xatt.work"]
         npass = sum(xatt["passes_per_step"].values())
-        assert len(work["passes"]) == npass == 4 * 3
+        # the backward walks the slots once in a fused layer, twice in the
+        # last
+        assert xatt["fused_backward"] == [True, True, False]
+        assert len(work["passes"]) == npass == 3 + 3 + (1 + 1 + 2)
         assert slots == npass * xatt["executed_slots_per_pass"]
         assert per["true_edges"] == [npass * x
                                      for x in xatt["true_edges_per_pass"]]
+        assert {(p["layer"], p["tags"][0]) for p in work["passes"]
+                if p["way"] == "bwd"} == {
+            (0, "xatt_src"), (1, "xatt_src"), (2, "xatt_dst"),
+            (2, "xatt_src")}
         assert {tuple(p["tags"]) for p in work["passes"]} == {
             ("xatt_max",), ("xatt_fwd",), ("xatt_dst",), ("xatt_src",)}
+        # Σ lanes a true edge: the counter's, and the two-pass backward's
+        # (destination 2·K·C, source 2·K·C + 3K) to the lane
+        lanes = sum(p["lanes"] for p in work["passes"])
+        assert lanes == xatt["lanes_per_edge"] == sum(
+            k * c + 2 * k * c + 2 * k * c + (2 * k * c + 3 * k)
+            for k, c in zip(xatt["heads"], xatt["channels"]))
     elif model.startswith("deepergcn"):
         deep = c["deep.work"]
         npass = sum(deep["agg_passes_per_step"].values())

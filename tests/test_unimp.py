@@ -5,7 +5,8 @@ residual, LayerNorm, a label input.
   * (a) logits, loss and EVERY gradient leaf equal a dense-matrix reference
     at k = 1 and on 4 virtual devices with a real partition — where the
     exchange runs and a halo row's cotangent reaches its owner; every pass
-    gathers one table a slot, the source pass included;
+    gathers one table a slot, and a layer whose K·C is a whole number of
+    lane tiles walks its backward once (both forms against the reference);
   * (b) the diagonal is excluded (a row whose only entry is its own gives
     o = 0: the skip alone), the label input reads P's rows only and the loss
     skips them; the benchmark's plain reference agrees with the dense one;
@@ -240,10 +241,11 @@ def slot_gathers(tr, monkeypatch) -> collections.Counter:
 
 @pytest.mark.parametrize("k", [1, 4])
 def test_every_pass_gathers_one_table_a_slot(plans, inputs, monkeypatch, k):
-    """The source pass gathers ``[q/√C ‖ g ‖ m, 1/D, c]`` as ONE table: in
-    every store it holds as many slot gathers as the destination pass over
-    the same slots (two tables a slot would double them), and the counter
-    says so."""
+    """Layers whose K·C is not a whole lane tile (8 and 10 here) keep the
+    two backward walks, each gathering ONE table a slot: in every store the
+    destination walk (``[k ‖ v]``), the source walk (``[q/√C ‖ g ‖ m, 1/D,
+    c]``), the forward and the max pass hold as many slot gathers (two
+    tables a slot would double them), and the counter says so."""
     run = _run(plans, inputs, k)
     found = slot_gathers(run["tr"], monkeypatch)
     want = {"agg_slots", "agg_tail"} | ({"agg_halo_fold"} if k > 1 else set())
@@ -252,8 +254,72 @@ def test_every_pass_gathers_one_table_a_slot(plans, inputs, monkeypatch, k):
     for store in want:
         assert (found["xatt_src", store] == found["xatt_dst", store]
                 == found["xatt_fwd", store] == found["xatt_max", store] > 0)
-    assert run["work"]["gathers_per_slot"] == {
-        "max": 1, "fwd": 1, "dst": 1, "src": 1}
+    work = run["work"]
+    assert work["fused_backward"] == [False] * len(WIDTHS)
+    assert work["gathers_per_slot"] == {"max": 1, "fwd": 1, "dst": 1,
+                                        "src": 1}
+    assert work["passes_per_step"]["backward"] == 2 * len(WIDTHS)
+
+
+ALIGNED = 256       # hidden: 2 heads of 128 lanes, every block on a lane tile
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_the_backward_walks_each_store_once(plans, inputs, adjacency,
+                                            monkeypatch, k):
+    """Hidden layers whose K·C is a whole number of lane tiles walk their
+    backward ONCE, gathering one row of ``[k ‖ q/√C ‖ g ‖ v ‖ m, 1/D, c]``
+    a slot; the last layer (K·C = 10) keeps its two walks in the same step.
+    Logits, loss and every gradient equal the dense reference (at k = 4
+    through the fused walk's halo table), and the counter and the traced
+    step agree on which layer walks how: under ``xatt_src`` each store
+    holds as many slot gathers as the forward and the max pass, under
+    ``xatt_dst`` only the last layer's."""
+    tr = _trainer(plans[k], widths=[ALIGNED, ALIGNED, NCLS],
+                  model_args=dict(MODEL, hidden=ALIGNED),
+                  optimizer=optax.sgd(RATE))
+    work = tracing.counters()["xatt.work"]
+    assert work["fused_backward"] == [True, True, False]
+    assert work["passes_per_step"]["backward"] == 1 + 1 + 2
+    assert work["gathers_per_slot"] == {"max": 1, "fwd": 1, "bwd": 1,
+                                        "dst": 1, "src": 1}
+    assert [x.get("bwd") for x in work["lanes_per_pass"]] == [
+        4 * ALIGNED + 3 * HEADS] * 2 + [None]
+    # Σ lanes a true edge: the two-walk backward's, to the lane
+    assert work["lanes_per_edge"] == sum(
+        c + 2 * c + 2 * c + (2 * c + 3 * HEADS)
+        for c in (ALIGNED, ALIGNED, HEADS * NCLS))
+    data = _data(tr, inputs)
+    before = jax.tree.map(np.asarray, tr.params)
+    logits = tr.predict(data)
+    loss = float(tr.step(data))
+    got = jax.tree.map(lambda a, b: (a - b) / RATE, before,
+                       jax.tree.map(np.asarray, tr.params))
+    feats, labels = inputs
+    args = (jax.tree.map(jnp.asarray, before), jnp.asarray(feats),
+            jnp.asarray(labels),
+            jnp.asarray(unimp.label_rows(N, MODEL["label_rate"],
+                                         MODEL["label_seed"])),
+            jnp.asarray(adjacency.toarray() > 0))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(dense_forward(*args))
+        want_loss, grads = jax.value_and_grad(dense_loss)(*args)
+    rms = float(np.sqrt((want ** 2).mean()))
+    np.testing.assert_allclose(logits, want, atol=2e-6 * rms, rtol=0)
+    assert loss == pytest.approx(float(want_loss), rel=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(grads)):
+        np.testing.assert_allclose(g, w, atol=ATOL, rtol=0,
+                                   err_msg=jax.tree_util.keystr(path))
+    found = slot_gathers(tr, monkeypatch)
+    stores = {"agg_slots", "agg_tail"} | ({"agg_halo_fold"} if k > 1
+                                          else set())
+    for store in stores:
+        # three layers' forward, max pass and source-role walk; the last
+        # layer's destination walk alone
+        assert (found["xatt_src", store] == found["xatt_fwd", store]
+                == found["xatt_max", store]
+                == 3 * found["xatt_dst", store] > 0)
 
 
 def test_halo_cotangents_reach_their_owners_at_k4(plans, inputs):
