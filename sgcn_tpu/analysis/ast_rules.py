@@ -26,7 +26,12 @@ Rules (see ``docs/static_analysis.md`` for the table):
     CLI defines maps to a mode-matrix axis (``modes.MODE_FLAGS``) or is a
     recorded non-axis (``modes.NON_AXIS_FLAGS``), and every axis flag
     exists on the trainer CLI: a new transport/wire knob cannot land
-    outside the audited matrix.
+    outside the audited matrix;
+  * ``model-name-free`` — no comparison against a model's name (a
+    ``MODELS`` key) in the layers below the models: the trainers (bar the
+    CLI's input validation), the memory model, checkpoints and
+    ``parallel/``.  What differs between models is a field of their
+    ``models/setup.py`` record or of what their setup hook returns.
 """
 
 from __future__ import annotations
@@ -54,6 +59,13 @@ MODE_FLAG_FILES = ("sgcn_tpu/train/__main__.py",
 _MODE_LIKE_RE = re.compile(r"^--(comm|halo)-")
 
 _FIELDS_NAME_RE = re.compile(r"^_?[A-Z][A-Z0-9_]*_FIELDS[A-Z0-9_]*$")
+
+# the keys of train/fullbatch.py::MODELS (tests/test_model_seam.py holds
+# the two equal), and the modules that must not compare against them
+MODEL_NAMES = ("gcn", "gat", "mhgat", "deepergcn", "rgcn", "rgat", "unimp")
+NAME_FREE_PREFIXES = ("sgcn_tpu/train/", "sgcn_tpu/parallel/",
+                      "sgcn_tpu/obs/memory.py", "sgcn_tpu/utils/checkpoint.py")
+NAME_FREE_EXEMPT = ("sgcn_tpu/train/__main__.py",)
 
 # PREFIX roots (the whole dotted name starts with these — bare "random."
 # must not be a containment match or jax.random.* would false-positive)
@@ -178,6 +190,25 @@ def rule_consumer_registered(relpath: str, src: str) -> list[str]:
     return out
 
 
+def rule_model_name_free(relpath: str, src: str) -> list[str]:
+    out = []
+    for node in ast.walk(ast.parse(src)):
+        if not isinstance(node, ast.Compare):
+            continue
+        for operand in (node.left, *node.comparators):
+            items = (operand.elts if isinstance(
+                operand, (ast.Tuple, ast.List, ast.Set)) else [operand])
+            names = [e.value for e in items if isinstance(e, ast.Constant)
+                     and e.value in MODEL_NAMES]
+            if names:
+                out.append(f"{relpath}:{node.lineno}: compares against the "
+                           f"model name(s) {names} — read a field of the "
+                           "model's record or ModelSetup (models/setup.py) "
+                           "instead")
+                break
+    return out
+
+
 def rule_mode_flag_enumerated(sources: dict) -> list[str]:
     """Cross-file rule over ``MODE_FLAG_FILES``: takes ``{relpath: src}``."""
     from .modes import MODE_FLAGS, NON_AXIS_FLAGS
@@ -223,6 +254,9 @@ _SCOPES = {
         lambda p: p.startswith("sgcn_tpu/"),
     "mode-flag-enumerated":
         lambda p: p in MODE_FLAG_FILES,
+    "model-name-free":
+        lambda p: (p.startswith(NAME_FREE_PREFIXES)
+                   and p not in NAME_FREE_EXEMPT),
 }
 
 RULES = (
@@ -236,6 +270,10 @@ RULES = (
     Rule("mode-flag-enumerated",
          "train/serve CLIs (cross-file)",
          rule_mode_flag_enumerated),
+    Rule("model-name-free",
+         "sgcn_tpu/{train,parallel}/ minus train/__main__.py, "
+         "obs/memory.py, utils/checkpoint.py",
+         rule_model_name_free),
 )
 
 

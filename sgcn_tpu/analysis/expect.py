@@ -173,10 +173,10 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
         # full exact exchange
         rep_wire = bool(mode.replica) and not fresh
         # forward: every layer — but the exact full-batch trainer hoists an
-        # aggregate-first layer 0 out of the step (agg0_hoisted: Â·h0 is
+        # aggregate-first layer 0 out of the step (its Hoist: Â·h0 is
         # made once per data set), so its step starts at layer 1, the way
         # bwd_layers below already drops layer 0's dead backward
-        fwd_layers = range(1 if trainer.agg0_hoisted else 0, L)
+        fwd_layers = range(1 if trainer.hoist is not None else 0, L)
         for i in fwd_layers:
             exp.exchanges += _exchange_ops(plan, mode.schedule, fs[i], fdt,
                                            replica=rep_wire)
@@ -269,10 +269,14 @@ def train_expectation(trainer, mode, fresh: bool = False) -> Expectation:
     groups += [("keep", trainer.pa)]
     exp.args = _classify_args(groups)
     k, b = plan.k, plan.b
-    exp.args += [((k, b, trainer.fin), "f32", "keep")]   # h0
-    # a model's hoisted arrays ride beside h0 (models/setup.py::Hoist)
-    exp.args += [((k, *shape), "f32", "keep") for shape in (
-        trainer._hoist.shapes if trainer._hoist is not None else ())]
+    # a model's hoisted arrays ride beside h0, or in its place
+    # (models/setup.py::Hoist)
+    hoist = trainer.hoist
+    built = [((k, *shape), "f32", "keep")
+             for shape in (hoist.shapes if hoist is not None else ())]
+    if hoist is None or not hoist.replaces:
+        built.insert(0, ((k, b, trainer.fin), "f32", "keep"))   # h0
+    exp.args += built
     exp.args += [((k, b), "i32", "keep"),                # labels
                  ((k, b), "f32", "keep")]                # valid
     return exp

@@ -77,7 +77,8 @@ from ..obs.tracing import scope, subscope
 from ..ops.pspmm import (_FOLD_SCAN_LIVE, _SCAN_LIVE_LIMIT, pass_store_forms,
                          pspmm_ell_sym_detached)
 from ..parallel.mesh import AXIS, vary
-from .setup import ModelSetup, plan_true_edges, slot_pass, slot_work
+from .setup import (ModelSetup, Request, plan_true_edges,
+                    refuse_beyond_full_f32, slot_pass, slot_work)
 
 # the exact GCN step's slot-form arrays (``GCN_PLAN_FIELDS_SLOTS``), every
 # weight array narrowed to a 0/1 mask (``ModelSetup.mask_fields``), and the
@@ -409,9 +410,8 @@ def slot_passes(plan, layers: int, hidden: int, keep: str,
 
 
 # -------------------------------------------------------------- the registry
-def model_setup(plan, fin: int, widths, model_args: dict | None, *,
-                comm_schedule: str, compute_dtype, serve_subgraph: bool
-                ) -> ModelSetup:
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
     """The ``MODELS`` entry's setup hook (``models/setup.py``): validates
     ``model_args``, refuses what the stack has no form for, builds the
     plan's slot-form fold stores, and hands the shared code the statics,
@@ -421,14 +421,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         raise ValueError(
             "deepergcn's gather-only backward needs a symmetric edge "
             "pattern; this plan is asymmetric (models/deepergcn.py)")
-    if comm_schedule != "a2a" or serve_subgraph:
-        raise ValueError(
-            "deepergcn runs the dense a2a schedule and the full forward "
-            f"only (comm_schedule={comm_schedule!r}, "
-            f"serve_subgraph={serve_subgraph})")
-    if compute_dtype is not None:
-        raise ValueError(
-            f"deepergcn is float32 only (compute_dtype={compute_dtype!r})")
+    refuse_beyond_full_f32("deepergcn", request)
     args = resolve_args(widths, model_args)
     layers, hidden, keep = args["layers"], args["hidden"], args["keep"]
     classes = int(list(widths)[-1])
@@ -455,8 +448,10 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         # stabiliser's max once a layer; recomputation runs none
         "stat_collectives_per_step": {"psum": 4 * layers, "pmax": layers},
     }
-    statics = {**args, "n_rows": int(plan.n), "fold_classes": fold_classes}
+    statics = {"ell_buckets": plan.ell_buckets, **args,
+               "n_rows": int(plan.n), "fold_classes": fold_classes}
     return ModelSetup(
+        plan_fields=DEEPERGCN_PLAN_FIELDS,
         fwd_static=statics,
         init_static={"layers": layers, "hidden": hidden},
         extra_arrays={},
@@ -469,5 +464,4 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         counters={"deep.work": counter,
                   "slots.work": slot_work(slot_passes(
                       plan, layers, hidden, keep, fold_classes))},
-        allow_pallas=False,         # no VMEM form of the two-width rule
         checkpointed=True)          # per layer, always: remat=True refused

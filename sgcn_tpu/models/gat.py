@@ -34,6 +34,8 @@ from ..ops.pspmm import (a2a_or_identity, halo_exchange, halo_exchange_ragged,
                          halo_exchange_ragged_multi)
 from ..parallel.mesh import AXIS, vary
 from .activations import get_activation
+from .setup import (ModelSetup, Request, refuse_args, refuse_carried_halo,
+                    select_pallas)
 
 # plan arrays the GAT forward consumes (fullbatch ships exactly these):
 # the bucketed combined-edge layout plus its hub tail
@@ -929,3 +931,60 @@ def gat_forward_local(
     if collect_stabilizers:
         return h, jnp.stack(cgs)
     return h
+
+
+# -------------------------------------------------------------- the registry
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
+    """The ``MODELS`` entry's setup hook (``models/setup.py``): the
+    combined-edge layout (built lazily: only GAT ships it, and it duplicates
+    the edge storage), its ragged-ring and Pallas-tile forms, the int8 edge
+    masks, the attention tables' lanes, the parameter count, the per-slot
+    score workspace and the capacity pre-flight."""
+    refuse_args("gat", model_args)
+    refuse_carried_halo(request)
+    fields = GAT_PLAN_FIELDS
+    static = {"cell_buckets": plan.ensure_cell().cell_buckets}
+    ragged = request.comm_schedule == "ragged"
+    if ragged:
+        # the attention tables ride the plan's model-independent per-vertex
+        # ring layout; the combined slot passes are schedule-blind, so only
+        # the shipped exchange arrays and the static ring spec change
+        fields = GAT_PLAN_FIELDS_RAGGED
+        static = dict(static, comm_schedule="ragged", rr_sizes=plan.rr_sizes,
+                      halo_r=plan.r)
+    pallas = select_pallas(plan, fin, widths, "gat", request)
+    if pallas is not None:
+        fields = GAT_PLAN_FIELDS_PALLAS_RAGGED if ragged \
+            else GAT_PLAN_FIELDS_PALLAS
+        static = dict(cell_buckets=plan.cell_buckets, **pallas)
+        if ragged:      # the ring concat needs only rr_sizes
+            static.update(comm_schedule="ragged", rr_sizes=plan.rr_sizes)
+    lanes = tuple(gat_exchange_lane_widths(widths, request.compute_dtype))
+    slots = sum(nb * wb for nb, wb in plan.cell_buckets) + int(plan.ctl or 0)
+    isize = 2 if request.compute_dtype == "bfloat16" else 4
+    tail = int(plan.ctail_nnz.max()) if plan.ctail_nnz is not None else 0
+    dims = list(zip([fin] + list(widths[:-1]), widths))
+
+    def preflight(device) -> None:
+        # the measured single-chip capacity edge: a clear error beats a
+        # compile OOM or a dead TPU worker, both seen at products scale
+        check_gat_memory(device, plan.b, int(plan.halo_counts.max()), fin,
+                         widths, nnz=int(plan.nnz.max()), tail=tail,
+                         dtype=request.compute_dtype)
+
+    return ModelSetup(
+        plan_fields=fields, fwd_static=static, init_static={},
+        extra_arrays={},
+        # attention ignores Â's values: masks on w != 0 (plan padding
+        # carries weight exactly 0, so every real edge survives even on a
+        # signed graph), the Pallas tiles' 0/1 mask too; the f32 forms are
+        # ~0.6 GB of per-chip arguments at products scale
+        mask_fields=tuple(f for f in ("cell_w", "ctail_w", "ptile_cw")
+                          if f in fields),
+        lane_widths=lanes, lane_widths_bwd=(),
+        param_count=sum(a * b + 2 * b for a, b in dims),
+        counters={"slots.work": None},
+        # the edge-softmax's per-slot scores over the combined layout
+        slot_bytes=slots * max(lanes) * isize,
+        preflight=preflight)

@@ -30,7 +30,8 @@ from ..ops.pspmm import (pass_store_forms, pspmm_ell_sym, pspmm_ell_sym_coo,
 from ..obs.tracing import scope
 from ..parallel.mesh import AXIS
 from .activations import get_activation
-from .setup import plan_true_edges, slot_pass
+from .setup import (Hoist, ModelSetup, Request, plan_true_edges, refuse_args,
+                    select_pallas, slot_pass, slot_work)
 
 # plan arrays the GCN forward consumes (fullbatch ships exactly these).
 # Symmetric Â takes the ELL + symmetric-backward fast path; general Â the
@@ -42,7 +43,7 @@ GCN_PLAN_FIELDS_SYM = ("send_idx", "halo_src", "ell_idx", "ell_w",
                        "hedge_dst", "hedge_src", "hedge_w")
 # the exact full-batch step on symmetric Â: the same exchange and ELL, the
 # hub tail and the halo-source edges in slot form instead of the COO lists
-# (CommPlan.ensure_fold_slots; chosen by resolve_forward_setup)
+# (CommPlan.ensure_fold_slots; chosen by model_setup)
 GCN_PLAN_FIELDS_SLOTS = ("send_idx", "halo_src", "ell_idx", "ell_w",
                          "ft_idx", "ft_w", "ft_row",
                          "fh_idx", "fh_w", "fh_row")
@@ -52,9 +53,6 @@ GCN_PLAN_FIELDS_RAGGED = ("rsend_idx", "ell_idx", "ell_w",
                           "ltail_dst", "ltail_src", "ltail_w",
                           "redge_dst", "redge_src", "redge_w")
 
-
-def gcn_plan_fields(plan):
-    return GCN_PLAN_FIELDS_SYM if plan.symmetric else GCN_PLAN_FIELDS_GEN
 
 # minimum input width (f32 elements) for the project-before-aggregate layer
 # order to win: below this, random row gathers are HBM-access-bound, so
@@ -70,8 +68,8 @@ def exchange_widths(fin: int, widths) -> list[int]:
 
     Where layer 0 is aggregate-first (``out[0] == fin``), the exact
     full-batch GCN trainer pays layer 0's entry ONCE PER DATA SET, not per
-    step: ``agg(h0)`` is loop-invariant there and ``FullBatchTrainer``
-    hoists it (``gcn_forward_local(input_aggregated=True)``).  The entry
+    step: ``agg(h0)`` is loop-invariant there and ``model_setup``'s
+    ``Hoist`` makes it outside the step.  The entry
     keeps its meaning — the width that exchange has whenever it runs (the
     one-off build, the stale/replica families, serving, mini-batch)."""
     out, f = [], fin
@@ -223,7 +221,7 @@ def _gcn_aggregator(
 def gcn_forward_local(
     params,
     h,                      # (B, f_in) local feature rows
-    pa,                     # plan arrays dict (gcn_plan_fields(plan))
+    pa,                     # plan arrays dict (model_setup's fields)
     activation: str = "relu",
     final_activation: str = "none",
     symmetric: bool = False,
@@ -248,9 +246,9 @@ def gcn_forward_local(
                                         # (GCN_PLAN_FIELDS_SLOTS); None: the
                                         # COO lists of GCN_PLAN_FIELDS_SYM
     axis_name: str = AXIS,
-    input_aggregated: bool = False,     # static: ``h`` is already Â·h0
-                                        # (gcn_aggregate_local) — layer 0
-                                        # goes straight to its dense product
+    hoisted: tuple | None = None,       # (Â·h0,), made by the Hoist of
+                                        # model_setup — layer 0 goes
+                                        # straight to its dense product
 ):
     """Per-chip forward: L × (pspmm ⊗ dense matmul → activation) → (B, nout).
 
@@ -269,16 +267,15 @@ def gcn_forward_local(
     pay and aggregate-first (the reference's fixed order,
     ``GPU/PGCN.py:144-148``) is kept.  Identical math either way.
 
-    ``input_aggregated=True`` says ``h`` is not ``h0`` but ``agg(h0)``,
-    made once by ``gcn_aggregate_local`` with these same statics: layer 0
-    then skips its aggregation (in full-batch training ``h0`` and Â never
+    ``hoisted=(Â·h0,)`` takes the place of ``h`` (then ``None``): made
+    once by ``gcn_aggregate_local`` with these same statics, so layer 0
+    skips its aggregation (in full-batch training ``h0`` and Â never
     change, so ``Â·h0`` is the same array every step — and with it go layer
     0's exchange and folds, forward and, under ``remat``, backward).  Legal
     only where layer 0 is aggregate-first (``exchange_widths(fin,
     widths)[0] == fin``): under project-first layer 0 aggregates
-    ``h0·W⁰``, which moves with the weights, and the call raises.  Off (the
-    default) is the program every caller compiled before the argument
-    existed; only the exact full-batch trainer sets it.
+    ``h0·W⁰``, which moves with the weights, and the call raises.  Only the
+    exact full-batch trainer passes it (``model_setup``'s ``Hoist``).
     """
     act = get_activation(activation)
     fact = get_activation(final_activation)
@@ -292,19 +289,21 @@ def gcn_forward_local(
         rr_sizes=rr_sizes, rr_edge_sizes=rr_edge_sizes,
         fold_classes=fold_classes, axis_name=axis_name)
 
+    if hoisted is not None:
+        (h,) = hoisted
     for i, w in enumerate(params):
         with scope("layer", i):
             if w.shape[1] < h.shape[1] and h.shape[1] >= PROJECT_FIRST_MIN_FIN:
-                if i == 0 and input_aggregated:
+                if i == 0 and hoisted is not None:
                     raise ValueError(
-                        "input_aggregated needs an aggregate-first layer 0; "
+                        "a hoisted Â·h0 needs an aggregate-first layer 0; "
                         f"widths {h.shape[1]} -> {w.shape[1]} project first, "
                         "and agg(h0 @ W) moves with the weights")
                 with scope("dense"):
                     x = h @ w
                 z = agg(x)
             else:
-                z = h if (i == 0 and input_aggregated) else agg(h)
+                z = h if (i == 0 and hoisted is not None) else agg(h)
                 with scope("dense"):
                     z = z @ w
             h = fact(z) if i == nl - 1 else act(z)
@@ -313,7 +312,7 @@ def gcn_forward_local(
 
 def gcn_aggregate_local(h, pa, **statics):
     """``agg(h0)`` alone: layer 0's aggregation of ``gcn_forward_local`` as
-    a per-chip program of its own, for ``input_aggregated=True``.
+    a per-chip program of its own, for its ``hoisted=``.
     ``statics`` are the forward's aggregator statics (``symmetric``,
     ``ell_buckets``, the Pallas and ragged ones, ``halo_dtype``), so the
     same kernel and the same wire make the array, under the scopes layer 0
@@ -595,6 +594,115 @@ def gcn_forward_local_replica(
     if track_base:
         return h, new_reps, new_bases, nships
     return h, new_reps
+
+
+# -------------------------------------------------------------- the registry
+def _agg0(pa, h0, statics: dict) -> tuple:
+    """``gcn``'s ``Hoist`` build: ``(Â·h0,)`` in f32."""
+    return (gcn_aggregate_local(h0, pa, **statics).astype("float32"),)
+
+
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
+    """The ``MODELS`` entry's setup hook (``models/setup.py``): the plan
+    arrays and statics of the step the request asks for — the ELL exchange
+    (symmetric Â) or the split COO lists, the ragged ring, the replica and
+    stale × ragged contract tuples, the Pallas VMEM aggregator, or the exact
+    step's slot-form fold — layer 0's ``Â·h0`` as a ``Hoist``, the wire's
+    lanes and itemsizes, and the counter ``slots.work``."""
+    refuse_args("gcn", model_args)
+    sched, stale = request.comm_schedule, request.halo_staleness
+    budget, ragged = request.replica_budget, sched == "ragged"
+    fields, static = ((GCN_PLAN_FIELDS_SYM, {"ell_buckets": plan.ell_buckets})
+                      if plan.symmetric else (GCN_PLAN_FIELDS_GEN, {}))
+    if ragged:
+        # the fold-as-you-arrive scatter over the per-owner edge split; the
+        # composed (stale × ragged) step ships the same ring arrays under
+        # its own contract tuple
+        from ..parallel.plan import STALE_PLAN_FIELDS_RAGGED
+        fields = STALE_PLAN_FIELDS_RAGGED if stale else GCN_PLAN_FIELDS_RAGGED
+        static = {"ell_buckets": plan.ell_buckets, "comm_schedule": "ragged",
+                  "rr_sizes": plan.rr_sizes,
+                  "rr_edge_sizes": plan.rr_edge_sizes}
+    pallas = None
+    if budget:
+        # hot-halo replication (docs/replication.md): the UNION of the full
+        # exchange layout (the refresh program = the exact program + replica
+        # gathers; evaluate() rides it) and the shrunken no-replica layout;
+        # the statics stay the exact forward's (the replica-only ones live
+        # on the trainer).  The composed (replica × stale) step ships its
+        # own tuples: the stale carry subsumes the replica tables, and the
+        # ragged flavour adds the carry scatter map ``nrep_ring_dst``;
+        # ``refresh_band`` adds the partial refresh's side channel
+        from ..parallel.plan import (REPLICA_PARTIAL_PLAN_FIELDS,
+                                     REPLICA_PLAN_FIELDS,
+                                     REPLICA_PLAN_FIELDS_RAGGED,
+                                     REPLICA_STALE_PLAN_FIELDS,
+                                     REPLICA_STALE_PLAN_FIELDS_RAGGED)
+        plan.ensure_replicas(budget)
+        if stale:
+            fields = (REPLICA_STALE_PLAN_FIELDS_RAGGED if ragged
+                      else REPLICA_STALE_PLAN_FIELDS)
+        elif request.refresh_band is not None:
+            fields = REPLICA_PARTIAL_PLAN_FIELDS
+        else:
+            fields = (REPLICA_PLAN_FIELDS_RAGGED if ragged
+                      else REPLICA_PLAN_FIELDS)
+    elif not stale:
+        # the carried families keep the ELL aggregator: their carry
+        # contracts are built around it
+        pallas = select_pallas(plan, fin, widths, "gcn", request)
+    if pallas is not None:
+        from ..ops.pallas_spmm import (PALLAS_PLAN_FIELDS,
+                                       PALLAS_PLAN_FIELDS_RAGGED)
+        fields = PALLAS_PLAN_FIELDS_RAGGED if ragged else PALLAS_PLAN_FIELDS
+        static = dict(pallas)
+        if ragged:      # the ring concat needs only rr_sizes
+            static.update(comm_schedule="ragged", rr_sizes=plan.rr_sizes)
+    elif (plan.symmetric and sched == "a2a" and not stale and not budget
+            and not request.serve_subgraph and not request.shared_envelope):
+        # the exact step folds the hub tail and the halo-source edges as
+        # slot passes over virtual rows, INSTEAD of the COO lists (a fold by
+        # scatter-add costs two slots an edge: PERF.md §6).  The
+        # carried families fold inside their own ops, sub-graph serving
+        # mirrors the COO chains row by row, and a shared envelope has no
+        # virtual-row count: they keep the lists
+        plan.ensure_fold_slots()
+        fields = GCN_PLAN_FIELDS_SLOTS
+        static = dict(static, fold_classes=(plan.fold_tail_classes,
+                                            plan.fold_halo_classes))
+    lanes = tuple(exchange_widths(fin, widths))
+    hoist = None
+    if (not stale and not budget and not request.shared_envelope
+            and lanes[0] == fin):
+        # full-batch h0 and Â never change: layer 0's Â·h0 is made once per
+        # h0 object, by the forward's own aggregator and statics (a shared
+        # envelope brings new data every batch)
+        statics = dict(symmetric=plan.symmetric, **static)
+        if request.halo_dtype is not None:
+            statics["halo_dtype"] = request.halo_dtype
+        rows = int(plan.b * plan.k)
+        hoist = Hoist(
+            build=partial(_agg0, statics=statics), shapes=((plan.b, fin),),
+            span="agg0.build", counter="agg0", replaces=True,
+            report=lambda builds, served: {
+                "engaged": True, "builds": builds, "steps_served": served,
+                "rows": rows, "width": int(fin)})
+    # the halo-delta cache narrows only the feature wire; the gradient wire
+    # follows halo_dtype
+    narrow = "bfloat16" in (request.halo_dtype, request.compute_dtype)
+    dims = list(zip([fin] + list(widths[:-1]), widths))
+    return ModelSetup(
+        plan_fields=fields, fwd_static=static, init_static={},
+        extra_arrays={}, mask_fields=(), lane_widths=lanes,
+        lane_widths_bwd=(), param_count=sum(a * b for a, b in dims),
+        counters={"slots.work": slot_work(gcn_slot_passes(
+            plan, fin, widths, static["fold_classes"],
+            hoisted=hoist is not None, remat=request.remat))
+            if "fold_classes" in static else None},
+        wire_itemsize=(2 if narrow or request.halo_delta else 4,
+                       2 if narrow else 4),
+        hoist=hoist)
 
 
 def masked_softmax_xent_local(logits, labels, valid, axis_name: str = AXIS):

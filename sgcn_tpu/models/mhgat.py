@@ -86,7 +86,8 @@ from ..ops.pspmm import (bucket_forms, bucketed_slot_reduce,
                          fold_rows_scope, halo_exchange_multi)
 from ..parallel.mesh import AXIS
 from .activations import get_activation
-from .setup import ModelSetup, plan_true_edges, slot_pass, slot_work
+from .setup import (ModelSetup, Request, plan_true_edges,
+                    refuse_beyond_full_f32, slot_pass, slot_work)
 
 # plan arrays shipped as they are (``ell_w`` narrowed to a 0/1 mask by
 # ``ForwardSetup.ship_arrays``), and the virtual-row layouts of the hub
@@ -707,9 +708,8 @@ def estimate_mhgat_hbm_bytes(plan, fin: int, widths, heads, concat,
 
 
 # -------------------------------------------------------------- the registry
-def model_setup(plan, fin: int, widths, model_args: dict | None, *,
-                comm_schedule: str, compute_dtype, serve_subgraph: bool
-                ) -> ModelSetup:
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
     """The ``MODELS`` entry's setup hook (``models/setup.py``): validates
     ``model_args``, refuses what the layer has no form for, and hands the
     shared code the statics, the virtual-row arrays, the exchange's lane
@@ -719,14 +719,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         raise ValueError(
             "mhgat's gather-only backward needs a symmetric edge pattern; "
             "this plan is asymmetric (models/mhgat.py)")
-    if comm_schedule != "a2a" or serve_subgraph:
-        raise ValueError(
-            "mhgat runs the dense a2a schedule and the full forward only "
-            f"(comm_schedule={comm_schedule!r}, "
-            f"serve_subgraph={serve_subgraph})")
-    if compute_dtype is not None:
-        raise ValueError(
-            f"mhgat is float32 only (compute_dtype={compute_dtype!r})")
+    refuse_beyond_full_f32("mhgat", request)
     args = resolve_args(widths, model_args)
     hc = {"heads": args["heads"], "concat": args["concat"]}
     arrays, vshapes = plan_virtual_rows(plan)
@@ -752,7 +745,8 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                                if vshapes["halo_shape"] is not None else 0),
         "head_products": head_products()}
     return ModelSetup(
-        fwd_static={**args, **vshapes},
+        plan_fields=MHGAT_PLAN_FIELDS,
+        fwd_static={"ell_buckets": plan.ell_buckets, **args, **vshapes},
         init_static=args,
         extra_arrays=arrays,
         mask_fields=("ell_w",),     # attention reads Â's pattern only
@@ -763,5 +757,4 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
             estimate_mhgat_hbm_bytes, plan, fin, widths, **hc, **vshapes),
         counters={"att.work": counter,
                   "slots.work": slot_work(slot_passes(
-                      plan, fin, widths, **hc, **vshapes))},
-        allow_pallas=False)         # no VMEM form of the per-edge softmax
+                      plan, fin, widths, **hc, **vshapes))})

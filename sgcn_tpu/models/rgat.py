@@ -68,7 +68,8 @@ from ..ops.pspmm import halo_exchange_multi
 from ..parallel.mesh import AXIS
 from . import deepergcn, mhgat, rgcn
 from .activations import get_activation
-from .setup import ModelSetup, slot_pass, slot_work
+from .setup import (ModelSetup, Request, refuse_beyond_full_f32,
+                    slot_pass, slot_work)
 
 STORES = rgcn.STORES            # ELL slots, the runs past them, halo edges
 HEAD = {"norm": "batch", "activation": "relu"}   # the published head MLP
@@ -500,9 +501,8 @@ def rel_passes(args: dict, spec: RattSpec, layout: dict) -> tuple:
     return passes, rows
 
 
-def model_setup(plan, fin: int, widths, model_args: dict | None, *,
-                comm_schedule: str, compute_dtype, serve_subgraph: bool
-                ) -> ModelSetup:
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
     """The ``MODELS`` entry's setup hook (``models/setup.py``): validates
     ``model_args``, refuses what the model has no form for, derives rgcn's
     typed layout from the plan, and hands the shared code the statics, the
@@ -514,14 +514,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         raise ValueError(
             "rgat walks a relation's reverse pair for its backward; this "
             "plan is asymmetric (models/rgat.py)")
-    if comm_schedule != "a2a" or serve_subgraph:
-        raise ValueError(
-            "rgat runs the dense a2a schedule and the full forward only "
-            f"(comm_schedule={comm_schedule!r}, "
-            f"serve_subgraph={serve_subgraph})")
-    if compute_dtype is not None:
-        raise ValueError(
-            f"rgat is float32 only (compute_dtype={compute_dtype!r})")
+    refuse_beyond_full_f32("rgat", request)
     args = resolve_args(fin, widths, model_args)
     layout = rgcn.build_typed_layout(plan, args)
     spec = layer_plan(args, layout)
@@ -555,6 +548,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
     statics = {"relations": rels, "label": label, "heads": k,
                "slope": args["slope"], "spec": spec}
     return ModelSetup(
+        plan_fields=rgcn.RGCN_PLAN_FIELDS,
         fwd_static=statics,
         init_static={"relations": rels, "heads": k},
         extra_arrays=extra,
@@ -569,7 +563,6 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                   "slots.work": slot_work(passes, {
                       tag: _pair_name(tag, rels) for p in passes
                       for tag in p["tags"] if tag.startswith("pair_")})},
-        allow_pallas=False,         # no VMEM form of the per-edge softmax
         out_rows=("ratt_out_rows", "ratt_out_valid"))
 
 

@@ -95,7 +95,8 @@ from ..parallel.plan import (FOLD_ROW_COST, UNSNAPPED, _build_ell,
                              choose_fold_widths, fold_class_shapes,
                              padding_rows, snap_rows)
 from .activations import get_activation
-from .setup import Hoist, ModelSetup, slot_pass, slot_work
+from .setup import (Hoist, ModelSetup, Request, refuse_beyond_full_f32,
+                    slot_pass, slot_work)
 
 RGCN_PLAN_FIELDS = ("halo_src",)
 INPUTS = ("features", "embedding")
@@ -758,9 +759,8 @@ def estimate_rgcn_hbm_bytes(plan, fin: int, widths, args: dict, layout: dict,
 
 
 # -------------------------------------------------------------- the registry
-def model_setup(plan, fin: int, widths, model_args: dict | None, *,
-                comm_schedule: str, compute_dtype, serve_subgraph: bool
-                ) -> ModelSetup:
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
     """The ``MODELS`` entry's setup hook (``models/setup.py``): validates
     ``model_args``, refuses what the model has no form for, derives the
     typed layout from the plan, and hands the shared code the statics, the
@@ -771,14 +771,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         raise ValueError(
             "rgcn reads a relation and its reverse off one symmetric "
             "pattern; this plan is asymmetric (models/rgcn.py)")
-    if comm_schedule != "a2a" or serve_subgraph:
-        raise ValueError(
-            "rgcn runs the dense a2a schedule and the full forward only "
-            f"(comm_schedule={comm_schedule!r}, "
-            f"serve_subgraph={serve_subgraph})")
-    if compute_dtype is not None:
-        raise ValueError(
-            f"rgcn is float32 only (compute_dtype={compute_dtype!r})")
+    refuse_beyond_full_f32("rgcn", request)
     args = resolve_args(fin, widths, model_args)
     layout = build_typed_layout(plan, args)
     specs = layer_specs(args, layout)
@@ -843,6 +836,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                                        "steps_served": steps}}
 
     return ModelSetup(
+        plan_fields=RGCN_PLAN_FIELDS,
         fwd_static=statics,
         init_static={"types": types, "relations": rels,
                      "table_rows": tuple(layout["table_rows"])},
@@ -855,7 +849,6 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         counters={"rel.work": counter,
                   "slots.work": slot_work(*slot_passes(
                       args, layout, specs, [a for a, _ in dims]))},
-        allow_pallas=False,
         row_owned={"emb": {n: layout["table_rows"][t]
                            for t, (n, _, kind) in enumerate(types)
                            if kind == "embedding"}},

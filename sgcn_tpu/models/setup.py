@@ -1,13 +1,14 @@
-"""What a model with a configuration of its own hands the shared code.
+"""What every model hands the shared code, and the record that names it.
 
-A ``MODELS`` entry (``train/fullbatch.py``) may carry a fifth element, a
-setup hook ``hook(plan, fin, widths, model_args, *, comm_schedule,
-compute_dtype, serve_subgraph) -> ModelSetup``.  The hook validates
-``model_args``, refuses the modes the model has no form for, and returns
-everything the trainer, the memory model and ``CommStats`` would otherwise
-have to know the model's name for; they read the fields below and never
-compare names.  Models without a hook (``gcn``, ``gat``) take no
-``model_args``.
+A ``MODELS`` entry (``train/fullbatch.py``) is a ``Model`` record: the
+model's init and per-chip forward, a few facts the callers read before any
+plan exists, and its setup hook ``hook(plan, fin, widths, model_args,
+request) -> ModelSetup``.  Every model has one.  The hook validates
+``model_args``, refuses the ``Request``s the model has no form for, picks
+the plan arrays and statics its forward reads, and returns everything the
+trainer, the memory model and ``CommStats`` would otherwise have to know the
+model's name for; they read the fields below and never compare names.  A new
+model adds its module (forward, init, hook) and its ``MODELS`` record.
 """
 
 from __future__ import annotations
@@ -19,40 +20,88 @@ from ..obs.tracing import form_token
 
 
 @dataclasses.dataclass(frozen=True)
+class Model:
+    """One ``MODELS`` entry."""
+    init: Callable              # (rng, dims, **ModelSetup.init_static) -> params
+    forward: Callable           # the per-chip forward
+    hook: Callable              # -> ModelSetup (module docstring)
+    ragged: bool = False        # has a ragged transport (resolve_comm_schedule)
+    combined_edges: bool = False  # reads the combined-edge layout
+    #                             (``CommPlan.ensure_cell``): a caller that pads
+    #                             many plans to one envelope builds it first,
+    #                             with buckets they share
+    full_batch_only: bool = False  # no mini-batch trainer, no serve engine
+    activation: str = "relu"    # the train CLI's default ``--activation``
+
+
+@dataclasses.dataclass(frozen=True)
+class Request:
+    """What the caller asks of a hook, beside the plan and ``model_args``."""
+    comm_schedule: str          # resolved: 'a2a' or 'ragged'
+    compute_dtype: str | None = None
+    halo_dtype: str | None = None
+    halo_staleness: int = 0
+    halo_delta: bool = False
+    replica_budget: int = 0     # resolved: 'auto' -> the knee B
+    refresh_band: float | None = None
+    remat: bool = False
+    serve_subgraph: bool = False
+    allow_pallas: bool = True   # whether a Pallas aggregator may be selected
+    shared_envelope: bool = False  # one program for many padded plans
+    decision: dict = dataclasses.field(default_factory=dict)
+    #                             the resolver's selection log (the Pallas
+    #                             dispatch adds to it)
+
+
+@dataclasses.dataclass(frozen=True)
 class Hoist:
     """Work of a model's forward that reads data alone (the trainer's
     ``h0`` and the shipped arrays), made once per ``h0`` object outside the
     step: the trainer runs ``build(pa, h0)`` per chip under the step's
-    ``shard_map`` — compiled, called once and dropped, in the program span
-    ``span`` — keeps the tuple of arrays it returns (per chip ``shapes``) on
-    the device, and hands it to every exact program beside ``h0``, whose
-    forward gets it as ``hoisted=``; after each build or use it sets the
-    program counter ``counter`` to ``report(builds, steps_served)``."""
+    ``shard_map`` (after the ``compute_dtype`` cast) — compiled, called once
+    and dropped, in the program span ``span`` — keeps the tuple of arrays it
+    returns (per chip ``shapes``) on the device, and hands it to every exact
+    program beside ``h0`` or, where ``replaces``, in its place; the forward
+    gets it as ``hoisted=`` (and ``h0=None`` where it replaces).  After each
+    build or use the trainer sets the program counter ``counter`` to
+    ``report(builds, steps_served)``."""
     build: Callable
     shapes: tuple
     span: str
     counter: str
     report: Callable
+    replaces: bool = False      # what it builds takes h0's place (gcn: Â·h0)
 
 
 @dataclasses.dataclass(frozen=True)
 class ModelSetup:
-    fwd_static: dict            # statics of the forward, beside the registry's
+    plan_fields: tuple          # CommPlan array fields the forward reads
+    fwd_static: dict            # statics of the forward
     init_static: dict           # keyword arguments bound into the init function
     extra_arrays: dict          # arrays derived from the plan, shipped per chip
-    #                             beside the registry's plan fields
+    #                             beside the plan fields
     mask_fields: tuple          # shipped plan fields narrowed to an int8 0/1 mask
     lane_widths: tuple          # f32 lanes of each layer's exchange, forward
-    lane_widths_bwd: tuple      # ... and backward (CommStats books each)
+    lane_widths_bwd: tuple      # ... and backward (CommStats books each;
+    #                             empty: the forward's)
     param_count: int            # parameters of the whole model
-    estimate_memory: Callable   # (train: bool) -> itemised per-chip HBM bytes,
-    #                             with "total", "rows_kept", "rows_transient",
-    #                             "slot_temps" (obs/memory.py's workspace)
-    #                             and, where leaves are row-owned,
-    #                             "param_bytes": the tree's bytes on ONE chip
     counters: dict              # program counters the trainer leaves
     #                             (obs.tracing.set_counter), name -> value
-    allow_pallas: bool          # whether a Pallas aggregator may be selected
+    wire_itemsize: tuple = (4, 4)  # bytes a lane takes on the wire, forward
+    #                             and backward
+    estimate_memory: Callable | None = None  # (train: bool) -> itemised
+    #                             per-chip HBM bytes, with "total",
+    #                             "rows_kept", "rows_transient", "slot_temps"
+    #                             (obs/memory.py's workspace) and, where
+    #                             leaves are row-owned, "param_bytes": the
+    #                             tree's bytes on ONE chip; the trainer fences
+    #                             it (check_memory).  None: obs/memory.py's
+    #                             rule of rows
+    preflight: Callable | None = None  # (device) -> None: the model's own
+    #                             capacity fence, before any array ships
+    slot_bytes: int = 0         # bytes one pass materialises per chip over
+    #                             its slots beside the rows (GAT's per-slot
+    #                             scores; obs/memory.py's workspace)
     checkpointed: bool = False  # the forward checkpoints its own layers: the
     #                             trainer's whole-forward ``remat`` is refused
     row_owned: dict = dataclasses.field(default_factory=dict)
@@ -77,6 +126,74 @@ class ModelSetup:
     #                             trainer hands the forward ``label_ids`` (the
     #                             label there, -1 elsewhere) and leaves those
     #                             rows out of the loss
+
+
+def refuse_args(name: str, model_args: dict | None) -> None:
+    """A model with no configuration of its own refuses any."""
+    if model_args:
+        raise ValueError(f"model {name!r} takes no model_args "
+                         f"(got {sorted(model_args)})")
+
+
+def refuse_carried_halo(request: Request) -> None:
+    """Refuse what only ``gcn`` has a form for: the narrowed wire and the
+    carried-halo families (stale halo, halo-delta, replicas)."""
+    if request.halo_dtype is not None:
+        raise ValueError(
+            "halo_dtype is a GCN-trainer lever; for GAT use "
+            "compute_dtype='bfloat16' (the packed exchange already "
+            "ships half-width rows)")
+    if request.replica_budget:
+        raise ValueError(
+            "replica_budget replicates rows of the GCN feature "
+            "exchange; the GAT exchange ships per-layer attention "
+            "tables whose replication is not supported")
+    if request.halo_staleness:
+        raise ValueError(
+            "halo_staleness=1 pipelines the GCN hot path; the GAT "
+            "exchange ships per-layer attention tables whose "
+            "staleness is not supported (models/gat.py)")
+
+
+def refuse_beyond_full_f32(name: str, request: Request) -> None:
+    """What the models of one full-batch f32 form refuse: the carried
+    halos, the ragged ring, sub-graph serving and a narrowed compute
+    dtype."""
+    refuse_carried_halo(request)
+    if request.comm_schedule != "a2a" or request.serve_subgraph:
+        raise ValueError(
+            f"{name} runs the dense a2a schedule and the full forward only "
+            f"(comm_schedule={request.comm_schedule!r}, "
+            f"serve_subgraph={request.serve_subgraph})")
+    if request.compute_dtype is not None:
+        raise ValueError(f"{name} is float32 only "
+                         f"(compute_dtype={request.compute_dtype!r})")
+
+
+def select_pallas(plan, fin: int, widths, model: str,
+                  request: Request) -> dict | None:
+    """The Pallas VMEM aggregator's statics where the plan-driven rule
+    (``ops.pallas_spmm.use_pallas_spmm``: per-chip tables in the VMEM
+    regime, either transport) selects it for ``model`` and the request
+    allows it, the per-class dispatch logged into ``request.decision``;
+    ``None`` otherwise.  Sub-graph serving reproduces the ELL fold's
+    per-row chains (``serve/subgraph.py``), which the tile fold does not,
+    so it refuses a plan the rule would put on the kernel."""
+    if not request.allow_pallas:
+        return None
+    from ..ops.pallas_spmm import choose_pallas_dispatch, use_pallas_spmm
+    if not use_pallas_spmm(plan, fin, widths, model=model,
+                           compute_dtype=request.compute_dtype,
+                           schedule=request.comm_schedule):
+        return None
+    if request.serve_subgraph:
+        raise ValueError(
+            "sub-graph serving reproduces the ELL fold; this plan "
+            "resolved to the Pallas VMEM aggregator — serve with "
+            "mode='full' or set SGCN_PALLAS_SPMM=0")
+    return choose_pallas_dispatch(plan, model=model,
+                                  schedule=request.comm_schedule,
+                                  decision=request.decision)
 
 
 def slot_pass(layer: int, way: str, lanes: int, stores: dict, *, tags=(),

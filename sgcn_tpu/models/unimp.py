@@ -80,7 +80,8 @@ from ..ops.pspmm import halo_exchange_multi
 from ..parallel.mesh import AXIS
 from . import mhgat
 from .activations import get_activation
-from .setup import ModelSetup, plan_true_edges, slot_pass, slot_work
+from .setup import (ModelSetup, Request, plan_true_edges,
+                    refuse_beyond_full_f32, slot_pass, slot_work)
 
 # the plan fields mhgat ships; ``ell_w`` and the tail's mask arrive as 0/1
 # masks with the diagonal zeroed (``_masks``)
@@ -660,9 +661,8 @@ def _work(plan, fin, widths, heads, vshapes, true, diag) -> dict:
 
 
 # -------------------------------------------------------------- the registry
-def model_setup(plan, fin: int, widths, model_args: dict | None, *,
-                comm_schedule: str, compute_dtype, serve_subgraph: bool
-                ) -> ModelSetup:
+def model_setup(plan, fin: int, widths, model_args: dict | None,
+                request: Request) -> ModelSetup:
     """The ``MODELS`` entry's setup hook (``models/setup.py``): validates
     ``model_args``, refuses what the layer has no form for, and hands the
     shared code the statics, the diagonal-free masks and the virtual rows,
@@ -672,14 +672,7 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
         raise ValueError(
             "unimp's gather-only backward needs a symmetric edge pattern; "
             "this plan is asymmetric (models/unimp.py)")
-    if comm_schedule != "a2a" or serve_subgraph:
-        raise ValueError(
-            "unimp runs the dense a2a schedule and the full forward only "
-            f"(comm_schedule={comm_schedule!r}, "
-            f"serve_subgraph={serve_subgraph})")
-    if compute_dtype is not None:
-        raise ValueError(
-            f"unimp is float32 only (compute_dtype={compute_dtype!r})")
+    refuse_beyond_full_f32("unimp", request)
     args = resolve_args(widths, model_args)
     heads = args["heads"]
     arrays, vshapes = mhgat.plan_virtual_rows(plan)
@@ -692,7 +685,9 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
     true = [t - d for t, d in zip(plan_true_edges(plan), diag)]
     lanes_f, lanes_b = exchange_lane_widths(fin, widths, heads)
     return ModelSetup(
-        fwd_static={"heads": heads, **vshapes},
+        plan_fields=UNIMP_PLAN_FIELDS,
+        fwd_static={"ell_buckets": plan.ell_buckets, "heads": heads,
+                    **vshapes},
         init_static={"heads": heads},
         extra_arrays=arrays,
         mask_fields=(),             # ell_w arrives as a mask above
@@ -705,5 +700,4 @@ def model_setup(plan, fin: int, widths, model_args: dict | None, *,
                   "slots.work": slot_work(slot_passes(
                       plan, fin, widths, heads, **vshapes,
                       true_edges=true))},
-        allow_pallas=False,         # no VMEM form of the per-edge softmax
         label_input=LABEL_INPUT)

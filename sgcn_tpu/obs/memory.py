@@ -172,17 +172,6 @@ def _prod(shape) -> int:
     return out
 
 
-def model_param_bytes(fin: int, widths, model: str = "gcn") -> int:
-    """Replicated parameter bytes from the init formulas (f32 masters):
-    GCN one ``(fin, fout)`` Glorot matrix per layer
-    (``models/gcn.py::init_gcn_params`` — no bias); GAT adds the two
-    ``(fout,)`` attention vectors (``models/gat.py::init_gat_params``)."""
-    dims = list(zip([int(fin)] + [int(w) for w in widths][:-1],
-                    [int(w) for w in widths]))
-    per = [(fi * fo + (2 * fo if model == "gat" else 0)) for fi, fo in dims]
-    return 4 * sum(per)
-
-
 def memory_model(plan, fin: int, widths, *, workload: str = "train",
                  model: str = "gcn", comm_schedule: str = "a2a",
                  compute_dtype: str | None = None,
@@ -207,7 +196,8 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
             plan, fin, widths, model=model, comm_schedule=comm_schedule,
             compute_dtype=compute_dtype, halo_staleness=halo_staleness,
             replica_budget=replica_budget, refresh_band=refresh_band,
-            serve_subgraph=(workload == "serve_subgraph"))
+            serve_subgraph=(workload == "serve_subgraph"),
+            halo_dtype=halo_dtype, halo_delta=halo_delta)
     comm_schedule = setup.comm_schedule
     replica_budget = int(setup.replica_budget or 0)
     pallas = "pallas_tb" in setup.fwd_static
@@ -216,13 +206,13 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
     compute_isize = 2 if compute_dtype == "bfloat16" else 4
 
     families: dict[str, int] = {}
-    families["params"] = model_param_bytes(fin, widths, model=model)
-    custom = setup.custom       # a model with a setup hook prices itself
-    if custom is not None:
-        # (a model with leaves owned with the rows says what ONE chip holds
-        # of its tree: ``param_bytes`` of its estimate)
-        families["params"] = custom.estimate_memory(train=train).get(
-            "param_bytes", 4 * custom.param_count)
+    custom = setup.custom       # the model's hook prices what it knows
+    estimate = (custom.estimate_memory(train=train)
+                if custom.estimate_memory is not None else None)
+    # (a model with leaves owned with the rows says what ONE chip holds of
+    # its tree: ``param_bytes`` of its estimate; f32 masters otherwise)
+    families["params"] = (estimate or {}).get("param_bytes",
+                                              4 * custom.param_count)
     # Adam: count scalar + one mu and one nu tree (optax.adam — the only
     # optimizer the CLIs construct); inference carries no optimizer state
     families["opt_state"] = (2 * families["params"] + 4) if train else 0
@@ -238,22 +228,12 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
     families["plan_arrays"] = plan_b
     families["pallas_tiles"] = pallas_b
 
-    # per-layer exchanged row widths (f32-lane equivalents) + wire itemsize
-    # — the same split CommStats/step_cost price the wire with
-    if model == "gat":
-        from ..models.gat import gat_exchange_lane_widths
-        lane_widths = list(gat_exchange_lane_widths(widths, compute_dtype))
-        wire_isize = 4                        # lanes encode the dtype
-    elif custom is not None:
-        # the wider of the two directions' tables, layer by layer
-        lane_widths = [max(f, g) for f, g in zip(
-            custom.lane_widths, custom.lane_widths_bwd or custom.lane_widths)]
-        wire_isize = 4
-    else:
-        from ..models.gcn import exchange_widths
-        lane_widths = list(exchange_widths(fin, widths))
-        wire_isize = 2 if (halo_dtype == "bfloat16" or halo_delta
-                           or compute_dtype == "bfloat16") else 4
+    # per-layer exchanged row widths (f32-lane equivalents, the wider of
+    # the two directions' tables) + the feature wire's itemsize — the same
+    # split CommStats/step_cost price the wire with
+    lane_widths = [max(f, g) for f, g in zip(
+        custom.lane_widths, custom.lane_widths_bwd or custom.lane_widths)]
+    wire_isize = custom.wire_itemsize[0]
 
     # halo tables: the dense a2a aggregators gather a (R, f_ℓ) receive
     # table per exchange direction; the ragged ring folds receives as they
@@ -288,13 +268,10 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
     # working set, priced at the compute dtype over every layer width
     npass = 2 if train else 1
     workspace = npass * b * (fin + sum(widths)) * compute_isize
-    if model == "gat":
-        # the edge-softmax materializes per-slot attention scores over the
-        # combined-edge layout (cell slots + spill tail), per direction
-        slots = (sum(nb * wb for nb, wb in plan.cell_buckets)
-                 + int(plan.ctl or 0)) if plan.cell_buckets is not None else 0
-        workspace += npass * slots * max(lane_widths) * compute_isize
-    if custom is not None:
+    # the per-slot temporaries a pass materialises beside the rows (GAT's
+    # edge-softmax scores over the combined-edge layout), per direction
+    workspace += npass * custom.slot_bytes
+    if estimate is not None:
         # the model's own itemised estimate replaces the activation-mirror
         # figure (mhgat: per-row state kept for the backward and whole-row
         # slot temporaries; deepergcn: the rows its per-layer checkpoints
@@ -302,12 +279,11 @@ def memory_model(plan, fin: int, widths, *, workload: str = "train",
         # backward rows, the slot scans' budgets — the figure above would
         # price every layer's rows live at once, which is what per-layer
         # checkpointing exists to avoid)
-        est = custom.estimate_memory(train=train)
-        workspace = (est["rows_kept"] + est["rows_transient"]
-                     + est["slot_temps"])
+        workspace = (estimate["rows_kept"] + estimate["rows_transient"]
+                     + estimate["slot_temps"])
         # what a model makes once from the features and keeps beside them
         # (rgcn: layer 0's means over data; models/setup.py::Hoist)
-        families["features"] += est.get("hoisted", 0)
+        families["features"] += estimate.get("hoisted", 0)
     if pallas:
         # the VMEM kernel family's per-tile-block working set (operand
         # windows + accumulator at the tile row count ``pallas_tb``) — in

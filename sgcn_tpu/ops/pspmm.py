@@ -505,38 +505,33 @@ def fold_rows_scope():
 
 
 def fold_slots(out, table, idx, w, row, classes,
-               scan_live_limit: int = _FOLD_SCAN_LIVE, contrib=None):
+               scan_live_limit: int = _FOLD_SCAN_LIVE):
     """``out`` plus one COO edge store in slot form
     (``parallel.plan._build_virtual_rows``): every class ``(nv, W)`` of
     ``classes`` is a bucket of ``bucketed_slot_reduce`` (``take(table, idx)
     · w`` summed over its W slots, per virtual row), and one sorted
     scatter-add a class folds the ``nv`` row sums into their destinations
-    ``row``.  No classes, no pass.  Every class runs in the scan form: on
-    the v5e a scanned class of virtual rows cost 5–7 ns a slot where the
-    unrolled form of the same store cost 7–10 (PERF.md §6, PR 30, step 1:
-    gp4's tail at one width of 16, scanned, 18.5 ms a pass; at classes {8,
-    16}, fewer slots but unrolled, 29.9).  ``contrib(idx, w) -> rows shaped
-    like out's`` replaces the default ``take(table, idx) · w`` (the typed
-    aggregation decodes its slots itself; ``table`` is then unused), and
-    ``out`` may then be a pytree of row arrays."""
+    ``row``.  No classes, no pass.  Every class runs in the scan form, which
+    the v5e ran at fewer ns a slot than the unrolled form of the same store
+    when the choice was made (PERF.md §6); the ledger's ``fold_slot_ns`` of
+    the scanned classes reads 5.3263 ns (``products.fullbatch-gp4``) and
+    6.0476 ns (``products.fullbatch``) at 128 lanes f32, and the unrolled
+    form has not been measured again since."""
     if not classes:
         return out
-    if contrib is None:
-        def contrib(i, wt):
-            return jnp.take(table, i, axis=0) * wt[:, None]
-    f = sum(x.shape[-1] for x in jax.tree.leaves(out))
+
+    def contrib(i, wt):
+        return jnp.take(table, i, axis=0) * wt[:, None]
+
     parts = bucketed_slot_reduce(
         idx, w, classes, contrib=contrib,
-        init=lambda nv: jax.tree.map(
-            lambda x: jnp.zeros((nv, x.shape[-1]), x.dtype), out),
-        **fold_policy(f, scan_live_limit))
+        init=lambda nv: jnp.zeros((nv, out.shape[-1]), out.dtype),
+        **fold_policy(out.shape[-1], scan_live_limit))
     r0 = 0
     for (nv, _), part in zip(classes, parts):
         rows = row[r0: r0 + nv]
         with fold_rows_scope():
-            out = jax.tree.map(
-                lambda x, y, rows=rows: x.at[rows].add(
-                    y, indices_are_sorted=True), out, part)
+            out = out.at[rows].add(part, indices_are_sorted=True)
         r0 += nv
     return out
 

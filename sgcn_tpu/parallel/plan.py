@@ -76,10 +76,12 @@ PER_CHIP_ARRAY_FIELDS = (
 VROW_WIDTH = 32
 
 # the widths a fold store's virtual rows may take, and what one virtual row
-# costs beside one executed slot: the row's share of the sorted scatter-add
-# that folds the rows' sums into their destinations, in slots (measured at
-# 128 lanes f32 on the v5e: 7.8–8.1 ns a row of 0.65–2.26 M scattered into
-# 630,624, against 5.1–6.9 ns a scanned slot; PERF.md §6, PR 30, step 1)
+# costs beside one executed slot, in slots: the row's share of the sorted
+# scatter-add that folds the rows' sums into their destinations.  The value
+# was set when the fold stores were built (PERF.md §6) and has not been
+# measured again; the ledger's prices at 128 lanes f32 on the v5e read
+# nearer 3 slots a row (``fold_row_ns`` / ``fold_slot_ns``: 19.231 / 6.0476
+# ns on products.fullbatch, 15.407 / 5.3263 ns on products.fullbatch-gp4)
 FOLD_WIDTHS = (4, 8, 16, 32)
 FOLD_ROW_COST = 1.4
 
@@ -1363,7 +1365,8 @@ def resolve_comm_schedule(schedule: str | None, plans, model: str,
                           fin: int | None = None, widths=None,
                           compute_dtype: str | None = None,
                           replica_budget: int = 0,
-                          decision: dict | None = None) -> str:
+                          decision: dict | None = None,
+                          ragged: bool = True) -> str:
     """Resolve a ``comm_schedule`` knob to a concrete transport — THE one
     selection rule shared by both trainers (a second copy would drift).
 
@@ -1410,6 +1413,9 @@ def resolve_comm_schedule(schedule: str | None, plans, model: str,
     layouts on each plan as a side effect (both are lazy and idempotent;
     ``resolve_forward_setup`` would build them right after anyway).
 
+    ``ragged``: whether ``model`` has a ragged transport at all (its
+    ``MODELS`` record); one without resolves ``'auto'`` to ``'a2a'``.
+
     ``decision`` (optional dict, filled in place): the selection's inputs
     and the rule that fired — the trainers stash it and ``attach_recorder``
     logs it into the run manifest (``comm_schedule`` block), so an ``auto``
@@ -1435,7 +1441,7 @@ def resolve_comm_schedule(schedule: str | None, plans, model: str,
 
     if schedule != "auto":
         return resolved(schedule, "explicit")
-    if model not in ("gcn", "gat"):
+    if not ragged:
         return resolved("a2a", "model has no ragged transport")
     true = wire = wire_ragged = 0
     for p in plans:

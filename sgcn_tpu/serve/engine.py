@@ -152,9 +152,9 @@ class ServeEngine:
         if mode not in ("full", "subgraph"):
             raise ValueError(f"unknown serve mode {mode!r} "
                              "(know 'full', 'subgraph')")
-        from ..train.fullbatch import model_takes_args, resolve_forward_setup
+        from ..train.fullbatch import MODELS, resolve_forward_setup
 
-        if model_takes_args(model):
+        if MODELS[model].full_batch_only:
             raise ValueError(
                 f"model={model!r} is not served yet: the engine carries no "
                 "model_args (mhgat: heads, concat, slope) and its checkpoint "
@@ -176,7 +176,7 @@ class ServeEngine:
         self.halo_dtype = halo_dtype
         self.setup = resolve_forward_setup(
             plan, fin, widths, model=model, comm_schedule=comm_schedule,
-            serve_subgraph=(mode == "subgraph"))
+            serve_subgraph=(mode == "subgraph"), halo_dtype=halo_dtype)
         self.comm_schedule = self.setup.comm_schedule
         self.comm_decision = self.setup.decision
         # analytic per-chip HBM footprint (obs/memory.py) + the

@@ -406,7 +406,7 @@ def main() -> None:
     from ..parallel.plan import build_comm_plan
     from ..partition.emit import read_partvec, read_partvec_pickle
     from ..prep import normalize_adjacency
-    from .fullbatch import FullBatchTrainer, make_train_data
+    from .fullbatch import MODELS, FullBatchTrainer, make_train_data
     from .minibatch import MiniBatchTrainer
 
     feats = labels = None
@@ -449,9 +449,7 @@ def main() -> None:
 
     hidden = args.hidden or f
     widths = [hidden] * (args.nlayers - 1) + [nclasses]
-    # PGAT stacks bare modules: no inter-layer nonlinearity unless asked
-    activation = args.activation or {"gat": "none", "mhgat": "elu"}.get(
-        args.model, "relu")
+    activation = args.activation or MODELS[args.model].activation
     model_args = None
     if args.model == "mhgat":
         # hidden layers concatenate their heads, the last averages them
@@ -474,8 +472,7 @@ def main() -> None:
             "label_type": args.label_type}
         for t in model_args["types"]:
             t["count"] = int(t["count"])
-    if (args.model in ("mhgat", "deepergcn", "rgcn", "unimp")
-            and args.batch_size is not None):
+    if MODELS[args.model].full_batch_only and args.batch_size is not None:
         raise SystemExit(f"--model {args.model} is full-batch only; drop -n")
 
     prof = (jax.profiler.trace(args.profile) if args.profile

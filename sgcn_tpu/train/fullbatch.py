@@ -35,16 +35,9 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..models import deepergcn, mhgat, rgat, rgcn, unimp
+from ..models import deepergcn, gat, gcn, mhgat, rgat, rgcn, unimp
 from ..models import setup as model_setup
-from ..models.gat import GAT_PLAN_FIELDS, gat_forward_local, init_gat_params
 from ..models.gcn import (
-    exchange_widths,
-    gcn_aggregate_local,
-    gcn_forward_local,
-    gcn_plan_fields,
-    gcn_slot_passes,
-    init_gcn_params,
     masked_accuracy_local,
     masked_err_local,
     masked_sigmoid_bce_local,
@@ -57,63 +50,42 @@ from ..parallel.plan import CommPlan
 from ..utils.stats import CommStats
 from ..utils.timers import PhaseTimer
 
-# model registry: name → (param init, per-chip forward, plan→fields shipped
-# to the device). GAT is the reference's PGAT capability (GPU/PGAT.py) on the
-# same trainer scaffold — like the reference, only the nn.Module differs
-# between PGCN.py and PGAT.py. GCN ships the ELL fast-path arrays for
-# symmetric Â (split COO otherwise); GAT the combined edge list its
-# edge-softmax needs.
+# model registry: name -> models/setup.py::Model.  GAT is the reference's
+# PGAT capability (GPU/PGAT.py) on the same trainer scaffold — like the
+# reference, only the nn.Module differs between PGCN.py and PGAT.py; the
+# models after it are published configurations of their own, each with its
+# ``model_args``.  Each hook picks the plan arrays its forward reads.
+Model = model_setup.Model
 MODELS = {
-    # name -> (init, forward, plan->shipped array fields, plan->static kwargs
-    #          [, setup hook -> models/setup.py::ModelSetup: a model with a
-    #          configuration of its own (``model_args``); everything the
-    #          shared code would otherwise need the model's name for])
-    "gcn": (init_gcn_params, gcn_forward_local, gcn_plan_fields,
-            lambda plan: ({"ell_buckets": plan.ell_buckets}
-                          if plan.symmetric else {})),
-    "gat": (init_gat_params, gat_forward_local, lambda plan: GAT_PLAN_FIELDS,
-            # ensure_cell: the combined-edge layout is built lazily — only
-            # GAT ships it, and it duplicates the edge storage
-            lambda plan: {"cell_buckets": plan.ensure_cell().cell_buckets}),
+    "gcn": Model(gcn.init_gcn_params, gcn.gcn_forward_local, gcn.model_setup,
+                 ragged=True),
+    # PGAT stacks bare modules: no inter-layer nonlinearity unless asked
+    "gat": Model(gat.init_gat_params, gat.gat_forward_local, gat.model_setup,
+                 ragged=True, combined_edges=True, activation="none"),
     # multi-head attention as published (models/mhgat.py): the GCN's own
-    # plan arrays; its hyper-parameters arrive as ``model_args`` and are
-    # bound into init / forward statics through its hook
-    "mhgat": (mhgat.init_mhgat_params, mhgat.mhgat_forward_local,
-              lambda plan: mhgat.MHGAT_PLAN_FIELDS,
-              lambda plan: {"ell_buckets": plan.ell_buckets},
-              mhgat.model_setup),
+    # plan arrays
+    "mhgat": Model(mhgat.init_mhgat_params, mhgat.mhgat_forward_local,
+                   mhgat.model_setup, full_batch_only=True, activation="elu"),
     # the deep residual stack (models/deepergcn.py): the exact GCN step's
     # slot-form plan arrays with unit weights; one scanned, per-layer-
     # checkpointed body whatever the depth
-    "deepergcn": (deepergcn.init_deepergcn_params,
-                  deepergcn.deepergcn_forward_local,
-                  lambda plan: deepergcn.DEEPERGCN_PLAN_FIELDS,
-                  lambda plan: {"ell_buckets": plan.ell_buckets},
-                  deepergcn.model_setup),
+    "deepergcn": Model(deepergcn.init_deepergcn_params,
+                       deepergcn.deepergcn_forward_local,
+                       deepergcn.model_setup, full_batch_only=True),
     # typed rows and relations (models/rgcn.py): one slot layout per
     # relation, arrays its hook derives from the plan's edges; per-node
     # embeddings owned with the rows (``ModelSetup.row_owned``)
-    "rgcn": (rgcn.init_rgcn_params, rgcn.rgcn_forward_local,
-             lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
-             rgcn.model_setup),
+    "rgcn": Model(rgcn.init_rgcn_params, rgcn.rgcn_forward_local,
+                  rgcn.model_setup, full_batch_only=True),
     # attention inside the typed layouts (models/rgat.py): rgcn's layout per
     # relation, mhgat's slot bodies over it
-    "rgat": (rgat.init_rgat_params, rgat.rgat_forward_local,
-             lambda plan: rgcn.RGCN_PLAN_FIELDS, lambda plan: {},
-             rgat.model_setup),
+    "rgat": Model(rgat.init_rgat_params, rgat.rgat_forward_local,
+                  rgat.model_setup, full_batch_only=True),
     # dot-product attention with a gated residual and a label input
     # (models/unimp.py): mhgat's plan arrays and store machinery
-    "unimp": (unimp.init_unimp_params, unimp.unimp_forward_local,
-              lambda plan: unimp.UNIMP_PLAN_FIELDS,
-              lambda plan: {"ell_buckets": plan.ell_buckets},
-              unimp.model_setup),
+    "unimp": Model(unimp.init_unimp_params, unimp.unimp_forward_local,
+                   unimp.model_setup, full_batch_only=True),
 }
-
-
-def model_takes_args(model: str) -> bool:
-    """Whether ``model`` is configured by ``model_args`` (its registry entry
-    has a setup hook) — callers that carry none refuse such a model."""
-    return len(MODELS[model]) > 4
 
 
 # loss registry: 'xent' is the torch stack's log-softmax+NLL
@@ -136,39 +108,24 @@ class ForwardSetup:
     drift on exactly the branch parity depends on (Pallas auto-select,
     ragged field tuples, GAT table forms)."""
 
-    model: str
     comm_schedule: str            # resolved: 'a2a' or 'ragged', never 'auto'
     plan_fields: tuple            # CommPlan array fields the forward ships
     fwd_static: dict              # static kwargs of the forward fn
     forward_fn: object            # per-chip forward (MODELS registry)
     init_fn: object               # param init (MODELS registry)
     decision: dict                # resolve_comm_schedule's selection log
+    custom: "model_setup.ModelSetup"   # what the model's setup hook
+    #                                    resolved (models/setup.py)
     replica_budget: int = 0       # resolved: 'auto' -> the λ·degree knee B
-    # what the model's own setup hook resolved (models/setup.py), None for
-    # a model without one
-    custom: "model_setup.ModelSetup | None" = None
 
     def ship_arrays(self, plan) -> dict:
-        """The plan arrays the forward consumes, ready to shard — including
-        the GAT int8 edge-mask narrowing (attention ignores Â's values, and
-        the f32 forms are ~0.6 GB of per-chip arguments at products scale)."""
+        """The plan arrays the forward consumes, ready to shard: the fields
+        the hook names narrowed to int8 masks, and the arrays it derived
+        from the plan."""
         arrays = _plan_arrays(plan, self.plan_fields)
-        if self.model == "gat":
-            # mask on w != 0: plan padding carries weight exactly 0 by
-            # construction, so every real edge survives even for a signed/
-            # unnormalized weighted graph (ADVICE r4 — `> 0` dropped
-            # negative-weight edges).  The Pallas field set's plan-time 0/1
-            # mask tiles (ptile_cw) narrow the same way — gat_pallas_pass
-            # upcasts in-program, exactly like the slot passes.
-            for f in ("cell_w", "ctail_w", "ptile_cw"):
-                if f in arrays:
-                    arrays[f] = (arrays[f] != 0).astype(np.int8)
-        if self.custom is not None:
-            # the same narrowing for the fields the hook names, and the
-            # arrays it derived from the plan
-            for f in self.custom.mask_fields:
-                arrays[f] = (arrays[f] != 0).astype(np.int8)
-            arrays.update(self.custom.extra_arrays)
+        for f in self.custom.mask_fields:
+            arrays[f] = (arrays[f] != 0).astype(np.int8)
+        arrays.update(self.custom.extra_arrays)
         return arrays
 
 
@@ -182,51 +139,33 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
                           serve_subgraph: bool = False,
                           allow_pallas: bool = True,
                           model_args: dict | None = None,
-                          shared_envelope: bool = False
-                          ) -> ForwardSetup:
+                          shared_envelope: bool = False,
+                          halo_dtype: str | None = None,
+                          halo_delta: bool = False,
+                          remat: bool = False) -> ForwardSetup:
     """Resolve (schedule, shipped plan fields, static forward kwargs) for one
-    plan — the selection logic that used to live inline in
-    ``FullBatchTrainer.__init__``, factored out so the forward-only serve
-    engine rides the identical rules.  Builds the lazy plan layouts the
+    plan: the transport (``parallel/plan.py::resolve_comm_schedule``), then
+    the model's setup hook (``MODELS[model].hook``, ``models/setup.py``),
+    which validates ``model_args``, refuses what the model has no form for
+    and picks the arrays and statics of its forward.  Shared by both
+    trainers and the serve engine.  Builds the lazy plan layouts the
     selection needs (``ensure_ragged``, ``ensure_cell``,
-    ``ensure_pallas_tiles``, ``ensure_replicas``) as side effects, exactly
-    as the trainer did.  ``replica_budget`` is a TRAINING-only lever (the
-    trainer gates it; serving always runs the exact forward and never
-    passes it): it swaps the shipped fields for the replica union tuples —
-    ``fwd_static`` stays the EXACT forward's statics, because evaluation
-    and serving ride ``gcn_forward_local`` on the same (superset) plan
-    arrays, with jit pruning the ``nrep_*`` half.  ``allow_pallas=False``
-    keeps the selection on the slot-pass/ELL aggregators regardless of the
-    VMEM-fit rule — the mini-batch trainer's ONE compiled step must serve
-    every per-batch plan, and the Pallas tile layout (per-class Emax_c
-    statics, tiles built per plan) has no shared-envelope form.
-    ``shared_envelope=True`` says the same of every layout DERIVED from one
-    plan's edges: the program is compiled once for many plans padded to one
-    envelope (the mini-batch trainer again), so the forward keeps the COO
-    hub tail and halo-edge lists, which have an envelope (``tl``, ``eh``).
-    Without it the exact GCN setup — symmetric Â, a2a schedule, no carried
-    halo, the full forward — ships both stores in slot form instead
-    (``CommPlan.ensure_fold_slots``, ``ops.pspmm.pspmm_ell_sym``): chosen
-    here from what the setup observes, never by an option.
-    ``model_args`` is the configuration of a model that has one (``mhgat``:
-    heads per layer, concat or mean, slope, skip, bias): validated by the
-    registry entry's setup hook and bound into the init function and the
-    forward's statics, so every caller reads one resolved form of it."""
+    ``ensure_pallas_tiles``, ``ensure_replicas``, ``ensure_fold_slots``) as
+    side effects.  ``replica_budget`` is a TRAINING-only lever (serving
+    never passes it).  ``allow_pallas=False`` keeps the selection on the
+    slot-pass/ELL aggregators whatever the VMEM-fit rule says, and
+    ``shared_envelope=True`` says the program is compiled once for many
+    plans padded to one envelope, so nothing derived from one plan's edges
+    may shape it: the mini-batch trainer's ONE compiled step passes both."""
     from ..parallel.plan import choose_replica_budget, resolve_comm_schedule
 
+    entry = MODELS[model]
     decision: dict = {}
-    init_fn, forward_fn, fields_fn, static_fn, *hook = MODELS[model]
-    if model_args and not hook:
-        raise ValueError(f"model {model!r} takes no model_args "
-                         f"(got {sorted(model_args)})")
     if replica_budget == "auto":
         # --replica-budget auto: the λ·degree-knee rule, resolved BEFORE
         # the schedule selection so the auto transport scores the wire at
         # the chosen shrink; the knee log lands in the manifest's
         # comm_schedule block (docs/replication.md)
-        if model != "gcn":
-            raise ValueError("replica_budget='auto' is a GCN lever "
-                             "(replication is GCN-only)")
         knee: dict = {}
         replica_budget = choose_replica_budget(plan, decision=knee)
         decision["replica_auto"] = knee
@@ -234,7 +173,7 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
     comm_schedule = resolve_comm_schedule(
         comm_schedule, [plan], model, halo_staleness,
         fin=fin, widths=list(widths), compute_dtype=compute_dtype,
-        replica_budget=replica_budget if model == "gcn" else 0,
+        replica_budget=replica_budget, ragged=entry.ragged,
         decision=decision)
     if comm_schedule == "ragged":
         if not plan.symmetric:
@@ -243,145 +182,19 @@ def resolve_forward_setup(plan: "CommPlan", fin: int, widths,
                 "backward (the gradient rides the same ppermute ring); "
                 "this plan is asymmetric — run the a2a schedule")
         plan.ensure_ragged()
-    plan_fields = fields_fn(plan)
-    fwd_static = static_fn(plan)
-    custom = None
-    if hook:
-        custom = hook[0](plan, fin, widths, model_args,
-                         comm_schedule=comm_schedule,
-                         compute_dtype=compute_dtype,
-                         serve_subgraph=serve_subgraph)
-        fwd_static = dict(fwd_static, **custom.fwd_static)
-        init_fn = functools.partial(init_fn, **custom.init_static)
-        allow_pallas = allow_pallas and custom.allow_pallas
-    if model == "gcn" and comm_schedule == "ragged":
-        # the ragged ELL aggregation path (fold-as-you-arrive scatter over
-        # the per-owner edge split); the Pallas selection below may swap
-        # it for the schedule-agnostic VMEM kernel family.  The composed
-        # (stale × ragged) step ships the same ring arrays under its own
-        # contract tuple.
-        from ..models.gcn import GCN_PLAN_FIELDS_RAGGED
-        from ..parallel.plan import STALE_PLAN_FIELDS_RAGGED
-        plan_fields = (STALE_PLAN_FIELDS_RAGGED if halo_staleness
-                       else GCN_PLAN_FIELDS_RAGGED)
-        fwd_static = {"ell_buckets": plan.ell_buckets,
-                      "comm_schedule": "ragged",
-                      "rr_sizes": plan.rr_sizes,
-                      "rr_edge_sizes": plan.rr_edge_sizes}
-    if model == "gcn" and replica_budget:
-        # hot-halo replication (docs/replication.md): the shipped fields
-        # are the UNION of the full exchange layout (the sync/refresh
-        # program = the exact program + replica gathers; evaluate() rides
-        # it) and the shrunken no-replica layout; fwd_static stays the
-        # exact forward's statics — the replica-only statics
-        # (nrep_rr_sizes, halo table height) live on the trainer.  The
-        # composed (replica × stale) step ships its own contract tuples:
-        # the stale carry subsumes the replica tables, so no rep/grep
-        # arrays ride along, and the ragged flavor adds the carry scatter
-        # map ``nrep_ring_dst``.  ``refresh_band`` adds the partial-
-        # refresh side channel (ronly buckets + baselines routing).
-        from ..parallel.plan import (REPLICA_PARTIAL_PLAN_FIELDS,
-                                     REPLICA_PLAN_FIELDS,
-                                     REPLICA_PLAN_FIELDS_RAGGED,
-                                     REPLICA_STALE_PLAN_FIELDS,
-                                     REPLICA_STALE_PLAN_FIELDS_RAGGED)
-        plan.ensure_replicas(replica_budget)
-        if halo_staleness:
-            plan_fields = (REPLICA_STALE_PLAN_FIELDS_RAGGED
-                           if comm_schedule == "ragged"
-                           else REPLICA_STALE_PLAN_FIELDS)
-        elif refresh_band is not None:
-            plan_fields = REPLICA_PARTIAL_PLAN_FIELDS
-        else:
-            plan_fields = (REPLICA_PLAN_FIELDS_RAGGED
-                           if comm_schedule == "ragged"
-                           else REPLICA_PLAN_FIELDS)
-    if model == "gat" and comm_schedule == "ragged":
-        # the attention tables ride the plan's model-independent
-        # per-vertex ring layout (rsend_idx/rhalo_dst); the combined
-        # bucketed slot passes are schedule-blind, so only the shipped
-        # exchange arrays and the static ring spec change
-        from ..models.gat import GAT_PLAN_FIELDS_RAGGED
-        plan_fields = GAT_PLAN_FIELDS_RAGGED
-        fwd_static = dict(fwd_static,
-                          comm_schedule="ragged",
-                          rr_sizes=plan.rr_sizes,
-                          halo_r=plan.r)
-    if not halo_staleness and not replica_budget and allow_pallas:
-        # plan-driven kernel choice (schedule- and model-agnostic since
-        # ISSUE 15): per-chip tables in the VMEM regime
-        # switch the aggregator to the Pallas kernel family, on
-        # EITHER transport and for BOTH models, with the kernel picked
-        # per degree-binned tile class (choose_pallas_dispatch — hub
-        # classes may stay on the XLA gather form while the dense
-        # low-degree mass rides VMEM; the per-bucket decision lands in
-        # the manifest decision log).  The stale mode stays on the ELL
-        # aggregator: pspmm_stale's carry contract is built around it,
-        # and hiding the exchange removes the latency the VMEM kernel
-        # would have overlapped; the replica mode likewise — its
-        # halo-table assembly and carry contract are built around the
-        # ELL + hedge fold; the mini-batch trainer passes
-        # allow_pallas=False (one compiled step, many per-batch plans —
-        # see the docstring).
-        from ..ops.pallas_spmm import (PALLAS_PLAN_FIELDS,
-                                       PALLAS_PLAN_FIELDS_RAGGED,
-                                       choose_pallas_dispatch,
-                                       use_pallas_spmm)
-        if use_pallas_spmm(plan, fin, widths, model=model,
-                           compute_dtype=compute_dtype,
-                           schedule=comm_schedule):
-            if serve_subgraph:
-                # the sub-graph serve engine's compact mirror reproduces
-                # the ELL fold's per-row chains (serve/subgraph.py); the
-                # Pallas tile fold has a different per-row addition
-                # sequence, so bit-parity would silently break — refuse
-                # here, in the ONE selection-rule home, rather than in
-                # the engine
-                raise ValueError(
-                    "sub-graph serving reproduces the ELL fold; this plan "
-                    "resolved to the Pallas VMEM aggregator — serve with "
-                    "mode='full' or set SGCN_PALLAS_SPMM=0")
-            pallas_static = choose_pallas_dispatch(
-                plan, model=model, schedule=comm_schedule,
-                decision=decision)
-            if model == "gat":
-                from ..models.gat import (GAT_PLAN_FIELDS_PALLAS,
-                                          GAT_PLAN_FIELDS_PALLAS_RAGGED)
-                plan_fields = (GAT_PLAN_FIELDS_PALLAS_RAGGED
-                               if comm_schedule == "ragged"
-                               else GAT_PLAN_FIELDS_PALLAS)
-                fwd_static = dict(
-                    cell_buckets=plan.cell_buckets, **pallas_static)
-            else:
-                plan_fields = (PALLAS_PLAN_FIELDS_RAGGED
-                               if comm_schedule == "ragged"
-                               else PALLAS_PLAN_FIELDS)
-                fwd_static = dict(pallas_static)
-            if comm_schedule == "ragged":
-                # both models thread the same static ring spec (the ring
-                # concat needs only rr_sizes — no redge fold, no halo_r)
-                fwd_static.update(comm_schedule="ragged",
-                                  rr_sizes=plan.rr_sizes)
-    if (model == "gcn" and plan.symmetric and comm_schedule == "a2a"
-            and not halo_staleness and not replica_budget
-            and not serve_subgraph and not shared_envelope
-            and "pallas_tb" not in fwd_static):
-        # the exact step folds the hub tail and the halo-source edges as
-        # slot passes over virtual rows, and ships that form INSTEAD of the
-        # COO lists (a fold by scatter-add costs two slots an edge: PERF.md
-        # §6, PR 30).  The carried-halo families fold inside their own ops,
-        # sub-graph serving mirrors the COO chains row by row, and a shared
-        # envelope has no virtual-row count: they keep the lists.
-        from ..models.gcn import GCN_PLAN_FIELDS_SLOTS
-        plan.ensure_fold_slots()
-        plan_fields = GCN_PLAN_FIELDS_SLOTS
-        fwd_static = dict(fwd_static, fold_classes=(plan.fold_tail_classes,
-                                                    plan.fold_halo_classes))
-    return ForwardSetup(model=model, comm_schedule=comm_schedule,
-                        plan_fields=plan_fields, fwd_static=fwd_static,
-                        forward_fn=forward_fn, init_fn=init_fn,
-                        decision=decision, replica_budget=replica_budget,
-                        custom=custom)
+    custom = entry.hook(plan, fin, widths, model_args, model_setup.Request(
+        comm_schedule=comm_schedule, compute_dtype=compute_dtype,
+        halo_dtype=halo_dtype, halo_staleness=halo_staleness,
+        halo_delta=halo_delta, replica_budget=replica_budget,
+        refresh_band=refresh_band, remat=remat,
+        serve_subgraph=serve_subgraph, allow_pallas=allow_pallas,
+        shared_envelope=shared_envelope, decision=decision))
+    return ForwardSetup(
+        comm_schedule=comm_schedule,
+        plan_fields=custom.plan_fields, fwd_static=custom.fwd_static,
+        forward_fn=entry.forward,
+        init_fn=functools.partial(entry.init, **custom.init_static),
+        decision=decision, replica_budget=replica_budget, custom=custom)
 
 
 @dataclass
@@ -493,34 +306,23 @@ def _global_grad_norm(grads):
 class FullBatchTrainer:
     """Distributed full-batch trainer (PGCN-equivalent, ``-b jax`` backend).
 
-    **Layer 0's aggregation is paid once per data set, not per step**, on
-    the exact GCN path (``model='gcn'``, ``halo_staleness=0``,
-    ``replica_budget=0``) wherever layer 0 is aggregate-first
-    (``exchange_widths(fin, widths)[0] == fin``): full-batch ``h0`` and Â
-    never change, so ``Â·h0`` is loop-invariant.  ``agg0_hoisted`` says
-    whether that holds for this trainer.  Where it does, the first
-    ``step`` / ``run_epochs`` / ``evaluate`` / ``predict`` that sees a
-    given ``data.h0`` (by identity, not value — do not mutate it in place)
-    runs the aggregation alone (``_agg0_for``: the forward's own aggregator,
-    statics and mesh; compiled, run once and dropped, host span
-    ``agg0.build``), keeps the ``(k, B, fin)`` f32 result on the device and
-    passes it where ``data.h0`` went, to step / epoch-loop / eval /
-    telemetry programs traced with ``input_aggregated=True`` — one
-    exchange, one slot pass and one fold fewer per step, same arithmetic in
-    the same order.  The caller's ``h0`` is never touched.  Counter
-    ``agg0`` (``obs.tracing.counters``) reports engaged / builds /
-    steps_served.  Not covered, and lowering exactly as before: the stale
-    and replica step families (their layer-0 exchange feeds a carried
-    table with contracts of its own), GAT (its aggregation depends on the
-    parameters), project-first layer 0, ``ServeEngine``, and
-    ``MiniBatchTrainer``, which turns ``agg0_hoisted`` off on its inner
-    trainer before any program is traced (its plan changes per batch).
-
-    A model whose setup hook brings a ``Hoist`` (``models/setup.py``;
-    ``rgcn``: layer 0's means over the features) gets the same treatment
-    for its own loop-invariant work: built per ``h0`` object by
-    ``_agg0_for`` in the hoist's span and counted in its counter, passed
-    to every exact program beside ``h0``."""
+    **Loop-invariant work over data is paid once per data set, not per
+    step.**  A model whose setup hook brings a ``Hoist`` (``models/setup.py``;
+    ``self.hoist``) — ``gcn``'s ``Â·h0`` on the exact path with an
+    aggregate-first layer 0, ``rgcn``'s layer-0 means over the features —
+    has it made by ``_agg0_for`` the first time a ``step`` / ``run_epochs`` /
+    ``evaluate`` / ``predict`` sees a given ``data.h0`` (by identity, not
+    value — do not mutate it in place): the hoist's build under the step's
+    ``shard_map``, compiled, run once and dropped, in the hoist's span
+    (``agg0.build`` for ``gcn``).  The result stays on the device and goes
+    to every exact program beside ``data.h0`` or, for ``gcn``, in its
+    place — one exchange, one slot pass and one fold fewer per step, same
+    arithmetic in the same order.  The caller's ``h0`` is never touched.
+    Counter ``agg0`` (``obs.tracing.counters``) reports engaged / builds /
+    steps_served for ``gcn``'s and says ``engaged: false`` otherwise; a
+    model's own hoist reports in its own counter.  A caller that feeds the
+    programs data of its own sets ``hoist`` to ``None`` before any of them
+    is traced (``MiniBatchTrainer.evaluate_fullgraph``)."""
 
     def __init__(
         self,
@@ -634,11 +436,6 @@ class FullBatchTrainer:
         exposure.  GCN + symmetric Â + f32 non-remat only; composition
         with ``halo_staleness=1`` is deferred with a clean error;
         evaluation always runs the exact forward."""
-        if halo_dtype is not None and model != "gcn":
-            raise ValueError(
-                "halo_dtype is a GCN-trainer lever; for GAT use "
-                "compute_dtype='bfloat16' (the packed exchange already "
-                "ships half-width rows)")
         if halo_staleness not in (0, 1):
             raise ValueError(
                 f"halo_staleness must be 0 (exact) or 1 (pipelined), got "
@@ -660,11 +457,6 @@ class FullBatchTrainer:
                 f"replica_budget must be >= 0 or 'auto', got "
                 f"{replica_budget}")
         if replica_budget:
-            if model != "gcn":
-                raise ValueError(
-                    "replica_budget replicates rows of the GCN feature "
-                    "exchange; the GAT exchange ships per-layer attention "
-                    "tables whose replication is not supported")
             if halo_delta:
                 raise ValueError(
                     "replica_budget composed with halo_delta is deferred: "
@@ -699,11 +491,6 @@ class FullBatchTrainer:
                     "halo carry, which partial refresh cannot address per "
                     "row — run full refreshes there (docs/replication.md)")
         if halo_staleness:
-            if model != "gcn":
-                raise ValueError(
-                    "halo_staleness=1 pipelines the GCN hot path; the GAT "
-                    "exchange ships per-layer attention tables whose "
-                    "staleness is not supported (models/gat.py)")
             if not plan.symmetric:
                 raise ValueError(
                     "halo_staleness=1 uses the symmetric-Â custom backward "
@@ -721,14 +508,15 @@ class FullBatchTrainer:
         # longer forfeits the Pallas VMEM aggregator); an explicit 'ragged'
         # is a contract, validated loudly inside the resolver.  Composition
         # with halo_staleness=1 is SUPPORTED (the round-structured carry of
-        # pspmm_stale_ragged); the staleness gates above (GCN, symmetric,
-        # f32 non-remat) already cover the genuinely unsupported combos.
+        # pspmm_stale_ragged); the gates above (symmetric, f32 non-remat)
+        # and the model's hook (what it has no form for) cover the rest.
         setup = resolve_forward_setup(
             plan, fin, widths, model=model, comm_schedule=comm_schedule,
             compute_dtype=compute_dtype, halo_staleness=halo_staleness,
             replica_budget=replica_budget, refresh_band=refresh_band,
             allow_pallas=allow_pallas, model_args=model_args,
-            shared_envelope=shared_envelope)
+            shared_envelope=shared_envelope, halo_dtype=halo_dtype,
+            halo_delta=halo_delta, remat=remat)
         self.comm_decision = setup.decision   # selection → run manifest
         comm_schedule = setup.comm_schedule
         replica_budget = setup.replica_budget   # 'auto' -> the knee B
@@ -797,74 +585,44 @@ class FullBatchTrainer:
         init_fn, self._forward_fn = setup.init_fn, setup.forward_fn
         self.plan_fields = setup.plan_fields
         self._fwd_static = setup.fwd_static  # e.g. the ELL bucket structure
-        if model == "gat":
-            # pre-flight the measured single-chip capacity edge: a clear
-            # error beats a compile OOM or a dead TPU worker — BOTH were
-            # observed at products scale (models/gat.py::check_gat_memory;
-            # static_fn above already ran ensure_cell, so tail size is known)
-            from ..models.gat import check_gat_memory
-            check_gat_memory(
-                self.mesh.local_devices[0],
-                plan.b, int(plan.halo_counts.max()), fin, widths,
-                nnz=int(plan.nnz.max()),
-                tail=int(plan.ctail_nnz.max()) if plan.ctail_nnz is not None
-                else 0,
-                dtype=compute_dtype)
-        self.model_memory = None
-        if remat and setup.custom is not None and setup.custom.checkpointed:
+        custom = setup.custom
+        if custom.preflight is not None:
+            custom.preflight(self.mesh.local_devices[0])
+        if remat and custom.checkpointed:
             raise ValueError(
                 f"model {model!r} checkpoints each of its layers itself; "
                 "remat=True (one checkpoint around the whole forward) has "
                 "nothing left to free — drop it")
-        if setup.custom is not None:
-            # the model's own estimate, from its per-row and per-table
-            # arrays; the old fence above is the factorised layer's and is
-            # not consulted
-            self.model_memory = setup.custom.estimate_memory(train=True)
+        # the model's own estimate, from its per-row and per-table arrays,
+        # fenced against the device's capacity
+        self.model_memory = None
+        if custom.estimate_memory is not None:
+            self.model_memory = custom.estimate_memory(train=True)
             model_setup.check_memory(self.mesh.local_devices[0],
                                      self.model_memory)
-            for name, value in setup.custom.counters.items():
-                set_counter(name, value)
+        for name, value in custom.counters.items():
+            set_counter(name, value)
         self.model = model
         # what this trainer's programs fold the hub tail and the halo-source
         # edges as — beside ``agg0``, for a reader of counters()
         fold_slots = "fold_classes" in setup.fwd_static
         if fold_slots or "ltail_src" in setup.plan_fields:
             set_counter("fold", plan.fold_counts(slots=fold_slots))
-        # layer 0's Â·h0 is loop-invariant on the exact GCN path with an
-        # aggregate-first layer 0 (class docstring): decided here from what
-        # the trainer can observe, read by the programs when they are traced
-        self.agg0_hoisted = (model == "gcn" and not halo_staleness
-                             and not replica_budget
-                             and exchange_widths(fin, widths)[0] == fin)
-        # the slot passes this trainer's step runs, by bucket and form (the
-        # hooks of the models with one leave theirs among their counters);
-        # a program with no such list leaves no stale one
-        if setup.custom is None:
-            set_counter("slots.work", model_setup.slot_work(gcn_slot_passes(
-                plan, fin, widths, setup.fwd_static["fold_classes"],
-                hoisted=self.agg0_hoisted, remat=remat))
-                if fold_slots and model == "gcn" else None)
-        self._agg0 = None           # (k, B, fin) f32 Â·h0, on the device
+        # loop-invariant work over data the model makes outside its step
+        # (models/setup.py::Hoist; _agg0_for builds it)
+        self.hoist = custom.hoist
+        self._agg0 = None           # what the hoist built, on the device
         self._agg0_src = None       # weakref to the data.h0 it was made from
         self._agg0_builds = self._agg0_served = 0
         self.loss_name = loss
         self._loss_fn = LOSSES[loss]
         dims = list(zip([fin] + widths[:-1], widths))
-        # what the model's hook says of its parameters and its output rows
-        # (models/setup.py): leaves owned with the rows, rows the forward
-        # returns — empty / None for every model without such a thing
-        self._row_owned = (setup.custom.row_owned
-                           if setup.custom is not None else {})
-        self._out_rows = (setup.custom.out_rows
-                          if setup.custom is not None else None)
-        # rows whose label the forward reads (models/setup.py): None for
-        # every model without a label input
-        self._label_input = (setup.custom.label_input
-                             if setup.custom is not None else None)
-        # loop-invariant work over data the model makes outside its step
-        # (models/setup.py::Hoist; _agg0_for builds it)
-        self._hoist = setup.custom.hoist if setup.custom is not None else None
+        # what the model's hook says of its parameters, its output rows and
+        # its label input (models/setup.py): leaves owned with the rows,
+        # rows the forward returns, rows whose label it reads
+        self._row_owned = custom.row_owned
+        self._out_rows = custom.out_rows
+        self._label_input = custom.label_input
         self.params = init_fn(jax.random.PRNGKey(seed), dims)
         self.opt = optimizer if optimizer is not None else optax.adam(lr)
         self.opt_state = self._init_opt_state(self.params)
@@ -872,40 +630,19 @@ class FullBatchTrainer:
         self.opt_state = self._place(self.opt_state)
         self.last_err = None
         self.pa = shard_stacked(self.mesh, setup.ship_arrays(plan))
-        # per-exchange wire lane widths (f32-lane equivalents) — the real
-        # table widths each model ships, so the CommStats byte gauges
-        # (halo_bytes_true/halo_bytes_wire) reconcile EXACTLY with the obs
-        # roofline's attribution (docs/observability.md): GCN ships feature
-        # rows at the project-first widths, GAT its attention tables (fused
-        # fout+1 / packed fout/2+1 / split pair)
-        lane_widths_bwd = ()                    # the forward's
-        if model == "gat":
-            from ..models.gat import gat_exchange_lane_widths
-            lane_widths = tuple(gat_exchange_lane_widths(
-                self.widths, compute_dtype))
-            wire_itemsize = wire_itemsize_bwd = 4   # lanes encode the dtype
-        elif setup.custom is not None:
-            # the hook's own tables, which may differ in width by direction
-            # (mhgat: [Z ‖ t] forward, [g ‖ s, m, 1/D, c] backward):
-            # CommStats books each direction at its own lanes
-            lane_widths = setup.custom.lane_widths
-            lane_widths_bwd = setup.custom.lane_widths_bwd
-            wire_itemsize = wire_itemsize_bwd = 4
-        else:
-            lane_widths = tuple(exchange_widths(fin, self.widths))
-            # per-DIRECTION wire itemsize (docs/observability.md): the
-            # halo-delta cache narrows only the FEATURE wire (and only on
-            # stale steps — count_step takes a per-step override for the
-            # f32 re-base syncs); the gradient wire follows --halo-dtype
-            wire_itemsize = 2 if (halo_dtype == "bfloat16" or halo_delta
-                                  or compute_dtype == "bfloat16") else 4
-            wire_itemsize_bwd = 2 if (halo_dtype == "bfloat16"
-                                      or compute_dtype == "bfloat16") else 4
-        self.stats = CommStats.from_plan(plan, schedule=comm_schedule,
-                                         lane_widths=lane_widths,
-                                         lane_widths_bwd=lane_widths_bwd,
-                                         wire_itemsize=wire_itemsize,
-                                         wire_itemsize_bwd=wire_itemsize_bwd)
+        # per-exchange wire lane widths (f32-lane equivalents) and itemsizes
+        # per direction — the real tables each model ships (GCN feature rows
+        # at the project-first widths, GAT its attention tables, mhgat [Z ‖
+        # t] forward and [g ‖ s, m, 1/D, c] backward), so the CommStats byte
+        # gauges (halo_bytes_true/halo_bytes_wire) reconcile EXACTLY with
+        # the obs roofline's attribution (docs/observability.md); count_step
+        # takes a per-step override for the halo-delta cache's f32 re-base
+        # syncs
+        self.stats = CommStats.from_plan(
+            plan, schedule=comm_schedule, lane_widths=custom.lane_widths,
+            lane_widths_bwd=custom.lane_widths_bwd,
+            wire_itemsize=custom.wire_itemsize[0],
+            wire_itemsize_bwd=custom.wire_itemsize[1])
         if replica_budget:
             # the shrunken no-replica exchange's per-rank/wire figures —
             # count_step(replica=True) books replica steps at these, so
@@ -1120,64 +857,56 @@ class FullBatchTrainer:
         params = jax.tree.map(lambda w: w.astype(dt), params)
         pa = {k: v.astype(dt) if v.dtype == jnp.float32 else v
               for k, v in pa.items()}
-        return params, pa, h0.astype(dt)
-
-    def _agg_statics(self) -> dict:
-        """The forward's aggregator statics (kernel, transport, wire)."""
-        extra = ({"halo_dtype": self.halo_dtype}
-                 if self.halo_dtype is not None else {})
-        return dict(symmetric=self.plan.symmetric, **self._fwd_static,
-                    **extra)
+        return params, pa, jax.tree.map(lambda x: x.astype(dt), h0)
 
     def _forward(self, params, pa, h0, label_ids=None):
-        """Per-chip logits.  On an ``agg0_hoisted`` trainer ``h0`` is
-        ``_agg0_for(data.h0)`` and layer 0 starts at its dense product; with
-        a model's ``Hoist`` it is ``(h0, what the hoist built)``; with its
-        label input ``label_ids`` are ``_label_feed``'s."""
-        hoisted = ({"input_aggregated": True} if self.agg0_hoisted else {})
-        if self._hoist is not None:
-            h0, hoisted["hoisted"] = h0
-        if label_ids is not None:
-            hoisted["label_ids"] = label_ids
+        """Per-chip logits.  With a ``Hoist`` ``h0`` is what ``_agg0_for``
+        gave: ``(h0, what the hoist built)``, or the latter alone where it
+        replaces ``h0``; with a label input ``label_ids`` are
+        ``_label_feed``'s."""
+        extra = {} if label_ids is None else {"label_ids": label_ids}
+        if self.halo_dtype is not None:     # the wire's (gcn)
+            extra["halo_dtype"] = self.halo_dtype
         params, pa, h0 = self._cast(params, pa, h0)
+        if self.hoist is not None:
+            h0, extra["hoisted"] = ((None, (h0,)) if self.hoist.replaces
+                                    else h0)
         out = self._forward_fn(
             params, h0, pa,
             activation=self.activation,
             final_activation=self.final_activation,
-            **self._agg_statics(),
-            **hoisted,
+            symmetric=self.plan.symmetric,
+            **self._fwd_static,
+            **extra,
         )
         return out.astype("float32")
 
-    # ------------------------------------------------- hoisted layer-0 Â·h0
+    # ------------------------------------------- hoisted loop-invariant work
     def _agg0_for(self, h0, served: int = 1):
         """What the exact programs take where ``data.h0`` went: ``h0``
-        itself, on an ``agg0_hoisted`` trainer ``Â·h0``, or with a model's
-        ``Hoist`` ``(h0, what its build gave)`` — built the first time this
+        itself, or with a ``Hoist`` ``(h0, what its build gave)`` — the
+        latter alone where it replaces ``h0`` — built the first time this
         ``h0`` object is seen.  ``served`` counts the steps / forwards the
-        caller is about to run on it (counter ``agg0``, or the hoist's).
+        caller is about to run on it (the hoist's counter).
 
-        The build is the forward's own aggregator under the forward's
-        ``shard_map`` (a hoist's: its ``build``), compiled ahead of time,
-        called once and dropped: no ``jit`` cache keeps the executable, so
-        its temporaries do not stay reserved beside the step program's
-        (PERF.md §2), and building before the step is first dispatched
-        means the two are never loaded together."""
-        hoist = self._hoist
-        engaged = self.agg0_hoisted or hoist is not None
-        if engaged and (self._agg0_src is None
-                        or self._agg0_src() is not h0):
-            # the old array goes before the new one comes
+        The build runs under the forward's ``shard_map``, compiled ahead of
+        time, called once and dropped: no ``jit`` cache keeps the
+        executable, so its temporaries do not stay reserved beside the step
+        program's (PERF.md §2), and building before the step is first
+        dispatched means the two are never loaded together."""
+        hoist = self.hoist
+        set_counter("agg0", {
+            "engaged": False, "builds": 0, "steps_served": 0,
+            "rows": int(self.plan.b * self.plan.k), "width": int(self.fin)})
+        if hoist is None:
+            return h0
+        if self._agg0_src is None or self._agg0_src() is not h0:
+            # the old arrays go before the new ones come
             self._agg0 = self._agg0_src = None
-            with self.spans.span("agg0.build" if hoist is None else hoist.span,
-                                 sync=lambda: self._agg0):
+            with self.spans.span(hoist.span, sync=lambda: self._agg0):
                 def per_chip(pa, h0):
-                    pa, h0 = _unblock((pa, h0))
-                    if hoist is not None:
-                        return _reblock(hoist.build(pa, h0))
-                    _, pa, h0 = self._cast((), pa, h0)
-                    out = gcn_aggregate_local(h0, pa, **self._agg_statics())
-                    return out.astype("float32")[None]
+                    _, pa, h0 = self._cast((), *_unblock((pa, h0)))
+                    return _reblock(hoist.build(pa, h0))
 
                 build = jax.jit(jax.shard_map(
                     per_chip, mesh=self.mesh, in_specs=(P(AXIS), P(AXIS)),
@@ -1186,18 +915,10 @@ class FullBatchTrainer:
                 del build
             self._agg0_src = weakref.ref(h0)
             self._agg0_builds += 1
-        if engaged:
-            self._agg0_served += served
-        gcn = self.agg0_hoisted
-        set_counter("agg0", {
-            "engaged": gcn, "builds": self._agg0_builds if gcn else 0,
-            "steps_served": self._agg0_served if gcn else 0,
-            "rows": int(self.plan.b * self.plan.k), "width": int(self.fin)})
-        if hoist is not None:
-            set_counter(hoist.counter, hoist.report(self._agg0_builds,
-                                                    self._agg0_served))
-            return h0, self._agg0
-        return self._agg0 if gcn else h0
+        self._agg0_served += served
+        set_counter(hoist.counter, hoist.report(self._agg0_builds,
+                                                self._agg0_served))
+        return self._agg0[0] if hoist.replaces else (h0, self._agg0)
 
     def _one_step(self, params, opt_state, pa, h0, labels, valid,
                   telemetry: bool = False):
@@ -1762,10 +1483,9 @@ class FullBatchTrainer:
         host does not have); ``None`` uses the trainer's own mesh.  Inputs
         are ShapeDtypeStructs shaped like this trainer's live arrays, so
         the lowered module is exactly the program ``step()`` runs, just
-        targeted at the given topology — on an ``agg0_hoisted`` trainer the
-        step that takes ``Â·h0`` (same shape and dtype as ``h0``) and holds
-        no layer-0 aggregation; with a model's ``Hoist``, the step that takes
-        ``h0`` and the hoisted arrays.
+        targeted at the given topology — with a ``Hoist``, the step that
+        takes what it builds beside ``h0`` or in its place (``gcn``: ``Â·h0``,
+        and no layer-0 aggregation).
 
         ``kind`` selects which of the trainer's step programs to lower:
         ``'step'`` the exact-mode step; ``'stale'`` / ``'sync'`` the
@@ -1823,10 +1543,11 @@ class FullBatchTrainer:
             lambda x: sds(x, rep))
         pa = jax.tree.map(lambda x: sds(x, shd), self.pa)
         h0 = jax.ShapeDtypeStruct((k, b, fin), np.float32, sharding=shd)
-        if self._hoist is not None:
-            h0 = (h0, tuple(jax.ShapeDtypeStruct((k, *shape), np.float32,
-                                                 sharding=shd)
-                            for shape in self._hoist.shapes))
+        if self.hoist is not None:
+            built = tuple(jax.ShapeDtypeStruct((k, *shape), np.float32,
+                                               sharding=shd)
+                          for shape in self.hoist.shapes)
+            h0 = built[0] if self.hoist.replaces else (h0, built)
         labels = jax.ShapeDtypeStruct((k, b), np.int32, sharding=shd)
         valid = jax.ShapeDtypeStruct((k, b), np.float32, sharding=shd)
         if kind != "step":
@@ -2027,20 +1748,21 @@ class FullBatchTrainer:
         key = bool(sync_step)
         if key not in self._cost_cache:
             from ..obs.attribution import step_cost
+            # (halo_dtype and halo_delta are gcn's: every other hook
+            # refuses them)
             wire = None
-            if self.model == "gcn":
-                if self.halo_delta and sync_step:
-                    # the re-base wire ships the FULL f32 row regardless of
-                    # --halo-dtype (ops/pspmm.py fresh-delta path) — must
-                    # match count_step's wire_itemsize=4 override exactly
-                    fwd = 4
-                elif self.halo_dtype == "bfloat16" or self.halo_delta:
-                    fwd = 2
-                else:
-                    fwd = None
-                bwd = 2 if self.halo_dtype == "bfloat16" else None
-                if fwd is not None or bwd is not None:
-                    wire = (fwd, bwd)
+            if self.halo_delta and sync_step:
+                # the re-base wire ships the FULL f32 row regardless of
+                # --halo-dtype (ops/pspmm.py fresh-delta path) — must
+                # match count_step's wire_itemsize=4 override exactly
+                fwd = 4
+            elif self.halo_dtype == "bfloat16" or self.halo_delta:
+                fwd = 2
+            else:
+                fwd = None
+            bwd = 2 if self.halo_dtype == "bfloat16" else None
+            if fwd is not None or bwd is not None:
+                wire = (fwd, bwd)
             self._cost_cache[key] = step_cost(
                 self.plan, self.fin, self.widths,
                 compute_dtype=self.compute_dtype,

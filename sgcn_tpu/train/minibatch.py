@@ -31,8 +31,8 @@ import scipy.sparse as sp
 from ..parallel.mesh import make_mesh_1d, shard_stacked
 from ..parallel.plan import build_comm_plan, pad_comm_plan, shared_ell_buckets
 from ..utils.stats import CommStats
-from .fullbatch import (FullBatchTrainer, TrainData, _plan_arrays,
-                        _unblock, make_train_data, model_takes_args)
+from .fullbatch import (MODELS, FullBatchTrainer, TrainData, _plan_arrays,
+                        _unblock, make_train_data)
 
 
 def sample_batches(n: int, batch_size: int, nbatches: int | None = None,
@@ -88,7 +88,7 @@ class MiniBatchTrainer:
         replica_budget: int = 0,
         memory_budget: int | None = None,
     ):
-        if model_takes_args(model):
+        if MODELS[model].full_batch_only:
             raise ValueError(
                 f"model={model!r} is a full-batch model here: its "
                 "model_args and per-row backward state have no mini-batch "
@@ -124,7 +124,7 @@ class MiniBatchTrainer:
                     for f in ("b", "s", "r", "e", "el", "eh", "tl"))
         shared = shared_ell_buckets(raw, env[0])
         self.plans = [pad_comm_plan(p, *env, ell_buckets=shared) for p in raw]
-        if model == "gat":
+        if MODELS[model].combined_edges:
             # the combined-edge (GAT) layout is lazy; build it ONCE per plan
             # with a shared bucket structure AND a shared tail length (the
             # spill is derivable from degree profiles without materializing)
@@ -156,7 +156,8 @@ class MiniBatchTrainer:
         self.comm_decision: dict = {}   # selection inputs → run manifest
         comm_schedule = resolve_comm_schedule(
             comm_schedule, self.plans, model, fin=fin, widths=list(widths),
-            compute_dtype=compute_dtype, decision=self.comm_decision)
+            compute_dtype=compute_dtype, decision=self.comm_decision,
+            ragged=MODELS[model].ragged)
         if comm_schedule == "ragged":
             # EVERY plan needs the layout (the fused sweep stacks the ragged
             # arrays across batches), padded to the shared round envelope;
@@ -180,7 +181,9 @@ class MiniBatchTrainer:
         # the shared envelope stays on the slot-pass/ELL aggregators.
         # shared_envelope=True, for the same reason: the hub tail and the
         # halo-source edges stay COO lists (padded to tl / eh); their slot
-        # form's virtual-row counts differ from plan to plan
+        # form's virtual-row counts differ from plan to plan, and every
+        # batch brings its own Â and h0, so nothing is hoisted out of the
+        # step (models/setup.py::Hoist)
         self.inner = FullBatchTrainer(
             self.plans[0], fin, widths, mesh=self.mesh, lr=lr,
             activation=activation, model=model, loss=loss,
@@ -188,10 +191,6 @@ class MiniBatchTrainer:
             compute_dtype=compute_dtype, comm_schedule=comm_schedule,
             allow_pallas=False, memory_budget=memory_budget,
             shared_envelope=True)
-        # every batch brings its own Â and h0, so Â·h0 is not loop-invariant
-        # here: the inner trainer's programs keep layer 0's aggregation
-        # (switched off before any of them is traced)
-        self.inner.agg0_hoisted = False
         # the inner trainer's plan IS the shared envelope every batch pads
         # to, so its analytic footprint (obs/memory.py) covers every batch's
         # step — the --memory-budget gate above already held it to account
@@ -482,12 +481,13 @@ class MiniBatchTrainer:
         if self._fullgraph_eval is None:
             plan = build_comm_plan(self.a, self.partvec, self.k)
             self._fullgraph_eval = (plan, FullBatchTrainer(
-                plan, features.shape[1], self._widths_from_params(),
+                plan, features.shape[1], self.inner.widths,
                 mesh=self.mesh, activation=self.inner.activation,
                 model=self.inner.model, loss=self.inner.loss_name,
                 compute_dtype=self.inner.compute_dtype))
-            # new data every call: nothing to hoist (cf. __init__)
-            self._fullgraph_eval[1].agg0_hoisted = False
+            # new data every call, fed to _eval directly: nothing to
+            # hoist (switched off before any program is traced)
+            self._fullgraph_eval[1].hoist = None
         plan, tr = self._fullgraph_eval
         tr.params = self.inner.params
         data = make_train_data(plan, features, labels,
@@ -497,8 +497,3 @@ class MiniBatchTrainer:
         loss, acc, _ = tr._eval(tr.params, tr.pa, data.h0, data.labels,
                                 data.eval_valid)
         return float(loss), float(acc)
-
-    def _widths_from_params(self) -> list[int]:
-        if self.inner.model == "gcn":
-            return [int(w.shape[1]) for w in self.inner.params]
-        return [int(p["w"].shape[1]) for p in self.inner.params]

@@ -144,10 +144,7 @@ def _norm(path: str) -> str:
 
 def model_config_of(trainer) -> dict | None:
     """The checkpoint's model-identity block, read off a trainer's attrs
-    (best-effort: a trainer that predates an attribute simply omits it).
-    ``gat_fused`` records the table-form lever (``$SGCN_GAT_FUSED``) the
-    params were trained under — the fused/split/packed forms share one param
-    tree, so it is provenance, not a load-blocking field."""
+    (best-effort: a trainer that predates an attribute simply omits it)."""
     cfg = {}
     for attr, key in (("model", "model"), ("fin", "fin"),
                       ("widths", "widths"), ("activation", "activation"),
@@ -156,9 +153,6 @@ def model_config_of(trainer) -> dict | None:
         v = getattr(trainer, attr, None)
         if v is not None:
             cfg[key] = list(v) if key == "widths" else v
-    if cfg.get("model") == "gat":
-        import os
-        cfg["gat_fused"] = os.environ.get("SGCN_GAT_FUSED", "1")
     return cfg or None
 
 

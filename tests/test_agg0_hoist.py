@@ -1,14 +1,14 @@
 """Layer 0's ``Â·h0`` is made once per data set, not every step (PR 26).
 
 On the exact full-batch GCN path with an aggregate-first layer 0 the trainer
-hoists the loop-invariant aggregation out of the step
-(``FullBatchTrainer.agg0_hoisted``, ``_agg0_for``;
-``gcn_forward_local(input_aggregated=True)``):
+hoists the loop-invariant aggregation out of the step (``gcn``'s
+``models/setup.py::Hoist``, ``FullBatchTrainer.hoist`` and ``_agg0_for``;
+``gcn_forward_local(hoisted=...)``):
 
   * (a) same arithmetic: losses, trained weights and ``predict()`` logits are
     bit-identical to the same trainer with the mechanism switched off before
-    any program is traced (what ``MiniBatchTrainer`` does to its inner
-    trainer; a test-local reference, not a user option);
+    any program is traced (what ``MiniBatchTrainer.evaluate_fullgraph`` does
+    to its trainer; a test-local reference, not a user option);
   * (b) ``step``, ``run_epochs``, ``evaluate`` and ``predict`` agree with
     each other as they always did;
   * (c) the lowered step holds 2·L − 2 exchanges (2·L − 1 before), the build
@@ -60,7 +60,7 @@ def _trainer(plan, hoist=True, fin=FIN, widths=WIDTHS, **kw):
     tr = FullBatchTrainer(plan, fin=fin, widths=list(widths),
                           mesh=make_mesh_1d(plan.k), seed=3, **kw)
     if not hoist:       # the reference: before any program is traced
-        tr.agg0_hoisted = False
+        tr.hoist = None
     return tr
 
 
@@ -130,7 +130,7 @@ def test_step_run_epochs_evaluate_predict_agree(plans, k):
 def test_exact_step_ships_one_exchange_fewer_and_counts_builds(ahat, plans):
     L = len(WIDTHS)
     tr, ref = _trainer(plans[4]), _trainer(plans[4], hoist=False)
-    assert tr.agg0_hoisted and exchange_widths(FIN, WIDTHS)[0] == FIN
+    assert tr.hoist is not None and exchange_widths(FIN, WIDTHS)[0] == FIN
     assert _a2a(ref.lower_step()) == 2 * L - 1      # the parent's step
     assert _a2a(tr.lower_step()) == 2 * L - 2
     assert "agg0" not in tracing.counters()
@@ -158,15 +158,15 @@ def test_exact_step_ships_one_exchange_fewer_and_counts_builds(ahat, plans):
     np.testing.assert_array_equal(
         plans[4].gather_rows(np.asarray(again.h0)), feats)
     np.testing.assert_allclose(
-        plans[4].gather_rows(np.asarray(tr._agg0)),
+        plans[4].gather_rows(np.asarray(tr._agg0[0])),
         np.asarray(ahat @ feats), rtol=2e-5, atol=2e-6)
 
 
 def test_the_forward_refuses_a_hoisted_project_first_layer():
     w = [np.zeros((300, 8), np.float32), np.zeros((8, 4), np.float32)]
     with pytest.raises(ValueError, match="aggregate-first layer 0"):
-        gcn_forward_local(w, np.zeros((5, 300), np.float32), {},
-                          input_aggregated=True)
+        gcn_forward_local(w, None, {},
+                          hoisted=(np.zeros((5, 300), np.float32),))
 
 
 # --------------------------------------------------------------- (d) bypass
@@ -205,7 +205,7 @@ BYPASS = {"project-first": 4,    # layer 0's backward exchange feeds dW
 def test_bypassed_paths_lower_what_they_lowered(case, ahat, plans):
     tr, other, lowered = _bypass(case, ahat, plans[4])
     assert _a2a(lowered) == BYPASS[case]
-    assert tr is None or not tr.agg0_hoisted
+    assert tr is None or tr.hoist is None
     if case in ("project-first", "stale", "replica"):
         data = _data(tr, *_inputs(fin=tr.fin))
         assert np.all(np.isfinite([tr.step(data) for _ in range(2)]))

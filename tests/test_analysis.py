@@ -28,8 +28,9 @@ import importlib
 import pytest
 
 from sgcn_tpu.analysis import hlo
-from sgcn_tpu.analysis.ast_rules import (rule_consumer_registered,
+from sgcn_tpu.analysis.ast_rules import (RULES, rule_consumer_registered,
                                          rule_mode_flag_enumerated,
+                                         rule_model_name_free,
                                          rule_sanctioned_sync_only,
                                          rule_traced_host_free,
                                          run_ast_pass)
@@ -158,7 +159,7 @@ def test_empty_rounds_elided_in_census(full_report):
     program must carry collective_permutes for EXACTLY the live rounds.
     Exact mode: 2 exchanges (layer 1 forward + backward — aggregate-first
     layer 0's backward exchange is dead code, and since PR 26 its forward
-    exchange is hoisted out of the step: ``agg0_hoisted``; 3 exchanges × 2
+    exchange is hoisted out of the step: ``gcn``'s ``Hoist``; 3 exchanges × 2
     before) × 2 live rounds; stale mode: 4 exchanges × 2."""
     from sgcn_tpu.ops.pspmm import ragged_live_rounds
 
@@ -468,6 +469,23 @@ def test_mutation_ast_unenumerated_mode_flag():
     assert any("--halo-compression" in x for x in v)
     # a trainer CLI missing an enumerated axis is the reverse drift
     assert any("dead matrix axis" in x for x in v)
+
+
+def test_mutation_ast_model_name_compared():
+    src = ('def f(model, setup):\n'
+           '    if model == "gat":\n        return 1\n'
+           '    return 2 if model not in ("gcn", "rgcn") else 3\n')
+    v = rule_model_name_free("sgcn_tpu/train/custom.py", src)
+    assert len(v) == 2 and "'gat'" in v[0] and "'gcn'" in v[1]
+    # a flag's value or a name that is no model's passes; the rule's scope
+    # leaves the CLI's input validation and the models themselves alone
+    assert not rule_model_name_free(
+        "sgcn_tpu/train/custom.py", 'def f(s):\n    return s == "auto"\n')
+    rule = next(r for r in RULES if r.name == "model-name-free")
+    assert rule.applies("sgcn_tpu/parallel/plan.py")
+    assert rule.applies("sgcn_tpu/obs/memory.py")
+    assert not rule.applies("sgcn_tpu/train/__main__.py")
+    assert not rule.applies("sgcn_tpu/models/gcn.py")
 
 
 def test_ast_pass_clean_at_head():

@@ -216,7 +216,7 @@ def test_analysis_cli_fast_smoke():
     assert rep["hlo"]["n_modes"] == 2 and rep["hlo"]["ok"] is True
     assert set(rep["ast"]["rules"]) == {
         "traced-host-free", "sanctioned-sync-only", "consumer-registered",
-        "mode-flag-enumerated"}
+        "mode-flag-enumerated", "model-name-free"}
     assert all(e["ok"] for e in rep["ast"]["rules"].values())
 
 

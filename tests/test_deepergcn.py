@@ -345,7 +345,7 @@ def test_lowered_step_holds_one_aggregating_body_whatever_the_depth(
         # the forward again where the checkpoint keeps the input alone)
         assert text.count("stablehlo.all_to_all") == exchanges, layers
         sizes[layers] = len(text)
-        assert tr.nlayers == layers and not tr.agg0_hoisted
+        assert tr.nlayers == layers and tr.hoist is None
     assert sizes[14] < 1.05 * sizes[3], sizes
     # the wire is booked at each direction's own lanes, per layer
     rows = int(tr.stats.send_volume_per_exchange.sum())

@@ -36,7 +36,7 @@ def _lowered_a2a_widths(fin, widths):
     dims = [int(m.group(1)) for m in re.finditer(
         r'stablehlo\.all_to_all.*?->\s*tensor<\d+x\d+x(\d+)xf32>', txt)]
     assert dims, "no all_to_all in lowered step"
-    return sorted(set(dims)), tr.agg0_hoisted
+    return sorted(set(dims)), tr.hoist is not None
 
 
 @pytest.mark.parametrize("fin,widths", [

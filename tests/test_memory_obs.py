@@ -35,8 +35,7 @@ from sgcn_tpu.analysis.hlo_audit import (AUDIT_FIN, AUDIT_WIDTHS, audit_plan,
 from sgcn_tpu.analysis.modes import Mode
 from sgcn_tpu.obs.memory import (MEM_MODEL_TOL, MemoryBudgetError,
                                  MemoryModel, check_memory_budget,
-                                 memory_model, model_param_bytes, parse_bytes,
-                                 reconcile)
+                                 memory_model, parse_bytes, reconcile)
 
 # one mode per array family the analytic model itemizes — the calibration
 # set MEM_MODEL_TOL was derived on (worst observed peak/total ratio: the
@@ -204,23 +203,33 @@ def test_parse_bytes():
 
 # ------------------------------------------------------- model vs real init
 def test_param_bytes_pin_real_init():
-    """``model_param_bytes`` prices exactly what the init functions
+    """The hooks' ``param_count`` prices exactly what the init functions
     allocate — the params line of the budget table cannot drift from the
     real weight trees."""
     import jax
 
     from sgcn_tpu.models.gat import init_gat_params
     from sgcn_tpu.models.gcn import init_gcn_params
+    from sgcn_tpu.train.fullbatch import resolve_forward_setup
 
     dims = list(zip([AUDIT_FIN] + list(AUDIT_WIDTHS)[:-1],
                     list(AUDIT_WIDTHS)))
     rng = jax.random.PRNGKey(0)
+
+    def priced(model):
+        setup = resolve_forward_setup(audit_plan(), AUDIT_FIN, AUDIT_WIDTHS,
+                                      model=model)
+        assert memory_model(audit_plan(), AUDIT_FIN, AUDIT_WIDTHS,
+                            model=model, setup=setup).families["params"] \
+            == 4 * setup.custom.param_count
+        return 4 * setup.custom.param_count
+
     gcn = sum(int(np.prod(w.shape)) * 4 for w in init_gcn_params(rng, dims))
-    assert model_param_bytes(AUDIT_FIN, AUDIT_WIDTHS, model="gcn") == gcn
+    assert priced("gcn") == gcn
     gat = sum(int(np.prod(leaf.shape)) * 4
               for layer in init_gat_params(rng, dims)
               for leaf in layer.values())
-    assert model_param_bytes(AUDIT_FIN, AUDIT_WIDTHS, model="gat") == gat
+    assert priced("gat") == gat
 
 
 # ------------------------------------------------- gauge reconciliation

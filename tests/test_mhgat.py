@@ -249,7 +249,7 @@ def test_the_hoist_is_off_and_each_layer_and_pass_has_one_exchange(plans,
                                                                    inputs):
     run = _run(plans, inputs, 4)
     tr = run["trainer"]
-    assert not tr.agg0_hoisted          # layer 0 projects first; W0 moves
+    assert tr.hoist is None             # layer 0 projects first; W0 moves
     text = tr.lower_step().as_text()
     # forward [Z ‖ t] and backward [g ‖ s, m, 1/D, c] of every layer: the
     # backward of layer 0 too, since ∂L/∂W0 needs ∂L/∂Z0

@@ -330,7 +330,7 @@ def test_nothing_recognises_padding_by_its_value():
     for pl in (plan, other):
         tr = FullBatchTrainer(pl, fin=x.shape[1], widths=[16, 7], seed=5,
                               mesh=make_mesh_1d(4))
-        assert tr.agg0_hoisted
+        assert tr.hoist is not None
         data = make_train_data(pl, x, y)
         losses.append([tr.step(data) for _ in range(2)])
     assert losses[0] == losses[1] and np.isfinite(losses[0]).all()

@@ -311,13 +311,11 @@ def test_modes_the_model_has_no_form_for_are_refused(plans, kw, match):
 
 
 def test_minibatch_serving_and_pallas_refuse_the_model(plans):
-    from sgcn_tpu.train.fullbatch import (model_takes_args,
-                                          resolve_forward_setup)
+    from sgcn_tpu.train.fullbatch import MODELS, resolve_forward_setup
 
-    assert model_takes_args("rgat")
+    assert MODELS["rgat"].full_batch_only
     setup = resolve_forward_setup(plans[1], FIN, WIDTHS, model="rgat",
                                   model_args=MODEL)
-    assert not setup.custom.allow_pallas
     assert "pallas_tb" not in setup.fwd_static
     with pytest.raises(ValueError, match="full forward only"):
         resolve_forward_setup(plans[1], FIN, WIDTHS, model="rgat",

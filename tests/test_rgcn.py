@@ -455,13 +455,11 @@ def test_modes_the_model_has_no_form_for_are_refused(plans, kw, match):
 
 
 def test_minibatch_serving_and_pallas_refuse_the_model(plans):
-    from sgcn_tpu.train.fullbatch import (model_takes_args,
-                                          resolve_forward_setup)
+    from sgcn_tpu.train.fullbatch import MODELS, resolve_forward_setup
 
-    assert model_takes_args("rgcn")
+    assert MODELS["rgcn"].full_batch_only
     setup = resolve_forward_setup(plans[1], FIN, WIDTHS, model="rgcn",
                                   model_args=MODEL)
-    assert not setup.custom.allow_pallas
     assert "pallas_tb" not in setup.fwd_static
     with pytest.raises(ValueError, match="full forward only"):
         resolve_forward_setup(plans[1], FIN, WIDTHS, model="rgcn",
@@ -796,7 +794,7 @@ def test_the_step_walks_no_layout_of_a_hoisted_relation(plans, inputs, k):
 def test_an_all_embedded_configuration_hoists_nothing(plans, k):
     embedded = dict(MODEL, types=[dict(t, input="embedding") for t in TYPES])
     tr = _trainer(plans[k], model_args=embedded)
-    assert tr._hoist is None
+    assert tr.hoist is None
     assert not any(s.hoisted for s in tr._fwd_static["specs"])
     assert not tracing.counters()["rel.work"]["hoisted"]["relations"]
     text = tr.lower_step().as_text()
@@ -808,7 +806,7 @@ def test_an_all_embedded_configuration_hoists_nothing(plans, k):
 def test_without_the_hoist_the_step_lowers_as_before(plans, k, monkeypatch):
     _unhoisted(monkeypatch)
     tr = _trainer(plans[k])
-    assert tr._hoist is None
+    assert tr.hoist is None
     assert hashlib.sha256(tr.lower_step().as_text().encode()).hexdigest() \
         == UNHOISTED_STEP_SHA["features", k]
 
@@ -856,13 +854,13 @@ def test_one_build_serves_steps_and_predict_and_a_new_h0_rebuilds(
     old = [weakref.ref(m) for m in made]
     del made
     seen = []
-    build = tr._hoist.build
+    build = tr.hoist.build
 
     def watched(pa, h):
         seen.append(tr._agg0)
         return build(pa, h)
 
-    tr._hoist = dataclasses.replace(tr._hoist, build=watched)
+    tr.hoist = dataclasses.replace(tr.hoist, build=watched)
     again = _data(tr, inputs)
     tr.step(again)
     tr.step(again)
@@ -885,7 +883,7 @@ def test_the_hoisted_step_equals_the_unhoisted(plans, inputs, k,
         if not hoist:
             _unhoisted(monkeypatch)
         tr = _trainer(plans[k], optimizer=optax.sgd(RATE))
-        assert (tr._hoist is not None) is hoist
+        assert (tr.hoist is not None) is hoist
         data = _data(tr, inputs)
         logits = tr.predict(data)
         losses = [tr.step(data), tr.step(data)]

@@ -110,7 +110,7 @@ def test_v5e_compiled_step_scopes_every_gather_scatter_and_exchange(
                and _leaf(op) == "agg_tail" for _, op in hot)
     # layer 0's aggregation is hoisted out of the step (PR 26): the step
     # keeps its dense product and none of its gathers, folds or exchanges
-    assert tr.agg0_hoisted
+    assert tr.hoist is not None
     assert not [op for _, op in hot if "sgcn.layer0" in op]
     assert any("sgcn.layer0" in op and _leaf(op) == "dense"
                for _, op in OP.findall(text))

@@ -231,8 +231,8 @@ def _three(plan):
 def test_the_counters_sums_are_the_older_counters(plans, model, hoist, k):
     tr, work = _trainer(plans, model, k)
     if hoist is False and model == "gcn":
-        # what MiniBatchTrainer does to its inner trainer, before a trace
-        tr.agg0_hoisted = False
+        # the hoist switched off before a trace (evaluate_fullgraph's)
+        tr.hoist = None
         work = model_setup.slot_work(gcn_slot_passes(
             tr.plan, FIN, MODELS[model]["widths"],
             tr._fwd_static["fold_classes"], hoisted=False))
@@ -242,7 +242,7 @@ def test_the_counters_sums_are_the_older_counters(plans, model, hoist, k):
     ell, fold = _three(tr.plan)
     slots = per["ell_slots"] + per["fold_slots"]
     if model.startswith("gcn"):
-        assert tr.agg0_hoisted is bool(hoist)
+        assert (tr.hoist is not None) is bool(hoist)
         # 2·L passes, less layer 0's forward where hoisted and its backward
         # where it aggregates first (its input is data)
         npass = {"gcn": 2 if hoist else 3, "gcn-project-first": 4}[model]

@@ -448,13 +448,12 @@ def test_modes_the_layer_has_no_form_for_are_refused(plans, kw, match):
 
 def test_minibatch_serving_pallas_and_asymmetry_refuse_the_model(plans):
     from sgcn_tpu.train.minibatch import MiniBatchTrainer
-    from sgcn_tpu.train.fullbatch import (model_takes_args,
-                                          resolve_forward_setup)
+    from sgcn_tpu.train.fullbatch import MODELS, resolve_forward_setup
 
-    assert model_takes_args("unimp")
+    assert MODELS["unimp"].full_batch_only
     setup = resolve_forward_setup(plans[1], FIN, WIDTHS, model="unimp",
                                   model_args=MODEL)
-    assert not setup.custom.allow_pallas
+    assert "pallas_tb" not in setup.fwd_static
     assert setup.custom.label_input == unimp.LABEL_INPUT
     with pytest.raises(ValueError, match="full forward only"):
         resolve_forward_setup(plans[1], FIN, WIDTHS, model="unimp",
